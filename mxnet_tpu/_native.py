@@ -88,8 +88,17 @@ def get_lib():
                         raise
             lib = ctypes.CDLL(_LIB_PATH)
         except Exception as e:
-            logging.info("native io unavailable (%s); using the "
-                         "pure-Python reader", e)
+            # mxnet_tpu/lib/ is git-ignored: a fresh checkout always
+            # builds from src/, so a failure here is a broken toolchain
+            # or broken sources, and the pure-Python reader it degrades
+            # to is several times slower — say so, with the compiler's
+            # own words
+            logging.warning(
+                "native io unavailable (%s); using the pure-Python "
+                "reader%s", e,
+                "\n" + e.stderr[-2000:]
+                if isinstance(e, subprocess.CalledProcessError)
+                and e.stderr else "")
             return None
         lib.mxtpu_reader_open.restype = ctypes.c_void_p
         lib.mxtpu_reader_open.argtypes = [ctypes.c_char_p]

@@ -31,18 +31,12 @@ def _wrapped_params(func_def):
 
 
 def _literal_argnums(node, assigns, depth=0):
-    """Int positions out of a donate_argnums expression, following the
-    ``safe_donate_argnums((...))`` guard wrapper (any single-positional-
-    arg call) and one local ``donate = ...`` assignment hop.  The guard
-    only ever SHRINKS the tuple at runtime, so the literal inside it is
-    the donation set this pass must check against."""
+    """Int positions out of a donate_argnums expression, following one
+    local ``donate = ...`` assignment hop."""
     if depth > 3:
         return []
     if isinstance(node, ast.Name) and assigns and node.id in assigns:
         return _literal_argnums(assigns[node.id], assigns, depth + 1)
-    if (isinstance(node, ast.Call) and len(node.args) == 1
-            and not node.keywords):
-        return _literal_argnums(node.args[0], assigns, depth + 1)
     elts = node.elts if isinstance(node, (ast.Tuple, ast.List)) else [node]
     return [e.value for e in elts
             if isinstance(e, ast.Constant) and isinstance(e.value, int)]

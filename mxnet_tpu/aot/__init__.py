@@ -2,12 +2,12 @@
 
 Two layers, composable:
 
-* **Persistent program cache** — ``MXNET_COMPILE_CACHE_DIR`` makes
-  every compiled executable (executor fwd/fwd_bwd, fused fit step,
-  kvstore programs, Pallas kernels) survive process restarts on disk;
-  a restarted process disk-loads instead of recompiling
-  (``aot_cache_hits`` counts the loads).  Auto-enabled at import when
-  the knob is set.
+* **Persistent program cache** — every compiled executable (executor
+  fwd/fwd_bwd, fused fit step, kvstore programs, Pallas kernels)
+  survives process restarts on disk; a restarted process disk-loads
+  instead of recompiling (``aot_cache_hits`` counts the loads).  On
+  from import, in ``JAX_COMPILATION_CACHE_DIR`` where that is set and
+  in ``<checkout>/.jax_cache`` where it is not.
 
 * **Warmup manifests** — ``capture()`` in a warmed process dumps every
   program signature; ``warm(manifest, server=..., engine=...)`` in a
@@ -21,7 +21,7 @@ Typical deploy::
 
     # warmed pod, once:
     mx.aot.save(mx.aot.capture(), "model.aot.json")
-    # every restart (MXNET_COMPILE_CACHE_DIR shared):
+    # every restart (JAX_COMPILATION_CACHE_DIR shared):
     server = serving.ModelServer(sym, params, ...,
                                  warmup_manifest="model.aot.json")
 """
@@ -89,5 +89,5 @@ def stats():
     }
 
 
-# deploys opt in with the env knob alone — no code change needed
+# on from import; JAX_COMPILATION_CACHE_DIR alone places it
 enable_persistent_cache()

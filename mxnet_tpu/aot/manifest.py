@@ -5,8 +5,8 @@ process dispatched — site, fn_name, full argument signature (treedef +
 per-leaf dtype/shape), donation mask — plus a compatibility header
 (jax version, backend, device kind, mesh fingerprint, cache dir).
 ``mx.aot.capture()`` dumps it from a warmed process;
-``mx.aot.warm(manifest)`` in a FRESH process AOT-compiles (or, with
-``MXNET_COMPILE_CACHE_DIR`` set, disk-loads) every entry before the
+``mx.aot.warm(manifest)`` in a FRESH process AOT-compiles (or, from
+the persistent compile cache, disk-loads) every entry before the
 process accepts traffic, so the first request/step launches with
 ``coldstart_compiles == 0``.
 

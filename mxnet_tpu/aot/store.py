@@ -4,7 +4,12 @@ Wraps jax's persistent compilation cache
 (``jax.experimental.compilation_cache``) so every RetraceSite dispatch
 — executor fwd/fwd_bwd, the fused fit step, the bucketed kvstore
 programs, and the Pallas kernels they embed — serializes its compiled
-executable to ``MXNET_COMPILE_CACHE_DIR``.  A restarted process pays
+executable to disk.  The directory is placed from OUTSIDE: where
+``JAX_COMPILATION_CACHE_DIR`` is set the cache lives there (jax reads
+that variable itself, and this package sets no other directory);
+where it is not, the cache goes to one fixed path inside the checkout
+(``.jax_cache/`` beside the package — the path is part of jax's cache
+key, so a directory that moves never hits).  A restarted process pays
 trace + disk-load instead of trace + XLA compile for every program it
 has compiled before (``jit_compile_ms`` collapses to trace time; the
 ``aot_cache_hits`` counter is the witness).
@@ -53,6 +58,11 @@ AOT_INDEX_ERRORS = _telemetry.REGISTRY.counter(
 _lock = threading.Lock()
 _STATE = {"dir": None, "listener": False}
 
+# the one fixed default: <checkout>/.jax_cache (git-ignored)
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 
 def _jax_version():
     import jax
@@ -62,34 +72,6 @@ def _jax_version():
 def cache_dir():
     """The active persistent-cache directory (None = disabled)."""
     return _STATE["dir"]
-
-
-def donation_safe():
-    """False while the persistent cache is enabled: buffer donation and
-    disk-loaded executables must not mix.
-
-    jax 0.4.37's DESERIALIZED executables mishandle input/output
-    aliasing — a donated program served from a persistent-cache entry
-    corrupts its buffers (wrong results, NaN, or a crash, typically
-    from the second chained step) on both the CPU and TPU backends.
-    Reproducible in pure jax with no framework code involved.  Freshly
-    compiled donated programs are correct, and NON-donated programs
-    disk-load correctly, so the framework-level guard is: while the
-    cache is active, program builders drop donation
-    (``safe_donate_argnums``).  Donation changes the program's aliasing
-    and therefore its cache key, so donated and non-donated variants
-    can never collide in the cache — a guarded process neither writes
-    donated entries nor loads one written by an unguarded process.
-    """
-    return _STATE["dir"] is None
-
-
-def safe_donate_argnums(argnums):
-    """``donate_argnums`` for program builders: the requested positions
-    when donation is safe, ``()`` while the persistent cache is active
-    (see ``donation_safe``).  Builders run lazily at first use, after
-    the import-time env enable, so the decision is current."""
-    return tuple(argnums) if donation_safe() else ()
 
 
 def _on_event(event, **kw):
@@ -104,12 +86,9 @@ def _on_event(event, **kw):
 def _install_listener():
     if _STATE["listener"]:
         return
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_event)
-        _STATE["listener"] = True
-    except Exception as e:                     # pragma: no cover
-        log.warning("aot: cache hit/miss telemetry unavailable: %s", e)
+    import jax
+    jax.monitoring.register_event_listener(_on_event)
+    _STATE["listener"] = True
 
 
 def _index_path(d):
@@ -186,39 +165,33 @@ def index_update(entries, mesh_fingerprint=None, d=None):
 
 
 def enable(path=None):
-    """Turn on the persistent compilation cache.  ``path`` overrides
-    the ``MXNET_COMPILE_CACHE_DIR`` knob; with neither set this is a
-    no-op returning None (how the package import auto-enables).  Safe
-    to call repeatedly; every process that should share the cache
-    applies these exact settings so the cache keys agree."""
-    d = path or os.environ.get("MXNET_COMPILE_CACHE_DIR")
-    if not d:
-        return None
-    d = os.path.abspath(d)
-    os.makedirs(d, exist_ok=True)
+    """Turn on the persistent compilation cache — the package import
+    does, so it is on unless :func:`disable` was called.  The directory
+    is ``path``, else ``JAX_COMPILATION_CACHE_DIR``, else
+    :data:`DEFAULT_DIR`.  Safe to call repeatedly; every process that
+    should share the cache applies these exact settings so the cache
+    keys agree.  A directory that cannot be created leaves the cache
+    off with a warning (a read-only checkout must still import)."""
     import jax
-    jax.config.update("jax_compilation_cache_dir", d)
+    d = path or os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_DIR
+    try:
+        os.makedirs(d, exist_ok=True)
+    except OSError as e:
+        log.warning("aot: compile cache off, cannot create %s (%s)", d, e)
+        return None
+    if jax.config.jax_compilation_cache_dir != d:
+        # never reached with only JAX_COMPILATION_CACHE_DIR set: jax
+        # took the directory from the environment itself
+        jax.config.update("jax_compilation_cache_dir", d)
     # cache every program: the default min-compile-time/entry-size
     # gates would skip exactly the small steady-state programs whose
     # compile storms make cold starts slow
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _install_listener()
+    d = os.path.abspath(d)
     with _lock:
         _STATE["dir"] = d
-    # programs jitted before this point kept their donation (safe: they
-    # compile in-process, and their aliasing gives them distinct cache
-    # keys) — but a process that builds donated programs BEFORE
-    # enabling and runs again with the same dir could disk-load them,
-    # which jax 0.4.37 corrupts (see donation_safe).  Warn so deploys
-    # enable the cache first (the env-var path always does).
-    if getattr(_telemetry.programs, "_donated", None):
-        log.warning(
-            "aot: %d donated program(s) were built before the "
-            "persistent cache was enabled; enable the cache before "
-            "constructing modules/engines (MXNET_COMPILE_CACHE_DIR "
-            "does this at import) so donation is dropped from cached "
-            "programs", len(_telemetry.programs._donated))
     # validate (and heal) the index up front so a corrupt file is
     # reported at enable time, not mid-deploy
     idx = load_index(d)

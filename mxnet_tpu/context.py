@@ -82,8 +82,7 @@ def _accelerators():
     # local_devices: in a multi-process (jax.distributed) world a Context
     # must name a device THIS process owns; identical to jax.devices()
     # when single-process
-    devs = [d for d in jax.local_devices() if d.platform != "cpu"]
-    return devs if devs else jax.local_devices()
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def _resolve_device(ctx: Context) -> jax.Device:
@@ -102,6 +101,14 @@ def _resolve_device(ctx: Context) -> jax.Device:
                 cpus = jax.local_devices()  # truly no host backend
         return cpus[min(ctx.device_id, len(cpus) - 1)]
     devs = _accelerators()
+    if not devs:
+        # an explicit mx.tpu(i)/mx.gpu(i) never means "the host if there
+        # is nothing better": ask mx.num_tpus() first, or take
+        # default_context(), which may pick the CPU
+        raise MXNetError(
+            "Context %s: jax sees no accelerator (backend %r); use "
+            "mx.cpu() or mx.context.default_context()"
+            % (ctx, jax.default_backend()))
     if ctx.device_id >= len(devs):
         raise MXNetError(
             "Context %s out of range: %d device(s) visible" % (ctx, len(devs)))

@@ -242,6 +242,10 @@ class DecodeEngine:
                 chunk_positions=(1, self._chunk_tokens),
                 chunk_start=(1,), chunk_len=(1,),
                 chunk_table=(1, self._table_width))
+        # the paged-attention implementation the step program is built
+        # with: the knob is read when the program is traced, so a later
+        # change of the environment must not change what stats() says
+        self._attn_impl = _paged_attn_impl()
         self._cache_names = []
         for i in range(self._num_layers):
             self._cache_names += ["layer%d_k_cache" % i,
@@ -255,15 +259,8 @@ class DecodeEngine:
         # no whole-cache copy in and out per token (docs/DECODE.md).
         # Block tables/positions are NOT donated: they are rebuilt
         # host-side and fed by copy each iteration.
-        # ... unless the persistent compilation cache is active: disk-
-        # loaded donated executables corrupt their buffers on this jax
-        # version, so the guard drops donation (even against an explicit
-        # MXNET_DECODE_DONATE=1) and stats() reports the truth
-        # (aot.store.donation_safe, docs/AOT.md).
-        from ..aot import store as _aot_store
-        self._donate = (_config.env_bool("MXNET_DECODE_DONATE",
-                                         default=True)
-                        and _aot_store.donation_safe())
+        self._donate = _config.env_bool("MXNET_DECODE_DONATE",
+                                        default=True)
         if self._donate:
             self._donate = bool(self._exe.donate_args(self._cache_names))
         self._inputs = ("data", "positions", "block_table", "chunk_data",
@@ -278,6 +275,7 @@ class DecodeEngine:
             {k: v if isinstance(v, NDArray) else NDArray(_np.asarray(v))
              for k, v in arg_params.items() if k in self._weight_names}, {},
             allow_extra_params=True)
+        self._exe._commit_args()
 
         # accounting (instance state; registry series are process-wide)
         self._warm = set()
@@ -398,8 +396,8 @@ class DecodeEngine:
         """Compile the ONE mixed step up front (vs the retired pow2
         ladder's one compile per bucket): a single all-slots-inactive,
         empty-chunk dispatch.  Runs inside an AOT-warming phase so the
-        step program is flagged ``warmed`` in telemetry.programs() and,
-        with MXNET_COMPILE_CACHE_DIR set, disk-loads on a restart
+        step program is flagged ``warmed`` in telemetry.programs() and
+        disk-loads from the persistent compile cache on a restart
         (docs/AOT.md)."""
         from ..telemetry import programs as _programs
         with self._step_lock, _programs.warming():
@@ -1244,7 +1242,7 @@ class DecodeEngine:
             "ttft_p99_ms": p99,
             "ttft_steps_p99": steps_p99,
             "model_version": self._model_version,
-            "attn_impl": _paged_attn_impl(),
+            "attn_impl": self._attn_impl,
             "cache_donation": self._donate,
             "spec_k": self._spec_k,
             "spec_impl": self._spec_impl,

@@ -525,9 +525,7 @@ class SparseApplyEngine:
 
 
 def _build_local(sig, vocab, threshold, has_state):
-    from ..aot.store import safe_donate_argnums as _donate
-
-    @partial(jax.jit, donate_argnums=_donate((0, 1, 2)))
+    @partial(jax.jit, donate_argnums=(0, 1, 2))
     def step(w, state, residual, idxs, rowss, lr, wd, rescale):
         _SITE.note()
         idx = jnp.concatenate(idxs) if len(idxs) > 1 else idxs[0]
@@ -555,13 +553,11 @@ def _build_partition_gspmd(sig, vocab, threshold, has_state, mesh):
     apply runs as a single launch whose cross-shard gathers/scatters
     XLA lowers to the fabric all-to-all."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ..aot.store import safe_donate_argnums as _donate
-
     def _rows_spec(x):
         return NamedSharding(mesh, P("dp") if x.ndim == 1
                              else P("dp", *([None] * (x.ndim - 1))))
 
-    @partial(jax.jit, donate_argnums=_donate((0, 1, 2)))
+    @partial(jax.jit, donate_argnums=(0, 1, 2))
     def step(w, state, residual, idx, rows, lr, wd, rescale):
         _SITE.note()
         w = jax.lax.with_sharding_constraint(w, _rows_spec(w))
@@ -594,9 +590,7 @@ def _build_partition_gspmd(sig, vocab, threshold, has_state, mesh):
 def _build_pre(vocab, threshold):
     """Local half of the host transport: coalesce (+ quantize against
     the host-local residual) before anything crosses the wire."""
-    from ..aot.store import safe_donate_argnums as _donate
-
-    @partial(jax.jit, donate_argnums=_donate((0,)))
+    @partial(jax.jit, donate_argnums=(0,))
     def pre(residual, idxs, rowss):
         _SITE.note()
         idx = jnp.concatenate(idxs) if len(idxs) > 1 else idxs[0]
@@ -616,9 +610,7 @@ def _build_pre(vocab, threshold):
 def _build_apply_only(sig, vocab, has_state):
     """Global half of the host transport: coalesce the rank-ordered
     union (already quantized per host) and apply."""
-    from ..aot.store import safe_donate_argnums as _donate
-
-    @partial(jax.jit, donate_argnums=_donate((0, 1)))
+    @partial(jax.jit, donate_argnums=(0, 1))
     def apply_(w, state, idx, rows, lr, wd, rescale):
         _SITE.note()
         uidx, g = _coalesce(idx, rows, vocab)

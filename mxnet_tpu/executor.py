@@ -590,6 +590,18 @@ class Executor:
         import jax as _jax
         return _jax.device_put(data, dev)
 
+    def _commit_args(self):
+        """Commit every bound array to the placement it already has.
+        Freshly allocated arrays are uncommitted; what a program returns
+        is committed, and jax lowers (and XLA compiles) again when that
+        changes.  Owners of state that is threaded through outputs (the
+        decode engine's caches) call this once after binding, so their
+        first real dispatch reuses the warmup's executable."""
+        import jax as _jax
+        for nd in list(self.arg_dict.values()) + list(
+                self.aux_dict.values()):
+            nd._set_data(_jax.device_put(nd._data, nd._data.sharding))
+
     def donate_args(self, names):
         """Route the named arguments through the donated inference
         forward: their device buffers are handed to XLA each eval
@@ -602,12 +614,7 @@ class Executor:
         corresponding outputs (engine._commit_caches) before anything
         reads them.  Stream-monitored debug forwards fall back to the
         copy-based program.  Pass an empty sequence to turn donation
-        back off.
-
-        With the persistent compilation cache enabled the request is
-        REFUSED (copy path kept, returns False): disk-loaded donated
-        executables corrupt their buffers on this jax version
-        (``mxnet_tpu.aot.store.donation_safe``, docs/AOT.md)."""
+        back off."""
         names = tuple(names)
         for n in names:
             if n not in self.arg_dict:
@@ -616,17 +623,6 @@ class Executor:
             self._donated_names = ()
             self._jit_fwd_eval_donated = None
             return True
-        from .aot import store as _aot_store
-        if not _aot_store.donation_safe():
-            import logging
-            logging.getLogger(__name__).warning(
-                "donate_args: refused — the persistent compilation "
-                "cache is active and disk-loaded donated executables "
-                "corrupt memory on this jax version; keeping the "
-                "copy-based forward (docs/AOT.md)")
-            self._donated_names = ()
-            self._jit_fwd_eval_donated = None
-            return False
         if self._group_devices is not None:
             raise MXNetError("donate_args: model-parallel (group2ctx) "
                              "binds are not supported")

@@ -64,8 +64,10 @@ def two_bit_quantize(residual, grad, threshold):
     fused Pallas kernel (pallas/quant.py — same op sequence, so still
     bit-exact) instead of this elementwise XLA chain."""
     from .pallas import two_bit_quantize_fused, use_q2bit_pallas
-    if use_q2bit_pallas():
-        return two_bit_quantize_fused(residual, grad, threshold)
+    kernel = use_q2bit_pallas()
+    if kernel:
+        return two_bit_quantize_fused(residual, grad, threshold,
+                                      interpret=kernel == "interpret")
     t = jnp.asarray(threshold, dtype=grad.dtype)
     acc = residual + grad
     q = jnp.where(acc > t, t, jnp.where(acc < -t, -t, jnp.zeros_like(acc)))
@@ -256,21 +258,19 @@ def _build_step(layout, n_dev, threshold, mode, tpls, mp_flags, use_wd,
                     (~jnp.isfinite(grads[d][i])).astype(jnp.float32))
         return nf
 
-    from .aot.store import safe_donate_argnums as _donate
-
     if mode is None:
         if sentinel:
             def step(residuals, grads, nf_acc):
                 _note_retrace()
                 reduced, new_res = _reduce(residuals, grads)
                 return tuple(reduced), new_res, nf_acc + _nonfinite(grads)
-            return jax.jit(step, donate_argnums=_donate((0, 2)))
+            return jax.jit(step, donate_argnums=(0, 2))
 
         def step(residuals, grads):
             _note_retrace()
             reduced, new_res = _reduce(residuals, grads)
             return tuple(reduced), new_res
-        return jax.jit(step, donate_argnums=_donate((0,)))
+        return jax.jit(step, donate_argnums=(0,))
 
     upd = _fused.build(mode)
 
@@ -296,14 +296,14 @@ def _build_step(layout, n_dev, threshold, mode, tpls, mp_flags, use_wd,
                 weights, states, residuals, grads, lr_vec, wd_vec,
                 rescale, extra)
             return new_ws, new_ss, new_res, nf_acc + _nonfinite(grads)
-        return jax.jit(step, donate_argnums=_donate((1, 2, 8)))
+        return jax.jit(step, donate_argnums=(1, 2, 8))
 
     def step(weights, states, residuals, grads, lr_vec, wd_vec, rescale,
              extra):
         _note_retrace()
         return _apply(weights, states, residuals, grads, lr_vec, wd_vec,
                       rescale, extra)
-    return jax.jit(step, donate_argnums=_donate((1, 2)))
+    return jax.jit(step, donate_argnums=(1, 2))
 
 
 class _Pending:

@@ -187,14 +187,12 @@ def _build_tpu_step(layout, n_dev, nproc, threshold, mode, tpls, mp_flags,
                    for off, size, shape in layout]
         return reduced, tuple(new_res)
 
-    from ..aot.store import safe_donate_argnums as _donate
-
     if mode is None:
         def step(residuals, grads):
             _note_retrace()
             reduced, new_res = _reduce(residuals, grads)
             return tuple(reduced), new_res
-        return jax.jit(step, donate_argnums=_donate((0,)))
+        return jax.jit(step, donate_argnums=(0,))
 
     upd = _fused.build(mode)
 
@@ -212,7 +210,7 @@ def _build_tpu_step(layout, n_dev, nproc, threshold, mode, tpls, mp_flags,
             new_ws.append(new_w)
             new_ss.append(tuple(_fused.flatten_state(new_s)[0]))
         return tuple(new_ws), tuple(new_ss), new_res
-    return jax.jit(step, donate_argnums=_donate((1, 2)))
+    return jax.jit(step, donate_argnums=(1, 2))
 
 
 def _build_local_reduce(layout, n_dev, threshold):
@@ -247,8 +245,7 @@ def _build_local_reduce(layout, n_dev, threshold):
         for q in dev_q[1:]:
             flat = flat + q
         return flat, tuple(new_res)
-    from ..aot.store import safe_donate_argnums as _donate
-    return jax.jit(step, donate_argnums=_donate((0,)))
+    return jax.jit(step, donate_argnums=(0,))
 
 
 def _build_local_apply(layout, tpls, mp_flags, use_wd, mode):
@@ -270,8 +267,7 @@ def _build_local_apply(layout, tpls, mp_flags, use_wd, mode):
             new_ws.append(new_w)
             new_ss.append(tuple(_fused.flatten_state(new_s)[0]))
         return tuple(new_ws), tuple(new_ss)
-    from ..aot.store import safe_donate_argnums as _donate
-    return jax.jit(step, donate_argnums=_donate((1,)))
+    return jax.jit(step, donate_argnums=(1,))
 
 
 class TPUBucketEngine(FusedBucketEngine):

@@ -56,8 +56,7 @@ class DataParallelExecutorGroup:
                 and smesh.devices.size == self._n_dev > 1:
             self._mesh = smesh
         else:
-            self._mesh = data_parallel_mesh(contexts) \
-                if self._n_dev > 1 else None
+            self._mesh = data_parallel_mesh(contexts)
 
         req = {}
         for name in self.arg_names:

@@ -310,15 +310,10 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
         return (new_ps, new_ss, new_res, macc, new_scaler, new_sent,
                 new_auxs, outs)
 
-    # params/states/residuals/macc/scaler/auxs donate in place — except
-    # under the persistent cache, where disk-loaded donated executables
-    # corrupt memory (aot.store.donation_safe): the guard trades the
-    # in-place update for correct zero-compile restarts.
-    from ..aot.store import safe_donate_argnums as _donate
-    donate = _donate((0, 1, 2, 3, 4, 5, 7))
+    # params/states/residuals/macc/scaler/sentinels/auxs donate in place
+    donate = (0, 1, 2, 3, 4, 5, 7)
     fn = jax.jit(step, donate_argnums=donate)
-    if donate:
-        _telemetry.programs.note_donation(fn, donate)
+    _telemetry.programs.note_donation(fn, donate)
     return fn
 
 
@@ -739,6 +734,23 @@ class FusedFitStep:
             if sent_state is None:
                 sent_state = jnp.zeros(8, jnp.float32)
         auxs = exe._auxs_values()
+        # Everything the program carries comes back COMMITTED to where
+        # it ran — under a mesh typed with it (replicated NamedSharding).
+        # jax keys the trace on that type and the lowering on the
+        # placement, so fresh state that goes in uncommitted (a new
+        # scalar accumulator, initializer outputs, lazily created
+        # optimizer state) costs a second trace under a mesh and a
+        # second full XLA compile without one.  Hand it over the way it
+        # comes back: the carry is stable from step one, and each
+        # device_put is a no-op for what the previous step returned.
+        macc, scaler_state, sent_state = jax.device_put(
+            (macc, scaler_state, sent_state),
+            group._repl_sharding() if group._mesh is not None
+            else exe._ctx.jax_device)
+        if self.launches == 0:
+            params, states, residuals, auxs = jax.tree.map(
+                lambda a: jax.device_put(a, a.sharding),
+                (params, states, residuals, auxs))
         if self._pmesh is not None:
             # lift every program input onto the cross-host mesh (no-op
             # for arrays the previous step already left there)

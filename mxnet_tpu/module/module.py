@@ -441,9 +441,10 @@ class Module(BaseModule):
         before its first real batch rather than during it.  Touches
         gradients only — parameters and optimizer state are untouched
         (no ``update``).  The fused fit step keys on live optimizer
-        state and compiles lazily on the first ``fit_step``; with
-        ``MXNET_COMPILE_CACHE_DIR`` set that compile is also a
-        disk-load.  Returns the number of programs dispatched."""
+        state and compiles lazily on the first ``fit_step``; on a
+        restart that compile is also a disk-load (the persistent
+        compile cache, docs/AOT.md).  Returns the number of programs
+        dispatched."""
         assert self.binded and self.params_initialized
         from ..io import DataBatch
         from ..ndarray import zeros as _nd_zeros

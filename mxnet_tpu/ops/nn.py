@@ -294,11 +294,13 @@ def layer_norm(data, gamma, beta, *, axis=-1, eps=1e-5, output_mean_var=False):
     propagate mean/inv_std cotangents, so routing requires
     ``output_mean_var=False`` (where they are structurally unused)."""
     ax = int(axis) % data.ndim
-    if (not output_mean_var and data.ndim >= 2
-            and _use_layernorm_kernel(ax == data.ndim - 1)):
+    kernel = (not output_mean_var and data.ndim >= 2
+              and _use_layernorm_kernel(ax == data.ndim - 1))
+    if kernel:
         from ..pallas import layernorm_fused
         out, mean, inv_std = layernorm_fused(
-            data, gamma.reshape(-1), beta.reshape(-1), eps=eps)
+            data, gamma.reshape(-1), beta.reshape(-1), eps=eps,
+            interpret=kernel == "interpret")
         return (out, mean, inv_std)
     xf = data.astype(jnp.float32)
     mean = jnp.mean(xf, axis=ax, keepdims=True)
@@ -728,17 +730,17 @@ def _use_flash_attention(seq_len, head_dim, dtype):
     ``pallas.dispatch.choose_impl``, shared with the paged-attention
     and quantize knobs so the three contracts cannot drift."""
     import os
-    from ..pallas.dispatch import choose_impl
-    supported = (jax.default_backend() == "tpu" and head_dim % 128 == 0
-                 and seq_len % 512 == 0
+    from ..pallas.dispatch import _compiles_here, choose_impl
+    here, why, reason = _compiles_here()
+    supported = (here and head_dim % 128 == 0 and seq_len % 512 == 0
                  and dtype in (jnp.bfloat16, jnp.float32))
     return choose_impl(
         "MXNET_ATTN_IMPL", os.environ.get("MXNET_ATTN_IMPL", "auto"),
         "flash", supported,
-        why=f"backend={jax.default_backend()}, head_dim={head_dim}, "
-            f"seq={seq_len}, dtype={dtype}; need TPU, head_dim%128==0, "
-            f"seq%512==0, bf16/f32",
-        fallback_reason="flash-geometry")
+        why=f"{why or 'one TPU device'}, head_dim={head_dim}, "
+            f"seq={seq_len}, dtype={dtype}; need a one-device TPU "
+            f"program, head_dim%128==0, seq%512==0, bf16/f32",
+        fallback_reason=reason or "flash-geometry")
 
 
 def _flash_attention(q, k, v, sm_scale):
@@ -748,6 +750,8 @@ def _flash_attention(q, k, v, sm_scale):
     XLA path)."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes as _BlockSizes, flash_attention as _flash)
+    from ..pallas.attention import _count_launch
+    _count_launch("flash_attention")
     blk = 512  # geometry gate guarantees S % 512 == 0
     bs = _BlockSizes(
         block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
@@ -932,7 +936,8 @@ def paged_decode_attention(data, qkv_weight, qkv_bias, proj_weight,
     vf = vf.at[widx].set(v.astype(vf.dtype), mode="drop")
 
     from ..pallas import paged_decode_attend, use_paged_pallas
-    if use_paged_pallas():
+    kernel = use_paged_pallas()
+    if kernel:
         # Pallas kernel (docs/KERNELS.md): walks the block table inside
         # the kernel — one (bs, H, D) K/V block in VMEM at a time with
         # an online softmax, so the (C, M*bs, H, D) gathered-context
@@ -941,7 +946,8 @@ def paged_decode_attention(data, qkv_weight, qkv_bias, proj_weight,
         # masked garbage; the engine masks both.
         o = paged_decode_attend(q, kf.reshape(k_cache.shape),
                                 vf.reshape(v_cache.shape), table, pos,
-                                scale=sc)
+                                scale=sc,
+                                interpret=kernel == "interpret")
     else:
         # gather the whole addressable context per slot and mask
         # causally; padded table entries read block 0 but sit behind
@@ -985,7 +991,8 @@ def paged_prefill_attention(data, qkv_weight, qkv_bias, proj_weight,
     sc = (1.0 / D ** 0.5) if scale is None else float(scale)
 
     from ..pallas import paged_prefill_attend, use_paged_pallas
-    if use_paged_pallas():
+    kernel = use_paged_pallas()
+    if kernel:
         # Pallas kernel (docs/KERNELS.md): causal attention per query
         # block with the cache scatter FUSED into the same kernel —
         # K/V rows land in their table-addressed cache blocks as they
@@ -998,7 +1005,8 @@ def paged_prefill_attention(data, qkv_weight, qkv_bias, proj_weight,
         v = jnp.einsum("bsd,hed->bshe", data, Wqkv[2]) + bqkv[2]
         o, kc, vc = paged_prefill_attend(
             q, k, v, k_cache, v_cache, block_table.astype(jnp.int32),
-            lengths.reshape(B).astype(jnp.int32), scale=sc)
+            lengths.reshape(B).astype(jnp.int32), scale=sc,
+            interpret=kernel == "interpret")
         out = jnp.einsum("bshe,dhe->bsd", o,
                          proj_weight.reshape(d, H, D)) + proj_bias
         return out, kc, vc
@@ -1073,7 +1081,8 @@ def paged_chunk_prefill_attention(data, qkv_weight, qkv_bias,
     nb, bs = k_cache.shape[0], k_cache.shape[1]
 
     from ..pallas import paged_chunk_prefill_attend, use_paged_pallas
-    if use_paged_pallas():
+    kernel = use_paged_pallas()
+    if kernel:
         # Pallas kernel (docs/KERNELS.md): streams the context cache
         # block by block with an online softmax, merging the chunk's
         # own K/V into each block in-kernel and writing it back through
@@ -1084,7 +1093,8 @@ def paged_chunk_prefill_attention(data, qkv_weight, qkv_bias,
         k = jnp.einsum("bsd,hed->bshe", data, Wqkv[1]) + bqkv[1]
         v = jnp.einsum("bsd,hed->bshe", data, Wqkv[2]) + bqkv[2]
         o, kc, vc = paged_chunk_prefill_attend(
-            q, k, v, k_cache, v_cache, table, st, L, scale=sc)
+            q, k, v, k_cache, v_cache, table, st, L, scale=sc,
+            interpret=kernel == "interpret")
         out = jnp.einsum("bshe,dhe->bsd", o,
                          proj_weight.reshape(d, H, D)) + proj_bias
         return out, kc, vc

@@ -19,11 +19,12 @@ this package holds the custom TPU kernels behind the framework's
   plus the ``pallas_kernel_launches`` / ``pallas_fallbacks``
   witnesses.
 
-Every kernel runs under ``interpret=True`` off-TPU, so the CPU
-container and tier-1 exercise the exact kernel code paths against the
-XLA reference paths (the interpret-mode testing convention,
-docs/KERNELS.md).  jax is imported lazily inside the kernel modules'
-functions where possible; importing this package does not require a
+Every kernel takes ``interpret=True``, and its knob forced onto a
+backend that cannot compile it says the same, so the CPU container and
+tier-1 exercise the exact kernel code paths against the XLA reference
+paths (the interpret-mode testing convention, docs/KERNELS.md).
+Nothing else selects interpret mode: ``auto`` means the compiled
+kernel or the XLA path.  Importing this package does not require a
 TPU.
 """
 from . import dispatch

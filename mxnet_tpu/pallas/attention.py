@@ -27,21 +27,25 @@ accumulates across blocks, and no gathered context tensor ever exists.
   fused through the same clamp-onto-last-real-block discipline but
   addressed at an absolute ``start`` offset into an EXISTING cache.
 
-Off-TPU the wrappers run ``interpret=True`` so CPU tier-1 executes the
-exact kernel logic against the XLA reference (parity pinned at
-rtol<=2e-5 f32 in tests/test_pallas.py).  Block-size tuning notes live
-in docs/KERNELS.md.
+What Mosaic (the TPU kernel compiler) needs, and tests/
+test_chip_compile.py holds at real widths: every ``dot_general``
+batches over the LEADING axis, so the cache blocks — stored seq-major
+``(bs, H, D)`` — are swapped head-major in VMEM before they meet the
+MXU; rows are picked by ``pl.ds`` on a ref or by a BlockSpec index
+map, never by ``lax.dynamic_slice`` on a value; masks are built at the
+full shape of what they select.
+
+The wrappers compile for the backend they run on.  ``interpret=True``
+(CPU tier-1: the exact kernel logic against the XLA reference, parity
+pinned at rtol<=2e-5 f32 in tests/test_pallas.py) is only ever an
+explicit argument.  Block-size tuning notes live in docs/KERNELS.md.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:               # pragma: no cover — the pinned
-    pl = pltpu = None           # toolchain always ships pallas
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry as _telemetry
 from ..telemetry.registry import RETRACE_SUPPRESS
@@ -55,16 +59,67 @@ _SITE = _telemetry.RetraceSite(PALLAS_RETRACES, _telemetry.JIT_COMPILE_MS,
 _note_kernel_build = _SITE.note
 
 
-def _interpret_default(interpret):
-    if interpret is not None:
-        return bool(interpret)
-    return jax.default_backend() != "tpu"
+# finite mask fill (not -inf): a whole block can be masked for a query
+# row, and exp(m_prev - max(m_prev, fill)) must stay 0/1, never NaN
+_NEG = -1e30
 
 
 def _count_launch(kernel):
     _note_kernel_build()
     if not RETRACE_SUPPRESS.on:   # skip program-registry re-lowers
         PALLAS_LAUNCHES.labels(kernel=kernel).inc()
+
+
+def _head_major(k, v, dtype):
+    """Seq-major cache blocks ``(bs, H, D)`` -> ``(H, bs, D)`` in the
+    query's dtype: Mosaic batches a matmul over the leading axis only."""
+    return (jnp.swapaxes(k.astype(dtype), 0, 1),
+            jnp.swapaxes(v.astype(dtype), 0, 1))
+
+
+def _bdot(a, b, b_contract):
+    """Head-batched matmul, f32 accumulation: contracts ``a``'s last
+    axis with axis ``b_contract`` of ``b``, batching over axis 0.
+    bf16 operands pin the MXU's native pass: a process-wide
+    ``jax_default_matmul_precision`` of float32 (tests/conftest.py sets
+    one) would otherwise ask Mosaic for an fp32 contraction of bf16
+    vectors, which it refuses."""
+    return jax.lax.dot_general(
+        a, b, (((2,), (b_contract,)), ((0,), (0,))),
+        precision=(jax.lax.Precision.DEFAULT
+                   if a.dtype == jnp.bfloat16 else None),
+        preferred_element_type=jnp.float32)
+
+
+def _online_softmax_step(s, v, acc_ref, m_ref, l_ref):
+    """One block of the running softmax: ``s (H, Q, bs)`` masked f32
+    scores (finite fill), ``v (H, bs, D)``; state refs ``(H, Q, D)`` /
+    ``(H, Q, 1)``."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + _bdot(p.astype(v.dtype), v, 1)
+    m_ref[...] = m_new
+
+
+def _softmax_init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _softmax_emit(o_ref, acc_ref, l_ref):
+    l = l_ref[...]
+    o_ref[0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(
+        o_ref.dtype)
+
+
+def _last_block(end, bs):
+    """Index of the last block holding any of rows ``[0, end)`` (block
+    0 for an empty row)."""
+    return jnp.maximum(-(-end // bs), 1) - 1
 
 
 # ----------------------------------------------------------------------
@@ -78,43 +133,27 @@ def _paged_decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(m == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _softmax_init(acc_ref, m_ref, l_ref)
 
-    # blocks past the slot's position are never loaded into the
-    # softmax — the online-softmax state simply skips them (and an
-    # inactive slot, pos < 0, skips every block)
+    # blocks past the slot's position never enter the softmax (their
+    # grid steps re-address the last real block, so they cost no DMA
+    # either); an inactive slot, pos < 0, skips every block
     @pl.when(jnp.logical_and(pos >= 0, m * bs <= pos))
     def _block():
-        q = q_ref[0].astype(jnp.float32)              # (H, D)
-        k = k_ref[0].astype(jnp.float32)              # (bs, H, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale   # (H, bs)
-        j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + m * bs
-        s = jnp.where(j <= pos, s, -jnp.inf)
-        m_prev = m_ref[...]                           # (H, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                        # (H, bs)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)       # (H, D)
-        m_ref[...] = m_new
+        q = q_ref[0]                                  # (H, 1, D)
+        k, v = _head_major(k_ref[0], v_ref[0], q.dtype)
+        s = _bdot(q, k, 2) * scale                    # (H, 1, bs)
+        j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + m * bs
+        _online_softmax_step(jnp.where(j <= pos, s, _NEG), v,
+                             acc_ref, m_ref, l_ref)
 
     @pl.when(m == pl.num_programs(1) - 1)
     def _emit():
-        l = l_ref[...]
-        o = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = o.astype(o_ref.dtype)
+        _softmax_emit(o_ref, acc_ref, l_ref)
 
 
 def paged_decode_attend(q, k_cache, v_cache, block_table, positions, *,
-                        scale, interpret=None):
+                        scale, interpret=False):
     """Paged decode attention: ``q (C, H, D)`` against cache rows
     ``[0, positions[c]]`` addressed through ``block_table (C, M)``;
     ``k_cache/v_cache (num_blocks, block_size, H, D)`` already hold
@@ -127,87 +166,82 @@ def paged_decode_attend(q, k_cache, v_cache, block_table, positions, *,
     bs = k_cache.shape[1]
     M = block_table.shape[1]
     _count_launch("paged_decode_attend")
+
+    def cache_block(c, m, t, p):
+        # steps past the slot's last block stay on it: an unchanged
+        # block index is not fetched again, and padded table entries
+        # are never dereferenced
+        return (t[c, jnp.minimum(m, _last_block(p[c] + 1, bs))], 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(C, M),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda c, m, t, p: (c, 0, 0)),
-            pl.BlockSpec((1, bs, H, D),
-                         lambda c, m, t, p: (t[c, m], 0, 0, 0)),
-            pl.BlockSpec((1, bs, H, D),
-                         lambda c, m, t, p: (t[c, m], 0, 0, 0)),
+            pl.BlockSpec((1, H, 1, D), lambda c, m, t, p: (c, 0, 0, 0)),
+            pl.BlockSpec((1, bs, H, D), cache_block),
+            pl.BlockSpec((1, bs, H, D), cache_block),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda c, m, t, p: (c, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, 1, D),
+                               lambda c, m, t, p: (c, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),   # online-softmax acc
-            pltpu.VMEM((H, 1), jnp.float32),   # running max
-            pltpu.VMEM((H, 1), jnp.float32),   # running denom
+            pltpu.VMEM((H, 1, D), jnp.float32),   # online-softmax acc
+            pltpu.VMEM((H, 1, 1), jnp.float32),   # running max
+            pltpu.VMEM((H, 1, 1), jnp.float32),   # running denom
         ],
     )
     fn = pl.pallas_call(
         functools.partial(_paged_decode_kernel, bs=bs,
                           scale=float(scale)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C, H, D), q.dtype),
-        interpret=_interpret_default(interpret),
+        out_shape=jax.ShapeDtypeStruct((C, H, 1, D), q.dtype),
+        interpret=interpret,
     )
     return fn(block_table.astype(jnp.int32),
-              positions.astype(jnp.int32), q, k_cache, v_cache)
+              positions.astype(jnp.int32), q.reshape(C, H, 1, D),
+              k_cache, v_cache).reshape(C, H, D)
 
 
 # ----------------------------------------------------------------------
 # prefill: causal MHA + the cache scatter fused into one kernel
 # ----------------------------------------------------------------------
 def _paged_prefill_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
-                          kc_ref, vc_ref, o_ref, ko_ref, vo_ref, *,
-                          bs, scale):
+                          kn_ref, vn_ref, kc_ref, vc_ref, o_ref, ko_ref,
+                          vo_ref, *, bs, scale):
     b = pl.program_id(0)
     m = pl.program_id(1)
     L = len_ref[b]
 
     # causal attention for query rows [m*bs, (m+1)*bs) against the
-    # row's full K/V (VMEM-resident: prefill buckets are short)
-    q = q_ref[0].astype(jnp.float32)                  # (bs, H, D)
-    k = k_ref[0].astype(jnp.float32)                  # (S, H, D)
-    v = v_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((1,), (1,))),
-        preferred_element_type=jnp.float32) * scale   # (H, bs, S)
+    # row's full K/V (VMEM-resident: prefill buckets are short); the
+    # wrapper hands q/k/v over head-major
+    q = q_ref[0]                                      # (H, bs, D)
+    s = _bdot(q, k_ref[0], 2) * scale                 # (H, bs, S)
     jq = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + m * bs
     jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(jq >= jk, s, -jnp.inf)
-    mx = jnp.max(s, axis=2, keepdims=True)
-    p = jnp.exp(s - mx)
+    s = jnp.where(jq >= jk, s, _NEG)
+    p = jnp.exp(s - jnp.max(s, axis=2, keepdims=True))
     p = p / jnp.sum(p, axis=2, keepdims=True)
-    o = jax.lax.dot_general(
-        p, v, (((2,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)           # (H, bs, D)
-    o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
+    o_ref[0] = _bdot(p.astype(q.dtype), v_ref[0], 1).astype(o_ref.dtype)
 
-    # fused scatter: this block's K/V rows into cache block
-    # table[b, m], masked to rows < L.  Grid steps PAST the row's last
-    # real block (m*bs >= L, where the table holds padding/garbage) are
-    # CLAMPED — index maps and this slice both redirect to the last
-    # real block, so the step re-emits that block's exact bytes: a
-    # duplicate idempotent write instead of a write through an
-    # untrusted table entry (the in-kernel analog of the XLA path's
-    # nb*bs OOB-drop sentinel, which likewise never dereferences
-    # padded entries).
-    m_eff = jnp.minimum(m, jnp.maximum(-(-L // bs), 1) - 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0) + m_eff * bs
+    # fused scatter: this block's K/V rows (kn/vn: the seq-major rows
+    # of block m_eff, picked by the index map) into cache block
+    # table[b, m_eff], masked to rows < L.  Grid steps PAST the row's
+    # last real block (m*bs >= L, where the table holds
+    # padding/garbage) are CLAMPED onto it by every index map, so the
+    # step re-emits that block's exact bytes: a duplicate idempotent
+    # write instead of a write through an untrusted table entry (the
+    # in-kernel analog of the XLA path's nb*bs OOB-drop sentinel,
+    # which likewise never dereferences padded entries).
+    m_eff = jnp.minimum(m, _last_block(L, bs))
+    row = (jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[1:], 0)
+           + m_eff * bs)
     keep = row < L
-    ko_ref[0] = jnp.where(
-        keep,
-        jax.lax.dynamic_slice_in_dim(k_ref[0], m_eff * bs, bs, 0)
-        .astype(ko_ref.dtype), kc_ref[0])
-    vo_ref[0] = jnp.where(
-        keep,
-        jax.lax.dynamic_slice_in_dim(v_ref[0], m_eff * bs, bs, 0)
-        .astype(vo_ref.dtype), vc_ref[0])
+    ko_ref[0] = jnp.where(keep, kn_ref[0].astype(ko_ref.dtype), kc_ref[0])
+    vo_ref[0] = jnp.where(keep, vn_ref[0].astype(vo_ref.dtype), vc_ref[0])
 
 
 def paged_prefill_attend(q, k, v, k_cache, v_cache, block_table,
-                         lengths, *, scale, interpret=None):
+                         lengths, *, scale, interpret=False):
     """Causal MHA over ``q/k/v (B, S, H, D)`` with the scatter of each
     row's first ``lengths[b]`` K/V rows into the paged cache fused into
     the same kernel (the caches are input/output aliased — in-place
@@ -235,26 +269,33 @@ def paged_prefill_attend(q, k, v, k_cache, v_cache, block_table,
             % (block_table.shape[1], Mq, S, bs))
     _count_launch("paged_prefill_attend")
 
-    def cache_block(b, m, t, l):
+    def clamped(b, m, l):
         # clamp to the row's LAST REAL block once m runs past the
         # length: table entries there are padding (the engine leaves
         # zeros) and must never be dereferenced — the kernel re-emits
         # the last real block instead (idempotent duplicate write)
-        last = jnp.maximum(-(-l[b] // bs), 1) - 1
-        return (t[b, jnp.minimum(m, last)], 0, 0, 0)
+        return jnp.minimum(m, _last_block(l[b], bs))
 
+    def cache_block(b, m, t, l):
+        return (t[b, clamped(b, m, l)], 0, 0, 0)
+
+    def new_rows(b, m, t, l):
+        return (b, clamped(b, m, l), 0, 0)
+
+    head_major = pl.BlockSpec((1, H, Sp, D), lambda b, m, t, l: (b, 0, 0, 0))
+    q_block = pl.BlockSpec((1, H, bs, D), lambda b, m, t, l: (b, 0, m, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, Mq),
         in_specs=[
-            pl.BlockSpec((1, bs, H, D), lambda b, m, t, l: (b, m, 0, 0)),
-            pl.BlockSpec((1, Sp, H, D), lambda b, m, t, l: (b, 0, 0, 0)),
-            pl.BlockSpec((1, Sp, H, D), lambda b, m, t, l: (b, 0, 0, 0)),
+            q_block, head_major, head_major,
+            pl.BlockSpec((1, bs, H, D), new_rows),
+            pl.BlockSpec((1, bs, H, D), new_rows),
             pl.BlockSpec((1, bs, H, D), cache_block),
             pl.BlockSpec((1, bs, H, D), cache_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, bs, H, D), lambda b, m, t, l: (b, m, 0, 0)),
+            q_block,
             pl.BlockSpec((1, bs, H, D), cache_block),
             pl.BlockSpec((1, bs, H, D), cache_block),
         ],
@@ -265,18 +306,22 @@ def paged_prefill_attend(q, k, v, k_cache, v_cache, block_table,
                           scale=float(scale)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sp, H, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sp, D), q.dtype),
             jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
             jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
         ],
         # cache in -> cache out: in-place block writes, no cache copy
-        input_output_aliases={5: 1, 6: 2},
-        interpret=_interpret_default(interpret),
+        # (scalar-prefetch args count: table=0, len=1, q=2, k=3, v=4,
+        # kn=5, vn=6, k_cache=7, v_cache=8)
+        input_output_aliases={7: 1, 8: 2},
+        interpret=interpret,
     )
+    hm = lambda x: x.transpose(0, 2, 1, 3)            # noqa: E731
     out, ko, vo = fn(block_table.astype(jnp.int32),
-                     lengths.astype(jnp.int32), q, k, v,
+                     lengths.astype(jnp.int32), hm(q),
+                     hm(k).astype(q.dtype), hm(v).astype(q.dtype), k, v,
                      k_cache, v_cache)
-    return out[:, :S], ko, vo
+    return hm(out)[:, :S], ko, vo
 
 
 # ----------------------------------------------------------------------
@@ -292,32 +337,34 @@ def _paged_chunk_prefill_kernel(table_ref, start_ref, len_ref, q_ref,
     L = len_ref[b]
     end = st + L
     # blocks holding real context once this chunk lands: [0, nctx)
-    nctx = jnp.maximum(-(-end // bs), 1)
+    nctx = _last_block(end, bs) + 1
     m_eff = jnp.minimum(m, nctx - 1)
 
     @pl.when(m == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _softmax_init(acc_ref, m_ref, l_ref)
 
     # merge the chunk's rows into this step's cache block: block m_eff
     # holds absolute rows [m_eff*bs, m_eff*bs + bs); rows inside
     # [start, end) come from the chunk (kpad carries bs zero rows on
-    # each side so the dynamic slice stays in-bounds even when the
-    # chunk straddles a block boundary), every other row keeps its
-    # existing cache bytes.  Clamped steps (m >= nctx) re-emit the last
-    # real block's exact bytes — the idempotent duplicate write that
-    # keeps padded table entries undereferenced.
-    row_abs = (jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
+    # each side so the slice stays in-bounds when the chunk straddles a
+    # block boundary; a block that lies wholly outside the chunk clamps
+    # its slice onto the padding, where `in_chunk` selects nothing),
+    # every other row keeps its existing cache bytes.  Clamped steps
+    # (m >= nctx) re-emit the last real block's exact bytes — the
+    # idempotent duplicate write that keeps padded table entries
+    # undereferenced.
+    row_abs = (jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[1:], 0)
                + m_eff * bs)
     in_chunk = jnp.logical_and(row_abs >= st, row_abs < end)
-    off = m_eff * bs - st           # chunk-local index of the block's
-    kslice = jax.lax.dynamic_slice_in_dim(   # first row (may be < 0)
-        kpad_ref[0], off + bs, bs, 0)
-    vslice = jax.lax.dynamic_slice_in_dim(vpad_ref[0], off + bs, bs, 0)
-    kblk = jnp.where(in_chunk, kslice.astype(kc_ref.dtype), kc_ref[0])
-    vblk = jnp.where(in_chunk, vslice.astype(vc_ref.dtype), vc_ref[0])
+    # chunk-local (padded) index of the block's first row
+    off = jnp.clip(m_eff * bs - st + bs, 0, kpad_ref.shape[1] - bs)
+    kblk = jnp.where(in_chunk,
+                     kpad_ref[0, pl.ds(off, bs)].astype(kc_ref.dtype),
+                     kc_ref[0])
+    vblk = jnp.where(in_chunk,
+                     vpad_ref[0, pl.ds(off, bs)].astype(vc_ref.dtype),
+                     vc_ref[0])
     ko_ref[0] = kblk
     vo_ref[0] = vblk
 
@@ -325,41 +372,24 @@ def _paged_chunk_prefill_kernel(table_ref, start_ref, len_ref, q_ref,
     # skipped so the duplicate write never double-counts a block
     @pl.when(m < nctx)
     def _block():
-        q = q_ref[0].astype(jnp.float32)              # (K, H, D)
-        kk = kblk.astype(jnp.float32)                 # (bs, H, D)
-        vv = vblk.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kk, (((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32) * scale   # (H, K, bs)
+        q = q_ref[0]                                  # (H, K, D)
+        kk, vv = _head_major(kblk, vblk, q.dtype)     # (H, bs, D)
+        s = _bdot(q, kk, 2) * scale                   # (H, K, bs)
         jq = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + st
         jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + m * bs
         # causal over the FULL context: prior chunks fully visible,
-        # in-chunk keys causally.  Finite fill (not -inf): a later
-        # block can be entirely masked for early queries, and
-        # exp(m_prev - max(m_prev, -1e30)) must stay 0/1, not NaN.
-        s = jnp.where(jk <= jq, s, -1e30)
-        m_prev = m_ref[...]                           # (H, K, 1)
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                        # (H, K, bs)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, vv, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)       # (H, K, D)
-        m_ref[...] = m_new
+        # in-chunk keys causally
+        _online_softmax_step(jnp.where(jk <= jq, s, _NEG), vv,
+                             acc_ref, m_ref, l_ref)
 
     @pl.when(m == pl.num_programs(1) - 1)
     def _emit():
-        l = l_ref[...]
-        o = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
+        _softmax_emit(o_ref, acc_ref, l_ref)
 
 
 def paged_chunk_prefill_attend(q, k, v, k_cache, v_cache, block_table,
                                start, lengths, *, scale,
-                               interpret=None):
+                               interpret=False):
     """Chunked prefill attention over an EXISTING cache: the chunk rows
     ``q/k/v (B, K, H, D)`` sit at absolute positions
     ``[start[b], start[b] + lengths[b])`` of their sequences; each
@@ -392,25 +422,23 @@ def paged_chunk_prefill_attend(q, k, v, k_cache, v_cache, block_table,
     def cache_block(b, m, t, st, l):
         # same clamp as paged_prefill_attend, but the last real block
         # is start+length blocks in — the chunk extends a live prefix
-        last = jnp.maximum(-(-(st[b] + l[b]) // bs), 1) - 1
-        return (t[b, jnp.minimum(m, last)], 0, 0, 0)
+        return (t[b, jnp.minimum(m, _last_block(st[b] + l[b], bs))],
+                0, 0, 0)
 
+    q_block = pl.BlockSpec((1, H, K, D),
+                           lambda b, m, t, st, l: (b, 0, 0, 0))
+    chunk_rows = pl.BlockSpec((1, Kp, H, D),
+                              lambda b, m, t, st, l: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, M),
         in_specs=[
-            pl.BlockSpec((1, K, H, D),
-                         lambda b, m, t, st, l: (b, 0, 0, 0)),
-            pl.BlockSpec((1, Kp, H, D),
-                         lambda b, m, t, st, l: (b, 0, 0, 0)),
-            pl.BlockSpec((1, Kp, H, D),
-                         lambda b, m, t, st, l: (b, 0, 0, 0)),
+            q_block, chunk_rows, chunk_rows,
             pl.BlockSpec((1, bs, H, D), cache_block),
             pl.BlockSpec((1, bs, H, D), cache_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, K, H, D),
-                         lambda b, m, t, st, l: (b, 0, 0, 0)),
+            q_block,
             pl.BlockSpec((1, bs, H, D), cache_block),
             pl.BlockSpec((1, bs, H, D), cache_block),
         ],
@@ -425,7 +453,7 @@ def paged_chunk_prefill_attend(q, k, v, k_cache, v_cache, block_table,
                           scale=float(scale)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, K, H, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, K, D), q.dtype),
             jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
             jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
         ],
@@ -433,8 +461,10 @@ def paged_chunk_prefill_attend(q, k, v, k_cache, v_cache, block_table,
         # (scalar-prefetch args count: table=0, start=1, len=2, q=3,
         # kpad=4, vpad=5, k_cache=6, v_cache=7)
         input_output_aliases={6: 1, 7: 2},
-        interpret=_interpret_default(interpret),
+        interpret=interpret,
     )
-    return fn(block_table.astype(jnp.int32), start.astype(jnp.int32),
-              lengths.astype(jnp.int32), q, kpad, vpad,
-              k_cache, v_cache)
+    out, ko, vo = fn(
+        block_table.astype(jnp.int32), start.astype(jnp.int32),
+        lengths.astype(jnp.int32), q.transpose(0, 2, 1, 3), kpad, vpad,
+        k_cache, v_cache)
+    return out.transpose(0, 2, 1, 3), ko, vo

@@ -10,9 +10,12 @@ route through :func:`choose_impl`, so the three knobs cannot drift:
 * ``xla`` — force the reference path (A/B runs);
 * ``<kernel>`` — require the kernel; raise instead of silently
   measuring the wrong path when it cannot run.  The paged/quant
-  kernels are *forceable anywhere* because ``interpret=True`` executes
-  them on any backend — that is the tier-1/CI testing convention
-  (docs/KERNELS.md).
+  kernels are *forceable anywhere*: forced onto a backend that cannot
+  compile them they run ``interpret=True`` — the tier-1/CI testing
+  convention (docs/KERNELS.md).  That is the ONLY way a kernel ever
+  runs interpreted: :func:`choose_impl` answers ``"interpret"`` for a
+  forced knob and nothing else, so ``auto`` is either the compiled
+  kernel or the XLA path, never a silent emulation.
 
 The decisions here run at TRACE time (inside the enclosing jitted
 program's Python), so they are per-program-construction, not
@@ -49,16 +52,18 @@ def choose_impl(env_var, impl, kernel, supported, why, *,
     ``impl`` is the knob's raw value — the CALLER reads it with a
     literal env-var name (``os.environ.get("MXNET_X_IMPL", "auto")``)
     so the envknobs analyze pass can see the read site; ``env_var`` is
-    only for error messages.  Returns True when the custom kernel
-    should be used.  Raises ``ValueError`` for an unknown value, and
-    when the kernel is forced (``<env_var>=<kernel>``) but cannot run —
-    never silently measure the wrong path.  ``supported`` gates the
+    only for error messages.  Returns False for the XLA path, else how
+    the custom kernel runs: ``"compiled"``, or ``"interpret"`` when the
+    knob forced it where ``supported`` is false.  Raises ``ValueError``
+    for an unknown value, and when the kernel is forced
+    (``<env_var>=<kernel>``) but cannot run — never silently measure
+    the wrong path.  ``supported`` gates the
     ``auto`` choice; ``force_supported`` (default: same as
-    ``supported``) gates the forced one — interpret-mode kernels pass
-    ``force_supported=True`` since they run on any backend when
-    explicitly requested.  ``count=False`` suppresses the fallback
-    counter for observer-only calls (stats/bench polling must not
-    inflate the per-trace witness).
+    ``supported``) gates the forced one — kernels that can run
+    interpreted pass ``force_supported=True`` since they then run on
+    any backend when explicitly requested.  ``count=False`` suppresses
+    the fallback counter for observer-only calls (stats/bench polling
+    must not inflate the per-trace witness).
     """
     if impl == "xla":
         return False
@@ -69,31 +74,53 @@ def choose_impl(env_var, impl, kernel, supported, why, *,
         if not ok:
             raise ValueError("%s=%s but the kernel cannot run here (%s)"
                              % (env_var, impl, why))
-        return True
+        return "compiled" if supported else "interpret"
     if not supported:
         if count and not RETRACE_SUPPRESS.on:   # not a registry re-lower
             PALLAS_FALLBACKS.labels(reason=fallback_reason).inc()
         return False
-    return True
+    return "compiled"
+
+
+def _compiles_here():
+    """Where ``auto`` may take a compiled Pallas kernel: on a TPU, in a
+    program that runs on ONE device.  Mosaic kernels cannot be
+    partitioned automatically (jax refuses them under GSPMD without a
+    ``shard_map``), so under a selected mesh (``mx.sharding.set_mesh``,
+    ``fleet.tp_mesh``) or the implicit 'dp' mesh of a multi-context
+    bind the XLA path, which GSPMD does partition, is the one that
+    runs.  Returns ``(ok, why, fallback_reason)``."""
+    import jax
+    from .. import sharding
+    from ..parallel.mesh import current_mesh
+    backend = jax.default_backend()
+    if backend != "tpu":
+        return False, "backend=%s" % backend, "backend"
+    mesh = sharding.get_mesh() or current_mesh()
+    if mesh is not None and mesh.devices.size > 1:
+        return (False, "the program is partitioned over mesh %s and "
+                "Mosaic kernels need a shard_map for that"
+                % dict(mesh.shape), "mesh")
+    return True, "", None
 
 
 def use_paged_pallas(count=True):
     """Trace-time paged-attention impl decision shared by the decode
     and prefill ops (ops/nn.py) and the engine's stats/bench reporting.
-    ``auto`` prefers the Pallas kernels on a TPU backend (where decode
-    is bandwidth-bound on exactly the gather traffic they remove) and
-    the XLA gather path elsewhere; ``MXNET_PAGED_ATTN_IMPL=pallas``
-    forces the kernels anywhere via interpret mode.  ``count=False``
-    suppresses the fallback counter for observer-only calls (stats)."""
-    import jax
-    on_tpu = jax.default_backend() == "tpu"
+    ``auto`` prefers the Pallas kernels where they compile
+    (:func:`_compiles_here`: decode there is bandwidth-bound on exactly
+    the gather traffic they remove) and the XLA gather path elsewhere;
+    ``MXNET_PAGED_ATTN_IMPL=pallas`` forces the kernels — off a TPU in
+    interpret mode.  ``count=False`` suppresses the fallback counter
+    for observer-only calls (stats)."""
+    ok, why, reason = _compiles_here()
     return choose_impl(
         "MXNET_PAGED_ATTN_IMPL",
-        os.environ.get("MXNET_PAGED_ATTN_IMPL", "auto"), "pallas", on_tpu,
-        why="backend=%s; auto uses the compiled kernels only on TPU — "
-            "force 'pallas' to run them in interpret mode anywhere"
-            % jax.default_backend(),
-        force_supported=True, fallback_reason="backend", count=count)
+        os.environ.get("MXNET_PAGED_ATTN_IMPL", "auto"), "pallas", ok,
+        why=why + "; auto uses the compiled kernels only there — force "
+            "'pallas' to run them in interpret mode off a TPU",
+        force_supported=reason != "mesh", fallback_reason=reason,
+        count=count)
 
 
 def paged_attn_impl():
@@ -104,33 +131,32 @@ def paged_attn_impl():
 
 def use_layernorm_pallas(axis_last=True):
     """Impl decision for the fused LayerNorm (+residual) kernel
-    (``MXNET_LN_IMPL``): auto = kernel on TPU when normalizing the
-    LAST axis (the transformer symbol path), forceable anywhere via
-    interpret mode — forcing with a non-last axis still raises, since
-    the kernel's row-tile layout only covers ``axis=-1``."""
-    import jax
-    on_tpu = jax.default_backend() == "tpu"
+    (``MXNET_LN_IMPL``): auto = kernel where it compiles
+    (:func:`_compiles_here`) when normalizing the LAST axis (the
+    transformer symbol path), forceable off a TPU via interpret mode —
+    forcing with a non-last axis still raises, since the kernel's
+    row-tile layout only covers ``axis=-1``."""
+    ok, why, reason = _compiles_here()
     return choose_impl(
         "MXNET_LN_IMPL",
         os.environ.get("MXNET_LN_IMPL", "auto"), "pallas",
-        axis_last and on_tpu,
-        why="backend=%s, axis_last=%s; auto uses the compiled kernel "
-            "only on TPU with axis=-1 — force 'pallas' to run it in "
-            "interpret mode anywhere (axis=-1 still required)"
-            % (jax.default_backend(), axis_last),
-        force_supported=axis_last, fallback_reason="backend")
+        axis_last and ok,
+        why="%s, axis_last=%s; auto uses the compiled kernel only "
+            "there with axis=-1 — force 'pallas' to run it in "
+            "interpret mode off a TPU (axis=-1 still required)"
+            % (why, axis_last),
+        force_supported=axis_last and reason != "mesh",
+        fallback_reason=reason or "axis")
 
 
 def use_q2bit_pallas():
     """Impl decision for the fused 2-bit quantize kernel on the
     kvstore bucket path (``MXNET_Q2BIT_IMPL``): same semantics as the
-    paged knob — auto = kernel on TPU, forceable anywhere (interpret)."""
-    import jax
-    on_tpu = jax.default_backend() == "tpu"
+    paged knob."""
+    ok, why, reason = _compiles_here()
     return choose_impl(
         "MXNET_Q2BIT_IMPL",
-        os.environ.get("MXNET_Q2BIT_IMPL", "auto"), "pallas", on_tpu,
-        why="backend=%s; auto uses the compiled kernel only on TPU — "
-            "force 'pallas' to run it in interpret mode anywhere"
-            % jax.default_backend(),
-        force_supported=True, fallback_reason="backend")
+        os.environ.get("MXNET_Q2BIT_IMPL", "auto"), "pallas", ok,
+        why=why + "; auto uses the compiled kernel only there — force "
+            "'pallas' to run it in interpret mode off a TPU",
+        force_supported=reason != "mesh", fallback_reason=reason)

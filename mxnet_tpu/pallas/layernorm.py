@@ -32,12 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-except Exception:               # pragma: no cover — the pinned
-    pl = None                   # toolchain always ships pallas
+from jax.experimental import pallas as pl
 
-from .attention import _count_launch, _interpret_default
+from .attention import _count_launch
 
 # one (8, C_pad) f32 row tile per grid step: 8 sublanes is the native
 # f32 tile height and a whole (padded) feature row must sit in VMEM for
@@ -216,7 +213,7 @@ _layernorm.defvjp(_layernorm_fwd, _layernorm_bwd_rule)
 
 
 def layernorm_fused(x, gamma, beta, *, residual=None, eps=1e-5,
-                    interpret=None):
+                    interpret=False):
     """Fused LayerNorm over the LAST axis, optionally fused with a
     preceding residual add (``x + residual`` never materializes in
     HBM).  Returns ``(out, mean, inv_std)`` — out in ``x.dtype``,
@@ -228,9 +225,7 @@ def layernorm_fused(x, gamma, beta, *, residual=None, eps=1e-5,
     lead = x.shape[:-1]
     x2 = x.reshape(-1, cols)
     r2 = residual.reshape(-1, cols) if residual is not None else None
-    out, mean, rstd = _layernorm(float(eps),
-                                 bool(_interpret_default(interpret)),
-                                 x2, gamma.reshape(-1), beta.reshape(-1),
-                                 r2)
+    out, mean, rstd = _layernorm(float(eps), bool(interpret), x2,
+                                 gamma.reshape(-1), beta.reshape(-1), r2)
     return (out.reshape(x.shape), mean.reshape(lead),
             rstd.reshape(lead))

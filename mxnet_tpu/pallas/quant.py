@@ -9,20 +9,17 @@ computes both outputs in ONE pass over VMEM tiles — ``acc`` is never
 materialized and each element is read once and written twice, the
 minimum possible traffic for the op pair.  Dispatch rides
 ``MXNET_Q2BIT_IMPL`` through the same ``choose_impl`` contract as the
-attention kernels; off-TPU the wrapper runs ``interpret=True``
-(parity vs the XLA sequence is bit-exact — same select constants,
-same subtract — pinned in tests/test_pallas.py).
+attention kernels; forced off-TPU by that knob it runs
+``interpret=True`` (parity vs the XLA sequence is bit-exact — same
+select constants, same subtract — pinned in tests/test_pallas.py).
 """
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:               # pragma: no cover — the pinned
-    pl = pltpu = None           # toolchain always ships pallas
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .attention import _count_launch, _interpret_default
+from .attention import _count_launch
 
 # one (rows, 128) f32 tile per grid step — 8 sublanes x 128 lanes is
 # the native f32 VMEM tile; 64 rows keeps the working set tiny while
@@ -41,7 +38,8 @@ def _two_bit_quantize_kernel(thr_ref, res_ref, grad_ref, q_ref,
     out_res_ref[...] = acc - q
 
 
-def two_bit_quantize_fused(residual, grad, threshold, *, interpret=None):
+def two_bit_quantize_fused(residual, grad, threshold, *,
+                           interpret=False):
     """Error-feedback 2-bit quantize, one fused pass: returns
     ``(q, new_residual)`` with the exact op sequence (and therefore
     bit pattern) of ``kvstore_fused.two_bit_quantize``.  Accepts any
@@ -72,7 +70,7 @@ def two_bit_quantize_fused(residual, grad, threshold, *, interpret=None):
             num_scalar_prefetch=1, grid=grid,
             in_specs=[spec, spec], out_specs=[spec, spec]),
         out_shape=[jax.ShapeDtypeStruct((rows_pad, cols), dtype)] * 2,
-        interpret=_interpret_default(interpret),
+        interpret=interpret,
     )
     q, new_res = fn(thr, tile(residual), tile(grad))
     return (q.reshape(-1)[:n].reshape(shape),

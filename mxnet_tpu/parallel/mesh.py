@@ -33,10 +33,12 @@ def make_mesh(axis_sizes: dict, devices=None) -> Mesh:
     return mesh
 
 
-def data_parallel_mesh(contexts) -> Mesh:
-    """Mesh with a single 'dp' axis over the given Contexts."""
+def data_parallel_mesh(contexts):
+    """Mesh with a single 'dp' axis over the given Contexts; None for
+    one Context (a one-device program has no mesh — and the registry
+    says so, since kernel selection asks it)."""
     devs = [c.jax_device for c in contexts]
-    mesh = Mesh(_np.array(devs), ("dp",))
+    mesh = Mesh(_np.array(devs), ("dp",)) if len(devs) > 1 else None
     _CURRENT["mesh"] = mesh
     return mesh
 

@@ -33,14 +33,6 @@ from jax import lax
 __all__ = ["pipeline_apply", "stack_stage_params"]
 
 
-def _shard_map():
-    try:
-        return jax.shard_map          # jax >= 0.8
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-
-
 def stack_stage_params(stage_params):
     """Stack a list of S per-stage parameter pytrees into one pytree
     whose leaves carry a leading stage axis (to shard over ``pp``)."""
@@ -105,9 +97,8 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, n_microbatches,
         # constants start device-invariant; mark them varying over every
         # sharded axis so the scan carry types line up (shard_map vma)
         vary_axes = tuple(a for a in (batch_axis, axis) if a)
-        if hasattr(lax, "pcast"):
-            cur0, outs0 = (lax.pcast(v, vary_axes, to="varying")
-                           for v in (cur0, outs0))
+        cur0, outs0 = (lax.pcast(v, vary_axes, to="varying")
+                       for v in (cur0, outs0))
         perm = [(i, (i + 1) % S) for i in range(S)]
 
         def tick(carry, t):
@@ -137,6 +128,6 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, n_microbatches,
         return outs.reshape((M * mshape[0],) + mshape[1:])
 
     in_specs = (param_spec, x_spec)
-    fn = _shard_map()(local, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                       out_specs=out_spec)
     return fn(stacked_params, x)

@@ -35,23 +35,11 @@ def _shard_map():
     (``src <= rank``) is device-varying; both branches produce values
     varying over the same mesh axes, but the static rep/vma checker
     cannot type a varying-predicate cond and rejects the (correct)
-    program — jax's own error message prescribes ``check_rep=False``
+    program — jax's own error message prescribes ``check_vma=False``
     as the workaround.  Gradient parity against the single-device
     oracle is pinned by tests/test_ring_attention.py."""
     import functools
-    import inspect
-    try:
-        sm = jax.shard_map              # jax >= 0.8
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        params = ()
-    for kw in ("check_rep", "check_vma"):   # renamed across versions
-        if kw in params:
-            return functools.partial(sm, **{kw: False})
-    return sm
+    return functools.partial(jax.shard_map, check_vma=False)
 
 
 def attention_reference(q, k, v, causal=False, scale=None):
@@ -114,9 +102,8 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, scale=None,
         # constants start device-invariant; mark them varying over every
         # sharded axis so the scan carry types line up (shard_map vma)
         vary_axes = tuple(a for a in (batch_axis, axis) if a)
-        if hasattr(lax, "pcast"):
-            o0, l0, m0 = (lax.pcast(x, vary_axes, to="varying")
-                          for x in (o0, l0, m0))
+        o0, l0, m0 = (lax.pcast(x, vary_axes, to="varying")
+                      for x in (o0, l0, m0))
         perm = [(j, (j - 1) % n) for j in range(n)]
 
         # block 0 is local — no rotation; iterations 1..n-1 rotate THEN

@@ -327,8 +327,8 @@ class TrainStep:
         Zero/One/Constant + the standard name-suffix rules) generate
         directly on the accelerator with XLA's RNG — no host->device
         weight transfer at all (the reference always inits on cpu and
-        copies, module.py:270; for multi-GB models over PCIe/tunnel the
-        device path is the difference between seconds and minutes).
+        copies, module.py:270; for multi-GB models over PCIe the device
+        path is the difference between seconds and minutes).
         Everything else falls back to the host initializer."""
         from ..initializer import InitDesc
         from ..ndarray.ndarray import NDArray
@@ -396,9 +396,8 @@ class TrainStep:
                     optimizer, idx[n], n, params[n], g, states[n], lr)
             return new_params, new_states, new_auxs, outs
 
-        from ..aot.store import safe_donate_argnums as _donate
         if self._mesh is None:
-            return jax.jit(step_fn, donate_argnums=_donate((0, 1, 2)))
+            return jax.jit(step_fn, donate_argnums=(0, 1, 2))
 
         param_sh = {n: self._param_sharding(n) for n in param_names}
         state_sh = {n: tuple(param_sh[n] for _ in self.states[n])
@@ -410,7 +409,7 @@ class TrainStep:
             step_fn,
             in_shardings=(param_sh, state_sh, aux_sh, batch_sh, repl, repl),
             out_shardings=(param_sh, state_sh, aux_sh, None),
-            donate_argnums=_donate((0, 1, 2)))
+            donate_argnums=(0, 1, 2))
 
     def step(self, batch):
         """Run one training step; ``batch`` maps input name → array.

@@ -12,10 +12,11 @@ kernel language:
                        grid=(blocks,))
     y = k.launch([a, x], mx.tpu(0))
 
-A kernel body takes ``(*input_refs, out_ref)`` pallas Refs. On
-non-TPU backends kernels run in pallas interpret mode, so the same code
-tests on CPU. ``CudaModule`` raises with guidance — CUDA source cannot
-target a TPU.
+A kernel body takes ``(*input_refs, out_ref)`` pallas Refs.  Kernels
+compile for the backend they launch on; ``get_kernel(...,
+interpret=True)`` runs one in pallas interpret mode instead, so the
+same code tests on CPU.  ``CudaModule`` raises with guidance — CUDA
+source cannot target a TPU.
 """
 from __future__ import annotations
 
@@ -56,9 +57,6 @@ class PallasKernel:
             from jax.experimental import pallas as pl
             import jax.numpy as jnp
 
-            interpret = self._interpret
-            if interpret is None:
-                interpret = jax.default_backend() != "tpu"
             kwargs = {}
             if self._grid is not None:
                 kwargs["grid"] = self._grid
@@ -71,7 +69,7 @@ class PallasKernel:
                 self._body,
                 out_shape=jax.ShapeDtypeStruct(self._out_shape,
                                                jnp.dtype(self._out_dtype)),
-                interpret=interpret, **kwargs)
+                interpret=self._interpret, **kwargs)
             # analyze: ok(retrace) user-authored RTC kernel compiles once per CudaKernel construction (the reference's nvrtc contract)
             self._compiled = jax.jit(call)
         return self._compiled
@@ -102,9 +100,11 @@ class PallasModule:
         self._kernels = dict(kernels)
 
     def get_kernel(self, name, out_shape, out_dtype="float32", grid=None,
-                   in_specs=None, out_specs=None, interpret=None):
+                   in_specs=None, out_specs=None, interpret=False):
         """Bind a kernel body to output shape/dtype (+ optional pallas
-        grid/BlockSpecs); mirrors CudaModule.get_kernel(name, signature)."""
+        grid/BlockSpecs); mirrors CudaModule.get_kernel(name, signature).
+        ``interpret=True`` emulates the kernel (any backend) instead of
+        compiling it — never inferred from where the process runs."""
         if name not in self._kernels:
             raise MXNetError("no kernel '%s' in module (have %s)"
                              % (name, sorted(self._kernels)))

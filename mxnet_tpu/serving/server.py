@@ -273,9 +273,10 @@ class ModelServer:
         across (replica, bucket) pairs; MXNET_AOT_WARMUP_THREADS)
     warmup_manifest : AOT manifest path or dict (mx.aot.capture) — warm
         only the buckets a previous process actually served, marking
-        their programs ``warmed`` in telemetry.programs(); with
-        MXNET_COMPILE_CACHE_DIR set the warmup disk-loads instead of
-        compiling (docs/AOT.md).  Default: the MXNET_AOT_MANIFEST knob.
+        their programs ``warmed`` in telemetry.programs(); on a
+        restart the warmup disk-loads from the persistent compile cache
+        instead of compiling (docs/AOT.md).  Default: the
+        MXNET_AOT_MANIFEST knob.
     """
 
     def __init__(self, symbol, arg_params, aux_params, input_shapes,
@@ -580,7 +581,7 @@ class ModelServer:
         return m
 
     def aot_warm(self, manifest=None):
-        """Compile (or, with MXNET_COMPILE_CACHE_DIR, disk-load) every
+        """Compile (or, on a restart, disk-load from the cache) every
         (replica, bucket) program BEFORE the server accepts traffic —
         the mx.aot warmup hook (docs/AOT.md).  ``manifest`` defaults to
         the server's ``warmup_manifest``; programs dispatched here are

@@ -151,6 +151,7 @@ def set_mesh(axes=None, devices=None):
     if axes is None:
         _STATE["mesh"] = None
         _STATE["env_checked"] = True       # explicit clear beats the env
+        _mesh_mod._CURRENT["mesh"] = None
         return None
     if isinstance(axes, Mesh):
         mesh = axes

@@ -28,8 +28,8 @@ Exported surfaces: ``telemetry.programs()`` (list of dicts),
 ``top_programs(k)`` (by FLOPs — the flight-dump table),
 ``mfu_measured(flops_per_step, seconds)`` (gauge ``mfu_measured``:
 compiler-reported model FLOP/s over the chip's peak), and
-``peak_tflops()`` — the one device-kind → peak-bf16-TFLOP/s table,
-shared with bench.py.
+``peak_tflops()`` over ``PEAKS`` — the one table of published peaks,
+keyed by exact ``device_kind`` and shared with bench.py.
 """
 from __future__ import annotations
 
@@ -55,16 +55,14 @@ MFU_MEASURED = REGISTRY.gauge(
     "FLOPs (cost_analysis) over the chip's peak bf16 throughput — the "
     "measured counterpart of bench.py's hand-math `mfu`", unit="ratio")
 
-# Peak bf16 TFLOP/s per chip, keyed by substrings of jax device_kind —
-# the ONE table (bench.py imports it; keep in sync with vendor specs)
-PEAK_TFLOPS_TABLE = (
-    ("v6", 918.0),      # Trillium
-    ("v5p", 459.0),
-    ("v5", 197.0),      # v5e / "v5 lite"
-    ("v4", 275.0),
-    ("v3", 123.0),
-    ("v2", 45.0),
-)
+# Published peaks per chip, keyed by the EXACT jax ``device_kind`` — the
+# ONE table (bench.py reads it).  A TPU that is not here is an error
+# where a utilisation is computed, never a neighbour's number.
+# Source: Google Cloud TPU documentation, "TPU v5e" (system
+# architecture): 197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s per chip.
+PEAKS = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
+}
 
 _lock = threading.Lock()
 _programs = {}          # key -> entry dict
@@ -119,19 +117,21 @@ def note_donation(fn, argnums):
 
 
 def peak_tflops(device_kind=None):
-    """Peak bf16 TFLOP/s for ``device_kind`` (default: device 0); None
-    for chips not in the table (CPU containers)."""
+    """Peak bf16 TFLOP/s for ``device_kind`` (default: device 0).
+    None on the CPU, which has no published peak; an accelerator that
+    is not in :data:`PEAKS` raises ``KeyError``."""
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return None
-    kind = str(device_kind).lower()
-    for key, peak in PEAK_TFLOPS_TABLE:
-        if key in kind:
-            return peak
-    return None
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    if str(device_kind).lower() == "cpu":
+        return None
+    if device_kind not in PEAKS:
+        raise KeyError(
+            "no published peak for device_kind %r in "
+            "telemetry.programs.PEAKS (have %s); add the chip with its "
+            "source before computing a utilisation on it"
+            % (device_kind, sorted(PEAKS)))
+    return PEAKS[device_kind]["bf16_tflops"]
 
 
 def _abstractify(args):
@@ -436,9 +436,9 @@ def top_programs(k=5, analyze=False, by="flops"):
 
 def mfu_measured(flops_per_step, seconds_per_step, device_kind=None):
     """Set (and return) the ``mfu_measured`` gauge from compiler-
-    reported FLOPs: ``flops/s / peak``.  None (gauge untouched) when
-    the chip has no known peak (CPU containers) or inputs are
-    missing."""
+    reported FLOPs: ``flops/s / peak``.  None (gauge untouched) on
+    the CPU or when inputs are missing; an accelerator without a
+    published peak raises (``peak_tflops``)."""
     if not flops_per_step or not seconds_per_step:
         return None
     peak = peak_tflops(device_kind)

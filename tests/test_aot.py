@@ -2,7 +2,7 @@
 
 Covers the zero-cold-start contract (docs/AOT.md): manifest capture ->
 warm round-trips in the same process AND across a real process restart
-(subprocess arms share MXNET_COMPILE_CACHE_DIR); a persistent-cache hit
+(subprocess arms share JAX_COMPILATION_CACHE_DIR); a persistent-cache hit
 serves the bit-identical program while booking ``aot_cache_hits``; a
 corrupted index or cache entry falls back to a fresh compile instead of
 failing the deploy; ModelServer construction warms every bucket through
@@ -66,9 +66,13 @@ def _run_py(code, env_extra=None, timeout=300):
     cover compile options, so a config fork turns hits into misses)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
-    env.pop("MXNET_COMPILE_CACHE_DIR", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop("MXNET_AOT_MANIFEST", None)
     env.update(env_extra or {})
+    if "JAX_COMPILATION_CACHE_DIR" not in env:
+        # the package's cache is on by default (<checkout>/.jax_cache);
+        # an arm that is given no directory runs with jax's cache off
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "0"
     proc = subprocess.run([sys.executable, "-c", MODEL_SRC + code],
                           env=env, capture_output=True, text=True,
                           timeout=timeout, cwd=ROOT)
@@ -299,7 +303,7 @@ def test_manifest_subprocess_restart(tmp_path):
     manifest = str(tmp_path / "model.aot.json")
     cache = str(tmp_path / "cache")
     seed = _run_py(_SEED % {"manifest": manifest},
-                   {"MXNET_COMPILE_CACHE_DIR": cache})
+                   {"JAX_COMPILATION_CACHE_DIR": cache})
     assert seed["misses"] > 0                # seed populated the cache
     # restart WITHOUT the cache: warmup compiles, first request doesn't
     warm = _run_py(_RESTART % {"manifest": manifest})
@@ -308,7 +312,7 @@ def test_manifest_subprocess_restart(tmp_path):
     assert warm["cache_hits"] == 0
     # restart WITH the cache: same contract plus disk-loads
     cached = _run_py(_RESTART % {"manifest": manifest},
-                     {"MXNET_COMPILE_CACHE_DIR": cache})
+                     {"JAX_COMPILATION_CACHE_DIR": cache})
     assert cached["warmed_programs"] == 3
     assert cached["first_request_retraces"] == 0
     assert cached["cache_hits"] > 0
@@ -323,7 +327,7 @@ def test_corrupt_cache_entry_falls_back(tmp_path):
     manifest = str(tmp_path / "model.aot.json")
     cache = str(tmp_path / "cache")
     _run_py(_SEED % {"manifest": manifest},
-            {"MXNET_COMPILE_CACHE_DIR": cache})
+            {"JAX_COMPILATION_CACHE_DIR": cache})
     corrupted = 0
     for dirpath, _, files in os.walk(cache):
         for name in files:
@@ -335,36 +339,49 @@ def test_corrupt_cache_entry_falls_back(tmp_path):
             corrupted += 1
     assert corrupted > 0
     out = _run_py(_RESTART % {"manifest": manifest},
-                  {"MXNET_COMPILE_CACHE_DIR": cache})
+                  {"JAX_COMPILATION_CACHE_DIR": cache})
     assert out["first_request_retraces"] == 0
     reference = _run_py(_RESTART % {"manifest": manifest})
     assert out["output"] == reference["output"]
 
 
 # ----------------------------------------------------------------------
-# donation guard: the persistent cache must never serve donated programs
-# (jax 0.4.37 deserialized executables mishandle input/output aliasing —
-# wrong results/NaN/crash on CPU and TPU; see aot.store.donation_safe)
+# donation under the cache: a donated program served from disk computes
+# what the freshly compiled one does (some jax releases did not; the
+# pure-jax reproduction tells on the installed one)
 # ----------------------------------------------------------------------
-def test_donation_guard_under_cache(tmp_path):
-    from mxnet_tpu.aot import store
+def test_donated_program_from_cache_is_bit_equal(tmp_path):
+    def arm(which):
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop("XLA_FLAGS", None)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        proc = subprocess.run(
+            [sys.executable,
+             os.path.join(ROOT, "tests", "donation_cache_worker.py"),
+             str(tmp_path / "cache"), which],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        return json.loads(proc.stdout.splitlines()[-1])
 
-    assert store.donation_safe()
-    assert store.safe_donate_argnums((0, 1, 2)) == (0, 1, 2)
+    truth, seeded, restarted = arm("copy"), arm("donate"), arm("donate")
+    assert seeded["cache_hits"] == 0
+    assert restarted["cache_hits"] > 0       # its step came from disk
+    assert truth["finite"] and restarted["finite"]
+    assert truth["digest"] == seeded["digest"] == restarted["digest"]
+
+
+def test_cache_on_keeps_donation(tmp_path):
+    """With the persistent cache on, program builders still donate."""
     aot.enable_persistent_cache(str(tmp_path / "cache"))
     try:
-        assert not store.donation_safe()
-        assert store.safe_donate_argnums((0, 1, 2)) == ()
-        # the executor's donated inference forward refuses too
         sym, params = _ns["build"]()
         exe = sym.simple_bind(mx.cpu(), data=(1, 12))
         for n, v in params.items():
             exe.arg_dict[n][:] = v
-        assert exe.donate_args(["fc1_weight"]) is False
-        assert exe._jit_fwd_eval_donated is None
+        assert exe.donate_args(["fc1_weight"]) is True
+        assert exe._jit_fwd_eval_donated is not None
     finally:
         aot.disable_persistent_cache()
-    assert store.donation_safe()
 
 
 _FIT = r'''
@@ -395,15 +412,13 @@ print(json.dumps({"hash": h.hexdigest(),
 
 
 def test_fit_restart_cache_bitidentical(tmp_path):
-    """Training correctness across a cached restart — the regression
-    that motivated the guard: a fused-fit run whose programs disk-load
-    must produce the EXACT weights of a cache-less run.  (Without the
-    guard the donated fit step executes from a deserialized executable
-    and corrupts its buffers from step 2.)"""
+    """Training correctness across a cached restart: a fused-fit run
+    whose DONATED programs disk-load must produce the EXACT weights of
+    a cache-less run."""
     cache = str(tmp_path / "cache")
     truth = _run_py(_FIT)
-    seeded = _run_py(_FIT, {"MXNET_COMPILE_CACHE_DIR": cache})
-    restarted = _run_py(_FIT, {"MXNET_COMPILE_CACHE_DIR": cache})
+    seeded = _run_py(_FIT, {"JAX_COMPILATION_CACHE_DIR": cache})
+    restarted = _run_py(_FIT, {"JAX_COMPILATION_CACHE_DIR": cache})
     assert seeded["misses"] > 0              # first cached run populates
     assert restarted["misses"] == 0          # restart is all disk-loads
     assert restarted["hits"] > 0

@@ -1,0 +1,124 @@
+"""The chip's compiler on the main path's kernels, without the chip.
+
+libtpu is installed here, and it compiles for a TPU that is described
+and not attached (``on-chip-measurement`` guide, section 2, third
+rehearsal).  Interpret-mode parity (tests/test_pallas.py) says a kernel
+computes the right thing; only Mosaic says whether it will START on the
+chip — every paged kernel passed every interpret-mode test for eight PRs
+while the chip's compiler refused all three (CHANGES.md, PR 21).  So the
+kernels ``auto`` selects on a TPU are compiled here at the widths
+chip_smoke.py runs them at: H16 D128, block 16, capacity 32, chunk 64,
+d2048, S1024.  A compile that passes is not a chip run and says nothing
+about results or times.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: one process at a time may load libtpu, xdist workers all import
+every test file, and only the worker that RUNS this file may load it.
+Compiles happen in the test's own process for the same reason.
+"""
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+H, D, BS, C, M, K, NB = 16, 128, 16, 32, 64, 64, 256
+SCALE = D ** -0.5
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — no libtpu, or it is held
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *specs):
+    """Compile ``fn`` for the described chip over (shape, dtype) specs;
+    the Mosaic kernel must be IN the program, not merely accepted."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+    # jax's own matmul precision, as on the chip: conftest pins float32
+    # for CPU parity tests, which is not what a deployment compiles
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_decode_attend_compiles(one_chip, dtype):
+    from mxnet_tpu.pallas import paged_decode_attend
+    cache = ((NB, BS, H, D), dtype)
+    _compile(functools.partial(paged_decode_attend, scale=SCALE), one_chip,
+             ((C, H, D), dtype), cache, cache,
+             ((C, M), jnp.int32), ((C,), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_chunk_prefill_attend_compiles(one_chip, dtype):
+    """The engine's one mixed step: a 64-token chunk of one prompt."""
+    from mxnet_tpu.pallas import paged_chunk_prefill_attend
+    chunk, cache = ((1, K, H, D), dtype), ((NB, BS, H, D), dtype)
+    _compile(functools.partial(paged_chunk_prefill_attend, scale=SCALE),
+             one_chip, chunk, chunk, chunk, cache, cache,
+             ((1, M), jnp.int32), ((1,), jnp.int32), ((1,), jnp.int32))
+
+
+def test_paged_chunk_prefill_attend_compiles_as_spec_verify(one_chip):
+    """The same kernel as the speculative step calls it: one short
+    span (spec_k 4 + 1 rows) per slot."""
+    from mxnet_tpu.pallas import paged_chunk_prefill_attend
+    dtype = jnp.bfloat16
+    span, cache = ((C, 5, H, D), dtype), ((NB, BS, H, D), dtype)
+    _compile(functools.partial(paged_chunk_prefill_attend, scale=SCALE),
+             one_chip, span, span, span, cache, cache,
+             ((C, M), jnp.int32), ((C,), jnp.int32), ((C,), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_prefill_attend_compiles(one_chip, dtype):
+    from mxnet_tpu.pallas import paged_prefill_attend
+    rows, cache = ((4, 256, H, D), dtype), ((NB, BS, H, D), dtype)
+    _compile(functools.partial(paged_prefill_attend, scale=SCALE),
+             one_chip, rows, rows, rows, cache, cache,
+             ((4, M), jnp.int32), ((4,), jnp.int32))
+
+
+@pytest.mark.parametrize("with_residual", [False, True])
+def test_layernorm_fused_fwd_bwd_compiles(one_chip, with_residual):
+    from mxnet_tpu.pallas import layernorm_fused
+
+    def loss(x, g, b, res):
+        out, _, _ = layernorm_fused(x, g, b,
+                                    residual=res if with_residual else None)
+        return out.astype(jnp.float32).sum()
+
+    x = ((4, 1024, 2048), jnp.bfloat16)
+    vec = ((2048,), jnp.float32)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)
+                                if with_residual else (0, 1, 2)),
+             one_chip, x, vec, vec, x)
+
+
+def test_two_bit_quantize_fused_compiles(one_chip):
+    from mxnet_tpu.pallas import two_bit_quantize_fused
+    g = ((2048, 8192), jnp.float32)
+    _compile(lambda r, x: two_bit_quantize_fused(r, x, 0.5), one_chip, g, g)
+
+
+def test_flash_attention_fwd_grad_compiles(one_chip):
+    """The library flash kernel at the 512-blocks ops/nn.py picks."""
+    from mxnet_tpu.ops.nn import _flash_attention
+
+    def loss(q, k, v):
+        return _flash_attention(q, k, v, SCALE).astype(jnp.float32).sum()
+
+    qkv = ((4, H, 1024, D), jnp.bfloat16)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+             qkv, qkv, qkv)

@@ -82,7 +82,8 @@ def test_decode_kernel_parity_matrix(bs, H, D):
     pos = np.array([bs - 2, 3 * bs + 1, -1, M * bs - 1], np.int32)
     sc = 1.0 / np.sqrt(D)
     out = paged_decode_attend(q, kc, vc, jnp.asarray(table),
-                              jnp.asarray(pos), scale=sc)
+                              jnp.asarray(pos), scale=sc,
+                              interpret=True)
     ref = _decode_reference(q, kc, vc, table, pos, sc)
     active = pos >= 0
     np.testing.assert_allclose(np.asarray(out)[active], ref[active],
@@ -102,7 +103,8 @@ def test_decode_kernel_bf16_cache():
     pos = np.array([2 * bs, bs - 1], np.int32)
     sc = 1.0 / np.sqrt(D)
     out = paged_decode_attend(q, kc, vc, jnp.asarray(table),
-                              jnp.asarray(pos), scale=sc)
+                              jnp.asarray(pos), scale=sc,
+                              interpret=True)
     ref = _decode_reference(q, np.asarray(kc, np.float32),
                             np.asarray(vc, np.float32), table, pos, sc)
     assert out.dtype == q.dtype
@@ -132,7 +134,8 @@ def test_prefill_kernel_parity_and_scatter(bs, S):
     L = np.array([S, max(1, S - bs - 1)], np.int32)
     sc = 1.0 / np.sqrt(D)
     out, ko, vo = paged_prefill_attend(
-        q, k, v, kc, vc, jnp.asarray(table), jnp.asarray(L), scale=sc)
+        q, k, v, kc, vc, jnp.asarray(table), jnp.asarray(L), scale=sc,
+        interpret=True)
 
     # attention reference: plain causal softmax, seq-major
     s = np.einsum("bqhe,bkhe->bhqk", np.asarray(q), np.asarray(k)) * sc
@@ -179,7 +182,7 @@ def test_chunk_prefill_kernel_parity_with_unchunked(bs, S, K):
     sc = 1.0 / np.sqrt(D)
     ref_o, ref_k, ref_v = paged_prefill_attend(
         q, k, v, kc, vc, jnp.asarray(table),
-        jnp.asarray([S], jnp.int32), scale=sc)
+        jnp.asarray([S], jnp.int32), scale=sc, interpret=True)
     kcur, vcur = kc, vc
     outs = []
     st = 0
@@ -194,7 +197,7 @@ def test_chunk_prefill_kernel_parity_with_unchunked(bs, S, K):
         o, kcur, vcur = paged_chunk_prefill_attend(
             qp, kp, vp, kcur, vcur, jnp.asarray(table),
             jnp.asarray([st], jnp.int32), jnp.asarray([L], jnp.int32),
-            scale=sc)
+            scale=sc, interpret=True)
         outs.append(np.asarray(o)[:, :L])
         st += L
     np.testing.assert_array_equal(np.asarray(ref_k), np.asarray(kcur))
@@ -214,7 +217,7 @@ def test_chunk_prefill_kernel_zero_length_is_noop():
     table = jnp.zeros((B, M), jnp.int32)
     _, ko, vo = paged_chunk_prefill_attend(
         z, z, z, kc, vc, table, jnp.asarray([0], jnp.int32),
-        jnp.asarray([0], jnp.int32), scale=0.5)
+        jnp.asarray([0], jnp.int32), scale=0.5, interpret=True)
     np.testing.assert_array_equal(np.asarray(kc), np.asarray(ko))
     np.testing.assert_array_equal(np.asarray(vc), np.asarray(vo))
 
@@ -227,7 +230,8 @@ def test_prefill_kernel_rejects_short_table():
     table = jnp.zeros((B, 2), jnp.int32)            # needs 4 blocks
     with pytest.raises(ValueError, match="block_table"):
         paged_prefill_attend(a, a, a, kc, kc, table,
-                             jnp.asarray([S], jnp.int32), scale=0.5)
+                             jnp.asarray([S], jnp.int32), scale=0.5,
+                             interpret=True)
 
 
 # ----------------------------------------------------------------------
@@ -347,12 +351,16 @@ def test_choose_impl_semantics():
     # xla always wins, even when supported
     assert choose_impl("MXNET_X", "xla", "pallas", True, why="w") is False
     # auto follows `supported`
-    assert choose_impl("MXNET_X", "auto", "pallas", True, why="w") is True
+    assert choose_impl("MXNET_X", "auto", "pallas", True,
+                       why="w") == "compiled"
     assert choose_impl("MXNET_X", "auto", "pallas", False, why="w",
                        count=False) is False
-    # forcing the kernel honors force_supported (interpret mode)
+    # forcing the kernel honors force_supported, and is the one way
+    # to interpret mode: never where the kernel compiles, never on auto
     assert choose_impl("MXNET_X", "pallas", "pallas", False, why="w",
-                       force_supported=True) is True
+                       force_supported=True) == "interpret"
+    assert choose_impl("MXNET_X", "pallas", "pallas", True, why="w",
+                       force_supported=True) == "compiled"
     with pytest.raises(ValueError, match="cannot run here"):
         choose_impl("MXNET_X", "pallas", "pallas", False, why="w")
     with pytest.raises(ValueError, match=r"use auto\|pallas\|xla"):
@@ -374,7 +382,7 @@ def test_flash_and_paged_knobs_share_one_contract(monkeypatch):
     with pytest.raises(ValueError, match="cannot run here"):
         _use_flash_attention(512, 128, jnp.float32)
     monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "pallas")
-    assert use_paged_pallas() is True
+    assert use_paged_pallas() == "interpret"
     monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "xla")
     assert use_paged_pallas() is False
     monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "bogus")
@@ -400,7 +408,8 @@ def test_fallback_counter_and_launch_witnesses(monkeypatch):
     paged_decode_attend(_rand(rng, 1, 2, 4), _rand(rng, 2, 4, 2, 4),
                         _rand(rng, 2, 4, 2, 4),
                         jnp.zeros((1, 2), jnp.int32),
-                        jnp.asarray([3], jnp.int32), scale=0.5)
+                        jnp.asarray([3], jnp.int32), scale=0.5,
+                        interpret=True)
     assert lc.value == lb + 1
 
 
@@ -418,7 +427,7 @@ def test_two_bit_quantize_kernel_bit_exact(monkeypatch):
         grad = _rand(rng, *shape)
         monkeypatch.setenv("MXNET_Q2BIT_IMPL", "xla")
         q_ref, r_ref = two_bit_quantize(res, grad, 0.5)
-        q_k, r_k = two_bit_quantize_fused(res, grad, 0.5)
+        q_k, r_k = two_bit_quantize_fused(res, grad, 0.5, interpret=True)
         np.testing.assert_array_equal(np.asarray(q_k), np.asarray(q_ref))
         np.testing.assert_array_equal(np.asarray(r_k), np.asarray(r_ref))
         monkeypatch.setenv("MXNET_Q2BIT_IMPL", "pallas")
@@ -637,7 +646,7 @@ def test_layernorm_knob_contract(monkeypatch):
     monkeypatch.setenv("MXNET_LN_IMPL", "auto")
     assert use_layernorm_pallas(True) is False      # CPU container
     monkeypatch.setenv("MXNET_LN_IMPL", "pallas")
-    assert use_layernorm_pallas(True) is True       # interpret mode
+    assert use_layernorm_pallas(True) == "interpret"
     with pytest.raises(ValueError, match="cannot run here"):
         use_layernorm_pallas(False)                 # axis != -1
     monkeypatch.setenv("MXNET_LN_IMPL", "bogus")

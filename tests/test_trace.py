@@ -377,7 +377,7 @@ def test_top_programs_and_flight_table(tmp_path):
 def test_mfu_measured_gauge():
     from mxnet_tpu.telemetry import programs as programs_mod
     assert programs_mod.peak_tflops("TPU v5 lite") == 197.0
-    assert programs_mod.peak_tflops("weird-chip") is None
+    assert programs_mod.peak_tflops("cpu") is None
     got = programs_mod.mfu_measured(197e12 * 0.5, 1.0, "TPU v5 lite")
     assert got == pytest.approx(0.5)
     assert telemetry.REGISTRY.get("mfu_measured").value \

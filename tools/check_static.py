@@ -19,9 +19,9 @@ Usage:
     python tools/check_static.py --list-passes
     python tools/check_static.py --show-waived   # baseline as text
 
-Stdlib-only: imports the analyzer with the package DIRECTORY on
-sys.path (``import analyze``), so neither jax nor the mxnet_tpu
-runtime is ever imported — safe and <15 s as a tier-1 subprocess on a
+Stdlib-only: loads the analyzer sub-package alone, by path
+(tools/_analyze.py), so neither jax nor the mxnet_tpu runtime is ever
+imported — safe and <15 s as a tier-1 subprocess on a
 1-core container.
 """
 import argparse
@@ -31,10 +31,9 @@ import os
 import subprocess
 import sys
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "mxnet_tpu"))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import analyze                                    # noqa: E402
+from _analyze import ROOT, analyze                # noqa: E402
 from analyze import envknobs as _envknobs         # noqa: E402
 
 BASELINE = os.path.join(ROOT, "tools", "static_baseline.json")

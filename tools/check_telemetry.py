@@ -17,10 +17,9 @@ subprocess inside the test suite).
 import os
 import sys
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "mxnet_tpu"))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import analyze                                    # noqa: E402
+from _analyze import ROOT, analyze                # noqa: E402
 from analyze.telemetry import TelemetryPass       # noqa: E402
 
 
