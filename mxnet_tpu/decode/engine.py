@@ -512,7 +512,8 @@ class DecodeEngine:
             if drained:
                 return
             try:
-                worked = self._tick()
+                with _tracing.span("decode.tick"):
+                    worked = self._tick()
             except Exception as exc:   # noqa: BLE001 — engine must survive
                 self._fail_everything(exc)
                 continue
@@ -579,7 +580,8 @@ class DecodeEngine:
                 QUEUE_DEPTH.set(len(self._sched.waiting))
             slot = self._sched.free_slot()
             try:
-                self._admit(seq, slot)
+                with _tracing.span("decode.admit"):
+                    self._admit(seq, slot)
                 progressed = True
             except Exception as exc:   # noqa: BLE001 — the sequence is
                 # already off the wait queue and may not be placed yet,
@@ -756,7 +758,7 @@ class DecodeEngine:
             cstart[0] = s0
             clen[0] = chunk_len
             ctable[0, :len(chunk_seq.blocks)] = chunk_seq.blocks
-        with self._step_lock:
+        with _tracing.span("decode.step"), self._step_lock:
             outs, dd = self._dispatch(
                 self._exe, "mixed", data=data, positions=pos,
                 block_table=table, chunk_data=cdata,
@@ -768,34 +770,35 @@ class DecodeEngine:
         self._occ_sum += len(active)
         self._cache_occ_sum += self.cache.occupancy
         STEPS.inc()
-        if chunk_seq is not None:
-            self._advance_chunk(chunk_seq, chunk_len, outs)
-        # ONE host copy of the (capacity, vocab) logits per step, shared
-        # by every sampling/temperature/collect_logits sequence (rows
-        # are per-slot, so a misbehaving user sampler can only touch its
-        # own row)
-        logits_host = None
-        if any(self._needs_logits(s) for _, s in decoding):
-            # analyze: ok(hostsync) the step's ONE logits readback, shared by every sampling/temperature slot (documented in the module doc)
-            logits_host = outs[0].asnumpy()
-        # likewise ONE readback of the greedy-token output for the
-        # whole step, not one per active slot
-        next_host = None
-        if decoding:
-            # analyze: ok(hostsync) the greedy-token readback IS the streamed response — the documented one sync per decode iteration
-            next_host = outs[1].asnumpy()
-        for slot, seq in decoding:
-            seq.pos += 1
-            self._n_slot_iters += 1
-            try:
-                tok = self._pick_token(seq, outs, slot, logits_host,
-                                       next_host)
-            except Exception as exc:   # noqa: BLE001 — user sampler;
-                self._finish(seq, error=exc)   # contain to this stream
-                continue
-            self._n_slot_tokens += 1
-            self._emit(seq, tok)
-            self._maybe_finish(seq, tok)
+        with _tracing.span("decode.emit"):
+            if chunk_seq is not None:
+                self._advance_chunk(chunk_seq, chunk_len, outs)
+            # ONE host copy of the (capacity, vocab) logits per step, shared
+            # by every sampling/temperature/collect_logits sequence (rows
+            # are per-slot, so a misbehaving user sampler can only touch its
+            # own row)
+            logits_host = None
+            if any(self._needs_logits(s) for _, s in decoding):
+                # analyze: ok(hostsync) the step's ONE logits readback, shared by every sampling/temperature slot (documented in the module doc)
+                logits_host = outs[0].asnumpy()
+            # likewise ONE readback of the greedy-token output for the
+            # whole step, not one per active slot
+            next_host = None
+            if decoding:
+                # analyze: ok(hostsync) the greedy-token readback IS the streamed response — the documented one sync per decode iteration
+                next_host = outs[1].asnumpy()
+            for slot, seq in decoding:
+                seq.pos += 1
+                self._n_slot_iters += 1
+                try:
+                    tok = self._pick_token(seq, outs, slot, logits_host,
+                                           next_host)
+                except Exception as exc:   # noqa: BLE001 — user sampler;
+                    self._finish(seq, error=exc)   # contain to this stream
+                    continue
+                self._n_slot_tokens += 1
+                self._emit(seq, tok)
+                self._maybe_finish(seq, tok)
         if it_spans:
             for sp in it_spans:
                 sp.end()
@@ -942,7 +945,7 @@ class DecodeEngine:
             cstart[0] = s0
             clen[0] = chunk_len
             ctable[0, :len(chunk_seq.blocks)] = chunk_seq.blocks
-        with self._step_lock:
+        with _tracing.span("decode.step"), self._step_lock:
             outs, dd = self._dispatch(
                 self._exe, "spec", data=data, positions=pos,
                 span_start=sstart, span_len=slen, block_table=table,
@@ -954,49 +957,50 @@ class DecodeEngine:
         self._occ_sum += len(active)
         self._cache_occ_sum += self.cache.occupancy
         STEPS.inc()
-        if chunk_seq is not None:
-            self._advance_chunk(chunk_seq, chunk_len, outs)
-        # same readback discipline as the mixed step: ONE logits copy
-        # shared by every sampling slot, ONE greedy-token copy for the
-        # whole step — span rows are (slot * S + j)
-        logits_host = None
-        if any(self._needs_logits(s) for _, s in decoding):
-            # analyze: ok(hostsync) the step's ONE logits readback, shared by every sampling/temperature slot (documented in the module doc)
-            logits_host = outs[0].asnumpy()
-        next_host = None
-        if decoding:
-            # analyze: ok(hostsync) the greedy-token readback IS the streamed response — the documented one sync per decode iteration
-            next_host = outs[1].asnumpy()
-        for slot, seq in decoding:
-            draft = drafts.get(slot, [])
-            L = 1 + len(draft)
-            self._n_slot_iters += 1
-            accepted = 0
-            for j in range(L):
-                # row j is the target's verdict GIVEN span tokens
-                # 0..j; it is reached only while every earlier draft
-                # token matched the target's greedy choice
-                seq.pos += 1
-                try:
-                    tok = self._pick_token(seq, outs, slot * S + j,
-                                           logits_host, next_host)
-                except Exception as exc:   # noqa: BLE001 — user
-                    self._finish(seq, error=exc)   # sampler: contain
-                    break
-                self._n_slot_tokens += 1
-                if j > 0:
-                    accepted += 1
-                self._emit(seq, tok)
-                self._maybe_finish(seq, tok)
-                if seq.slot is None:
-                    break                  # finished mid-span
-                if j < L - 1 and draft[j] != tok:
-                    break                  # tail rejected: cursor stays
-            if accepted:
-                self._n_spec_accepted += accepted
-                SPEC_ACCEPTED.inc(accepted)
-            if draft:
-                self._spec_window.append((len(draft), accepted))
+        with _tracing.span("decode.emit"):
+            if chunk_seq is not None:
+                self._advance_chunk(chunk_seq, chunk_len, outs)
+            # same readback discipline as the mixed step: ONE logits copy
+            # shared by every sampling slot, ONE greedy-token copy for the
+            # whole step — span rows are (slot * S + j)
+            logits_host = None
+            if any(self._needs_logits(s) for _, s in decoding):
+                # analyze: ok(hostsync) the step's ONE logits readback, shared by every sampling/temperature slot (documented in the module doc)
+                logits_host = outs[0].asnumpy()
+            next_host = None
+            if decoding:
+                # analyze: ok(hostsync) the greedy-token readback IS the streamed response — the documented one sync per decode iteration
+                next_host = outs[1].asnumpy()
+            for slot, seq in decoding:
+                draft = drafts.get(slot, [])
+                L = 1 + len(draft)
+                self._n_slot_iters += 1
+                accepted = 0
+                for j in range(L):
+                    # row j is the target's verdict GIVEN span tokens
+                    # 0..j; it is reached only while every earlier draft
+                    # token matched the target's greedy choice
+                    seq.pos += 1
+                    try:
+                        tok = self._pick_token(seq, outs, slot * S + j,
+                                               logits_host, next_host)
+                    except Exception as exc:   # noqa: BLE001 — user
+                        self._finish(seq, error=exc)   # sampler: contain
+                        break
+                    self._n_slot_tokens += 1
+                    if j > 0:
+                        accepted += 1
+                    self._emit(seq, tok)
+                    self._maybe_finish(seq, tok)
+                    if seq.slot is None:
+                        break                  # finished mid-span
+                    if j < L - 1 and draft[j] != tok:
+                        break                  # tail rejected: cursor stays
+                if accepted:
+                    self._n_spec_accepted += accepted
+                    SPEC_ACCEPTED.inc(accepted)
+                if draft:
+                    self._spec_window.append((len(draft), accepted))
         if self._n_spec_proposed:
             ACCEPT_RATE.set(self._n_spec_accepted
                             / float(self._n_spec_proposed))

@@ -27,6 +27,7 @@ from .base import MXNetError
 from .context import Context, current_context
 from .ndarray.ndarray import NDArray, zeros as nd_zeros
 from .ops import registry as _reg
+from . import profiler as _prof
 from . import telemetry as _telemetry
 
 __all__ = ["Executor"]
@@ -61,7 +62,6 @@ _DISPATCH_TALLY = _telemetry.TraceTally()
 def _count_dispatch():
     """Bump the global device-launch witness (profiler.DEVICE_DISPATCHES)
     — bench.py --mode train reads deltas for train_dispatches_per_step."""
-    from . import profiler as _prof
     _prof.DEVICE_DISPATCHES.increment()
     _DISPATCH_TALLY.count += 1
 
@@ -71,6 +71,35 @@ def _timed_dispatch(fn, *args):
     time -> executor_dispatch_ms; calls during which this thread
     (re)traced additionally observe into jit_compile_ms."""
     return _SITE.timed(fn, *args, dispatch_hist=EXECUTOR_DISPATCH_MS)
+
+
+class _dispatch_span:
+    """ONE context around a program dispatch.  It opens the
+    ``telemetry.tracing`` span ``name`` (always an annotation in a
+    running ``jax.profiler`` trace; a ring record when tracing is
+    enabled) and, while ``mx.profiler`` profiles symbolic execution,
+    records its host event under the reference's name ``prof_name``
+    (``Executor::forward``, ...: MXNet's ``profile_symbolic``)."""
+
+    __slots__ = ("_span", "_prof_name", "_scope")
+
+    def __init__(self, name, prof_name):
+        self._span = _telemetry.tracing.span(name)
+        self._prof_name = prof_name
+
+    def __enter__(self):
+        self._scope = _prof.scope(self._prof_name, "symbolic") \
+            if _prof.SYMBOLIC_ON else None
+        if self._scope is not None:
+            self._scope.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        if self._scope is not None:
+            self._scope.__exit__(*exc)
+        return False
 
 
 def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
@@ -96,7 +125,15 @@ def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
     reference's PlaceDevice pass + _CrossDeviceCopy insertion
     (graph_executor.cc:408): one XLA program spanning the devices, with
     transfers exactly at group boundaries, and gradients transferring
-    back through the transposed copies."""
+    back through the transposed copies.
+
+    Every operator node is traced under ``jax.named_scope(<op name>)``
+    then ``jax.named_scope(<node name>)``: an instruction's ``op_name``
+    reads ``.../FullyConnected/layer3_ffn1/dot_general`` forward and
+    ``transpose(jvp(FullyConnected))/...`` backward in every program
+    built from this function, whatever the compiler calls the
+    instruction (trace-time metadata only; docs/OBSERVABILITY.md,
+    "Scope names")."""
     topo = symbol._topo()
     entries = list(symbol._entries)
     aux_names = set(symbol.list_auxiliary_states())
@@ -167,7 +204,9 @@ def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
                         _emit_tap(node.name, env[(id(node), 0)])
                     continue
                 ins = [env[(id(inp), oi)] for inp, oi in node.inputs]
-                raw = node.op.fn(*ins, **node.attrs)
+                with jax.named_scope(node.op.name), \
+                        jax.named_scope(node.name):
+                    raw = node.op.fn(*ins, **node.attrs)
                 if group_devices:
                     raw = (tuple(_place(node, r) for r in raw)
                            if isinstance(raw, (tuple, list))
@@ -664,14 +703,6 @@ class Executor:
             self._run_fwd(False)
         return self.outputs if not is_train else _LazyOutputs(self)
 
-    @staticmethod
-    def _prof_scope(name):
-        from . import profiler as _prof
-        if _prof.SYMBOLIC_ON:
-            return _prof.scope(name, "symbolic")
-        import contextlib
-        return contextlib.nullcontext()
-
     def _run_fwd(self, is_train):
         monitored = self._monitor_active()
         stream = monitored and self._monitor_mode == "stream"
@@ -688,7 +719,7 @@ class Executor:
                     self._fire_monitor(True, seed, auxs)
                 fwd = (self._stream_fns()["fwd_train"] if stream
                        else self._jit_fwd_train)
-                with self._prof_scope("Executor::forward"):
+                with _dispatch_span("executor.forward", "Executor::forward"):
                     _count_dispatch()
                     outs, new_auxs = _timed_dispatch(
                         fwd, self._args_values(), auxs, seed)
@@ -701,7 +732,7 @@ class Executor:
                               if not stream else None)
                 fwd = (self._stream_fns()["fwd_eval"] if stream
                        else self._jit_fwd_eval)
-                with self._prof_scope("Executor::forward"):
+                with _dispatch_span("executor.forward", "Executor::forward"):
                     _count_dispatch()
                     if donated_fn is not None:
                         vals = self._args_values()
@@ -763,7 +794,8 @@ class Executor:
         try:
             fwd_bwd = (self._stream_fns()["fwd_bwd"] if stream
                        else self._jit_fwd_bwd)
-            with self._prof_scope("Executor::forward_backward"):
+            with _dispatch_span("executor.forward_backward",
+                                "Executor::forward_backward"):
                 _count_dispatch()
                 outs, new_auxs, grads = _timed_dispatch(
                     fwd_bwd, self._args_values(), auxs, seed, ograds)

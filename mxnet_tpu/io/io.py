@@ -436,12 +436,12 @@ class PrefetchingIter(DataIter):
 
     def next(self):
         import time as _time
-        sp = _telemetry.tracing.start_span("io.data_wait")
-        t0 = _time.perf_counter()
-        batches = self._queue.get()
-        wait_ms = (_time.perf_counter() - t0) * 1e3
+        with _telemetry.tracing.span("io.data_wait") as sp:
+            t0 = _time.perf_counter()
+            batches = self._queue.get()
+            wait_ms = (_time.perf_counter() - t0) * 1e3
+            sp.set(occupancy=self._queue.qsize())
         _DATA_WAIT_MS.observe(wait_ms)
-        sp.end(occupancy=self._queue.qsize())
         # occupancy AFTER the get: batches still staged for future steps
         # — 0 here while the device is busy means the input pipeline is
         # the bottleneck (docs/OBSERVABILITY.md)

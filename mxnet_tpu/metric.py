@@ -18,6 +18,7 @@ import numpy as _np
 
 from .base import MXNetError
 from . import profiler as _profiler
+from .telemetry import tracing as _tracing
 
 __all__ = ["EvalMetric", "CompositeEvalMetric", "Accuracy", "TopKAccuracy",
            "F1", "MCC", "Perplexity", "MAE", "MSE", "RMSE", "CrossEntropy",
@@ -170,8 +171,11 @@ class EvalMetric:
         if self._dev_sum is None:
             return self.sum_metric, self.num_inst
         HOST_SYNCS.increment()
-        return (self.sum_metric + float(self._dev_sum),
-                self.num_inst + float(self._dev_num))
+        # the wait for the step and the two scalar transfers, on the
+        # profiler's clock (docs/OBSERVABILITY.md)
+        with _tracing.span("metric.readback"):
+            return (self.sum_metric + float(self._dev_sum),
+                    self.num_inst + float(self._dev_num))
 
     def get(self):
         total, num = self._totals()

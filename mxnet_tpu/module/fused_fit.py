@@ -62,7 +62,8 @@ from .. import optimizer as opt_mod
 from .. import telemetry as _telemetry
 from ..kvstore import KVStore, _updater_key
 from ..kvstore_fused import two_bit_quantize
-from ..executor import _compiled_cache, _count_dispatch
+from ..executor import (_compiled_cache, _count_dispatch,
+                        _dispatch_span)
 from ..model import _local_updater_key
 
 __all__ = ["FusedFitStep", "TRACE_COUNT"]
@@ -239,74 +240,83 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
             return (new_ps, new_ss,
                     new_res if threshold is not None else residuals)
 
+        # stable region names in every instruction's op_name, whatever
+        # the compiler calls the instruction: fit.update (also the
+        # scaler's cond and what sits under it), fit.metric,
+        # fit.sentinel; the graph's own nodes carry <op>/<node name>
+        # (executor._build_graph_fn)
         if scaler is not None:
-            finite = jnp.bool_(True)
-            for name in param_order:
-                finite = jnp.logical_and(
-                    finite, jnp.all(jnp.isfinite(g32[name])))
-            new_ps, new_ss, new_res = jax.lax.cond(
-                finite, apply_updates, lambda _: (params, states, residuals),
-                None)
-            new_scaler = scaler.step_fn(finite, scaler_state)
+            with jax.named_scope("fit.update"):
+                finite = jnp.bool_(True)
+                for name in param_order:
+                    finite = jnp.logical_and(
+                        finite, jnp.all(jnp.isfinite(g32[name])))
+                new_ps, new_ss, new_res = jax.lax.cond(
+                    finite, apply_updates,
+                    lambda _: (params, states, residuals), None)
+                new_scaler = scaler.step_fn(finite, scaler_state)
         else:
-            new_ps, new_ss, new_res = apply_updates(None)
+            with jax.named_scope("fit.update"):
+                new_ps, new_ss, new_res = apply_updates(None)
             new_scaler = scaler_state
 
         bsum = bnum = None
         if metric_fn is not None:
-            bsum, bnum = metric_fn(inputs, outs)
-            macc = (macc[0] + bsum, macc[1] + bnum)
+            with jax.named_scope("fit.metric"):
+                bsum, bnum = metric_fn(inputs, outs)
+                macc = (macc[0] + bsum, macc[1] + bnum)
 
         new_sent = sent_state
         if sentinel:
-            # in-launch numerics witnesses: a few reductions over
-            # arrays this program already holds, carried in one donated
-            # f32[8] vector — [metric_ema, metric_var, n_steps,
-            # cum_nonfinite, grad_norm, zscore, residual_ema,
-            # residual_drift]. Same launch, zero host syncs; the host
-            # reads it only at sync boundaries (publish_sentinels).
-            gnsq = jnp.float32(0.0)
-            nonfin = jnp.float32(0.0)
-            for name in param_order:
-                g = g32[name]
-                gnsq = gnsq + jnp.sum(jnp.square(g))
-                nonfin = nonfin + jnp.sum(
-                    (~jnp.isfinite(g)).astype(jnp.float32))
-            gnorm = jnp.sqrt(gnsq)
-            if bsum is not None:
-                mval = (bsum / jnp.maximum(bnum, 1)).astype(jnp.float32)
-            else:
-                mval = gnorm    # no device metric: track the grad norm
-            ema, emvar, n, cnf, rema = (sent_state[0], sent_state[1],
-                                        sent_state[2], sent_state[3],
-                                        sent_state[6])
-            d = mval - ema
-            z = jnp.where(n >= _SENT_WARMUP,
-                          d * jax.lax.rsqrt(emvar + jnp.float32(1e-12)),
-                          jnp.float32(0.0))
-            # a non-finite sample must trip the z-score/counter, not
-            # poison the running baseline forever
-            ok = jnp.isfinite(mval)
-            new_ema = jnp.where(ok, ema + (1.0 - _SENT_DECAY) * d, ema)
-            new_var = jnp.where(
-                ok, _SENT_DECAY * (emvar + (1.0 - _SENT_DECAY) * d * d),
-                emvar)
-            if threshold is not None:
-                rnsq = jnp.float32(0.0)
+            with jax.named_scope("fit.sentinel"):
+                # in-launch numerics witnesses: a few reductions over
+                # arrays this program already holds, carried in one donated
+                # f32[8] vector — [metric_ema, metric_var, n_steps,
+                # cum_nonfinite, grad_norm, zscore, residual_ema,
+                # residual_drift]. Same launch, zero host syncs; the host
+                # reads it only at sync boundaries (publish_sentinels).
+                gnsq = jnp.float32(0.0)
+                nonfin = jnp.float32(0.0)
                 for name in param_order:
-                    rnsq = rnsq + jnp.sum(jnp.square(new_res[name]))
-                rnorm = jnp.sqrt(rnsq)
-                drift = jnp.where(rema > 0.0,
-                                  rnorm / (rema + jnp.float32(1e-30)),
-                                  jnp.float32(1.0))
-                new_rema = _SENT_DECAY * rema \
-                    + (1.0 - _SENT_DECAY) * rnorm
-            else:
-                drift = jnp.float32(0.0)
-                new_rema = rema
-            new_sent = jnp.stack(
-                [new_ema, new_var, n + 1.0, cnf + nonfin, gnorm, z,
-                 new_rema, drift]).astype(jnp.float32)
+                    g = g32[name]
+                    gnsq = gnsq + jnp.sum(jnp.square(g))
+                    nonfin = nonfin + jnp.sum(
+                        (~jnp.isfinite(g)).astype(jnp.float32))
+                gnorm = jnp.sqrt(gnsq)
+                if bsum is not None:
+                    mval = (bsum / jnp.maximum(bnum, 1)).astype(jnp.float32)
+                else:
+                    mval = gnorm    # no device metric: track the grad norm
+                ema, emvar, n, cnf, rema = (sent_state[0], sent_state[1],
+                                            sent_state[2], sent_state[3],
+                                            sent_state[6])
+                d = mval - ema
+                z = jnp.where(n >= _SENT_WARMUP,
+                              d * jax.lax.rsqrt(emvar + jnp.float32(1e-12)),
+                              jnp.float32(0.0))
+                # a non-finite sample must trip the z-score/counter, not
+                # poison the running baseline forever
+                ok = jnp.isfinite(mval)
+                new_ema = jnp.where(ok, ema + (1.0 - _SENT_DECAY) * d, ema)
+                new_var = jnp.where(
+                    ok, _SENT_DECAY * (emvar + (1.0 - _SENT_DECAY) * d * d),
+                    emvar)
+                if threshold is not None:
+                    rnsq = jnp.float32(0.0)
+                    for name in param_order:
+                        rnsq = rnsq + jnp.sum(jnp.square(new_res[name]))
+                    rnorm = jnp.sqrt(rnsq)
+                    drift = jnp.where(rema > 0.0,
+                                      rnorm / (rema + jnp.float32(1e-30)),
+                                      jnp.float32(1.0))
+                    new_rema = _SENT_DECAY * rema \
+                        + (1.0 - _SENT_DECAY) * rnorm
+                else:
+                    drift = jnp.float32(0.0)
+                    new_rema = rema
+                new_sent = jnp.stack(
+                    [new_ema, new_var, n + 1.0, cnf + nonfin, gnorm, z,
+                     new_rema, drift]).astype(jnp.float32)
         return (new_ps, new_ss, new_res, macc, new_scaler, new_sent,
                 new_auxs, outs)
 
@@ -585,11 +595,51 @@ class FusedFitStep:
     def step(self, data_batch, eval_metric=None):
         """Run one single-launch training step. Returns False when this
         batch can't take the fused path (residuals are spilled first so
-        the eager fallback continues exactly)."""
+        the eager fallback continues exactly).
+
+        Three host spans on the profiler's clock split the step's host
+        time (docs/OBSERVABILITY.md): ``fit.prepare`` (eligibility,
+        placing inputs, gathering the program's arguments),
+        ``fit.fused_dispatch`` (the jit call) and ``fit.rebind`` (every
+        donated buffer handed its new value)."""
+        tracing = _telemetry.tracing
+        with tracing.span("fit.prepare"):
+            prep = self._prepare(data_batch, eval_metric)
+        if prep is None:
+            return False
+        fn, args, carried = prep
+        _count_dispatch()
+        track_mem = (self._mem_tracker is not None
+                     and self.launches % _MEM_EVERY == 0)
+        if track_mem:
+            self._mem_tracker.begin()
+        try:
+            with _dispatch_span("fit.fused_dispatch",
+                                "Module::fused_fit_step"):
+                result = _SITE.timed(fn, *args)
+        except Exception:
+            # a runtime failure after donation consumes the donated
+            # buffers — drop our residual refs so a later spill doesn't
+            # resurrect deleted arrays, then surface the error (the
+            # module's device state is not recoverable at this point)
+            self._residuals = None
+            self._sent_state = None
+            raise
+        if track_mem:
+            self._mem_tracker.end()
+        with tracing.span("fit.rebind"):
+            self._rebind(result, eval_metric, *carried)
+        self.launches += 1
+        return True
+
+    def _prepare(self, data_batch, eval_metric):
+        """Everything of a step before its launch: ``(the compiled
+        program, its arguments, what _rebind needs afterwards)``; None
+        when this batch can't take the fused path."""
         mod = self._mod
         if getattr(mod, "_monitor_installed", False):
             self._release()
-            return False
+            return None
         # re-check the mutable bits of build-time eligibility: a swapped
         # updater (kv.set_updater after init) or a mutated optimizer
         # hyperparameter must not silently keep the stale program
@@ -597,11 +647,11 @@ class FusedFitStep:
             else mod._updater
         if live_updater is not self._updater:
             self._release()
-            return False
+            return None
         mode = mod._optimizer._fused_fit_sig()
         if mode is None or not _fused.supported(mode):
             self._release()
-            return False
+            return None
         group = mod._exec_group
         exe = group._exec
         data = getattr(data_batch, "data", None)
@@ -610,12 +660,12 @@ class FusedFitStep:
                 or (group.label_names
                     and len(labels) < len(group.label_names)):
             self._release()
-            return False
+            return None
         for v in list(data) + list(labels):
             if isinstance(v, NDArray) \
                     and getattr(v, "stype", "default") != "default":
                 self._release()
-                return False
+                return None
 
         inputs = {}
         try:
@@ -628,7 +678,7 @@ class FusedFitStep:
             if dbg:
                 dbg("fused fit step falling back for this batch: %s", e)
             self._release()
-            return False
+            return None
 
         if self._order is None:
             self._order = self._param_order()
@@ -659,7 +709,7 @@ class FusedFitStep:
                 leaves, _ = _fused.flatten_state(st)
                 if not all(isinstance(l, NDArray) for l in leaves):
                     self._release()
-                    return False   # e.g. a host-side custom state blob
+                    return None    # e.g. a host-side custom state blob
         states_nd, tpls, mp_flags = [], [], []
         for n, uk in zip(order, ukeys):
             if uk not in updater.states:
@@ -771,31 +821,17 @@ class FusedFitStep:
 
         seed = exe._next_seed()
         rescale = _np.float32(optimizer.rescale_grad)
-        _count_dispatch()
-        track_mem = (self._mem_tracker is not None
-                     and self.launches % _MEM_EVERY == 0)
-        if track_mem:
-            self._mem_tracker.begin()
-        try:
-            with exe._prof_scope("Module::fused_fit_step"), \
-                    _telemetry.tracing.span("fit.fused_dispatch"):
-                (new_ps, new_ss, new_res, macc, new_scaler, new_sent,
-                 new_auxs, outs) = _SITE.timed(
-                    fn, params, states, residuals, macc, scaler_state,
-                    sent_state, inputs, auxs, lr_vec, wd_vec, rescale,
-                    extra, seed)
-        except Exception:
-            # a runtime failure after donation consumes the donated
-            # buffers — drop our residual refs so a later spill doesn't
-            # resurrect deleted arrays, then surface the error (the
-            # module's device state is not recoverable at this point)
-            self._residuals = None
-            self._sent_state = None
-            raise
-        if track_mem:
-            self._mem_tracker.end()
+        args = (params, states, residuals, macc, scaler_state, sent_state,
+                inputs, auxs, lr_vec, wd_vec, rescale, extra, seed)
+        return fn, args, (exe, order, states_nd, scaler, sent_on,
+                          metric_fn is not None)
 
-        # rebind every donated buffer to its new value
+    def _rebind(self, result, eval_metric, exe, order, states_nd, scaler,
+                sent_on, has_metric):
+        """Hand every donated buffer its new value."""
+        (new_ps, new_ss, new_res, macc, new_scaler, new_sent, new_auxs,
+         outs) = result
+        mod = self._mod
         kv_store = self._kv._store \
             if (self._kv is not None and mod._update_on_kvstore) else None
         for n, st in zip(order, states_nd):
@@ -815,9 +851,7 @@ class FusedFitStep:
         exe._pending_train_fwd = False
         exe._train_seed = None
         exe._train_auxs = None
-        if metric_fn is not None:
+        if has_metric:
             eval_metric._dev_sum, eval_metric._dev_num = macc
             eval_metric._device_consumed = True
         mod._params_dirty = True
-        self.launches += 1
-        return True

@@ -117,14 +117,16 @@ def _pallas_fwd(x2d, scale, shift, w2d, res):
     else:
         kern = partial(_matmul_kernel, relu=True, out_dtype=x2d.dtype)
     _count_launch("fused_scale_relu_matmul")
-    return pl.pallas_call(
+    fn = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x2d.dtype),
         interpret=_interpret_mode(),
-    )(*args)
+    )
+    with jax.named_scope("pallas.fused_scale_relu_matmul"):
+        return fn(*args)
 
 
 def _jnp_fwd(x2d, scale, shift, w2d, res):

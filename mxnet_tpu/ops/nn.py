@@ -758,7 +758,9 @@ def _flash_attention(q, k, v, sm_scale):
         block_q_major_dkv=blk, block_k_major_dkv=blk,
         block_k_dkv=blk, block_q_dkv=blk,
         block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
-    return _flash(q, k, v, causal=True, sm_scale=sm_scale, block_sizes=bs)
+    with jax.named_scope("pallas.flash_attention"):
+        return _flash(q, k, v, causal=True, sm_scale=sm_scale,
+                      block_sizes=bs)
 
 
 @register("_contrib_CausalSelfAttention", aliases=("CausalSelfAttention",))

@@ -65,6 +65,10 @@ _NEG = -1e30
 
 
 def _count_launch(kernel):
+    """One kernel build (trace time).  Each site then runs its kernel
+    under ``jax.named_scope("pallas." + kernel)``, the label
+    ``PALLAS_LAUNCHES`` carries: the kernel's instructions keep that
+    name in a device trace (docs/OBSERVABILITY.md, "Scope names")."""
     _note_kernel_build()
     if not RETRACE_SUPPRESS.on:   # skip program-registry re-lowers
         PALLAS_LAUNCHES.labels(kernel=kernel).inc()
@@ -196,9 +200,10 @@ def paged_decode_attend(q, k_cache, v_cache, block_table, positions, *,
         out_shape=jax.ShapeDtypeStruct((C, H, 1, D), q.dtype),
         interpret=interpret,
     )
-    return fn(block_table.astype(jnp.int32),
-              positions.astype(jnp.int32), q.reshape(C, H, 1, D),
-              k_cache, v_cache).reshape(C, H, D)
+    with jax.named_scope("pallas.paged_decode_attend"):
+        return fn(block_table.astype(jnp.int32),
+                  positions.astype(jnp.int32), q.reshape(C, H, 1, D),
+                  k_cache, v_cache).reshape(C, H, D)
 
 
 # ----------------------------------------------------------------------
@@ -317,10 +322,11 @@ def paged_prefill_attend(q, k, v, k_cache, v_cache, block_table,
         interpret=interpret,
     )
     hm = lambda x: x.transpose(0, 2, 1, 3)            # noqa: E731
-    out, ko, vo = fn(block_table.astype(jnp.int32),
-                     lengths.astype(jnp.int32), hm(q),
-                     hm(k).astype(q.dtype), hm(v).astype(q.dtype), k, v,
-                     k_cache, v_cache)
+    with jax.named_scope("pallas.paged_prefill_attend"):
+        out, ko, vo = fn(block_table.astype(jnp.int32),
+                         lengths.astype(jnp.int32), hm(q),
+                         hm(k).astype(q.dtype), hm(v).astype(q.dtype), k, v,
+                         k_cache, v_cache)
     return hm(out)[:, :S], ko, vo
 
 
@@ -463,8 +469,9 @@ def paged_chunk_prefill_attend(q, k, v, k_cache, v_cache, block_table,
         input_output_aliases={6: 1, 7: 2},
         interpret=interpret,
     )
-    out, ko, vo = fn(
-        block_table.astype(jnp.int32), start.astype(jnp.int32),
-        lengths.astype(jnp.int32), q.transpose(0, 2, 1, 3), kpad, vpad,
-        k_cache, v_cache)
+    with jax.named_scope("pallas.paged_chunk_prefill_attend"):
+        out, ko, vo = fn(
+            block_table.astype(jnp.int32), start.astype(jnp.int32),
+            lengths.astype(jnp.int32), q.transpose(0, 2, 1, 3), kpad, vpad,
+            k_cache, v_cache)
     return out.transpose(0, 2, 1, 3), ko, vo

@@ -148,7 +148,8 @@ def _ln_forward(eps, interpret, x2d, gamma, beta, residual):
     if with_res:
         args.append(_pad2(residual, rows_pad, cols_pad))
     args += [_vec_pad(gamma, cols_pad), _vec_pad(beta, cols_pad)]
-    out, mean, rstd = fn(*args)
+    with jax.named_scope("pallas.layernorm_fused"):
+        out, mean, rstd = fn(*args)
     return out[:rows, :cols], mean[:rows, 0], rstd[:rows, 0]
 
 
@@ -181,9 +182,11 @@ def _ln_backward(eps, interpret, saved, dy):
     rstd_t = stat.at[:rows, :].set(rstd.reshape(-1, 1))
     res_t = _pad2(residual, rows_pad, cols_pad) if with_res \
         else jnp.zeros((rows_pad, cols_pad), x2d.dtype)
-    dx, dg, db = fn(_pad2(x2d, rows_pad, cols_pad), res_t,
-                    _vec_pad(gamma, cols_pad), mean_t, rstd_t,
-                    _pad2(dy, rows_pad, cols_pad))
+    args = (_pad2(x2d, rows_pad, cols_pad), res_t,
+            _vec_pad(gamma, cols_pad), mean_t, rstd_t,
+            _pad2(dy, rows_pad, cols_pad))
+    with jax.named_scope("pallas.layernorm_fused_bwd"):
+        dx, dg, db = fn(*args)
     dx = dx[:rows, :cols]
     dg = dg[0, :cols].astype(gamma.dtype)
     db = db[0, :cols]

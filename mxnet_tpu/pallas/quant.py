@@ -72,6 +72,7 @@ def two_bit_quantize_fused(residual, grad, threshold, *,
         out_shape=[jax.ShapeDtypeStruct((rows_pad, cols), dtype)] * 2,
         interpret=interpret,
     )
-    q, new_res = fn(thr, tile(residual), tile(grad))
+    with jax.named_scope("pallas.two_bit_quantize_fused"):
+        q, new_res = fn(thr, tile(residual), tile(grad))
     return (q.reshape(-1)[:n].reshape(shape),
             new_res.reshape(-1)[:n].reshape(shape))

@@ -10,14 +10,25 @@ already exports answer "how fast is the fleet"; spans answer "where did
 
 Design rules (the same overhead contract as the registry):
 
-* **Near-zero when disabled.**  Tracing is OFF by default; every
-  instrumentation site goes through :func:`span`/:func:`start_span`,
-  which cost one module-global check and return shared no-op objects
-  when disabled.  No allocation, no clock read, no lock.
+* **Near-zero when disabled.**  Recording (the ring, ids,
+  ``traceparent``) is OFF by default; every instrumentation site goes
+  through :func:`span`/:func:`start_span`, which cost one module-global
+  check when disabled.  No clock read, no lock, nothing recorded.
+* **Always on the profiler's clock.**  The context form :func:`span`
+  enters a ``jax.profiler.TraceAnnotation`` of the span's name whether
+  or not recording is enabled, so any ``jax.profiler`` trace (a
+  benchmark's, ``mx.profiler`` with ``trace_dir``, a user's) holds the
+  program's spans on its host plane, on the device's clock, with no
+  switch thrown.  With no trace running an annotation is one inactive
+  C++ object (a fraction of a microsecond; PERF.md has the number).
+  :func:`start_span` spans overlap and cross threads, which a
+  thread-nested annotation cannot: they stay ring-only.
 * **Host-only.**  Spans bracket *dispatch* wall time on the host —
   never code inside a traced program — so enabling tracing can never
   add a retrace or a device launch (pinned by
-  ``tests/test_trace.py::test_tracing_overhead_guard_*``).
+  ``tests/test_trace.py::test_tracing_overhead_guard_*``).  Inside a
+  compiled program the stable names are ``jax.named_scope``s
+  (docs/OBSERVABILITY.md, "Scope names").
 * **Thread-local context + explicit parents.**  Within one thread,
   ``with span(...)`` nests automatically (the fit loop's child spans
   need no plumbing).  Across threads — an HTTP handler submitting to
@@ -33,8 +44,9 @@ Design rules (the same overhead contract as the registry):
 Finished spans land in a bounded ring (:func:`spans` /
 :func:`drain_spans`) and export through both existing surfaces: the
 flight recorder appends them to every dump (``{"span": {...}}`` lines),
-and ``profiler.dump()`` renders them as chrome-trace ``X`` events with
-``trace_id``/``span_id``/``parent_id`` args (:func:`chrome_events`).
+and ``profiler.dump()`` renders the ring, once, as chrome-trace ``X``
+events with ``trace_id``/``span_id``/``parent_id`` args
+(:func:`chrome_events`).
 """
 from __future__ import annotations
 
@@ -122,14 +134,14 @@ class SpanContext:
 
 class Span:
     """One live span.  ``end()`` (or exiting the context manager) stamps
-    the duration, records the span in the ring, and exports it into a
-    running profiler.  Thread-compatible: a span may be *ended* by a
-    different thread than opened it (a serving request settles on the
-    replica thread), but only one thread may mutate it at a time —
-    which the single-owner request objects guarantee."""
+    the duration and records the span in the ring.  Thread-compatible:
+    a span may be *ended* by a different thread than opened it (a
+    serving request settles on the replica thread), but only one thread
+    may mutate it at a time — which the single-owner request objects
+    guarantee."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0",
-                 "t_mono", "attrs", "_ended", "_tid", "_restore")
+                 "t_mono", "attrs", "_ended", "_tid", "_restore", "_ann")
 
     def __init__(self, name, trace_id, parent_id, attrs):
         self.name = name
@@ -142,6 +154,7 @@ class Span:
         self._ended = False
         self._tid = threading.get_ident()
         self._restore = None
+        self._ann = None        # the profiler annotation (span() form)
 
     @property
     def context(self):
@@ -177,9 +190,13 @@ class Span:
     def __enter__(self):
         self._restore = getattr(_tls, "ctx", None)
         _tls.ctx = self.context
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         _tls.ctx = self._restore
         self._restore = None
         if exc_type is not None:
@@ -211,6 +228,33 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+_annotation_type = None
+
+
+def _annotation(name):
+    """A ``jax.profiler.TraceAnnotation`` of ``name`` that also answers
+    the span protocol with no-ops (``set``/``end``/``context``), so the
+    disabled path of :func:`span` IS the annotation: one C++ object,
+    nothing recorded.  Built at first use: jax stays a lazy import of
+    this package."""
+    global _annotation_type
+    if _annotation_type is None:
+        from jax.profiler import TraceAnnotation
+
+        class _Annotation(TraceAnnotation):
+            __slots__ = ()
+            context = None
+            trace_id = span_id = parent_id = None
+
+            def set(self, **attrs):
+                return self
+
+            def end(self, **attrs):
+                return self
+
+        _annotation_type = _Annotation
+    return _annotation_type(name)
+
 
 def _record(rec):
     layer = rec["name"].split(".", 1)[0]
@@ -219,20 +263,6 @@ def _record(rec):
         if len(_ring) == _ring.maxlen:
             DROPPED.inc()
         _ring.append(rec)
-    # live export into a running profiler (host-side, ph='X' span)
-    try:
-        from .. import profiler as _prof
-        if _prof.state() == "run":
-            now = _prof._now_us()
-            _prof.add_event(
-                rec["name"], "trace", now - rec["dur_ms"] * 1e3,
-                rec["dur_ms"] * 1e3, tid=rec["tid"],
-                args={"trace_id": rec["trace_id"],
-                      "span_id": rec["span_id"],
-                      "parent_id": rec["parent_id"],
-                      **(rec.get("attrs") or {})})
-    except Exception:
-        pass
 
 
 def current():
@@ -264,10 +294,16 @@ def span(name, parent="current", **attrs):
 
         with tracing.span("fit.step", step=n):
             ...                       # children parent automatically
-    """
+
+    Its body is always a ``jax.profiler.TraceAnnotation`` of ``name``
+    (a running device trace shows it, whoever started the trace); the
+    ring records it only when tracing is enabled."""
+    ann = _annotation(name)
     if not _ENABLED:
-        return NULL_SPAN
-    return start_span(name, parent=parent, **attrs)
+        return ann
+    sp = start_span(name, parent=parent, **attrs)
+    sp._ann = ann
+    return sp
 
 
 # ----------------------------------------------------------------------
