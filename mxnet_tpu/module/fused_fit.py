@@ -36,7 +36,10 @@ residuals operate on the f32 master-gradient view, and a
 ``DynamicLossScaler`` (fused_update.py) rides along — its scale is a
 runtime scalar, the inf/nan overflow check is folded into the program,
 and the skip-update decision is a ``lax.cond``, so overflow handling
-costs zero host syncs.
+costs zero host syncs. A gradient reaches that ``cond`` in the dtype
+the backward wrote it and is widened by the instruction that consumes
+it: an operand of a conditional is a buffer in HBM, and a float32 view
+of every bf16 gradient there is a second, wider copy of it.
 
 The compiled step is cached per SYMBOL (sharing executables across
 rebinds like executor._compiled_cache) and keyed by everything that
@@ -180,8 +183,8 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
     (tests/test_fused_fit.py pins the tolerance).
 
     With a loss scaler, the entire compress+update block sits under a
-    ``lax.cond`` on a device-side finiteness check of the f32
-    master-gradient view — an overflow step updates neither weights,
+    ``lax.cond`` on a device-side finiteness check of the gradients
+    as the backward wrote them — an overflow step updates neither weights,
     nor optimizer state, nor error-feedback residuals — and the
     scaler's (scale, good_steps, skips) triple is donated through the
     program so skip bookkeeping never touches the host. The scale
@@ -210,24 +213,28 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
         cts = [jnp.ones_like(o) for o in outs]
         (grads,) = vjp_fn(cts)
 
-        # the f32 master-gradient view: error-feedback residuals and
-        # the optimizer math both run on it, so bf16 model grads are
-        # widened exactly once, before compression
-        g32 = {name: grads[name].astype(jnp.float32)
-               for name in param_order}
+        # a gradient crosses into the update stage in the dtype the
+        # backward wrote it: an operand of the scaler's ``lax.cond`` is
+        # a buffer in HBM, so a float32 view built HERE would cost every
+        # bf16 gradient a second, twice as wide, copy. Each consumer
+        # widens in registers instead (apply_one, the 2-bit arm below,
+        # the sentinel's sums); bf16 -> f32 is exact, so each sees the
+        # value the backward wrote (docs/TRAINING.md, Mixed precision)
+        f32 = jnp.float32
 
         def apply_updates(_):
-            # 2-bit quantize with donated error-feedback residual; a
-            # mesh-sharded batch already yielded psum-reduced
-            # (replicated) grads from the vjp, so there is no separate
-            # reduce stage to launch
+            # 2-bit quantize with donated error-feedback residual, on
+            # the f32 master-gradient view; a mesh-sharded batch
+            # already yielded psum-reduced (replicated) grads from the
+            # vjp, so there is no separate reduce stage to launch
             new_res, red = {}, {}
             for name in param_order:
                 if threshold is not None:
                     red[name], new_res[name] = two_bit_quantize(
-                        residuals[name], g32[name], threshold)
+                        residuals[name], grads[name].astype(f32),
+                        threshold)
                 else:
-                    red[name] = g32[name]
+                    red[name] = grads[name]
             new_ps, new_ss = {}, {}
             for i, name in enumerate(param_order):
                 st = _fused.unflatten(tpls[i], states[name])
@@ -245,12 +252,25 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
         # scaler's cond and what sits under it), fit.metric,
         # fit.sentinel; the graph's own nodes carry <op>/<node name>
         # (executor._build_graph_fn)
+        #
+        # every reader of a gradient outside the update is ONE
+        # expression per gradient (the scaler's ``finite`` is the
+        # sentinel's non-finite count == 0): the compiler then carries
+        # the scalars out of the fusion that writes the gradient, and
+        # nothing reads it again before the update
+        gnsq = nonfin = f32(0.0)
+        if sentinel or scaler is not None:
+            with jax.named_scope("fit.sentinel" if sentinel
+                                 else "fit.update"):
+                for name in param_order:
+                    g = grads[name]
+                    nonfin = nonfin + jnp.sum(
+                        (~jnp.isfinite(g)).astype(f32))
+                    if sentinel:
+                        gnsq = gnsq + jnp.sum(jnp.square(g.astype(f32)))
         if scaler is not None:
             with jax.named_scope("fit.update"):
-                finite = jnp.bool_(True)
-                for name in param_order:
-                    finite = jnp.logical_and(
-                        finite, jnp.all(jnp.isfinite(g32[name])))
+                finite = nonfin == 0.0
                 new_ps, new_ss, new_res = jax.lax.cond(
                     finite, apply_updates,
                     lambda _: (params, states, residuals), None)
@@ -275,13 +295,6 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
                 # cum_nonfinite, grad_norm, zscore, residual_ema,
                 # residual_drift]. Same launch, zero host syncs; the host
                 # reads it only at sync boundaries (publish_sentinels).
-                gnsq = jnp.float32(0.0)
-                nonfin = jnp.float32(0.0)
-                for name in param_order:
-                    g = g32[name]
-                    gnsq = gnsq + jnp.sum(jnp.square(g))
-                    nonfin = nonfin + jnp.sum(
-                        (~jnp.isfinite(g)).astype(jnp.float32))
                 gnorm = jnp.sqrt(gnsq)
                 if bsum is not None:
                     mval = (bsum / jnp.maximum(bnum, 1)).astype(jnp.float32)

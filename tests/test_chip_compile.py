@@ -122,3 +122,73 @@ def test_flash_attention_fwd_grad_compiles(one_chip):
     qkv = ((4, H, 1024, D), jnp.bfloat16)
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
              qkv, qkv, qkv)
+
+
+def test_fit_program_conditional_takes_gradients_narrow(one_chip):
+    """A 2-layer bf16 transformer's fused fit program (Adam with f32
+    masters, the loss scaler's ``cond``, the sentinel on), compiled for
+    the described chip: among the conditional's operands the only
+    float32 arrays of a bf16 parameter's shape are that parameter's
+    optimizer state (mean, variance, master).  One more would be a
+    float32 copy of its gradient: an operand of a conditional is a
+    buffer in HBM, and the update widens the bf16 gradient itself."""
+    import collections
+    import re
+
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import fused_update, models
+
+    S, V = 256, 384
+    mod = mx.Module(models.get_symbol(
+        "transformer", num_classes=V, num_layers=2, d_model=256,
+        num_heads=2, ffn_dim=512, seq_len=S, dtype="bfloat16"),
+        context=mx.cpu())
+    mod.bind(data_shapes=[("data", (2, S))],
+             label_shapes=[("softmax_label", (2 * S,))])
+    mod.init_params(mx.init.Normal(0.02))
+    mod.init_optimizer(optimizer="adam", optimizer_params={
+        "learning_rate": 2e-4, "wd": 0.1, "multi_precision": True})
+    tokens = np.arange(2 * S, dtype=np.float32) % V
+    batch = mx.io.DataBatch(data=[mx.nd.array(tokens.reshape(2, S))],
+                            label=[mx.nd.array(tokens)])
+    ff = mod._get_fused_fit()
+    fn, args, _ = ff._prepare(batch, mx.metric.create("ce"))
+    assert ff._scaler is not None
+    params, states = args[0], args[1]
+    assert {str(p.dtype) for p in params.values()} == {"bfloat16",
+                                                       "float32"}
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+    with jax.default_matmul_precision("default"):
+        text = fn.lower(*specs).compile().as_text()
+
+    # the float32 arrays each parameter shape may have among the
+    # operands: its state leaves, and for a float32 parameter (the
+    # embeddings) itself and its gradient
+    allowed = collections.Counter()
+    for n, p in params.items():
+        assert all(str(l.dtype) == "float32" for l in states[n])
+        allowed[tuple(p.shape)] += len(states[n]) + (
+            0 if fused_update.is_low_precision(p.dtype) else 2)
+
+    types = {m.group(1): m.group(2) for m in re.finditer(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) [\w\-]+\(", text, re.M)}
+    conds = re.findall(r"^.* conditional\((.*?)\), ", text, re.M)
+    assert len(conds) == 1
+    elements = set()
+    for operand in re.findall(r"%([\w.\-]+)", conds[0])[1:]:
+        line = re.search(r"^\s*%?" + re.escape(operand)
+                         + r" = .*? tuple\((.*)\)", text, re.M)
+        elements.update(re.findall(r"%([\w.\-]+)", line.group(1)))
+    seen = collections.Counter()
+    for e in elements:
+        m = re.match(r"(\w+)\[([\d,]*)\]", types[e])
+        if m and m.group(1) == "f32" and m.group(2):
+            seen[tuple(int(d) for d in m.group(2).split(","))] += 1
+    narrow = {tuple(p.shape) for p in params.values()
+              if fused_update.is_low_precision(p.dtype)}
+    assert narrow and all(seen[s] > 0 for s in narrow)
+    assert {s: seen[s] for s in narrow} == {s: allowed[s] for s in narrow}

@@ -178,10 +178,16 @@ def test_waived_eager_optimizer_counts_bounded_slug():
 # ----------------------------------------------------------------------
 # zero steady-state retraces: lr schedule + ragged batches
 # ----------------------------------------------------------------------
-def _mlp(low_precision=False):
+def _mlp(low_precision=False, plant=False):
+    """``plant`` adds a gain whose gradient is non-finite exactly in
+    the columns where a batch holds a zero (d/dp sqrt(x*p) at x == 0)
+    while the forward and every other gradient stay finite."""
     data = sym.Variable("data")
     if low_precision:
         data = sym.Cast(data, dtype="bfloat16")
+    if plant:
+        gain = sym.Variable("plant_gain", shape=(1, 6), dtype="bfloat16")
+        data = data + sym.sqrt(sym.broadcast_mul(data, gain))
     net = sym.FullyConnected(data, num_hidden=8, name="fc1")
     net = sym.Activation(net, act_type="relu")
     net = sym.FullyConnected(net, num_hidden=4, name="fc2")
@@ -202,11 +208,13 @@ def _make_mod(optimizer="adam", opt_params=None, low_precision=False,
     return mod
 
 
-def _batch(n=16, seed=0, bad=False):
+def _batch(n=16, seed=0, bad=False, zero_at=()):
     rng = np.random.RandomState(seed)
     X = rng.rand(n, 6).astype(np.float32)
     if bad:
         X[0, 0] = np.inf       # forward -> inf logits -> nan grads
+    for r, c in zero_at:       # _mlp(plant=True): that column's gradient
+        X[r, c] = 0.0
     y = rng.randint(0, 4, n).astype(np.float32)
     return mx.io.DataBatch(data=[nd.array(X)], label=[nd.array(y)])
 
@@ -357,3 +365,245 @@ def test_bf16_adam_checkpoint_resume_parity(tmp_path):
     res._loss_scaler.publish()
     assert res._loss_scaler.scale == mod._loss_scaler.scale
     assert res._loss_scaler.skips == mod._loss_scaler.skips
+
+
+# ----------------------------------------------------------------------
+# gradients cross into the guarded update in the backward's own dtype
+# ----------------------------------------------------------------------
+def _lp_mod(optimizer, opt_params, compress=None, plant=False, fused=True):
+    mod = mx.Module(_mlp(True, plant), context=mx.cpu(), compression_params=(
+        {"type": "2bit", "threshold": compress} if compress else None))
+    mod._fused_fit_enabled = fused
+    mod.bind(data_shapes=[("data", (16, 6))],
+             label_shapes=[("softmax_label", (16,))])
+    np.random.seed(3)       # initializers draw from numpy's global RNG
+    mod.init_params(initializer=mx.initializer.One() if plant
+                    else mx.initializer.Xavier())
+    mod.init_optimizer(
+        kvstore=mx.kv.create("device") if compress else "local",
+        optimizer=optimizer, optimizer_params=opt_params)
+    return mod
+
+
+# Hyperparameters that are powers of two (rescale_grad is 1/16, the
+# batch), no weight decay: every product of the update is then exact,
+# so a mul-add that LLVM contracts to an FMA in one program and not in
+# another rounds the same, and two differently laid out programs can be
+# held to atol=0.  (With wd=1e-3 one Adam mean in 48 differs by an ulp.)
+_LP_CASES = {
+    "adam": ("adam", {"learning_rate": 2.0 ** -5, "beta1": 0.5,
+                      "beta2": 0.5, "multi_precision": True}, None),
+    "sgd_mom": ("sgd", {"learning_rate": 2.0 ** -4, "momentum": 0.5,
+                        "multi_precision": True}, None),
+    "adam_2bit": ("adam", {"learning_rate": 2.0 ** -5, "beta1": 0.5,
+                           "beta2": 0.5, "multi_precision": True},
+                  2.0 ** -8),
+}
+
+
+def _widen_first_reference(mod, ff, use_wd):
+    """The fit step put together from its own pieces (``jax.vjp`` over
+    the module's ``graph_fn``, ``two_bit_quantize``,
+    ``_fused.apply_one``, the scaler's ``step_fn``) with every gradient
+    widened to float32 FIRST, at the top of the program, as the fit
+    program did until PR 25.  ``lax.reduce_precision`` holds XLA to the
+    bf16 value the backward states: left alone it drops a bf16 -> f32
+    widening that sits next to the dot TOGETHER with the dot's own
+    rounding to bf16 (``xla_allow_excess_precision``), and the update
+    then runs from an accumulator that no gradient array ever held."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.executor import _compiled_cache
+    from mxnet_tpu.kvstore_fused import two_bit_quantize
+    graph_fn = _compiled_cache(mod._symbol)["graph_fn"]
+    order = tuple(ff._order)
+    opt = mod._optimizer
+    upd = fused_update.build(opt._fused_fit_sig())
+    exe = mod._exec_group._exec
+    tpls = [fused_update.state_template(ff._updater.states[uk])
+            for uk in ff._ukeys]
+    mp = [bool(opt.multi_precision)
+          and fused_update.is_low_precision(exe.arg_dict[n].dtype)
+          for n in order]
+    scaler, threshold = ff._scaler, ff._threshold
+
+    @jax.jit
+    def reference(params, states, residuals, scaler_state, inputs, auxs,
+                  lr_vec, wd_vec, rescale, extra, seed):
+        outs, vjp_fn, _ = jax.vjp(
+            lambda p: graph_fn({**inputs, **p}, auxs, seed, True),
+            params, has_aux=True)
+        (grads,) = vjp_fn([jnp.ones_like(o) for o in outs])
+        assert all(grads[n].dtype == jnp.bfloat16 for n in order)
+        g32 = {n: jax.lax.reduce_precision(
+            grads[n].astype(jnp.float32), 8, 7) for n in order}
+        finite = jnp.bool_(True)
+        for n in order:
+            finite = jnp.logical_and(finite,
+                                     jnp.all(jnp.isfinite(g32[n])))
+
+        def apply(_):
+            ps, ss, rs = {}, {}, {}
+            for i, n in enumerate(order):
+                g = g32[n]
+                if threshold is not None:
+                    g, rs[n] = two_bit_quantize(residuals[n], g, threshold)
+                w, s = fused_update.apply_one(
+                    upd, params[n], g,
+                    fused_update.unflatten(tpls[i], states[n]), mp[i],
+                    lr_vec[i], wd_vec[i], rescale,
+                    extra[i] if upd.n_extra else (), use_wd)
+                ps[n] = w
+                ss[n] = tuple(fused_update.flatten_state(s)[0])
+            return ps, ss, (rs if threshold is not None else residuals)
+
+        ps, ss, rs = jax.lax.cond(
+            finite, apply, lambda _: (params, states, residuals), None)
+        return ps, ss, rs, scaler.step_fn(finite, scaler_state)
+    return reference
+
+
+@pytest.mark.parametrize("case", sorted(_LP_CASES))
+def test_fit_step_bit_equal_to_widen_first_reference(case):
+    """bf16 parameters with f32 masters, 3 fused steps: the weights,
+    the masters and moments, the 2-bit residuals and the scaler's
+    triple are BIT-EQUAL to a step that widens every gradient to
+    float32 before anything reads it.  The fit program hands the
+    gradients over narrow and each reader widens for itself; bf16 ->
+    f32 is exact, so widening early or late gives the same bits."""
+    import jax
+    optimizer, opt_params, compress = _LP_CASES[case]
+    mod = _lp_mod(optimizer, opt_params, compress)
+    ff = mod._get_fused_fit()
+    assert ff is not None
+    reference = None
+    for t in range(3):
+        fn, args, carried = ff._prepare(_batch(seed=t), None)
+        host = jax.tree.map(np.asarray, args)   # before the donation
+        (params, states, residuals, _, scaler_state, _, inputs, auxs,
+         lr_vec, wd_vec, rescale, extra, seed) = host
+        if reference is None:
+            reference = _widen_first_reference(
+                mod, ff, bool(np.any(wd_vec != 0)))
+        want = reference(params, states, residuals, scaler_state, inputs,
+                         auxs, lr_vec, wd_vec, rescale, extra, seed)
+        got = fn(*args)
+        ff._rebind(got, None, *carried)
+        ff.launches += 1
+        got = (got[0], got[1], got[2], got[4])
+        flat_got = jax.tree_util.tree_leaves_with_path(got)
+        flat_want = jax.tree.leaves(want)
+        assert len(flat_got) == len(flat_want)
+        for (path, a), b in zip(flat_got, flat_want):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                err_msg="step %d %s" % (t, jax.tree_util.keystr(path)))
+        # the step really trained: every weight moved
+        for n in ff._order:
+            assert not np.array_equal(np.asarray(got[0][n], np.float32),
+                                      np.asarray(params[n], np.float32)), n
+    if compress:
+        assert any(float(np.abs(np.asarray(r)).sum()) > 0
+                   for r in got[2].values())
+
+
+def test_planted_nonfinite_gradient_skips_and_is_counted():
+    """Two columns of ONE bf16 gradient are made non-finite (forward
+    and every other gradient finite): nothing updates — weights,
+    masters, moments bit-identical —, the scaler's skips advance by
+    one, and the sentinel's non-finite count rises by exactly the two
+    planted elements.  The finiteness check and the count read the
+    gradient in bf16; the predicate is that of the widened copy."""
+    mod = _lp_mod("adam", {"learning_rate": 0.05,
+                           "multi_precision": True}, plant=True)
+    ff = mod._get_fused_fit()
+    for t in range(2):
+        assert mod.fit_step(_batch(seed=t))
+    sent0 = np.asarray(ff._sent_state)
+    assert sent0[3] == 0 and np.isfinite(sent0[4])
+
+    def state():
+        leaves = {}
+        for n, uk in zip(ff._order, ff._ukeys):
+            leaves[n] = mod._exec_group._exec.arg_dict[n].asnumpy()
+            for i, l in enumerate(fused_update.flatten_state(
+                    ff._updater.states[uk])[0]):
+                leaves[n, i] = l.asnumpy()
+        return leaves
+
+    before = state()
+    assert mod.fit_step(_batch(seed=7, zero_at=[(0, 1), (3, 4), (5, 4)]))
+    after = state()
+    assert len(before) == 5 * 4     # weight + (mean, var, master) a leaf
+    for k in before:
+        np.testing.assert_array_equal(before[k], after[k], err_msg=str(k))
+    scaler = mod._loss_scaler
+    scaler.publish()
+    assert scaler.skips == 1
+    sent1 = np.asarray(ff._sent_state)
+    assert sent1[3] - sent0[3] == 2         # columns 1 and 4
+    assert not np.isfinite(sent1[4])        # the norm saw them too
+
+    assert mod.fit_step(_batch(seed=8))       # finite again: applied
+    moved = state()
+    assert all(not np.array_equal(before[n], moved[n]) for n in ff._order)
+    scaler.publish()
+    assert scaler.skips == 1
+    assert np.asarray(ff._sent_state)[3] == sent1[3]
+
+
+def test_fit_step_2bit_residuals_bit_identical_to_eager_path():
+    """bf16 multi-precision Adam with 2-bit compression through
+    ``Module.fit_step``: the fused program's error-feedback residuals
+    (float32, on the master-gradient view) equal the eager fwd_bwd +
+    kvstore path's BIT FOR BIT, and so do the bf16 weights.  Both
+    widen the bf16 gradient the backward WROTE; while the fused step
+    widened at the top of its program, XLA updated from the dot's
+    unrounded accumulator and the residuals parted from the eager
+    path's in their last bits."""
+    res, weights = {}, {}
+    for fused in (True, False):
+        mod = _lp_mod(*_LP_CASES["adam_2bit"], fused=fused)
+        for t in range(3):
+            assert mod.fit_step(_batch(seed=t)) == fused
+        if fused:
+            res[fused] = {n: np.asarray(r) for n, r in
+                          mod._fused_fit._residuals.items()}
+        else:
+            mod._kvstore._sync_engine()     # spill the flat buckets
+            res[fused] = {k[0]: r.asnumpy() for k, r in
+                          mod._kvstore._compression_residuals.items()}
+        weights[fused] = {n: w.asnumpy()
+                          for n, w in mod.get_params()[0].items()}
+    assert sorted(res[True]) == sorted(res[False]) and res[True]
+    for n in res[True]:
+        assert res[True][n].dtype == np.float32
+        np.testing.assert_array_equal(res[True][n], res[False][n],
+                                      err_msg=n)
+        np.testing.assert_array_equal(weights[True][n], weights[False][n],
+                                      err_msg=n)
+    assert any(float(np.abs(r).sum()) > 0 for r in res[True].values())
+
+
+def test_cond_gradient_operands_keep_parameter_dtype():
+    """Structure of the traced step: every gradient the scaler's
+    ``cond`` takes as an operand has its parameter's dtype.  An operand
+    of a conditional is a buffer in memory, so a float32 view of a
+    bf16 gradient there is a second, wider copy of it."""
+    import jax
+    mod = _lp_mod("adam", {"learning_rate": 0.05, "multi_precision": True})
+    fn, args, _ = mod._get_fused_fit()._prepare(_batch(), None)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    while len(jaxpr.eqns) == 1 and "jaxpr" in jaxpr.eqns[0].params:
+        jaxpr = jaxpr.eqns[0].params["jaxpr"].jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    inputs = set(map(id, jaxpr.invars))
+    # the cond's operands the step computed itself (not its arguments)
+    computed = [v for v in conds[0].invars[1:]
+                if hasattr(v, "count") and id(v) not in inputs]
+    want = sorted((tuple(p.shape), str(p.dtype)) for p in args[0].values())
+    got = sorted((tuple(v.aval.shape), str(v.aval.dtype)) for v in computed
+                 if v.aval.shape)
+    assert got == want and all(d == "bfloat16" for _, d in got)
