@@ -4,7 +4,10 @@ Reference parity: example/image-classification/symbols/ (mlp, lenet,
 alexnet, vgg, resnet, resnext, mobilenet, inception-bn, googlenet,
 squeezenet, densenet). Each module exposes ``get_symbol(num_classes, ...)``
 returning a Symbol ending in SoftmaxOutput, so any of them drops into
-``Module.fit`` / ``bench.py`` unchanged.
+``Module.fit`` / ``bench.py`` unchanged.  Two language-model families
+beside them, with the same factory signature: ``transformer`` (GPT-2's
+block) and ``zaya`` (compressed convolutional attention and a dropless
+top-1 expert sublayer; its second output is the experts' token counts).
 
 These are fresh TPU-first definitions (bf16-friendly: ``dtype`` casts the
 trunk while the final classifier/softmax stays fp32), not translations of
@@ -22,9 +25,11 @@ from . import googlenet
 from . import squeezenet
 from . import densenet
 from . import transformer
+from . import zaya
 
 _NETWORKS = {
     "transformer": transformer,
+    "zaya": zaya,
     "mlp": mlp,
     "lenet": lenet,
     "alexnet": alexnet,

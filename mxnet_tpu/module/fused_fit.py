@@ -370,6 +370,9 @@ class FusedFitStep:
         # the cumulative non-finite count already pushed to the registry
         self._sent_state = None
         self._published_nonfinite = 0.0
+        # (output index, held_first, held_count) when the graph hands
+        # out its experts' token counts (telemetry.moe), else None
+        self._moe_counts = _telemetry.moe.find(module._symbol)
         self.launches = 0
         self._mem_tracker = _telemetry.StepMemoryTracker() \
             if _MEM_EVERY else None
@@ -861,6 +864,11 @@ class FusedFitStep:
         self._sent_state = new_sent if sent_on else None
         exe._write_auxs(new_auxs)
         exe._outputs = [NDArray(o, exe._ctx) for o in outs]
+        if self._moe_counts is not None:
+            # a reference to the counts' device array, read only when
+            # telemetry.moe.publish() is asked: no sync in the step
+            i, first, held = self._moe_counts
+            _telemetry.moe.note(outs[i], first, held)
         exe._pending_train_fwd = False
         exe._train_seed = None
         exe._train_auxs = None

@@ -17,6 +17,7 @@ from ..base import MXNetError
 from ..context import Context, cpu, current_context
 from ..initializer import Uniform, InitDesc
 from .. import optimizer as opt
+from .. import telemetry as _telemetry
 from ..model import (_create_kvstore, _initialize_kvstore,
                      _update_params_on_kvstore, _update_params,
                      load_checkpoint, save_checkpoint)
@@ -507,6 +508,9 @@ class Module(BaseModule):
             # (grad norm, non-finite count, z-score, residual drift)
             # into the registry
             self._fused_fit.publish_sentinels()
+        # same boundary: per-expert load from the counts the last fused
+        # step noted (returns at once when none did)
+        _telemetry.moe.publish()
         kv = self._kvstore
         if kv is not None and getattr(kv, "_engine", None) is not None:
             # the bucketed kvstore engine carries its own non-finite

@@ -312,6 +312,19 @@ def layer_norm(data, gamma, beta, *, axis=-1, eps=1e-5, output_mean_var=False):
     return (out.astype(data.dtype), jnp.squeeze(mean, ax), jnp.squeeze(inv_std, ax))
 
 
+@register("RMSNorm", aliases=("rms_norm",))
+def rms_norm(data, gamma, *, axis=-1, eps=1e-5):
+    """Root-mean-square normalization with a gain and no shift:
+    ``x / sqrt(mean(x^2) + eps) * gamma`` over ``axis``; the statistic
+    and the scaling in float32, the result in the data's dtype."""
+    ax = int(axis) % data.ndim
+    xf = data.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=ax, keepdims=True) + eps)
+    bshape = tuple(data.shape[i] if i == ax else 1 for i in range(data.ndim))
+    out = xf * inv * gamma.astype(jnp.float32).reshape(bshape)
+    return out.astype(data.dtype)
+
+
 @register("InstanceNorm")
 def instance_norm(data, gamma, beta, *, eps=1e-3):
     red = tuple(range(2, data.ndim))
@@ -721,26 +734,37 @@ def softmax_activation(data, *, mode="instance"):
 # attention entirely, SURVEY.md §5.7; sequence-parallel forms live in
 # parallel/ring_attention.py)
 # ----------------------------------------------------------------------
-def _use_flash_attention(seq_len, head_dim, dtype):
+def _use_flash_attention(seq_len, head_dim, dtype, kv_group=1):
     """Select the fused Pallas flash kernel.  MXNET_ATTN_IMPL:
     ``auto`` (default) = flash when the backend/geometry supports it,
     ``xla`` = force the materialized-softmax path (A/B runs),
     ``flash`` = require the kernel — raise instead of silently measuring
     the wrong path when it cannot run.  The selection semantics live in
     ``pallas.dispatch.choose_impl``, shared with the paged-attention
-    and quantize knobs so the three contracts cannot drift."""
+    and quantize knobs so the three contracts cannot drift.
+
+    ``kv_group`` is the number of query heads to a key/value head.  The
+    kernel takes equal head counts, so a caller with ``kv_group`` > 1
+    hands it K and V repeated over their group; the geometry it needs is
+    the same, and a fallback there is counted under its own reason."""
     import os
     from ..pallas.dispatch import _compiles_here, choose_impl
     here, why, reason = _compiles_here()
     supported = (here and head_dim % 128 == 0 and seq_len % 512 == 0
                  and dtype in (jnp.bfloat16, jnp.float32))
+    grouped = int(kv_group) > 1
     return choose_impl(
         "MXNET_ATTN_IMPL", os.environ.get("MXNET_ATTN_IMPL", "auto"),
         "flash", supported,
         why=f"{why or 'one TPU device'}, head_dim={head_dim}, "
-            f"seq={seq_len}, dtype={dtype}; need a one-device TPU "
-            f"program, head_dim%128==0, seq%512==0, bf16/f32",
-        fallback_reason=reason or "flash-geometry")
+            f"seq={seq_len}, dtype={dtype}"
+            + (f", {kv_group} query heads to a key/value head (K/V "
+               f"repeated over their group for the kernel)"
+               if grouped else "")
+            + "; need a one-device TPU "
+              "program, head_dim%128==0, seq%512==0, bf16/f32",
+        fallback_reason=reason or ("flash-geometry-gqa" if grouped
+                                   else "flash-geometry"))
 
 
 def _flash_attention(q, k, v, sm_scale):
@@ -870,6 +894,128 @@ def fused_causal_self_attention(data, qkv_weight, qkv_bias, proj_weight,
     o = _shard_heads(o)
     return jnp.einsum("bhse,dhe->bsd", o,
                       proj_weight.reshape(d, H, D)) + proj_bias
+
+
+def _shift_right(x, n, axis):
+    """``x`` moved ``n`` positions later along ``axis``, zeros first: a
+    causal tap (position t reads t - n, and nothing before position 0)."""
+    if n == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (n, 0)
+    return lax.slice_in_dim(jnp.pad(x, pad), 0, x.shape[axis], axis=axis)
+
+
+def _rotary_half(x, rot, theta):
+    """Rotary position on the first ``rot`` of the last axis of a
+    head-major (B, H, S, D) float32 tensor, the default pairing of
+    halves (i with i + rot/2); positions 0..S-1."""
+    if not rot:
+        return x
+    half = rot // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
+    ang = jnp.arange(x.shape[2], dtype=jnp.float32)[:, None] * freq[None]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :half], x[..., half:rot]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                            x[..., rot:]], axis=-1)
+
+
+@register("_contrib_CompressedConvAttention",
+          aliases=("CompressedConvAttention",))
+def compressed_conv_attention(data, q_weight, k_weight, v_weight,
+                              conv0_weight, conv1_weight, temp, o_weight, *,
+                              q_heads, kv_heads, head_dim, conv_k0=2,
+                              conv_k1=2, rotary_frac=0.5, rope_theta=5e6):
+    """Compressed convolutional attention (CCA, arXiv:2510.04476) as
+    one sublayer, (B, S, d) -> (B, S, d), on an already normalised
+    stream; no linear map has a bias.
+
+    Queries live in a ``q_heads * head_dim`` latent and keys/values in a
+    ``kv_heads * head_dim`` one, both narrower than the model:
+    ``q0 = h Wq``, ``k0 = h Wk``; the channels of ``[q0, k0]`` pass a
+    depthwise causal convolution over the sequence (``conv0_weight``
+    (channels, conv_k0)) and a causal convolution that mixes the
+    channels of each head (``conv1_weight`` (heads, out, in, conv_k1));
+    the mean of q0 and its key head's k0 is added back (to the key: that
+    mean over its query heads); the value's first head comes from this
+    token and its second from the token before; q and k are scaled to
+    length sqrt(head_dim), k times a learned ``temp`` a key/value head;
+    rotary position turns the first ``rotary_frac`` of each head; causal
+    softmax attention with ``q_heads / kv_heads`` query heads to a
+    key/value head; output projection.  Weights are (out, in), as
+    FullyConnected's.
+
+    Head-major like FusedCausalSelfAttention: the projections emit and
+    consume (B, H, S, D).  The Pallas flash kernel takes K/V repeated
+    over their group where ``_use_flash_attention`` allows it, the
+    checkpointed XLA path otherwise.  Everything between the
+    projections and the attention is cheap and rematerialized in the
+    backward pass, so a layer saves its latents and no float32 copy of
+    them.  Scopes for a reader of the raw trace: ``cca.proj``,
+    ``cca.conv``, ``cca.attention``."""
+    B, S, d = data.shape
+    Hq, Hk, D = int(q_heads), int(kv_heads), int(head_dim)
+    K0, K1 = int(conv_k0), int(conv_k1)
+    if Hq % Hk:
+        raise ValueError("q_heads %d not a multiple of kv_heads %d"
+                         % (Hq, Hk))
+    if Hk != 2:
+        raise ValueError("the value shift gives one value head to this "
+                         "token and one to the token before: kv_heads "
+                         "must be 2, got %d" % Hk)
+    G, H = Hq // Hk, Hq + Hk
+    rot = int(round(float(rotary_frac) * D))
+    f32 = jnp.float32
+
+    with jax.named_scope("cca.proj"):
+        z = jnp.einsum("bsd,hed->bhse", data,
+                       jnp.concatenate([q_weight, k_weight], 0)
+                       .reshape(H, D, d))
+        v2 = jnp.einsum("bsd,hed->bhse", data, v_weight.reshape(2, D, d))
+
+    @jax.checkpoint
+    def mix(z, v2, w0, w1, temp):
+        zf = z.astype(f32)
+        w0 = w0.astype(f32).reshape(1, H, 1, D, K0)
+        z1 = sum(_shift_right(zf, K0 - 1 - j, 2) * w0[..., j]
+                 for j in range(K0)).astype(z.dtype)
+        z2 = sum(jnp.einsum("bhsi,hoi->bhso", _shift_right(z1, K1 - 1 - j, 2),
+                            w1[..., j]) for j in range(K1)).astype(f32)
+        q0 = zf[:, :Hq].reshape(B, Hk, G, S, D)
+        mq = 0.5 * (q0 + zf[:, Hq:, None])
+        q = z2[:, :Hq] + mq.reshape(B, Hq, S, D)
+        k = z2[:, Hq:] + jnp.mean(mq, axis=2)
+        unit = lambda t: t * lax.rsqrt(
+            jnp.sum(jnp.square(t), -1, keepdims=True)) * D ** 0.5
+        q = _rotary_half(unit(q), rot, float(rope_theta))
+        k = _rotary_half(unit(k) * temp.astype(f32).reshape(1, Hk, 1, 1),
+                         rot, float(rope_theta))
+        v = jnp.stack([v2[:, 0], _shift_right(v2[:, 1], 1, 1)], axis=1)
+        return q.astype(z.dtype), k.astype(z.dtype), v
+
+    with jax.named_scope("cca.conv"):
+        q, k, v = mix(z, v2, conv0_weight, conv1_weight, temp)
+
+    sc = 1.0 / D ** 0.5
+    with jax.named_scope("cca.attention"):
+        if _use_flash_attention(S, D, data.dtype, kv_group=G):
+            o = _flash_attention(q, jnp.repeat(k, G, axis=1),
+                                 jnp.repeat(v, G, axis=1), sc)
+        else:
+            @jax.checkpoint
+            def attn(q, k, v):
+                s = jnp.einsum("bgrqe,bgke->bgrqk",
+                               q.reshape(B, Hk, G, S, D), k) * sc
+                mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+                s = jnp.where(mask, s.astype(f32), -1e30)
+                p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+                return jnp.einsum("bgrqk,bgke->bgrqe", p, v) \
+                    .reshape(B, Hq, S, D)
+            o = attn(q, k, v)
+
+    with jax.named_scope("cca.proj"):
+        return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
 
 
 # ----------------------------------------------------------------------
@@ -1182,6 +1328,54 @@ def switch_moe_op(data, router_weight, expert_up_weight, expert_up_bias,
     y, aux = _switch(params, tokens, k=int(k),
                      capacity_factor=float(capacity_factor))
     return y.reshape(data.shape), aux
+
+
+@register("_contrib_RoutedExperts", aliases=("RoutedExperts",),
+          num_outputs=3, num_visible_outputs=3)
+def routed_experts(data, router_in_weight, router_norm_gamma,
+                   router_fc1_weight, router_fc2_weight, router_out_weight,
+                   gate_weight, up_weight, down_weight, router_state=None,
+                   router_carry=None, *, num_experts, held_first=0,
+                   held_count=None, num_hidden, router_hidden,
+                   carry_in=True):
+    """The dropless top-1 expert sublayer of a chip that holds
+    ``held_count`` of ``num_experts`` experts (``held_first`` onwards),
+    on an already normalised stream (..., d).
+
+    The ZAYA router scores ALL experts in float32: its state
+    ``r = h W_in + carry * r_prev`` takes the previous layer's state
+    (``router_state`` and the scalar ``router_carry``, the last two
+    inputs, both absent with ``carry_in=False``: the first layer),
+    then a 3-layer GELU MLP of width ``router_hidden`` and a softmax.
+    Each token goes to its best expert; the tokens of the experts held
+    here are sorted and run through three grouped matrix products
+    (SiLU-gated FFN of width ``num_hidden``; stacks (held, out, in)),
+    weighted by the expert's probability.  A token whose expert lives on
+    another chip gets 0 from this sublayer.  No capacity, no dropped
+    token (``SwitchMoE`` keeps its capacity semantics).
+
+    Outputs: ``y`` like ``data``; the router state (..., router_hidden)
+    float32 for the next layer; int32 (num_experts,) tokens an expert,
+    over all experts.  Scopes: ``moe.router``, ``moe.dispatch``,
+    ``moe.experts``, ``moe.combine``."""
+    from ..parallel.moe import dropless_top1_experts, zaya_router
+    lead, d = data.shape[:-1], data.shape[-1]
+    R = int(router_hidden)
+    held = int(num_experts) - int(held_first) if held_count is None \
+        else int(held_count)
+    if gate_weight.shape[0] != held:
+        raise ValueError("expert stacks hold %d experts, held_count=%d"
+                         % (gate_weight.shape[0], held))
+    x = data.reshape(-1, d)
+    with jax.named_scope("moe.router"):
+        r, prob = zaya_router(
+            x, router_state.reshape(-1, R) if carry_in else None,
+            router_in_weight, router_carry, router_norm_gamma,
+            router_fc1_weight, router_fc2_weight, router_out_weight)
+    y, counts = dropless_top1_experts(x, prob, gate_weight, up_weight,
+                                      down_weight, int(held_first))
+    return (y.reshape(data.shape), r.reshape(lead + (R,)),
+            lax.stop_gradient(counts))
 
 
 # ----------------------------------------------------------------------

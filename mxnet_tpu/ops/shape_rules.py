@@ -111,6 +111,55 @@ def _switch_moe_shapes(known, attrs):
 _set("_contrib_SwitchMoE", _switch_moe_shapes)
 
 
+def _routed_experts_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    E, h, R = (int(attrs[k]) for k in ("num_experts", "num_hidden",
+                                       "router_hidden"))
+    held = attrs.get("held_count")
+    held = E - int(attrs.get("held_first", 0)) if held is None else int(held)
+    return {"router_state": tuple(data[:-1]) + (R,),
+            "router_in_weight": (R, d), "router_carry": (1,),
+            "router_norm_gamma": (R,), "router_fc1_weight": (R, R),
+            "router_fc2_weight": (R, R), "router_out_weight": (E, R),
+            "gate_weight": (held, h, d), "up_weight": (held, h, d),
+            "down_weight": (held, d, h)}
+
+
+_set("_contrib_RoutedExperts", _routed_experts_shapes,
+     lambda attrs: set() if attrs.get("carry_in", True)
+     else {"router_state", "router_carry"})
+
+
+def _cca_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    Hq, Hk, D = (int(attrs[k]) for k in ("q_heads", "kv_heads", "head_dim"))
+    H = Hq + Hk
+    return {"q_weight": (Hq * D, d), "k_weight": (Hk * D, d),
+            "v_weight": (2 * D, d),
+            "conv0_weight": (H * D, int(attrs.get("conv_k0", 2))),
+            "conv1_weight": (H, D, D, int(attrs.get("conv_k1", 2))),
+            "temp": (Hk,), "o_weight": (d, Hq * D)}
+
+
+_set("_contrib_CompressedConvAttention", _cca_shapes)
+
+
+def _rms_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    return {"gamma": (data[int(attrs.get("axis", -1)) % len(data)],)}
+
+
+_set("RMSNorm", _rms_shapes)
+
+
 def _fused_attn_shapes(known, attrs):
     data = known.get("data")
     if data is None:

@@ -24,6 +24,18 @@ Capacity semantics: each expert processes at most
 ``ceil(k·N/E · capacity_factor)`` tokens; overflowing tokens are
 dropped from that expert (their combine weight is zero), the standard
 Switch behavior.
+
+Which routine drops and which does not: ``switch_moe`` DROPS (capacity,
+dense one-hot dispatch ``(N, E, C)``); ``dropless_top1_experts`` never
+does.  It is the expert layer of a chip that holds a SHARE of the
+experts (``model-configs`` guide, section 4): probabilities come over
+all experts, the tokens of the experts held here are sorted by expert
+and run through three grouped matrix products (gate, up, down of a
+SiLU-gated FFN; ``grouped_matmul``: the Pallas kernel where it compiles,
+``jax.lax.ragged_dot_general`` elsewhere), and a token whose expert
+lives elsewhere contributes 0.  No capacity, no exchange, and nothing that
+stands in for the absent chips; ``zaya_router`` is the router that
+goes with it (docs/TRAINING.md, "The dropless expert layer").
 """
 from __future__ import annotations
 
@@ -33,7 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["switch_moe", "moe_reference", "init_moe_params"]
+__all__ = ["switch_moe", "moe_reference", "init_moe_params",
+           "zaya_router", "dropless_top1_experts"]
 
 
 def init_moe_params(key, d_model, d_hidden, n_experts, dtype=jnp.float32):
@@ -139,3 +152,133 @@ def switch_moe(params, x, k=1, capacity_factor=1.25, mesh=None,
     p = probs.astype(jnp.float32).mean(0)
     aux = E * jnp.sum(f * p)
     return y, aux
+
+
+# ----------------------------------------------------------------------
+# The dropless top-1 layer of a chip that holds some of the experts
+# ----------------------------------------------------------------------
+def _rms(x, gain, eps=1e-5):
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
+                         + eps) * gain
+
+
+def zaya_router(h, r_prev, w_in, carry, norm_gain, w1, w2, w_out):
+    """The ZAYA router on tokens ``h`` (N, d), in float32 whatever the
+    model's dtype: ``r = h W_in + carry * r_prev`` (the state the next
+    layer's router receives; ``r_prev`` None for the first layer), then
+    ``softmax(W_out gelu(W2 gelu(W1 RMSNorm(r))))`` over ALL experts.
+    Weights are (out, in).  Returns ``(r, probabilities (N, E))``."""
+    f32 = jnp.float32
+    # float32 products, not the TPU's one bfloat16 pass over float32
+    # operands: a score's last bits decide which expert a token gets
+    mm = lambda a, w: jnp.einsum("nd,rd->nr", a, w.astype(f32),
+                                 precision=lax.Precision.HIGHEST)
+    r = mm(h.astype(f32), w_in)
+    if r_prev is not None:
+        r = r + carry.astype(f32) * r_prev.astype(f32)
+    u = _rms(r, norm_gain.astype(f32))
+    u = jax.nn.gelu(mm(u, w1), approximate=False)
+    u = jax.nn.gelu(mm(u, w2), approximate=False)
+    return r, jax.nn.softmax(mm(u, w_out), axis=-1)
+
+
+_RAGGED_OUT_IN = lax.RaggedDotDimensionNumbers(
+    # (rows, in) x (group, out, in) -> (rows, out): the stacks keep
+    # FullyConnected's (out, in) layout
+    dot_dimension_numbers=(((1,), (2,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
+
+# rows, contraction, columns of a tile of the Pallas grouped matmul
+# (jax's megablox): the faster of the two tried on the v5e at 8192 x
+# 2048 x 2048 over 8 groups (PERF.md, PR 26)
+_GMM_TILING = (512, 1024, 1024)
+
+
+def _grouped_matmul_impl(rows, dtype):
+    """How :func:`grouped_matmul` runs when not told: the Pallas kernel
+    (``"compiled"``) in a one-device TPU program whose rows fill whole
+    tiles, else XLA's ragged product (False; the fallback is counted in
+    ``pallas_fallbacks{reason}``).  No knob: a test passes ``impl``."""
+    from ..pallas.dispatch import _compiles_here, choose_impl
+    here, why, reason = _compiles_here()
+    supported = (here and rows % _GMM_TILING[0] == 0
+                 and dtype in (jnp.bfloat16, jnp.float32))
+    return choose_impl(
+        "grouped_matmul (no knob)", "auto", "grouped_matmul", supported,
+        why=f"{why or 'one TPU device'}, rows={rows}, dtype={dtype}; need "
+            f"a one-device TPU program, rows%{_GMM_TILING[0]}==0, bf16/f32",
+        fallback_reason=reason or "grouped-geometry")
+
+
+def grouped_matmul(x, w, group_sizes, impl=None):
+    """Rows of ``x`` (N, in), sorted by group, times their group's
+    matrix of ``w`` (G, out, in) -> (N, out).  Rows past the last group
+    belong to no expert held here: what comes out of them is NOT
+    defined (XLA's ragged product writes zeros forward and garbage
+    backward, the kernel the reverse), so the caller masks them.
+
+    ``impl``: None chooses (:func:`_grouped_matmul_impl`);
+    ``"compiled"`` / ``"interpret"`` is the Pallas grouped matmul under
+    scope ``pallas.grouped_matmul`` (forward, and both backward
+    products: its own VJP); False is ``jax.lax.ragged_dot_general``."""
+    if impl is None:
+        impl = _grouped_matmul_impl(x.shape[0], x.dtype)
+    if not impl:
+        return lax.ragged_dot_general(x, w, group_sizes, _RAGGED_OUT_IN)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
+    from ..pallas.attention import _count_launch
+    _count_launch("grouped_matmul")
+    tiling = tuple(min(t, n) for t, n in zip(
+        _GMM_TILING, (x.shape[0], x.shape[1], w.shape[1])))
+    with jax.named_scope("pallas.grouped_matmul"):
+        return _megablox.gmm(x, w, group_sizes,
+                             preferred_element_type=x.dtype, tiling=tiling,
+                             transpose_rhs=True,
+                             interpret=impl == "interpret")
+
+
+def dropless_top1_experts(x, prob, w_gate, w_up, w_down, held_first=0,
+                          impl=None):
+    """Top-1 SiLU-gated expert FFNs for the experts held here, nothing
+    dropped.  ``x`` (N, d) tokens, ``prob`` (N, E) float32 over ALL
+    experts; ``w_gate``/``w_up`` (held, hidden, d), ``w_down`` (held, d,
+    hidden) are experts ``held_first .. held_first + held - 1``.
+    Returns ``(y (N, d), tokens an expert int32 (E,))`` with
+    ``y = p_e * down_e(silu(gate_e x) * up_e x)`` for a token whose
+    expert ``e = argmax(prob)`` (ties: the lower index) is held here,
+    0 otherwise.  The gradient reaches the router through ``p_e``.
+    ``impl`` is :func:`grouped_matmul`'s (None: chosen once for all
+    three products)."""
+    N, _ = x.shape
+    E = prob.shape[-1]
+    held = w_gate.shape[0]
+    f32 = jnp.float32
+    if impl is None:
+        impl = _grouped_matmul_impl(N, x.dtype)
+    with jax.named_scope("moe.dispatch"):
+        e = jnp.argmax(prob, axis=-1).astype(jnp.int32)
+        pe = jnp.take_along_axis(prob, e[:, None], axis=-1)[:, 0]
+        counts = jnp.sum(e[:, None] == jnp.arange(E, dtype=jnp.int32),
+                         axis=0, dtype=jnp.int32)
+        local = e - int(held_first)
+        here = (local >= 0) & (local < held)
+        # held tokens first, grouped by expert; the others after them
+        order = jnp.argsort(jnp.where(here, local, held), stable=True)
+        sizes = lax.slice_in_dim(counts, int(held_first),
+                                 int(held_first) + held)
+        here_s = jnp.take(here, order)[:, None]
+        # what a grouped product leaves in the rows of no group is not
+        # defined, forward or backward (it may be NaN): every operand
+        # and result is masked there, and with it its gradient
+        own = lambda t: jnp.where(here_s, t, 0)
+        xs = own(jnp.take(x, order, axis=0, unique_indices=True))
+    with jax.named_scope("moe.experts"):
+        g = own(grouped_matmul(xs, w_gate, sizes, impl))
+        u = own(grouped_matmul(xs, w_up, sizes, impl))
+        mid = own((jax.nn.silu(g.astype(f32)) * u.astype(f32))
+                  .astype(x.dtype))
+        ys = own(grouped_matmul(mid, w_down, sizes, impl))
+    with jax.named_scope("moe.combine"):
+        ys = (ys.astype(f32) * jnp.take(pe, order)[:, None]).astype(x.dtype)
+        y = jnp.zeros_like(ys).at[order].set(ys, unique_indices=True)
+    return y, counts

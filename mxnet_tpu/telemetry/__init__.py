@@ -37,6 +37,10 @@ One place to read every operational witness the framework emits
   once-per-incident alerts, plus the in-launch numerics witness series
   (``grad_norm``/``nonfinite_grads``/``residual_drift``/
   ``loss_zscore``).
+* :mod:`moe` — per-expert load of the dropless expert layer
+  (``moe_expert_tokens{layer,expert}``, ``moe_tokens_away{layer}``,
+  ``moe_expert_load_max_over_mean``), read from the last step's count
+  outputs at sync boundaries, never in the step.
 
 This package is stdlib-only at import (jax is touched lazily inside
 :mod:`memory`/:mod:`programs`), so the registry is safe to import from
@@ -62,6 +66,7 @@ from .health import PodHealthMonitor, Watchdog
 from . import aggregate
 from .aggregate import PodMetricsAggregator
 from . import sentinel
+from . import moe
 
 
 class _ProgramsFacade:
@@ -80,7 +85,7 @@ programs = _ProgramsFacade()
 
 __all__ = [
     "registry", "export", "flight", "memory", "chrome", "tracing",
-    "health", "programs", "aggregate", "sentinel",
+    "health", "programs", "aggregate", "sentinel", "moe",
     "PodMetricsAggregator",
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
     "counter", "gauge", "histogram", "enable", "disable", "enabled",

@@ -192,3 +192,52 @@ def test_fit_program_conditional_takes_gradients_narrow(one_chip):
               if fused_update.is_low_precision(p.dtype)}
     assert narrow and all(seen[s] > 0 for s in narrow)
     assert {s: seen[s] for s in narrow} == {s: allowed[s] for s in narrow}
+
+
+def test_grouped_expert_products_fwd_grad_compile(one_chip):
+    """The dropless expert layer at the ZAYA cell's widths (8192 tokens,
+    8 held of 16 experts, 2048 -> 2048 -> 2048 in bf16): sort, gather,
+    the three grouped products over (held, out, in) stacks with the
+    Pallas grouped matmul at the tiles ``parallel/moe.py`` picks,
+    scatter, forward and backward.  The choice asks
+    ``jax.default_backend()``, which is the CPU here: the test says
+    ``impl`` as the chip would choose."""
+    from mxnet_tpu.parallel.moe import dropless_top1_experts
+    N, d, F, E, held = 8192, 2048, 2048, 16, 8
+
+    def loss(x, logits, wg, wu, wd):
+        y, counts = dropless_top1_experts(
+            x, jax.nn.softmax(logits, -1), wg, wu, wd, held_first=0,
+            impl="compiled")
+        return y.astype(jnp.float32).sum() + counts.sum()
+
+    stack = ((held, F, d), jnp.bfloat16)
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+        ((N, d), jnp.bfloat16), ((N, E), jnp.float32), stack, stack,
+        ((held, d, F), jnp.bfloat16))
+    assert "ragged" not in compiled.as_text()
+
+
+def test_compressed_conv_attention_fwd_grad_compiles_with_flash(
+        one_chip, monkeypatch):
+    """CCA at the ZAYA cell's widths (one sequence of 8192, 8 query to 2
+    key/value heads of 128, d 2048, bf16) with the flash kernel, which
+    takes K and V repeated over their group.  The kernel choice asks
+    ``jax.default_backend()``, which is the CPU here: the test steers
+    it, as the chip would answer."""
+    from mxnet_tpu.ops import nn
+    monkeypatch.setattr(nn, "_use_flash_attention",
+                        lambda *a, **k: "compiled")
+    S, d, Hq, Hk, Dh = 8192, 2048, 8, 2, 128
+
+    def loss(h, *ws):
+        return nn.compressed_conv_attention(
+            h, *ws, q_heads=Hq, kv_heads=Hk, head_dim=Dh) \
+            .astype(jnp.float32).sum()
+
+    bf = jnp.bfloat16
+    _compile(jax.value_and_grad(loss, argnums=tuple(range(8))), one_chip,
+             ((1, S, d), bf), ((Hq * Dh, d), bf), ((Hk * Dh, d), bf),
+             ((2 * Dh, d), bf), ((10 * Dh, 2), bf), ((10, Dh, Dh, 2), bf),
+             ((Hk,), bf), ((d, Hq * Dh), bf))
