@@ -734,7 +734,7 @@ def softmax_activation(data, *, mode="instance"):
 # attention entirely, SURVEY.md §5.7; sequence-parallel forms live in
 # parallel/ring_attention.py)
 # ----------------------------------------------------------------------
-def _use_flash_attention(seq_len, head_dim, dtype, kv_group=1):
+def _use_flash_attention(seq_len, head_dim, dtype):
     """Select the fused Pallas flash kernel.  MXNET_ATTN_IMPL:
     ``auto`` (default) = flash when the backend/geometry supports it,
     ``xla`` = force the materialized-softmax path (A/B runs),
@@ -743,48 +743,113 @@ def _use_flash_attention(seq_len, head_dim, dtype, kv_group=1):
     ``pallas.dispatch.choose_impl``, shared with the paged-attention
     and quantize knobs so the three contracts cannot drift.
 
-    ``kv_group`` is the number of query heads to a key/value head.  The
-    kernel takes equal head counts, so a caller with ``kv_group`` > 1
-    hands it K and V repeated over their group; the geometry it needs is
-    the same, and a fallback there is counted under its own reason."""
+    The geometry is the same whatever the head counts: the kernel shares
+    a key/value head among its query heads itself (``_flash_attention``).
+    It is never interpreted here: only a test passes ``interpret=True``
+    to ``_flash_attention``."""
     import os
     from ..pallas.dispatch import _compiles_here, choose_impl
     here, why, reason = _compiles_here()
     supported = (here and head_dim % 128 == 0 and seq_len % 512 == 0
                  and dtype in (jnp.bfloat16, jnp.float32))
-    grouped = int(kv_group) > 1
     return choose_impl(
         "MXNET_ATTN_IMPL", os.environ.get("MXNET_ATTN_IMPL", "auto"),
         "flash", supported,
         why=f"{why or 'one TPU device'}, head_dim={head_dim}, "
-            f"seq={seq_len}, dtype={dtype}"
-            + (f", {kv_group} query heads to a key/value head (K/V "
-               f"repeated over their group for the kernel)"
-               if grouped else "")
-            + "; need a one-device TPU "
-              "program, head_dim%128==0, seq%512==0, bf16/f32",
-        fallback_reason=reason or ("flash-geometry-gqa" if grouped
-                                   else "flash-geometry"))
+            f"seq={seq_len}, dtype={dtype}; need a one-device TPU "
+            "program, head_dim%128==0, seq%512==0, bf16/f32",
+        fallback_reason=reason or "flash-geometry")
 
 
-def _flash_attention(q, k, v, sm_scale):
-    """Invoke the Pallas flash kernel on head-major (B, H, S, D) inputs
-    with the 512x512 block geometry measured fastest on v5e at S1024/D128
-    (docs/PERF.md round 5 — the library defaults measure SLOWER than the
-    XLA path)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes as _BlockSizes, flash_attention as _flash)
+def _flash_block_sizes(seq_len, head_dim):
+    """The flash kernel's tiles, from the shapes alone (``seq_len`` is a
+    multiple of 512 and ``head_dim`` of 128: the geometry gate).
+
+    Every block is a whole number of 512-row tiles that divides the
+    sequence.  Forward: 1024 query rows against 1024 resident key/value
+    rows where that divides it, the scores computed 512 columns at a
+    time.
+
+    Backward: ONE kernel walks the key/value sequence in resident blocks
+    of ``block_kv_dkv`` rows, computes each 512 x 512 score block of it
+    once and emits dq, dk and dv from it.  Its dq comes out as one
+    partial per resident block, ``seq_len / block_kv_dkv`` of them in
+    q's dtype, summed afterwards: each a copy of q written and read
+    back.  A larger resident block makes fewer partials, but the kernel
+    skips a resident block only when ALL of it lies above the diagonal:
+    inside one it also computes the 512-blocks the causal mask would
+    drop.  At most a quarter of the sequence balances the two: 4
+    partials, no wasted block at 2048 (the LM cell: 512 rows resident)
+    and 160 blocks computed for 136 at 8192 (the ZAYA cell: 2048 rows);
+    the partials are rounded to q's dtype before their sum.  k, v and
+    the float32 dk, dv of the resident rows live in VMEM: 2048 rows of
+    128 at most (on the v5e 4096 ran out of it beside 2048 query rows,
+    and were slower where they fit: PERF.md, PR 27)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
+    tiles = seq_len // 512
+
+    def rows(at_most):
+        """512 x the largest divisor of ``tiles`` within ``at_most``."""
+        return 512 * max(n for n in range(1, max(1, at_most) + 1)
+                         if tiles % n == 0)
+
+    fwd = rows(2)
+    resident = rows(min(tiles // 4, 4 * 128 // head_dim))
+    return BlockSizes(
+        block_q=fwd, block_kv=fwd, block_kv_compute=512,
+        block_q_dkv=512, block_kv_dkv=resident, block_kv_dkv_compute=512,
+        use_fused_bwd_kernel=True)
+
+
+@_functools.lru_cache(maxsize=None)
+def _flash_kernel(q_heads, kv_heads, seq_len, head_dim, interpret):
+    """jax's splash-attention kernel for one causal sequence, built once
+    per geometry: the mask's block tables are host numpy work at trace
+    time, and every layer of a model asks for the same ones."""
+    import numpy as np
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        CausalMask, MultiHeadMask, make_splash_mha)
+    mask = MultiHeadMask([CausalMask((seq_len, seq_len))] * q_heads)
+    # the kernel keeps its tables as arrays: made outside whatever trace
+    # asks first, and kept on the host, they are constants of every
+    # program that uses them
+    with jax.ensure_compile_time_eval():
+        kernel = make_splash_mha(
+            mask, head_shards=1, q_seq_shards=1, interpret=interpret,
+            block_sizes=_flash_block_sizes(seq_len, head_dim))
+    return jax.tree_util.tree_map(np.asarray, kernel)
+
+
+def _flash_attention(q, k, v, *, interpret=False):
+    """Causal attention of head-major q (B, Hq, S, D) over k, v
+    (B, Hk, S, D), Hq a multiple of Hk, by jax's splash-attention Pallas
+    kernel: float32 scores, statistics and accumulators whatever the
+    operands' dtype; a key/value head is shared by its Hq / Hk query
+    heads inside the kernel (no repeated K/V, dK and dV summed over the
+    group in VMEM); the backward computes every score block once and
+    emits dq, dk, dv from one kernel (``_flash_block_sizes``).
+
+    The kernel takes no softmax scale: ``q`` carries it (a caller scales
+    q where it is still float32, so q is rounded once).  ``interpret``
+    is for the tests; ``_use_flash_attention`` never asks for it."""
     from ..pallas.attention import _count_launch
     _count_launch("flash_attention")
-    blk = 512  # geometry gate guarantees S % 512 == 0
-    bs = _BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-        block_q_major_dkv=blk, block_k_major_dkv=blk,
-        block_k_dkv=blk, block_q_dkv=blk,
-        block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
+    kernel = _flash_kernel(q.shape[1], k.shape[1], q.shape[2], q.shape[3],
+                           bool(interpret))
     with jax.named_scope("pallas.flash_attention"):
-        return _flash(q, k, v, causal=True, sm_scale=sm_scale,
-                      block_sizes=bs)
+        return jax.vmap(kernel)(q, k, v)
+
+
+def _project_heads(spec, data, weight, bias, scale=None):
+    """``data`` times a head-major ``weight`` plus ``bias``.  With
+    ``scale`` the float32 accumulator is scaled before it is rounded to
+    ``data``'s dtype: how a query gets the softmax scale that the flash
+    kernel does not take, with one rounding."""
+    if scale is None:
+        return jnp.einsum(spec, data, weight) + bias
+    acc = jnp.einsum(spec, data, weight,
+                     preferred_element_type=jnp.float32)
+    return ((acc + bias) * scale).astype(data.dtype)
 
 
 @register("_contrib_CausalSelfAttention", aliases=("CausalSelfAttention",))
@@ -814,7 +879,10 @@ def causal_self_attention(qkv, *, num_heads, scale=None):
         # would re-pay the whole kernel a third time).
         q, k, v = jnp.split(qkv, 3, axis=-1)
         to_heads = lambda t: t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-        o = _flash_attention(to_heads(q), to_heads(k), to_heads(v), sc)
+        # the kernel takes no scale and q arrives rounded: scaled in
+        # float32 and rounded once more
+        q = (q.astype(jnp.float32) * sc).astype(qkv.dtype)
+        o = _flash_attention(to_heads(q), to_heads(k), to_heads(v))
         return o.transpose(0, 2, 1, 3).reshape(B, S, d)
 
     @jax.checkpoint
@@ -875,12 +943,14 @@ def fused_causal_self_attention(data, qkv_weight, qkv_bias, proj_weight,
 
     Wqkv = qkv_weight.reshape(3, H, D, d)
     bqkv = qkv_bias.reshape(3, H, 1, D)
-    q = _shard_heads(jnp.einsum("bsd,hed->bhse", data, Wqkv[0]) + bqkv[0])
-    k = _shard_heads(jnp.einsum("bsd,hed->bhse", data, Wqkv[1]) + bqkv[1])
-    v = _shard_heads(jnp.einsum("bsd,hed->bhse", data, Wqkv[2]) + bqkv[2])
+    flash = _use_flash_attention(S, D, data.dtype)
+    proj = _functools.partial(_project_heads, "bsd,hed->bhse", data)
+    q = _shard_heads(proj(Wqkv[0], bqkv[0], sc if flash else None))
+    k = _shard_heads(proj(Wqkv[1], bqkv[1]))
+    v = _shard_heads(proj(Wqkv[2], bqkv[2]))
 
-    if _use_flash_attention(S, D, data.dtype):
-        o = _flash_attention(q, k, v, sc)
+    if flash:
+        o = _flash_attention(q, k, v)
     else:
         @jax.checkpoint
         def attn(q, k, v):
@@ -947,9 +1017,11 @@ def compressed_conv_attention(data, q_weight, k_weight, v_weight,
     FullyConnected's.
 
     Head-major like FusedCausalSelfAttention: the projections emit and
-    consume (B, H, S, D).  The Pallas flash kernel takes K/V repeated
-    over their group where ``_use_flash_attention`` allows it, the
-    checkpointed XLA path otherwise.  Everything between the
+    consume (B, H, S, D).  Where ``_use_flash_attention`` allows it the
+    Pallas flash kernel takes K and V at their own ``kv_heads`` and
+    shares each among its query heads itself (q then carries the softmax
+    scale: it is given length 1, not sqrt(head_dim)); the checkpointed
+    XLA path otherwise.  Everything between the
     projections and the attention is cheap and rematerialized in the
     backward pass, so a layer saves its latents and no float32 copy of
     them.  Scopes for a reader of the raw trace: ``cca.proj``,
@@ -974,6 +1046,12 @@ def compressed_conv_attention(data, q_weight, k_weight, v_weight,
                        .reshape(H, D, d))
         v2 = jnp.einsum("bsd,hed->bhse", data, v_weight.reshape(2, D, d))
 
+    sc = 1.0 / D ** 0.5
+    flash = _use_flash_attention(S, D, data.dtype)
+    # the flash kernel takes no softmax scale: there q carries it, in
+    # the float32 length it is given before its one rounding
+    q_length = 1.0 if flash else D ** 0.5
+
     @jax.checkpoint
     def mix(z, v2, w0, w1, temp):
         zf = z.astype(f32)
@@ -986,10 +1064,11 @@ def compressed_conv_attention(data, q_weight, k_weight, v_weight,
         mq = 0.5 * (q0 + zf[:, Hq:, None])
         q = z2[:, :Hq] + mq.reshape(B, Hq, S, D)
         k = z2[:, Hq:] + jnp.mean(mq, axis=2)
-        unit = lambda t: t * lax.rsqrt(
-            jnp.sum(jnp.square(t), -1, keepdims=True)) * D ** 0.5
-        q = _rotary_half(unit(q), rot, float(rope_theta))
-        k = _rotary_half(unit(k) * temp.astype(f32).reshape(1, Hk, 1, 1),
+        unit = lambda t, length: t * lax.rsqrt(
+            jnp.sum(jnp.square(t), -1, keepdims=True)) * length
+        q = _rotary_half(unit(q, q_length), rot, float(rope_theta))
+        k = _rotary_half(unit(k, D ** 0.5)
+                         * temp.astype(f32).reshape(1, Hk, 1, 1),
                          rot, float(rope_theta))
         v = jnp.stack([v2[:, 0], _shift_right(v2[:, 1], 1, 1)], axis=1)
         return q.astype(z.dtype), k.astype(z.dtype), v
@@ -997,11 +1076,9 @@ def compressed_conv_attention(data, q_weight, k_weight, v_weight,
     with jax.named_scope("cca.conv"):
         q, k, v = mix(z, v2, conv0_weight, conv1_weight, temp)
 
-    sc = 1.0 / D ** 0.5
     with jax.named_scope("cca.attention"):
-        if _use_flash_attention(S, D, data.dtype, kv_group=G):
-            o = _flash_attention(q, jnp.repeat(k, G, axis=1),
-                                 jnp.repeat(v, G, axis=1), sc)
+        if flash:
+            o = _flash_attention(q, k, v)
         else:
             @jax.checkpoint
             def attn(q, k, v):
@@ -1161,12 +1238,14 @@ def paged_prefill_attention(data, qkv_weight, qkv_bias, proj_weight,
 
     Wqkv = qkv_weight.reshape(3, H, D, d)
     bqkv = qkv_bias.reshape(3, H, 1, D)
-    q = jnp.einsum("bsd,hed->bhse", data, Wqkv[0]) + bqkv[0]
-    k = jnp.einsum("bsd,hed->bhse", data, Wqkv[1]) + bqkv[1]
-    v = jnp.einsum("bsd,hed->bhse", data, Wqkv[2]) + bqkv[2]
+    flash = _use_flash_attention(S, D, data.dtype)
+    proj = _functools.partial(_project_heads, "bsd,hed->bhse", data)
+    q = proj(Wqkv[0], bqkv[0], sc if flash else None)
+    k = proj(Wqkv[1], bqkv[1])
+    v = proj(Wqkv[2], bqkv[2])
 
-    if _use_flash_attention(S, D, data.dtype):
-        o = _flash_attention(q, k, v, sc)
+    if flash:
+        o = _flash_attention(q, k, v)
     else:
         s = jnp.einsum("bhqe,bhke->bhqk", q, k) * sc
         mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]

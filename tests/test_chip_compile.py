@@ -113,13 +113,15 @@ def test_two_bit_quantize_fused_compiles(one_chip):
 
 
 def test_flash_attention_fwd_grad_compiles(one_chip):
-    """The library flash kernel at the 512-blocks ops/nn.py picks."""
+    """The flash kernel (jax's splash attention, fused dq/dk/dv
+    backward) at the LM cell's geometry and the tiles ops/nn.py picks
+    for it: 2 sequences of 2048, 16 heads of 128."""
     from mxnet_tpu.ops.nn import _flash_attention
 
     def loss(q, k, v):
-        return _flash_attention(q, k, v, SCALE).astype(jnp.float32).sum()
+        return _flash_attention(q, k, v).astype(jnp.float32).sum()
 
-    qkv = ((4, H, 1024, D), jnp.bfloat16)
+    qkv = ((2, H, 2048, D), jnp.bfloat16)
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
              qkv, qkv, qkv)
 
@@ -223,9 +225,10 @@ def test_compressed_conv_attention_fwd_grad_compiles_with_flash(
         one_chip, monkeypatch):
     """CCA at the ZAYA cell's widths (one sequence of 8192, 8 query to 2
     key/value heads of 128, d 2048, bf16) with the flash kernel, which
-    takes K and V repeated over their group.  The kernel choice asks
-    ``jax.default_backend()``, which is the CPU here: the test steers
-    it, as the chip would answer."""
+    takes K and V at their own two heads and shares each among its four
+    query heads.  The kernel choice asks ``jax.default_backend()``,
+    which is the CPU here: the test steers it, as the chip would
+    answer."""
     from mxnet_tpu.ops import nn
     monkeypatch.setattr(nn, "_use_flash_attention",
                         lambda *a, **k: "compiled")
@@ -237,7 +240,9 @@ def test_compressed_conv_attention_fwd_grad_compiles_with_flash(
             .astype(jnp.float32).sum()
 
     bf = jnp.bfloat16
-    _compile(jax.value_and_grad(loss, argnums=tuple(range(8))), one_chip,
-             ((1, S, d), bf), ((Hq * Dh, d), bf), ((Hk * Dh, d), bf),
-             ((2 * Dh, d), bf), ((10 * Dh, 2), bf), ((10, Dh, Dh, 2), bf),
-             ((Hk,), bf), ((d, Hq * Dh), bf))
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=tuple(range(8))), one_chip,
+        ((1, S, d), bf), ((Hq * Dh, d), bf), ((Hk * Dh, d), bf),
+        ((2 * Dh, d), bf), ((10 * Dh, 2), bf), ((10, Dh, Dh, 2), bf),
+        ((Hk,), bf), ((d, Hq * Dh), bf))
+    assert "splash_mha" in compiled.as_text()

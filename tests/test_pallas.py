@@ -376,8 +376,9 @@ def test_flash_and_paged_knobs_share_one_contract(monkeypatch):
     monkeypatch.setenv("MXNET_ATTN_IMPL", "bogus")
     with pytest.raises(ValueError, match=r"use auto\|flash\|xla"):
         _use_flash_attention(512, 128, jnp.float32)
-    # flash forced off-TPU raises (no interpret path for the library
-    # flash kernel); paged forced off-TPU runs via interpret mode
+    # flash forced off-TPU raises (the knob never interprets it: only a
+    # test hands _flash_attention interpret=True); paged forced off-TPU
+    # runs via interpret mode
     monkeypatch.setenv("MXNET_ATTN_IMPL", "flash")
     with pytest.raises(ValueError, match="cannot run here"):
         _use_flash_attention(512, 128, jnp.float32)
@@ -388,6 +389,169 @@ def test_flash_and_paged_knobs_share_one_contract(monkeypatch):
     monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "bogus")
     with pytest.raises(ValueError, match=r"use auto\|pallas\|xla"):
         use_paged_pallas()
+
+
+def _materialised_causal_attention(q, k, v):
+    """The checkpointed materialised-softmax path of ops/nn.py, grouped:
+    q (B, Hq, S, D) carries the softmax scale, k/v are (B, Hk, S, D)."""
+    B, Hq, S, D = q.shape
+    Hk = k.shape[1]
+
+    @jax.checkpoint
+    def attn(q, k, v):
+        s = jnp.einsum("bgrqe,bgke->bgrqk",
+                       q.reshape(B, Hk, Hq // Hk, S, D), k)
+        mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+        s = jnp.where(mask, s.astype(jnp.float32), -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        return jnp.einsum("bgrqk,bgke->bgrqe", p, v).reshape(B, Hq, S, D)
+
+    return attn(q, k, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("Hq,Hk,S", [(4, 4, 512), (8, 2, 1024)],
+                         ids=["h4to4_s512", "h8to2_s1024"])
+def test_flash_attention_parity_fwd_bwd(Hq, Hk, S, dtype):
+    """The flash kernel ops/nn.py builds (jax's splash attention: one
+    fused dq/dk/dv backward kernel, key/value heads shared by their
+    query heads inside it), interpreted: value and dq, dk, dv against
+    the materialised-softmax path, equal head counts and ZAYA's 4 : 1
+    grouping with K and V at their own head count, batch 2."""
+    from mxnet_tpu.ops.nn import _flash_attention
+    B, D = 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(Hq * S), 4)
+    q = (jax.random.normal(ks[0], (B, Hq, S, D)) * D ** -0.5).astype(dtype)
+    k = jax.random.normal(ks[1], (B, Hk, S, D)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, Hk, S, D)).astype(dtype)
+    w = jax.random.normal(ks[3], (B, Hq, S, D))
+
+    def run(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum(),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    launches = PALLAS_LAUNCHES.labels(kernel="flash_attention")
+    before = launches.value
+    got = run(lambda q, k, v: _flash_attention(q, k, v, interpret=True))
+    assert launches.value == before + 1
+    want = run(_materialised_causal_attention)
+    assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
+    # float32: the two orders of summation; bf16: one rounding of p and
+    # of each result, against gradients of size ~10-300
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * np.abs(b).max()
+
+
+@pytest.mark.parametrize("name,S,partials", [
+    ("cgpt13b_train_s2048", 2048, 4),
+    ("zaya1_8b_train_ep2", 8192, 4),
+    ("smallest", 512, 1),
+    ("odd_multiple", 1536, 3),
+])
+def test_flash_block_sizes_divide_the_sequence(name, S, partials):
+    """One function of the shapes gives the kernel's tiles: every block
+    divides S, the compute block divides its resident block, the
+    backward is the fused kernel, and the number of dq partials (one per
+    resident key/value block) is what the docstring states for the two
+    cells' real shapes."""
+    from mxnet_tpu.ops.nn import _flash_block_sizes
+    bs = _flash_block_sizes(S, 128)
+    for b in (bs.block_q, bs.block_kv, bs.block_q_dkv, bs.block_kv_dkv):
+        assert S % b == 0, (name, bs)
+    assert bs.block_kv % bs.block_kv_compute == 0
+    assert bs.block_kv_dkv % bs.block_kv_dkv_compute == 0
+    assert bs.use_fused_bwd_kernel and bs.has_backward_blocks
+    assert S // bs.block_kv_dkv == partials, (name, bs)
+
+
+def _flash_ops():
+    """The four operators that take the flash kernel, at the smallest
+    geometry its gate lets through (S 512, heads of 128), float32:
+    name -> (function of its array arguments, the arguments)."""
+    from mxnet_tpu.ops import nn
+    rng = np.random.RandomState(27)
+    S, D, H = 512, 128, 2
+    d = H * D
+    x = _rand(rng, 1, S, d)
+    Wqkv, bqkv = _rand(rng, 3 * d, d) * 0.1, _rand(rng, 3 * d)
+    Wp, bp = _rand(rng, d, d) * 0.1, _rand(rng, d)
+    nb, bs = S // 16, 16
+    cache = jnp.zeros((nb, bs, H, D), jnp.float32)
+    table = jnp.arange(nb, dtype=jnp.float32).reshape(1, nb)
+    Hq, Hk, dm = 4, 2, 64
+    cca = [_rand(rng, Hq * D, dm), _rand(rng, Hk * D, dm),
+           _rand(rng, 2 * D, dm), _rand(rng, (Hq + Hk) * D, 2),
+           _rand(rng, Hq + Hk, D, D, 2) * 0.1,
+           jnp.asarray([0.8, 1.3], jnp.float32), _rand(rng, dm, Hq * D)]
+    return {
+        "CausalSelfAttention": (
+            lambda qkv: nn.causal_self_attention(qkv, num_heads=H),
+            [_rand(rng, 1, S, 3 * d)]),
+        "FusedCausalSelfAttention": (
+            lambda *a: nn.fused_causal_self_attention(*a, num_heads=H),
+            [x, Wqkv, bqkv, Wp, bp]),
+        "CompressedConvAttention": (
+            lambda *a: nn.compressed_conv_attention(
+                *a, q_heads=Hq, kv_heads=Hk, head_dim=D),
+            [_rand(rng, 1, S, dm)] + cca),
+        "PagedPrefillAttention": (
+            lambda *a: nn.paged_prefill_attention(
+                *a, cache, cache, table,
+                jnp.full((1, 1), S, jnp.float32), num_heads=H)[0],
+            [x, Wqkv, bqkv, Wp, bp]),
+    }
+
+
+@pytest.mark.parametrize("op", ["CausalSelfAttention",
+                                "FusedCausalSelfAttention",
+                                "CompressedConvAttention",
+                                "PagedPrefillAttention"])
+def test_flash_branch_of_each_operator_matches_its_xla_branch(
+        monkeypatch, op):
+    """The kernel takes no softmax scale, so each caller folds it into
+    q on its flash branch only.  The branch is steered as the chip
+    would answer and the kernel interpreted: output and every gradient
+    agree with the operator's own XLA branch."""
+    from mxnet_tpu.ops import nn
+    fn, args = _flash_ops()[op]
+    w = _rand(np.random.RandomState(3), *fn(*args).shape)
+
+    def run():
+        return jax.value_and_grad(
+            lambda *a: (fn(*a) * w).sum(),
+            argnums=tuple(range(len(args))))(*args)
+
+    monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "xla")
+    want = run()
+    monkeypatch.setattr(nn, "_use_flash_attention",
+                        lambda *a, **k: "compiled")
+    kernel = nn._flash_attention
+    monkeypatch.setattr(
+        nn, "_flash_attention",
+        lambda q, k, v: kernel(q, k, v, interpret=True))
+    got = run()
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.abs(b).max() > 0
+        assert np.abs(a - b).max() <= 5e-5 * np.abs(b).max()
+
+
+def test_flash_kernel_is_built_once_per_geometry():
+    """The mask's block tables are host work at trace time: every layer
+    of a model asks for the same kernel object."""
+    from mxnet_tpu.ops.nn import _flash_kernel
+    a = _flash_kernel(4, 2, 512, 128, True)
+    assert _flash_kernel(4, 2, 512, 128, True) is a
+    assert _flash_kernel(4, 4, 512, 128, True) is not a
+    # kept on the host: constants of whatever program uses them
+    assert all(isinstance(x, np.ndarray)
+               for x in jax.tree_util.tree_leaves(a))
 
 
 def test_fallback_counter_and_launch_witnesses(monkeypatch):
