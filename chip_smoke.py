@@ -37,7 +37,7 @@ import urllib.request
 import numpy as np
 
 # ----------------------------------------------------------------------
-# full-width configurations (bench.py --lm-* defaults; BASELINE.json)
+# full-width configurations (BASELINE.json)
 # ----------------------------------------------------------------------
 LM = dict(num_classes=16384, num_layers=12, d_model=2048, num_heads=16,
           seq_len=1024, dtype="bfloat16")

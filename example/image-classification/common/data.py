@@ -108,7 +108,7 @@ def get_rec_iter(args, kv=None):
 
 class ChannelLastIter:
     """Wrap an NCHW iterator to yield NHWC batches — the TPU-preferred
-    layout (docs/PERF.md). The decode pipeline stays NCHW per the
+    layout. The decode pipeline stays NCHW per the
     reference iterator contract; the relayout happens host-side here."""
 
     def __init__(self, inner):

@@ -29,8 +29,7 @@ def main():
     parser.add_argument("--layout", type=str, default="NCHW",
                         choices=["NCHW", "NHWC"],
                         help="NHWC = channel-last end-to-end (the "
-                             "TPU-preferred layout, resnet only; "
-                             "docs/PERF.md)")
+                             "TPU-preferred layout, resnet only)")
     parser.set_defaults(network="resnet", num_layers=50,
                         image_shape="3,224,224", num_classes=1000,
                         num_epochs=80, lr_step_epochs="30,60,90",

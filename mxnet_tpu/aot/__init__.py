@@ -78,7 +78,7 @@ def warm(manifest, *, server=None, engine=None, module=None):
 
 
 def stats():
-    """Cache/warmup counters for quick inspection and bench gates."""
+    """Cache/warmup counters for quick inspection and the tests."""
     from ..telemetry.programs import PROGRAMS_WARMED
     return {
         "cache_dir": store.cache_dir(),

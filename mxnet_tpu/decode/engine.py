@@ -146,8 +146,7 @@ class DecodeEngine:
     max_prefill_len, prefill_buckets : accepted-but-ignored (the pow2
         prefill ladder these configured is retired; chunked prefill
         serves every prompt length through the one mixed step)
-    admission : 'continuous' (default) or 'static' (run-to-completion —
-        the A/B baseline for bench --mode decode)
+    admission : 'continuous' (default) or 'static' (run-to-completion)
     eos_id : default end-of-sequence token id (None = length-stop only)
     """
 

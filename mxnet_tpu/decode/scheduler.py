@@ -11,8 +11,7 @@ deliberately simple and documented (docs/DECODE.md):
   cache blocks are free (chunked prefill grows the rest incrementally,
   one chunk per decode iteration — Sarathi-style stall-free prefill).
   ``admission='static'`` degrades to run-to-completion batching (admit
-  only into an idle engine) — kept as the measured A/B baseline for
-  ``bench.py --mode decode``.
+  only into an idle engine).
 * **Preemption** — on cache pressure the YOUNGEST running sequence is
   preempted *by recompute*: its blocks are freed, its tokens so far
   fold into a new prompt, and it rejoins the FRONT of the wait queue,

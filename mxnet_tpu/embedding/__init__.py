@@ -15,8 +15,8 @@ layer that already exists:
   ``_fused_sparse_sig`` (SGD, AdaGrad, GroupAdaGrad);
 * sharded-table checkpoints (checkpoint.py) — each rank persists its
   owned row range under the PR 7 manifest protocol;
-* ``bench.py --mode dlrm`` exercises the whole stack and pins
-  ``sparse_dispatches_per_step <= 2`` and zero steady-state retraces.
+* ``tests/test_embedding.py`` exercises the whole stack and pins at
+  most two sparse dispatches a step and zero steady-state retraces.
 
 The symbol-level twin is the ``_contrib_ShardedEmbedding`` op
 (ops/nn.py) for compiled module graphs.

@@ -63,7 +63,7 @@ __all__ = ["SparseApplyEngine", "SPARSE_DISPATCHES", "SPARSE_RETRACES"]
 
 # compiled sparse-apply program launches (1 per push single-process,
 # 2 on the multi-process host transport); with embedding_lookups this
-# is the bench's sparse_dispatches_per_step witness
+# is the dispatches-per-step witness tests/test_embedding.py reads
 SPARSE_DISPATCHES = _telemetry.REGISTRY.counter(
     "embedding_sparse_dispatches",
     "compiled sparse-apply program dispatches", vital=True)

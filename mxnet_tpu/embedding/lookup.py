@@ -33,8 +33,8 @@ __all__ = ["lookup", "lookup_partitioned", "pad_length", "LOOKUPS",
            "LOOKUP_RETRACES"]
 
 # one increment per compiled-lookup dispatch; with
-# embedding_sparse_dispatches this is the numerator of the bench's
-# sparse_dispatches_per_step witness (docs/OBSERVABILITY.md)
+# embedding_sparse_dispatches this is the dispatches-per-step witness
+# tests/test_embedding.py reads (docs/OBSERVABILITY.md)
 LOOKUPS = _telemetry.REGISTRY.counter(
     "embedding_lookups",
     "compiled embedding lookup dispatches", vital=True)

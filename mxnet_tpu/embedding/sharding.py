@@ -46,8 +46,8 @@ EMBED_HBM = _telemetry.REGISTRY.gauge(
     "residuals), summed over tables", unit="bytes")
 # TABLE weight bytes this host actually holds: a replicated table
 # contributes its full (vocab, dim) footprint, a pod-partitioned one
-# only its owned row slab — the 1/W capacity-scaling witness the dlrm
-# bench gates (docs/EMBEDDING.md)
+# only its owned row slab — the 1/W capacity-scaling witness
+# tests/embedding_partition_worker.py checks (docs/EMBEDDING.md)
 EMBED_TBL_PER_HOST = _telemetry.REGISTRY.gauge(
     "embedding_table_bytes_per_host",
     "embedding table weight bytes resident on this host (a partitioned "

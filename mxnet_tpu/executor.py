@@ -61,7 +61,8 @@ _DISPATCH_TALLY = _telemetry.TraceTally()
 
 def _count_dispatch():
     """Bump the global device-launch witness (profiler.DEVICE_DISPATCHES)
-    — bench.py --mode train reads deltas for train_dispatches_per_step."""
+    — benchmark/layer_metrics/dispatches_per_step.train.py reads its
+    delta over the window's steps."""
     _prof.DEVICE_DISPATCHES.increment()
     _DISPATCH_TALLY.count += 1
 

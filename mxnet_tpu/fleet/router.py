@@ -251,7 +251,7 @@ class FleetRouter:
     def _pick(self, tokens, live):
         """Score the live ring.  ``affinity``: depth × (1 − occupancy),
         ties to the lighter replica; ``least_loaded``: scheduler depth
-        only (the A/B baseline the fleet bench gates against)."""
+        only (the baseline tests/test_fleet.py compares against)."""
         best, best_key, best_depth = None, None, 0
         for name, rec in live:
             eng = rec["engine"]

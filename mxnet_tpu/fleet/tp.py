@@ -6,7 +6,7 @@ and the executor resolves those annotations at bind time — so this
 module is deliberately thin: it validates the geometry EARLY (a head
 count the axis does not divide fails here with a message naming the
 config key, not deep inside GSPMD), selects the mesh, and exposes the
-per-device cache-bytes witness the fleet bench gates on.
+per-device cache-bytes witness tests/test_fleet.py gates on.
 """
 from __future__ import annotations
 
@@ -79,7 +79,7 @@ def make_tp_engine(arg_params, model_config, tensor_parallel=None,
 
 def per_device_cache_bytes(engine, device=None):
     """Bytes of paged-KV-cache storage resident on one device — the
-    fleet bench's TP witness: head-sharded caches put ~1/mp of the
+    TP witness of tests/test_fleet.py: head-sharded caches put ~1/mp of the
     replicated footprint on each device, and a regression here means
     the cache annotations stopped resolving (the engine would still be
     CORRECT, just silently paying replicated memory)."""

@@ -110,7 +110,7 @@ DISPATCH_MS = _telemetry.REGISTRY.histogram(
 # backward bucket's grads have even been enqueued — comms provably
 # overlap the remaining backward walk. Ticked only there (never by the
 # end-of-push flush), so a positive delta IS the overlap proof the
-# bench/tests gate on.
+# tests gate on (tests/test_kvstore_fused.py).
 OVERLAP_DISPATCHES = _telemetry.REGISTRY.counter(
     "kvstore_overlap_dispatches",
     "bucket programs dispatched by the streaming flush BEFORE the final "

@@ -74,8 +74,8 @@ def check_label_shapes(labels, preds, wrap=False, shape=False):
     return labels, preds
 
 
-# the fit loop's host-sync witness (bench.py --mode train
-# host_syncs_per_step): incremented on every blocking device->host
+# the fit loop's host-sync witness (tests/test_fused_fit.py,
+# tests/test_sentinel.py): incremented on every blocking device->host
 # readback the metric layer performs — per-batch update() conversions on
 # the eager path, get()-time accumulator folds on the device path.
 # Registry-backed (telemetry series ``fit_host_syncs``): this name is a

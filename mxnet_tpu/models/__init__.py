@@ -4,7 +4,7 @@ Reference parity: example/image-classification/symbols/ (mlp, lenet,
 alexnet, vgg, resnet, resnext, mobilenet, inception-bn, googlenet,
 squeezenet, densenet). Each module exposes ``get_symbol(num_classes, ...)``
 returning a Symbol ending in SoftmaxOutput, so any of them drops into
-``Module.fit`` / ``bench.py`` unchanged.  Two language-model families
+``Module.fit`` / ``benchmark/run.py`` unchanged.  Two language-model families
 beside them, with the same factory signature: ``transformer`` (GPT-2's
 block) and ``zaya`` (compressed convolutional attention and a dropless
 top-1 expert sublayer; its second output is the experts' token counts).

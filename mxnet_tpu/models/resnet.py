@@ -7,7 +7,7 @@ the classifier head kept fp32 — the MXU-friendly configuration — and every
 op lowers to a single conv/matmul HLO, so the whole network is one XLA
 computation once bound. ``layout='NHWC'`` builds the whole trunk
 channel-last (data, weights, pooling, BN axis), the TPU-preferred layout:
-no relayout copy anywhere in the step (docs/PERF.md).
+no relayout copy anywhere in the step.
 
 Depth table (ImageNet): 18/34 use the basic block, 50/101/152/200 use the
 bottleneck block. CIFAR shapes (image < 64px) use the 3-stage layout with

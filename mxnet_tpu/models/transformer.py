@@ -5,13 +5,13 @@ transformers entirely (SURVEY.md §5.7 maps its sequence stack to
 RNN/BucketingModule), so this is not a ported symbol — it is the
 arithmetic-intensity-dense model family that demonstrates the framework
 reaches MXU-bound MFU when the model is not HBM-bandwidth-bound the way
-ResNet/BatchNorm is (docs/PERF.md). Attention is the fused
+ResNet/BatchNorm is. Attention is the fused
 ``sym.contrib.CausalSelfAttention`` op (rematerialized backward, fp32
 softmax statistics); sequence/context-parallel training of the same
 architecture runs through ``parallel.ring_attention``.
 
 Builds a Symbol ending in SoftmaxOutput, so it drops into ``Module.fit``
-/ ``parallel.TrainStep`` / ``bench.py`` exactly like the CNN zoo:
+/ ``parallel.TrainStep`` exactly like the CNN zoo:
 ``data`` is (batch, seq_len) token ids and ``softmax_label`` is
 (batch*seq_len,) next-token targets.
 """

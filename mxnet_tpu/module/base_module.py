@@ -30,8 +30,8 @@ from ..ndarray.ndarray import concatenate
 __all__ = ["BaseModule"]
 
 # per-step wall time of the fit loop body (dispatch + staging + metric
-# bookkeeping — NOT device completion, which is async; bench.py --mode
-# fit reports device-independent launch counters for that reason)
+# bookkeeping — NOT device completion, which is async; the benchmark
+# reads launch counters and the device trace for that reason)
 FIT_STEP_MS = _telemetry.REGISTRY.histogram(
     "fit_step_ms", "wall time of one fit-loop step (host side)",
     unit="ms")

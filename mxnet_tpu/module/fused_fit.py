@@ -134,8 +134,8 @@ def _sentinel_enabled():
     of scalars — global grad norm, non-finite element count, metric
     EMA z-score, residual-norm drift — folded into the SAME donated
     program and read only at sync boundaries. On by default (the
-    overhead contract is zero extra dispatches/syncs and <2% step
-    time, gated by bench.py); ``MXNET_SENTINEL_NUMERICS=0`` disables."""
+    overhead contract is zero extra dispatches/syncs, held by
+    tests/test_sentinel.py); ``MXNET_SENTINEL_NUMERICS=0`` disables."""
     from ..telemetry.sentinel import numerics_enabled
     return numerics_enabled()
 

@@ -13,6 +13,8 @@ fused softmax-CE gradient → jax.custom_vjp).
 """
 from __future__ import annotations
 
+import functools as _functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -153,7 +155,7 @@ def deconvolution(data, weight, bias=None, *, kernel, num_filter, stride=(),
 def _bn_train_fused(red, bshape, eps, fix_gamma, n):
     """Training-mode batch norm as ONE fused stats pass + ONE apply pass,
     with a hand-derived backward (ONE reduction pass + ONE elementwise
-    pass). The HBM-bandwidth-optimal schedule (docs/PERF.md):
+    pass). The HBM-bandwidth-optimal schedule:
 
     * stats: sum(x) and sum(x^2) are independent reductions over the same
       operand, so XLA multi-output-fuses them into a single read of the
@@ -399,9 +401,6 @@ def pooling(data, *, kernel=(), pool_type="max", global_pool=False, stride=(),
             need = (out_sz - 1) * stride[i] + kernel[i] - size
             base_pad[sp0 + i] = (pad[i], pad[i] + max(0, need))
     if pool_type == "max":
-        if _use_argmax_maxpool(data.dtype):
-            return _maxpool_argmax_vjp(data, window, strides,
-                                       tuple(map(tuple, base_pad)))
         init = (-jnp.inf if jnp.issubdtype(data.dtype, jnp.floating)
                 else jnp.iinfo(data.dtype).min)
         return lax.reduce_window(data, init, lax.max, window, strides, base_pad)
@@ -420,94 +419,6 @@ def pooling(data, *, kernel=(), pool_type="max", global_pool=False, stride=(),
         return summed / counts
     raise ValueError("unsupported pool_type %s" % pool_type)
 
-
-
-# ----------------------------------------------------------------------
-# Max-pool with an elementwise backward.
-#
-# XLA differentiates reduce_window(max) into select_and_scatter, which
-# the r4 roofline measured at 540 GB/s (1.7 ms/step in ResNet-50 —
-# docs/ROOFLINE.md). The custom VJP below recomputes the argmax in
-# backward from shifted strided slices and scatters with dilating pads.
-# Tie-break matches select_and_scatter (first window position in
-# row-major order wins).
-#
-# MEASURED NEGATIVE (r5, docs/PERF.md): on the v5e ResNet-50 step this
-# formulation is ~23 ms SLOWER than select_and_scatter — XLA does not
-# fuse the 9 interior-dilated pads into one accumulation; each `placed`
-# array materialises at full padded size (~420 MB x 9 at batch 256).
-# Default is therefore the XLA path; the VJP stays selectable
-# (MXNET_MAXPOOL_VJP=argmax) as the reproducible experiment.
-# ----------------------------------------------------------------------
-def _use_argmax_maxpool(dtype):
-    import os
-    impl = os.environ.get("MXNET_MAXPOOL_VJP", "xla")
-    if impl == "xla":
-        return False
-    if impl != "argmax":
-        raise ValueError(f"MXNET_MAXPOOL_VJP={impl}; use argmax|xla")
-    return jnp.issubdtype(dtype, jnp.floating)
-
-
-import functools as _functools
-
-
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _maxpool_argmax_vjp(data, window, strides, pads):
-    init = -jnp.inf if jnp.issubdtype(data.dtype, jnp.floating) else \
-        jnp.iinfo(data.dtype).min
-    return lax.reduce_window(data, init, lax.max, window, strides,
-                             list(pads))
-
-
-def _maxpool_fwd(data, window, strides, pads):
-    y = _maxpool_argmax_vjp(data, window, strides, pads)
-    return y, (data, y)
-
-
-def _window_offsets(window):
-    import itertools
-    return itertools.product(*(range(k) for k in window))
-
-
-def _maxpool_bwd(window, strides, pads, residual, dy):
-    x, y = residual
-    neg = jnp.asarray(-jnp.inf, x.dtype)
-    x_padded = jnp.pad(x, list(pads), constant_values=neg) \
-        if any(lo or hi for lo, hi in pads) else x
-    padded_shape = x_padded.shape
-
-    taken = None
-    dx_padded = None
-    for offs in _window_offsets(window):
-        # the window element at `offs` across every output position
-        limits = [o + (ys - 1) * st + 1
-                  for o, ys, st in zip(offs, y.shape, strides)]
-        xk = lax.slice(x_padded, list(offs), limits, list(strides))
-        match = xk == y
-        if taken is None:
-            first = match
-            taken = match
-        else:
-            first = match & ~taken
-            taken = taken | match
-        gk = jnp.where(first, dy, jnp.zeros_like(dy))
-        # scatter back: dilate by stride, shift by the offset
-        cfg = [(int(o), int(ps - (o + (ys - 1) * st + 1)), int(st - 1))
-               for o, ps, ys, st in
-               zip(offs, padded_shape, y.shape, strides)]
-        placed = lax.pad(gk, jnp.asarray(0, gk.dtype), cfg)
-        dx_padded = placed if dx_padded is None else dx_padded + placed
-    if any(lo or hi for lo, hi in pads):
-        starts = [lo for lo, _ in pads]
-        limits = [lo + n for (lo, _), n in zip(pads, x.shape)]
-        dx = lax.slice(dx_padded, starts, limits)
-    else:
-        dx = dx_padded
-    return (dx,)
-
-
-_maxpool_argmax_vjp.defvjp(_maxpool_fwd, _maxpool_bwd)
 
 
 @register("UpSampling", key_var_num_args="num_args")
@@ -913,7 +824,7 @@ def fused_causal_self_attention(data, qkv_weight, qkv_bias, proj_weight,
     consume the HEAD-MAJOR (B, H, S, D) layout directly, so no transpose
     ever materialises between the matmuls and the fused Pallas flash
     kernel (a separate (B,S,H,D)->(B,H,S,D) copy costs ~0.5 ms/layer
-    fwd+bwd at d2048/S1024 on v5e — measured in docs/PERF.md).  Weight
+    fwd+bwd at d2048/S1024 on v5e, builders' measurement).  Weight
     layouts match the reference FullyConnected convention ((3d, d) /
     (d, d) row-major), so checkpoints from the unfused pair load
     unchanged.

@@ -62,7 +62,7 @@ def choose_impl(env_var, impl, kernel, supported, why, *,
     ``supported``) gates the forced one — kernels that can run
     interpreted pass ``force_supported=True`` since they then run on
     any backend when explicitly requested.  ``count=False`` suppresses
-    the fallback counter for observer-only calls (stats/bench polling
+    the fallback counter for observer-only calls (stats polling
     must not inflate the per-trace witness).
     """
     if impl == "xla":
@@ -106,7 +106,7 @@ def _compiles_here():
 
 def use_paged_pallas(count=True):
     """Trace-time paged-attention impl decision shared by the decode
-    and prefill ops (ops/nn.py) and the engine's stats/bench reporting.
+    and prefill ops (ops/nn.py) and the engine's stats reporting.
     ``auto`` prefers the Pallas kernels where they compile
     (:func:`_compiles_here`: decode there is bandwidth-bound on exactly
     the gather traffic they remove) and the XLA gather path elsewhere;
@@ -125,7 +125,7 @@ def use_paged_pallas(count=True):
 
 def paged_attn_impl():
     """The active paged-attention implementation name ('pallas' or
-    'xla') for stats()/bench JSON — no counter side effects."""
+    'xla') for stats() — no counter side effects."""
     return "pallas" if use_paged_pallas(count=False) else "xla"
 
 

@@ -15,7 +15,8 @@ Sharding model (SURVEY.md §2.3):
                      parallelism, graph_executor.cc:408)
 * gradients        → psum over ``dp`` inserted by XLA, riding ICI
 
-This is the component bench.py and the Module's `fused` mode drive.
+Its callers are tests, one example and ``__graft_entry__``; ``Module.fit``
+runs ``module/fused_fit.py`` instead.
 """
 from __future__ import annotations
 
@@ -250,7 +251,7 @@ def _device_init_rule(initializer, name, attrs, shape, dtype):
 class TrainStep:
     """symbol + optimizer + mesh → one compiled training step.
 
-    Usage (see bench.py)::
+    Usage::
 
         mesh = Mesh(np.array(jax.devices()).reshape(-1), ('dp',))
         ts = TrainStep(sym, optimizer, mesh=mesh,

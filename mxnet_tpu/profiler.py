@@ -345,8 +345,8 @@ class Counter:
 # global device-launch witness (docs/TRAINING.md): every compiled-program
 # dispatch on the training hot path increments this counter — executor
 # fwd / fused fwd+bwd launches, kvstore bucket programs, and the fused
-# fit-step program. bench.py --mode train reads deltas to report
-# train_dispatches_per_step independent of wall clock.
+# fit-step program. benchmark/layer_metrics/dispatches_per_step.train.py
+# reads its delta over the window's steps, independent of wall clock.
 DEVICE_DISPATCHES = Domain("device").new_counter("device_dispatches",
                                                  vital=True)
 

@@ -5,9 +5,8 @@ Every jit site that already reports retraces through
 fused fit step, the bucketed kvstore programs (single-host and tpu),
 and therefore the decode engine's prefill/step executors — registers
 the program it just compiled here, keyed by ``(site, fn, abstract
-argument signature)``.  The registry answers the question bench.py's
-hand FLOP math cannot: what does the COMPILER say each live program
-costs?
+argument signature)``.  The registry answers the question hand FLOP
+math cannot: what does the COMPILER say each live program costs?
 
 * **Recording is compile-path-only.**  ``RetraceSite.timed`` calls
   :func:`record` only on calls during which its thread (re)traced, so
@@ -19,8 +18,7 @@ costs?
   ``memory_analysis()`` need a compiled executable; re-lowering the
   jitted callable over the recorded abstract arguments costs one extra
   XLA compile the FIRST time a program is inspected (the same
-  ``lower().compile()`` idiom bench.py has always used) and nothing
-  after.  :func:`programs` with ``analyze=False`` (the flight-recorder
+  ``lower().compile()`` idiom) and nothing after.  :func:`programs` with ``analyze=False`` (the flight-recorder
   dump path) reports only already-computed analyses — a crash dump
   must never compile.
 
@@ -29,7 +27,9 @@ Exported surfaces: ``telemetry.programs()`` (list of dicts),
 ``mfu_measured(flops_per_step, seconds)`` (gauge ``mfu_measured``:
 compiler-reported model FLOP/s over the chip's peak), and
 ``peak_tflops()`` over ``PEAKS`` — the one table of published peaks,
-keyed by exact ``device_kind`` and shared with bench.py.
+keyed by exact ``device_kind``.  The benchmark keeps its own copy
+(``benchmark/peaks.json``): it may not import the package's, and the
+package may not import the benchmark.
 """
 from __future__ import annotations
 
@@ -53,10 +53,10 @@ PROGRAMS_WARMED = REGISTRY.gauge(
 MFU_MEASURED = REGISTRY.gauge(
     "mfu_measured", "model FLOP utilization from compiler-reported "
     "FLOPs (cost_analysis) over the chip's peak bf16 throughput — the "
-    "measured counterpart of bench.py's hand-math `mfu`", unit="ratio")
+    "measured counterpart of a hand count from shapes", unit="ratio")
 
 # Published peaks per chip, keyed by the EXACT jax ``device_kind`` — the
-# ONE table (bench.py reads it).  A TPU that is not here is an error
+# package's ONE table.  A TPU that is not here is an error
 # where a utilisation is computed, never a neighbour's number.
 # Source: Google Cloud TPU documentation, "TPU v5e" (system
 # architecture): 197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s per chip.
@@ -221,9 +221,9 @@ def record(site, fn, args, compile_ms=None):
 def register_compiled(site, compiled, fn_name=None, compile_ms=None,
                       signature=None, warmed=None):
     """Register an ALREADY-compiled executable (``jitted.lower(...)
-    .compile()``) — the AOT path tools/roofline.py, bench.py, and
-    mx.aot warmup use, so their programs appear in
-    ``telemetry.programs()`` and their analyses never recompile.
+    .compile()``) for a caller that compiles ahead of time itself, so
+    its programs appear in ``telemetry.programs()`` and their analyses
+    never recompile (today's callers: tests/test_aot.py).
 
     ``signature`` (an argument pytree or ShapeDtypeStruct skeleton)
     enables the (site, signature) double-registration guard: if the

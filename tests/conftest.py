@@ -31,7 +31,7 @@ def pytest_configure(config):
 jax.config.update("jax_platforms", "cpu")
 
 # CPU/TPU XLA default matmul precision is allowed to drop to bf16; numeric
-# parity tests need true f32 (bench.py keeps the fast default for the MXU).
+# parity tests need true f32 (off the tests the MXU keeps the fast default).
 jax.config.update("jax_default_matmul_precision", "float32")
 
 

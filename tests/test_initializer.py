@@ -103,7 +103,7 @@ def test_initializer_dumps_roundtrip():
 
 def test_device_init_matches_host_rules():
     """TrainStep's device-side init (_device_init_rule) must follow the
-    same name rules as the host Initializer (docs/PERF.md device-init)."""
+    same name rules as the host Initializer."""
     from mxnet_tpu.parallel.trainer import _device_init_rule
     import jax
 

@@ -209,16 +209,14 @@ def test_prefetching_iter_shards_across_devices():
 
 
 # ----------------------------------------------------------------------
-# (a) decode thread-scaling (real multi-core hosts only)
+# (a) decode work is spread over the pool's threads
 # ----------------------------------------------------------------------
-@pytest.mark.skipif((os.cpu_count() or 1) < 2,
-                    reason="decode scaling needs >=2 cores (this harness "
-                           "has 1; runs on real TPU-VM hosts)")
 def test_native_decode_thread_scaling(tmp_path):
-    """ImageRecordIter's threaded native decode must scale with
-    preprocess_threads on a multi-core host (reference
-    iter_image_recordio_2.cc OMP decode). Committed per VERDICT r3
-    item 4a; `bench.py --pipeline-scaling` prints the full curve."""
+    """ImageRecordIter's decode pool really runs `preprocess_threads`
+    records at once, each on a thread of its own (reference
+    iter_image_recordio_2.cc OMP decode), and the batches do not depend
+    on the thread count. The witness is a barrier inside the decode call,
+    not a wall-clock ratio: it holds whatever else loads the machine."""
     import io as pyio
     from PIL import Image
     from mxnet_tpu import recordio
@@ -227,8 +225,7 @@ def test_native_decode_thread_scaling(tmp_path):
     idx_path = str(tmp_path / "s.idx")
     rec = recordio.MXIndexedRecordIO(idx_path, rec_path, "w")
     rng = np.random.RandomState(0)
-    n_img = 256
-    for i in range(n_img):
+    for i in range(64):
         img = rng.randint(0, 255, (224, 224, 3), dtype=np.uint8)
         buf = pyio.BytesIO()
         Image.fromarray(img).save(buf, "JPEG", quality=90)
@@ -236,20 +233,35 @@ def test_native_decode_thread_scaling(tmp_path):
             recordio.IRHeader(0, float(i % 10), i, 0), buf.getvalue()))
     rec.close()
 
-    def rate(nthreads):
+    def epoch(nthreads):
         it = mx.io.ImageRecordIter(
             path_imgrec=rec_path, path_imgidx=idx_path,
             data_shape=(3, 224, 224), batch_size=32,
             preprocess_threads=nthreads)
-        next(iter(it))                     # warm up thread pool
-        t0 = time.perf_counter()
-        n = 0
-        for b in it:
-            n += b.data[0].shape[0]
-        return n / (time.perf_counter() - t0)
+        inner, idents, lock = it._process, set(), threading.Lock()
+        meet = threading.Barrier(nthreads)
+        early = [0]
 
-    r1 = rate(1)
-    rn = rate(min(8, os.cpu_count()))
-    assert rn > 1.3 * r1, \
-        "decode did not scale with threads: 1->%d gave %.0f -> %.0f img/s" \
-        % (min(8, os.cpu_count()), r1, rn)
+        def process(offset):
+            # the first `nthreads` calls wait for each other: they only
+            # get past if that many pool threads hold a record at once
+            with lock:
+                idents.add(threading.get_ident())
+                early[0] += 1
+                wait = early[0] <= nthreads
+            if wait:
+                meet.wait(timeout=120)
+            return inner(offset)
+
+        it._process = process
+        it.reset()              # the constructor's prefetch ran unwatched
+        out = [(b.data[0].asnumpy(), b.label[0].asnumpy()) for b in it]
+        return out, idents
+
+    one, ids1 = epoch(1)
+    many, ids4 = epoch(4)
+    assert len(ids1) == 1 and len(ids4) == 4, (ids1, ids4)
+    assert len(one) == len(many) == 2
+    for (d1, l1), (dn, ln) in zip(one, many):
+        np.testing.assert_array_equal(d1, dn)
+        np.testing.assert_array_equal(l1, ln)

@@ -386,19 +386,29 @@ def test_overlap_witness_ticks_on_streaming_flush(monkeypatch):
 def test_overlap_escape_hatch(monkeypatch):
     """MXNET_KVSTORE_OVERLAP=0 restores strictly serial dispatch: the
     streaming flush still runs (bucket planning is orthogonal) but the
-    overlap witness never ticks."""
+    overlap witness never ticks. Overlap reorders work and adds none:
+    the overlapped arm dispatches the serial arm's bucket programs."""
     from mxnet_tpu import telemetry
     monkeypatch.setenv("MXNET_KVSTORE_BIGARRAY_BOUND", "256")
-    monkeypatch.setenv("MXNET_KVSTORE_OVERLAP", "0")
-    kv = mx.kv.create("tpu")
-    kv.set_optimizer(mx.optimizer.SGD(learning_rate=0.1))
-    keys = ["k%d" % i for i in range(5)]
-    for k in keys:
-        kv.init(k, nd.zeros((4, 4)))
     wit = telemetry.REGISTRY.get("kvstore_overlap_dispatches")
-    w0 = wit.value
-    kv.set_async_push(True)
-    kv.push(keys, [[nd.ones((4, 4))]] * 5, priority=[0] * 5)
-    out = nd.zeros((4, 4))
-    kv.pull("k0", out=out)
-    assert wit.value == w0, "escape hatch leaked the overlap witness"
+
+    def arm(overlap):
+        monkeypatch.setenv("MXNET_KVSTORE_OVERLAP", overlap)
+        kv = mx.kv.create("tpu")
+        kv.set_optimizer(mx.optimizer.SGD(learning_rate=0.1))
+        keys = ["k%d" % i for i in range(5)]
+        for k in keys:
+            kv.init(k, nd.zeros((4, 4)))
+        w0 = wit.value
+        kv.set_async_push(True)
+        kv.push(keys, [[nd.ones((4, 4))]] * 5, priority=[0] * 5)
+        out = nd.zeros((4, 4))
+        kv.pull("k4", out=out)
+        kv._sync_engine()
+        return wit.value - w0, kv._engine.stats["buckets"]
+
+    serial_ticks, serial_buckets = arm("0")
+    assert serial_ticks == 0, "escape hatch leaked the overlap witness"
+    overlap_ticks, overlap_buckets = arm("1")
+    assert overlap_ticks > 0
+    assert overlap_buckets == serial_buckets > 0

@@ -1,6 +1,6 @@
 """NHWC layout support + fused one-pass BatchNorm numerics.
 
-Round-3 perf work (docs/PERF.md): Convolution/Pooling accept
+Convolution/Pooling accept
 channel-last layouts, the resnet builder threads layout end-to-end, and
 training BatchNorm runs the one-pass fused schedule with a hand-derived
 backward (ops/nn.py _bn_train_fused). These tests pin NHWC==NCHW

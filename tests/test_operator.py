@@ -397,31 +397,3 @@ def test_norm_and_l2norm():
     out = nd.L2Normalization(nd.array(x), mode="instance")
     expect = x / np.sqrt((x**2).sum(1, keepdims=True) + 1e-10)
     assert_almost_equal(out, expect, rtol=1e-4)
-
-
-def test_maxpool_argmax_vjp_matches_select_and_scatter():
-    """The committed maxpool-backward experiment (MXNET_MAXPOOL_VJP=argmax,
-    ops/nn.py) must stay bit-identical to XLA's select_and_scatter —
-    including tie positions (relu zeros) — even though it lost the perf
-    A/B (docs/PERF.md r5 measured negative)."""
-    import os
-    import jax
-    import jax.numpy as jnp
-    from mxnet_tpu.ops.nn import pooling
-
-    rng = np.random.RandomState(3)
-    x = rng.randn(2, 9, 9, 4).astype(np.float32)
-    x[x < 0] = 0.0  # relu-style ties
-    x = jnp.asarray(x)
-    kw = dict(kernel=(3, 3), pool_type="max", stride=(2, 2), pad=(1, 1),
-              layout="NHWC")
-
-    def grad_with(impl):
-        os.environ["MXNET_MAXPOOL_VJP"] = impl
-        try:
-            return jax.grad(lambda a: (pooling(a, **kw) ** 3).sum())(x)
-        finally:
-            os.environ.pop("MXNET_MAXPOOL_VJP", None)
-
-    np.testing.assert_array_equal(np.asarray(grad_with("argmax")),
-                                  np.asarray(grad_with("xla")))

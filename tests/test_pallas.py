@@ -681,6 +681,7 @@ def test_engine_tokens_invariant_under_impl_and_donation(model,
                 outs[(impl, donate)] = [h.result(timeout=120) for h in hs]
                 st = eng.stats()
                 assert st["steady_state_retraces"] == 0
+                assert st["dispatches_per_step"] == 1.0
                 assert st["attn_impl"] == impl
                 assert st["cache_donation"] == (donate == "1")
             finally:

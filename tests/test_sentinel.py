@@ -277,6 +277,12 @@ def test_fused_sentinels_off_switch(monkeypatch):
     assert mod._fused_fit is not None
     assert mod._fused_fit._sent_state is None
     assert mod._fused_fit.publish_sentinels() is None
+    # the same ONE launch a step as with the witnesses on (test above)
+    disp = telemetry.REGISTRY.get("device_dispatches")
+    d0 = disp.value
+    for _ in range(4):
+        assert mod.fit_step(batch_nd, m)
+    assert disp.value - d0 == 4
 
 
 def test_nan_trips_alert_within_one_sentinel_interval(monkeypatch):
