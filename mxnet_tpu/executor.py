@@ -104,7 +104,8 @@ class _dispatch_span:
 
 
 def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
-                    group_devices=None, tap_cb=None, tap_stat=None):
+                    group_devices=None, tap_cb=None, tap_stat=None,
+                    also=()):
     """Build a pure function (args, auxs, seed, is_train) ->
     (outputs, new_auxs) interpreting the DAG with registered op impls.
     With ``collect_taps`` the function also returns {tap_name: value} for
@@ -118,6 +119,10 @@ def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
     second tapped launch. ``tap_stat`` (a jnp function) is applied to
     each tap inside the program, so only the small statistic crosses to
     the host, not the full intermediate tensor.
+
+    With ``also``, a sequence of ``(node, output index)`` entries, the
+    function returns those interior values as a third result: how the
+    fused fit program reaches a loss head's stem (loss_head.py).
 
     ``group_devices`` maps a ctx_group name (``with AttrScope(
     ctx_group='dev1')``) to a ``jax.Device``: nodes carrying that attr
@@ -235,6 +240,8 @@ def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
             new_auxs.setdefault(name, auxs[name])
         if collect_taps:
             return outputs, new_auxs, taps
+        if also:
+            return outputs, new_auxs, [env[(id(n), oi)] for n, oi in also]
         return outputs, new_auxs
 
     return graph_fn
@@ -487,6 +494,10 @@ class Executor:
     def outputs(self):
         if self._pending_train_fwd:
             self._run_fwd(True)
+        elif callable(self._outputs):
+            # the last fused fit step deferred its loss heads
+            # (loss_head.DeferredOutputs): build them now, once
+            self._outputs = self._outputs()
         return self._outputs
 
     @property

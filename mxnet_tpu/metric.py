@@ -110,6 +110,20 @@ def _asnp(x):
     return _np.asarray(x)
 
 
+def _probs_at_labels(pred, label):
+    """``pred.reshape(-1, classes)[arange, label]`` inside a device
+    metric, for flat int32 ``label``.  Where the fused fit program
+    deferred the loss head (loss_head.DeferredHead), the head computes
+    these few probabilities from its stem: the array of all of them is
+    then never built."""
+    at_labels = getattr(pred, "at_labels", None)
+    if at_labels is not None:
+        return at_labels(label)
+    import jax.numpy as jnp
+    pred = pred.reshape(-1, pred.shape[-1])
+    return pred[jnp.arange(label.shape[0]), label]
+
+
 class EvalMetric:
     # device-resident accumulator (fed by the fused fit step); None means
     # "host accumulation only". _device_consumed marks a batch the fused
@@ -118,6 +132,10 @@ class EvalMetric:
     _dev_sum = None
     _dev_num = None
     _device_consumed = False
+    # True where device_fn reads a prediction through _probs_at_labels
+    # only: the fit program then hands it a deferred loss head as it is,
+    # and every other metric the head's full value
+    device_reads_at_labels = False
 
     def __init__(self, name, output_names=None, label_names=None, **kwargs):
         self.name = str(name)
@@ -401,6 +419,8 @@ class MCC(EvalMetric):
 
 @register
 class Perplexity(EvalMetric):
+    device_reads_at_labels = True
+
     def __init__(self, ignore_label=None, axis=-1, name="perplexity",
                  output_names=None, label_names=None):
         super().__init__(name, output_names, label_names,
@@ -434,8 +454,7 @@ class Perplexity(EvalMetric):
             num = jnp.float32(0.0)
             for label, pred in zip(labels, preds):
                 label = label.reshape(-1).astype(jnp.int32)
-                pred = pred.reshape(-1, pred.shape[-1])
-                probs = pred[jnp.arange(label.shape[0]), label]
+                probs = _probs_at_labels(pred, label)
                 num = num + jnp.float32(label.shape[0])
                 if ignore_label is not None:
                     ignore = (label == ignore_label)
@@ -538,6 +557,8 @@ class RMSE(MSE):
 
 @register(None, "crossentropy", "ce")
 class CrossEntropy(EvalMetric):
+    device_reads_at_labels = True
+
     def __init__(self, eps=1e-12, name="cross-entropy", output_names=None,
                  label_names=None):
         super().__init__(name, output_names, label_names, eps=eps)
@@ -562,7 +583,7 @@ class CrossEntropy(EvalMetric):
             n = 0
             for label, pred in zip(labels, preds):
                 label = label.reshape(-1).astype(jnp.int32)
-                prob = pred[jnp.arange(label.shape[0]), label]
+                prob = _probs_at_labels(pred, label)
                 s = s + (-jnp.log(prob + eps)).sum().astype(jnp.float32)
                 n += label.shape[0]
             return s, jnp.float32(n)

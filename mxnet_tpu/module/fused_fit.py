@@ -12,6 +12,14 @@ program per step for eligible configurations:
       -> fused optimizer apply (Optimizer._fused_fit_sig)
       -> device-side metric accumulation (EvalMetric.device_fn)
 
+What the program hands back of the graph's outputs is what a reader can
+use: a loss head's float32 probabilities (tokens x vocabulary elements,
+the largest array of a language model's step) are not written for a
+training loop that reads only its metric. The program returns the
+head's stem, the logits as their producer wrote them, and
+``get_outputs()`` builds the head from it on demand (loss_head.py;
+docs/TRAINING.md, "What a fused step returns").
+
 Parameters, optimizer state, residuals, aux states, and the metric
 accumulator are DONATED, so HBM holds one copy of the training state and
 a steady-state step is a single device launch with zero host syncs —
@@ -61,11 +69,12 @@ import jax.numpy as jnp
 
 from ..ndarray import NDArray
 from .. import fused_update as _fused
+from .. import loss_head as _loss_head
 from .. import optimizer as opt_mod
 from .. import telemetry as _telemetry
 from ..kvstore import KVStore, _updater_key
 from ..kvstore_fused import two_bit_quantize
-from ..executor import (_compiled_cache, _count_dispatch,
+from ..executor import (_build_graph_fn, _compiled_cache, _count_dispatch,
                         _dispatch_span)
 from ..model import _local_updater_key
 
@@ -149,10 +158,15 @@ _SENT_WARMUP = 8.0
 def _metric_closure(metric, label_names, output_names):
     """(metric_fn, cache_sig) folding ``metric``'s device accumulation
     into the step program with ``update_dict``'s output/label selection
-    semantics; (None, None) when the metric accumulates on the host."""
+    semantics; (None, None) when the metric accumulates on the host.
+    Where the program deferred a loss head, ``outs`` holds a
+    ``loss_head.DeferredHead`` in its place: a metric that reads the
+    probabilities at the labels only takes it as it is, every other
+    metric gets the head's full value, built inside the program."""
     fn = metric.device_fn() if metric is not None else None
     if fn is None:
         return None, None
+    at_labels = metric.device_reads_at_labels
     out_sel = tuple(metric.output_names) if metric.output_names else None
     lab_sel = tuple(metric.label_names) if metric.label_names else None
     label_names = tuple(label_names)
@@ -162,6 +176,9 @@ def _metric_closure(metric, label_names, output_names):
         pred_d = dict(zip(output_names, outs))
         preds = ([pred_d[n] for n in out_sel if n in pred_d]
                  if out_sel is not None else list(outs))
+        if not at_labels:
+            preds = [p.value if isinstance(p, _loss_head.DeferredHead)
+                     else p for p in preds]
         names = lab_sel if lab_sel is not None else label_names
         labels = [inputs[n] for n in names if n in inputs]
         return fn(labels, preds)
@@ -173,8 +190,15 @@ def _metric_closure(metric, label_names, output_names):
 
 def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
                        mp_flags, use_wd, metric_fn, mirror, scaler,
-                       sentinel=False):
+                       sentinel=False, heads=()):
     """ONE jitted program: fwd+bwd+compress+reduce+update(+metric).
+
+    With ``heads`` (loss_head.HeadPlan s; ``graph_fn`` then returns their
+    stems as a third result) the program returns each such head's stem,
+    after the outputs, and None in the head's own place.  The head is
+    traced all the same, so its ``custom_vjp`` writes the gradient it
+    always wrote; its forward value is left to the metric, and with no
+    reader the compiler drops it.
 
     The compress and optimizer math are the SAME functions the bucketed
     kvstore step compiles (kvstore_fused.two_bit_quantize and the
@@ -202,16 +226,24 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
         _note_retrace()   # trace-time host side effect only
 
         def f(p):
-            outs, new_auxs = graph_fn({**inputs, **p}, auxs, seed, True)
-            return outs, new_auxs
+            outs, new_auxs, *stems = graph_fn({**inputs, **p}, auxs, seed,
+                                              True)
+            return outs, (new_auxs, stems[0] if stems else ())
 
         if mirror:
             # MXNET_BACKWARD_DO_MIRROR: rematerialize the forward
             # (jax.checkpoint), matching executor._make_fwd_bwd
             f = jax.checkpoint(f)
-        outs, vjp_fn, new_auxs = jax.vjp(f, params, has_aux=True)
+        outs, vjp_fn, (new_auxs, stems) = jax.vjp(f, params, has_aux=True)
         cts = [jnp.ones_like(o) for o in outs]
         (grads,) = vjp_fn(cts)
+        results = list(outs)
+        if heads:
+            outs = list(outs)
+            for plan, stem in zip(heads, stems):
+                outs[plan.index] = _loss_head.DeferredHead(
+                    plan, stem, outs[plan.index])
+                results[plan.index] = None
 
         # a gradient crosses into the update stage in the dtype the
         # backward wrote it: an operand of the scaler's ``lax.cond`` is
@@ -331,7 +363,7 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
                     [new_ema, new_var, n + 1.0, cnf + nonfin, gnorm, z,
                      new_rema, drift]).astype(jnp.float32)
         return (new_ps, new_ss, new_res, macc, new_scaler, new_sent,
-                new_auxs, outs)
+                new_auxs, results, stems)
 
     # params/states/residuals/macc/scaler/sentinels/auxs donate in place
     donate = (0, 1, 2, 3, 4, 5, 7)
@@ -774,17 +806,40 @@ class FusedFitStep:
             self._scaler = scaler
         scaler_sig = scaler.trace_sig() if scaler is not None else None
         sent_on = _sentinel_enabled()
-        cache = _compiled_cache(mod._symbol).setdefault("fit_step", {})
+        # a loss head's output is deferred (loss_head.py) where no one
+        # reads the outputs step by step: the metric folds inside the
+        # program, or there is none.  A metric that accumulates on the
+        # host reads get_outputs() after every step, so its program
+        # keeps returning them.  (A pod's outputs span processes and
+        # stay as they were: a tail program there would be a collective
+        # that only the reading rank enters.)
+        heads, tail = (), None
+        if (eval_metric is None or metric_fn is not None) \
+                and self._pmesh is None:
+            heads = tuple(p for p in _loss_head.plans(mod._symbol)
+                          if p.label in inputs)
+        at = tuple(p.index for p in heads)
+        sym_cache = _compiled_cache(mod._symbol)
+        graph_fn = sym_cache["graph_fn"]
+        if heads:
+            head_fns = sym_cache.setdefault("fit_heads", {})
+            if at not in head_fns:
+                head_fns[at] = (
+                    _build_graph_fn(mod._symbol,
+                                    also=[p.stem for p in heads]),
+                    _loss_head.tail_program(heads))
+            graph_fn, tail = head_fns[at]
+        cache = sym_cache.setdefault("fit_step", {})
         # `mode` re-read above: mutating optimizer hyperparams mid-
         # training switches programs (one retrace), like the eager path
         key = (tuple(order), self._threshold, mode, tpls, mp_flags,
-               use_wd, msig, mirror, scaler_sig, sent_on)
+               use_wd, msig, mirror, scaler_sig, sent_on, at)
         fn = cache.get(key)
         if fn is None:
             fn = cache[key] = _build_fit_program(
-                _compiled_cache(mod._symbol)["graph_fn"], tuple(order),
+                graph_fn, tuple(order),
                 self._threshold, mode, tpls, mp_flags, use_wd,
-                metric_fn, mirror, scaler, sentinel=sent_on)
+                metric_fn, mirror, scaler, sentinel=sent_on, heads=heads)
 
         macc = ()
         if metric_fn is not None:
@@ -839,14 +894,16 @@ class FusedFitStep:
         rescale = _np.float32(optimizer.rescale_grad)
         args = (params, states, residuals, macc, scaler_state, sent_state,
                 inputs, auxs, lr_vec, wd_vec, rescale, extra, seed)
+        deferred = (heads, [inputs[p.label] for p in heads], tail) \
+            if heads else None
         return fn, args, (exe, order, states_nd, scaler, sent_on,
-                          metric_fn is not None)
+                          metric_fn is not None, deferred)
 
     def _rebind(self, result, eval_metric, exe, order, states_nd, scaler,
-                sent_on, has_metric):
+                sent_on, has_metric, deferred):
         """Hand every donated buffer its new value."""
         (new_ps, new_ss, new_res, macc, new_scaler, new_sent, new_auxs,
-         outs) = result
+         outs, stems) = result
         mod = self._mod
         kv_store = self._kv._store \
             if (self._kv is not None and mod._update_on_kvstore) else None
@@ -863,7 +920,13 @@ class FusedFitStep:
             scaler.set_device_state(new_scaler)
         self._sent_state = new_sent if sent_on else None
         exe._write_auxs(new_auxs)
-        exe._outputs = [NDArray(o, exe._ctx) for o in outs]
+        if deferred is not None:
+            # the heads' values are made when Executor.outputs is read
+            exe._outputs = _loss_head.DeferredOutputs(exe._ctx, outs,
+                                                      stems, *deferred)
+            _loss_head.DEFERRED.inc()
+        else:
+            exe._outputs = [NDArray(o, exe._ctx) for o in outs]
         if self._moe_counts is not None:
             # a reference to the counts' device array, read only when
             # telemetry.moe.publish() is asked: no sync in the step

@@ -246,3 +246,78 @@ def test_compressed_conv_attention_fwd_grad_compiles_with_flash(
         ((2 * Dh, d), bf), ((10 * Dh, 2), bf), ((10, Dh, Dh, 2), bf),
         ((Hk,), bf), ((d, Hq * Dh), bf))
     assert "splash_mha" in compiled.as_text()
+
+
+def _written_types(text):
+    """The result type of every instruction of a compiled program that
+    is written to memory: the instructions of the entry computation and
+    of the branches it calls, not those inside a fusion (a fusion's
+    interior lives in registers and VMEM)."""
+    import re
+    found = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)",
+                         text):
+        if "fused_computation" in comp.split("(", 1)[0]:
+            continue
+        for m in re.finditer(
+                r"^\s*(?:ROOT )?%?[\w.\-]+ = (\S+?)\{[^ ]*\} (?!parameter|tuple)"
+                r"[\w\-]+\(", comp, re.M):
+            found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("cell,B,S,V,bias", [
+    ("cgpt13b", 2, 2048, 50257, True), ("zaya1_8b", 1, 8192, 32784, False)])
+def test_fit_program_writes_no_float32_probabilities(one_chip, cell, B, S,
+                                                     V, bias):
+    """The tail of a training cell at its own widths (bf16 hidden states
+    (B, S, 2048) -> ``lm_head`` -> ``Cast`` float32 -> ``Reshape`` ->
+    ``SoftmaxOutput``; Adam with float32 masters), as the fused fit
+    program, compiled for the described chip.  With ``ce`` folded the
+    program returns the head's stem and writes NO float32 array of
+    tokens x vocabulary elements: neither a result, nor the operand of
+    the metric's read at the labels.  The program of a metric that
+    accumulates on the host returns the probabilities, as every fit
+    program did before (three writes of 823 MB a step on the LM cell:
+    PERF.md section 6, PR 29), and shows them here."""
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import sym
+
+    d = 2048
+
+    def program(metric):
+        x = sym.Cast(sym.Variable("data"), dtype="bfloat16", name="cast_in")
+        x = sym.FullyConnected(data=x, num_hidden=V, flatten=False,
+                               no_bias=not bias, name="lm_head")
+        x = sym.Cast(data=x, dtype="float32", name="cast_out")
+        x = sym.Reshape(data=x, shape=(-1, V), name="logits_2d")
+        mod = mx.Module(sym.SoftmaxOutput(data=x, name="softmax",
+                                          normalization="batch"),
+                        context=mx.cpu())
+        mod.bind(data_shapes=[("data", (B, S, d))],
+                 label_shapes=[("softmax_label", (B * S,))])
+        mod.init_params(mx.init.Zero())
+        mod.init_optimizer(optimizer="adam", optimizer_params={
+            "learning_rate": 2e-4, "wd": 0.1, "multi_precision": True})
+        batch = mx.io.DataBatch(data=[mx.nd.zeros((B, S, d))],
+                                label=[mx.nd.zeros((B * S,))])
+        fn, args, _ = mod._get_fused_fit()._prepare(batch, metric)
+        assert str(args[0]["lm_head_weight"].dtype) == "bfloat16"
+        specs = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+            if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+        with jax.default_matmul_precision("default"):
+            return fn.lower(*specs).compile().as_text()
+
+    wide = {"f32[%d,%d]" % (B * S, V), "f32[%d,%d,%d]" % (B, S, V)}
+    stem = "bf16[%d,%d,%d]" % (B, S, V)
+
+    deferred = _written_types(program(mx.metric.create("ce")))
+    assert stem in deferred
+    assert not wide & set(deferred)
+
+    returned = _written_types(program(mx.metric.np(lambda l, p: 0.0)))
+    assert wide & set(returned)
