@@ -212,7 +212,7 @@ def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
                 ins = [env[(id(inp), oi)] for inp, oi in node.inputs]
                 with jax.named_scope(node.op.name), \
                         jax.named_scope(node.name):
-                    raw = node.op.fn(*ins, **node.attrs)
+                    raw = node.apply(ins)
                 if group_devices:
                     raw = (tuple(_place(node, r) for r in raw)
                            if isinstance(raw, (tuple, list))
@@ -230,7 +230,7 @@ def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
                         _emit_tap(node.output_name(i), v)
                 # aux-state updates (reference FMutateInputs)
                 if node.op.mutate_inputs and is_train:
-                    in_names = node.op.input_names
+                    in_names = node.input_names()
                     for mut_name, out_idx in node.op.mutate_inputs:
                         for (inp, _), nm in zip(node.inputs, in_names):
                             if nm == mut_name and inp.is_var and inp.name in aux_names:

@@ -4,10 +4,12 @@ Reference parity: example/image-classification/symbols/ (mlp, lenet,
 alexnet, vgg, resnet, resnext, mobilenet, inception-bn, googlenet,
 squeezenet, densenet). Each module exposes ``get_symbol(num_classes, ...)``
 returning a Symbol ending in SoftmaxOutput, so any of them drops into
-``Module.fit`` / ``benchmark/run.py`` unchanged.  Two language-model families
-beside them, with the same factory signature: ``transformer`` (GPT-2's
-block) and ``zaya`` (compressed convolutional attention and a dropless
-top-1 expert sublayer; its second output is the experts' token counts).
+``Module.fit`` / ``benchmark/run.py`` unchanged.  Three language-model
+families beside them, with the same factory signature: ``transformer``
+(GPT-2's block), ``zaya`` (compressed convolutional attention and a
+dropless top-1 expert sublayer) and ``qwen3_next`` (Gated DeltaNet layers
+beside gated attention, a dropless top-k sublayer with a shared expert);
+the second output of the last two is the experts' token counts.
 
 These are fresh TPU-first definitions (bf16-friendly: ``dtype`` casts the
 trunk while the final classifier/softmax stays fp32), not translations of
@@ -26,10 +28,12 @@ from . import squeezenet
 from . import densenet
 from . import transformer
 from . import zaya
+from . import qwen3_next
 
 _NETWORKS = {
     "transformer": transformer,
     "zaya": zaya,
+    "qwen3_next": qwen3_next,
     "mlp": mlp,
     "lenet": lenet,
     "alexnet": alexnet,

@@ -315,15 +315,18 @@ def layer_norm(data, gamma, beta, *, axis=-1, eps=1e-5, output_mean_var=False):
 
 
 @register("RMSNorm", aliases=("rms_norm",))
-def rms_norm(data, gamma, *, axis=-1, eps=1e-5):
+def rms_norm(data, gamma, *, axis=-1, eps=1e-5, zero_centered=False):
     """Root-mean-square normalization with a gain and no shift:
     ``x / sqrt(mean(x^2) + eps) * gamma`` over ``axis``; the statistic
-    and the scaling in float32, the result in the data's dtype."""
+    and the scaling in float32, the result in the data's dtype.  With
+    ``zero_centered`` the gain is ``1 + gamma`` (a gamma initialised 0
+    is the identity, and weight decay pulls the gain towards 1)."""
     ax = int(axis) % data.ndim
     xf = data.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=ax, keepdims=True) + eps)
     bshape = tuple(data.shape[i] if i == ax else 1 for i in range(data.ndim))
-    out = xf * inv * gamma.astype(jnp.float32).reshape(bshape)
+    gain = gamma.astype(jnp.float32).reshape(bshape)
+    out = xf * inv * (1.0 + gain if zero_centered else gain)
     return out.astype(data.dtype)
 
 
@@ -877,6 +880,27 @@ def fused_causal_self_attention(data, qkv_weight, qkv_bias, proj_weight,
                       proj_weight.reshape(d, H, D)) + proj_bias
 
 
+def _grouped_causal_attention(q, k, v, scale):
+    """Causal softmax attention of head-major q (B, Hq, S, D) over k, v
+    (B, Hk, S, D) by XLA, each key/value head shared by its Hq / Hk query
+    heads; float32 scores, the probabilities in q's dtype; checkpointed,
+    so the (S, S) scores are not kept for the backward pass.  What the
+    mixers run where the flash kernel cannot."""
+    B, Hq, S, D = q.shape
+    Hk = k.shape[1]
+
+    @jax.checkpoint
+    def attn(q, k, v):
+        s = jnp.einsum("bgrqe,bgke->bgrqk",
+                       q.reshape(B, Hk, Hq // Hk, S, D), k) * scale
+        mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+        s = jnp.where(mask, s.astype(jnp.float32), -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        return jnp.einsum("bgrqk,bgke->bgrqe", p, v).reshape(B, Hq, S, D)
+
+    return attn(q, k, v)
+
+
 def _shift_right(x, n, axis):
     """``x`` moved ``n`` positions later along ``axis``, zeros first: a
     causal tap (position t reads t - n, and nothing before position 0)."""
@@ -988,22 +1012,160 @@ def compressed_conv_attention(data, q_weight, k_weight, v_weight,
         q, k, v = mix(z, v2, conv0_weight, conv1_weight, temp)
 
     with jax.named_scope("cca.attention"):
-        if flash:
-            o = _flash_attention(q, k, v)
-        else:
-            @jax.checkpoint
-            def attn(q, k, v):
-                s = jnp.einsum("bgrqe,bgke->bgrqk",
-                               q.reshape(B, Hk, G, S, D), k) * sc
-                mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-                s = jnp.where(mask, s.astype(f32), -1e30)
-                p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-                return jnp.einsum("bgrqk,bgke->bgrqe", p, v) \
-                    .reshape(B, Hq, S, D)
-            o = attn(q, k, v)
+        o = _flash_attention(q, k, v) if flash \
+            else _grouped_causal_attention(q, k, v, sc)
 
     with jax.named_scope("cca.proj"):
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
+
+
+@register("_contrib_GatedCausalSelfAttention",
+          aliases=("GatedCausalSelfAttention",))
+def gated_causal_self_attention(data, q_weight, k_weight, v_weight,
+                                q_norm_gamma, k_norm_gamma, o_weight, *,
+                                q_heads, kv_heads, head_dim,
+                                rotary_frac=0.25, rope_theta=1e7, eps=1e-6):
+    """Causal softmax attention with an output gate, as one sublayer
+    (B, S, d) -> (B, S, d) on an already normalised stream; no bias.
+
+    ``q_weight`` (q_heads * 2 * head_dim, d) gives each query head its
+    query and, beside it, a gate of the same width; ``k_weight``,
+    ``v_weight`` (kv_heads * head_dim, d).  Query and key heads are
+    RMS-normalised over their channels with the zero-centred gain
+    ``1 + gamma`` (one gain vector for all heads); rotary position turns
+    the first ``rotary_frac`` of each head; causal attention at scale
+    ``head_dim ** -0.5`` with ``q_heads / kv_heads`` query heads to a
+    key/value head; the result times ``sigmoid(gate)``, then
+    ``o_weight`` (d, q_heads * head_dim).
+
+    Head-major like CompressedConvAttention.  Where
+    ``_use_flash_attention`` allows it the Pallas flash kernel takes K
+    and V at their own ``kv_heads`` (q then carries the softmax scale);
+    the checkpointed XLA path otherwise.  Norms and rotary are
+    rematerialized in the backward pass.  Scopes: ``gattn.proj``,
+    ``gattn.norm``, ``gattn.attention``."""
+    B, S, d = data.shape
+    Hq, Hk, D = int(q_heads), int(kv_heads), int(head_dim)
+    if Hq % Hk:
+        raise ValueError("q_heads %d not a multiple of kv_heads %d"
+                         % (Hq, Hk))
+    rot = int(round(float(rotary_frac) * D))
+    f32 = jnp.float32
+    with jax.named_scope("gattn.proj"):
+        qg = jnp.einsum("bsd,hted->tbhse", data,
+                        q_weight.reshape(Hq, 2, D, d))
+        q0, gate = qg[0], qg[1]
+        k0 = jnp.einsum("bsd,hed->bhse", data, k_weight.reshape(Hk, D, d))
+        v = jnp.einsum("bsd,hed->bhse", data, v_weight.reshape(Hk, D, d))
+
+    sc = D ** -0.5
+    flash = _use_flash_attention(S, D, data.dtype)
+
+    @jax.checkpoint
+    def prepare(q0, k0, gq, gk):
+        def norm(t, gain, scale):
+            t = t.astype(f32)
+            inv = lax.rsqrt(jnp.mean(jnp.square(t), -1, keepdims=True) + eps)
+            return t * inv * ((1.0 + gain.astype(f32)) * scale)
+        # the flash kernel takes no softmax scale: there q carries it
+        q = _rotary_half(norm(q0, gq, sc if flash else 1.0), rot,
+                         float(rope_theta))
+        k = _rotary_half(norm(k0, gk, 1.0), rot, float(rope_theta))
+        return q.astype(q0.dtype), k.astype(k0.dtype)
+
+    with jax.named_scope("gattn.norm"):
+        q, k = prepare(q0, k0, q_norm_gamma, k_norm_gamma)
+
+    with jax.named_scope("gattn.attention"):
+        o = _flash_attention(q, k, v) if flash \
+            else _grouped_causal_attention(q, k, v, sc)
+        o = (o.astype(f32) * jax.nn.sigmoid(gate.astype(f32))) \
+            .astype(data.dtype)
+
+    with jax.named_scope("gattn.proj"):
+        return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
+
+
+@register("_contrib_GatedDeltaNet", aliases=("GatedDeltaNet",))
+def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, A_log,
+                    dt_bias, norm_gamma, out_weight, *, k_heads, v_heads,
+                    k_dim, v_dim, conv_kernel=4, eps=1e-6):
+    """The Gated DeltaNet mixer (arXiv:2412.06464) as one sublayer,
+    (B, S, d) -> (B, S, d), on an already normalised stream; no bias.
+
+    ``qkvz_weight`` ((2 k_heads k_dim + 2 v_heads v_dim), d) gives, as
+    contiguous blocks of rows, q and k (``k_heads`` heads of ``k_dim``),
+    v and the output gate z (``v_heads`` of ``v_dim``); ``ba_weight``
+    (2 v_heads, d) the write strength b and the decay input a of every
+    value head.  ``[q; k; v]`` pass a causal depthwise convolution over
+    the sequence (``conv_weight`` (channels, conv_kernel), tap
+    ``conv_kernel - 1`` the position itself) and SiLU; q and k are
+    L2-normalised a head, q scaled by ``k_dim ** -0.5``; ``beta =
+    sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)`` in float32
+    (``A_log``, ``dt_bias`` (v_heads,)); each key head serves
+    ``v_heads / k_heads`` value heads through the gated delta rule
+    (``ops/delta_rule.py``: chunks of 64 tokens, state float32);
+    the result is RMS-normalised a value head with gain ``norm_gamma``
+    (v_dim,) and gated by ``silu(z)``, then ``out_weight`` (d, v_heads
+    v_dim).
+
+    Head-major: the projection emits (B, H, S, D).  Convolution,
+    normalisation and gates are rematerialized in the backward pass, and
+    so is the scan (its forward runs again there: a layer saves its
+    projections only).  Scopes: ``gdn.proj``, ``gdn.conv``,
+    ``gdn.scan``, ``gdn.norm``."""
+    from .delta_rule import chunk_gated_delta_rule
+    B, S, d = data.shape
+    Hk, Hv, Dk, Dv = int(k_heads), int(v_heads), int(k_dim), int(v_dim)
+    K = int(conv_kernel)
+    if Dk != Dv:
+        raise ValueError("the mixed q, k, v projection is head-major over "
+                         "one head width: k_dim %d != v_dim %d" % (Dk, Dv))
+    H = 2 * Hk + Hv
+    f32 = jnp.float32
+
+    with jax.named_scope("gdn.proj"):
+        w = qkvz_weight.reshape(H + Hv, Dk, d)
+        qkv = jnp.einsum("bsd,hed->bhse", data, w[:H])
+        z = jnp.einsum("bsd,hed->bhse", data, w[H:])
+        ba = jnp.einsum("bsd,thd->tbhs", data, ba_weight.reshape(2, Hv, d),
+                        preferred_element_type=f32)
+
+    @jax.checkpoint
+    def mix(qkv, ba, wc, a_log, dt):
+        x = qkv.astype(f32)
+        wc = wc.astype(f32).reshape(1, H, 1, Dk, K)
+        x = jax.nn.silu(sum(_shift_right(x, K - 1 - j, 2) * wc[..., j]
+                            for j in range(K)))
+        unit = lambda t: t * lax.rsqrt(
+            jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+        q = unit(x[:, :Hk]) * Dk ** -0.5
+        k = unit(x[:, Hk:2 * Hk])
+        beta = jax.nn.sigmoid(ba[0])
+        g = -jnp.exp(a_log.astype(f32)).reshape(1, Hv, 1) \
+            * jax.nn.softplus(ba[1] + dt.astype(f32).reshape(1, Hv, 1))
+        low = qkv.dtype
+        return q.astype(low), k.astype(low), x[:, 2 * Hk:].astype(low), \
+            g, beta
+
+    with jax.named_scope("gdn.conv"):
+        q, k, v, g, beta = mix(qkv, ba, conv_weight, A_log, dt_bias)
+
+    with jax.named_scope("gdn.scan"):
+        o = jax.checkpoint(chunk_gated_delta_rule)(q, k, v, g, beta)
+
+    @jax.checkpoint
+    def gated_norm(o, z, gain):
+        o = o.astype(f32)
+        inv = lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + eps)
+        return (gain.astype(f32) * o * inv
+                * jax.nn.silu(z.astype(f32))).astype(z.dtype)
+
+    with jax.named_scope("gdn.norm"):
+        o = gated_norm(o, z, norm_gamma)
+
+    with jax.named_scope("gdn.proj"):
+        return jnp.einsum("bhse,dhe->bsd", o, out_weight.reshape(d, Hv, Dv))
 
 
 # ----------------------------------------------------------------------
@@ -1322,50 +1484,81 @@ def switch_moe_op(data, router_weight, expert_up_weight, expert_up_bias,
 
 @register("_contrib_RoutedExperts", aliases=("RoutedExperts",),
           num_outputs=3, num_visible_outputs=3)
-def routed_experts(data, router_in_weight, router_norm_gamma,
-                   router_fc1_weight, router_fc2_weight, router_out_weight,
-                   gate_weight, up_weight, down_weight, router_state=None,
-                   router_carry=None, *, num_experts, held_first=0,
-                   held_count=None, num_hidden, router_hidden,
-                   carry_in=True):
-    """The dropless top-1 expert sublayer of a chip that holds
-    ``held_count`` of ``num_experts`` experts (``held_first`` onwards),
-    on an already normalised stream (..., d).
+def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
+                   router_fc1_weight=None, router_fc2_weight=None,
+                   router_out_weight=None, gate_weight=None, up_weight=None,
+                   down_weight=None, router_state=None, router_carry=None,
+                   router_weight=None, shared_gate_weight=None,
+                   shared_up_weight=None, shared_down_weight=None,
+                   shared_sg_weight=None, *, num_experts, held_first=0,
+                   held_count=None, num_hidden, router_hidden=0,
+                   carry_in=True, router="zaya", top_k=1, shared_hidden=0):
+    """The dropless expert sublayer of a chip that holds ``held_count``
+    of ``num_experts`` experts (``held_first`` onwards), on an already
+    normalised stream (..., d).  A token's ``top_k`` experts are chosen
+    among ALL experts in float32; the (token, choice) pairs whose expert
+    is held here are sorted and run through three grouped matrix
+    products (SiLU-gated FFN of width ``num_hidden``; stacks (held, out,
+    in)), each weighted by what the router gives it.  A pair whose
+    expert lives on another chip adds 0.  No capacity, no dropped token
+    (``SwitchMoE`` keeps its capacity semantics).
 
-    The ZAYA router scores ALL experts in float32: its state
-    ``r = h W_in + carry * r_prev`` takes the previous layer's state
-    (``router_state`` and the scalar ``router_carry``, the last two
-    inputs, both absent with ``carry_in=False``: the first layer),
-    then a 3-layer GELU MLP of width ``router_hidden`` and a softmax.
-    Each token goes to its best expert; the tokens of the experts held
-    here are sorted and run through three grouped matrix products
-    (SiLU-gated FFN of width ``num_hidden``; stacks (held, out, in)),
-    weighted by the expert's probability.  A token whose expert lives on
-    another chip gets 0 from this sublayer.  No capacity, no dropped
-    token (``SwitchMoE`` keeps its capacity semantics).
+    ``router="zaya"`` (top-1 only): the state ``r = h W_in + carry *
+    r_prev`` takes the previous layer's state (``router_state`` and the
+    scalar ``router_carry``, both absent with ``carry_in=False``: the
+    first layer), then a 3-layer GELU MLP of width ``router_hidden`` and
+    a softmax; the token's weight is its expert's probability.
+    ``router="linear"``: ``softmax(h W^T)`` with ``router_weight``
+    (num_experts, d), the ``top_k`` best, weights normalised over all
+    ``top_k`` whether held here or not.  With ``shared_hidden`` one
+    shared expert (the dense gated FFN ``parallel.moe.gated_ffn``) runs over
+    every token behind ``sigmoid(h w_sg)`` (``shared_sg_weight`` (1, d)) and
+    joins the result, whole on every chip.
 
-    Outputs: ``y`` like ``data``; the router state (..., router_hidden)
-    float32 for the next layer; int32 (num_experts,) tokens an expert,
-    over all experts.  Scopes: ``moe.router``, ``moe.dispatch``,
-    ``moe.experts``, ``moe.combine``."""
-    from ..parallel.moe import dropless_top1_experts, zaya_router
+    Outputs: ``y`` like ``data``; the router's second output: its state
+    (..., router_hidden) float32 for the next layer (zaya), the chosen
+    experts (..., top_k) int32 (linear); int32 (num_experts,) (token,
+    choice) pairs an expert, over all experts.  Scopes: ``moe.router``,
+    ``moe.dispatch``, ``moe.experts``, ``moe.combine``, ``moe.shared``."""
+    from ..parallel import moe
     lead, d = data.shape[:-1], data.shape[-1]
-    R = int(router_hidden)
-    held = int(num_experts) - int(held_first) if held_count is None \
-        else int(held_count)
+    E, k = int(num_experts), int(top_k)
+    held = E - int(held_first) if held_count is None else int(held_count)
     if gate_weight.shape[0] != held:
         raise ValueError("expert stacks hold %d experts, held_count=%d"
                          % (gate_weight.shape[0], held))
     x = data.reshape(-1, d)
-    with jax.named_scope("moe.router"):
-        r, prob = zaya_router(
-            x, router_state.reshape(-1, R) if carry_in else None,
-            router_in_weight, router_carry, router_norm_gamma,
-            router_fc1_weight, router_fc2_weight, router_out_weight)
-    y, counts = dropless_top1_experts(x, prob, gate_weight, up_weight,
-                                      down_weight, int(held_first))
-    return (y.reshape(data.shape), r.reshape(lead + (R,)),
-            lax.stop_gradient(counts))
+    if router == "zaya":
+        if k != 1:
+            raise ValueError("the zaya router is top-1, top_k=%d" % k)
+        R = int(router_hidden)
+        with jax.named_scope("moe.router"):
+            r, prob = moe.zaya_router(
+                x, router_state.reshape(-1, R) if carry_in else None,
+                router_in_weight, router_carry, router_norm_gamma,
+                router_fc1_weight, router_fc2_weight, router_out_weight)
+        y, counts = moe.dropless_top1_experts(
+            x, prob, gate_weight, up_weight, down_weight, int(held_first))
+        second = r.reshape(lead + (R,))
+    elif router == "linear":
+        with jax.named_scope("moe.router"):
+            chosen, weights = moe.linear_router(x, router_weight, k)
+        y, counts = moe.dropless_topk_experts(
+            x, chosen, weights, gate_weight, up_weight, down_weight, E,
+            int(held_first))
+        second = lax.stop_gradient(chosen).reshape(lead + (k,))
+    else:
+        raise ValueError("router=%r (zaya or linear)" % (router,))
+    if int(shared_hidden):
+        with jax.named_scope("moe.shared"):
+            open_ = jax.nn.sigmoid(jnp.einsum(
+                "nd,od->no", x, shared_sg_weight,
+                preferred_element_type=jnp.float32))
+            shared = moe.gated_ffn(x, shared_gate_weight, shared_up_weight,
+                                   shared_down_weight)
+            y = (y.astype(jnp.float32)
+                 + open_ * shared.astype(jnp.float32)).astype(data.dtype)
+    return (y.reshape(data.shape), second, lax.stop_gradient(counts))
 
 
 # ----------------------------------------------------------------------

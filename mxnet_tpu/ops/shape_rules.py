@@ -116,8 +116,9 @@ def _routed_experts_shapes(known, attrs):
     if data is None:
         return {}
     d = int(data[-1])
-    E, h, R = (int(attrs[k]) for k in ("num_experts", "num_hidden",
-                                       "router_hidden"))
+    E, h, R = (int(attrs.get(k, 0)) for k in ("num_experts", "num_hidden",
+                                              "router_hidden"))
+    Fs = int(attrs.get("shared_hidden", 0))
     held = attrs.get("held_count")
     held = E - int(attrs.get("held_first", 0)) if held is None else int(held)
     return {"router_state": tuple(data[:-1]) + (R,),
@@ -125,12 +126,63 @@ def _routed_experts_shapes(known, attrs):
             "router_norm_gamma": (R,), "router_fc1_weight": (R, R),
             "router_fc2_weight": (R, R), "router_out_weight": (E, R),
             "gate_weight": (held, h, d), "up_weight": (held, h, d),
-            "down_weight": (held, d, h)}
+            "down_weight": (held, d, h), "router_weight": (E, d),
+            "shared_gate_weight": (Fs, d), "shared_up_weight": (Fs, d),
+            "shared_down_weight": (d, Fs), "shared_sg_weight": (1, d)}
 
 
-_set("_contrib_RoutedExperts", _routed_experts_shapes,
-     lambda attrs: set() if attrs.get("carry_in", True)
-     else {"router_state", "router_carry"})
+_ZAYA_ROUTER = {"router_in_weight", "router_norm_gamma", "router_fc1_weight",
+                "router_fc2_weight", "router_out_weight", "router_state",
+                "router_carry"}
+_SHARED_EXPERT = {"shared_gate_weight", "shared_up_weight",
+                  "shared_down_weight", "shared_sg_weight"}
+
+
+def _routed_experts_unused(attrs):
+    """The router's inputs follow ``router``, the shared expert's
+    ``shared_hidden``; the zaya router's first layer takes no state."""
+    out = set() if int(attrs.get("shared_hidden", 0)) else set(_SHARED_EXPERT)
+    if attrs.get("router", "zaya") == "zaya":
+        out.add("router_weight")
+        if not attrs.get("carry_in", True):
+            out |= {"router_state", "router_carry"}
+    else:
+        out |= _ZAYA_ROUTER
+    return out
+
+
+_set("_contrib_RoutedExperts", _routed_experts_shapes, _routed_experts_unused)
+
+
+def _gated_attn_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    Hq, Hk, D = (int(attrs[k]) for k in ("q_heads", "kv_heads", "head_dim"))
+    return {"q_weight": (2 * Hq * D, d), "k_weight": (Hk * D, d),
+            "v_weight": (Hk * D, d), "q_norm_gamma": (D,),
+            "k_norm_gamma": (D,), "o_weight": (d, Hq * D)}
+
+
+_set("_contrib_GatedCausalSelfAttention", _gated_attn_shapes)
+
+
+def _gdn_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    Hk, Hv, Dk, Dv = (int(attrs[k]) for k in ("k_heads", "v_heads", "k_dim",
+                                              "v_dim"))
+    kd, vd = Hk * Dk, Hv * Dv
+    return {"qkvz_weight": (2 * kd + 2 * vd, d), "ba_weight": (2 * Hv, d),
+            "conv_weight": (2 * kd + vd, int(attrs.get("conv_kernel", 4))),
+            "A_log": (Hv,), "dt_bias": (Hv,), "norm_gamma": (Dv,),
+            "out_weight": (d, vd)}
+
+
+_set("_contrib_GatedDeltaNet", _gdn_shapes)
 
 
 def _cca_shapes(known, attrs):

@@ -39,6 +39,7 @@ goes with it (docs/TRAINING.md, "The dropless expert layer").
 """
 from __future__ import annotations
 
+import functools as _functools
 import math
 
 import jax
@@ -46,7 +47,8 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["switch_moe", "moe_reference", "init_moe_params",
-           "zaya_router", "dropless_top1_experts"]
+           "zaya_router", "linear_router", "gated_ffn",
+           "dropless_topk_experts", "dropless_top1_experts"]
 
 
 def init_moe_params(key, d_model, d_hidden, n_experts, dtype=jnp.float32):
@@ -188,25 +190,61 @@ _RAGGED_OUT_IN = lax.RaggedDotDimensionNumbers(
     dot_dimension_numbers=(((1,), (2,)), ((), ())),
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
 
-# rows, contraction, columns of a tile of the Pallas grouped matmul
-# (jax's megablox): the faster of the two tried on the v5e at 8192 x
-# 2048 x 2048 over 8 groups (PERF.md, PR 26)
-_GMM_TILING = (512, 1024, 1024)
+
+def linear_router(h, w, k):
+    """The plain router on tokens ``h`` (N, d), float32 whatever the
+    model's dtype: ``softmax(h W^T)`` over ALL experts (``w`` (E, d)),
+    the ``k`` best of a token (ties: the lower index) and their weights
+    normalised over all ``k``.  Returns ``(experts (N, k) int32, weights
+    (N, k) float32)``; the gradient reaches ``w`` through the weights."""
+    f32 = jnp.float32
+    prob = jax.nn.softmax(
+        jnp.einsum("nd,ed->ne", h.astype(f32), w.astype(f32),
+                   precision=lax.Precision.HIGHEST), axis=-1)
+    top, e = lax.top_k(prob, int(k))
+    return e.astype(jnp.int32), top / jnp.sum(top, -1, keepdims=True)
 
 
-def _grouped_matmul_impl(rows, dtype):
+def gated_ffn(x, w_gate, w_up, w_down):
+    """The dense SiLU-gated FFN ``down(silu(gate x) * up x)`` on rows
+    ``x`` (..., d); weights (out, in), no bias; the gate's product in
+    float32, rounded once."""
+    f32 = jnp.float32
+    g = jnp.einsum("...d,fd->...f", x, w_gate, preferred_element_type=f32)
+    u = jnp.einsum("...d,fd->...f", x, w_up, preferred_element_type=f32)
+    return jnp.einsum("...f,df->...d", (jax.nn.silu(g) * u).astype(x.dtype),
+                      w_down)
+
+
+def _gmm_tiling(rows, groups, k, n):
+    """Rows, contraction, columns of a tile of the Pallas grouped matmul
+    (jax's megablox), from the shapes it is given.  A tile that straddles
+    a group's end is visited once for each group in it, so its rows
+    follow the rows a group gets when the buffer is shared evenly: the
+    power of two below that, within 128 (the MXU's edge) and 512.
+    Contraction and columns take 1024 where the width has them.  At 8192
+    rows over 8 groups of width 2048 this is (512, 1024, 1024), the
+    faster of the two tried on the v5e (PERF.md, PR 26); 32 groups of
+    width 512 get 256-row tiles (PR 30)."""
+    per_group = max(1, rows // max(1, groups))
+    tm = min(512, max(128, 1 << (per_group.bit_length() - 1)))
+    return (min(tm, rows), min(1024, k), min(1024, n))
+
+
+def _grouped_matmul_impl(rows, dtype, groups=1):
     """How :func:`grouped_matmul` runs when not told: the Pallas kernel
     (``"compiled"``) in a one-device TPU program whose rows fill whole
     tiles, else XLA's ragged product (False; the fallback is counted in
     ``pallas_fallbacks{reason}``).  No knob: a test passes ``impl``."""
     from ..pallas.dispatch import _compiles_here, choose_impl
     here, why, reason = _compiles_here()
-    supported = (here and rows % _GMM_TILING[0] == 0
+    tm = _gmm_tiling(rows, groups, 1, 1)[0]
+    supported = (here and rows % tm == 0
                  and dtype in (jnp.bfloat16, jnp.float32))
     return choose_impl(
         "grouped_matmul (no knob)", "auto", "grouped_matmul", supported,
         why=f"{why or 'one TPU device'}, rows={rows}, dtype={dtype}; need "
-            f"a one-device TPU program, rows%{_GMM_TILING[0]}==0, bf16/f32",
+            f"a one-device TPU program, rows%{tm}==0, bf16/f32",
         fallback_reason=reason or "grouped-geometry")
 
 
@@ -220,16 +258,16 @@ def grouped_matmul(x, w, group_sizes, impl=None):
     ``impl``: None chooses (:func:`_grouped_matmul_impl`);
     ``"compiled"`` / ``"interpret"`` is the Pallas grouped matmul under
     scope ``pallas.grouped_matmul`` (forward, and both backward
-    products: its own VJP); False is ``jax.lax.ragged_dot_general``."""
+    products: its own VJP) at the tiles :func:`_gmm_tiling` derives from
+    the shapes; False is ``jax.lax.ragged_dot_general``."""
     if impl is None:
-        impl = _grouped_matmul_impl(x.shape[0], x.dtype)
+        impl = _grouped_matmul_impl(x.shape[0], x.dtype, w.shape[0])
     if not impl:
         return lax.ragged_dot_general(x, w, group_sizes, _RAGGED_OUT_IN)
     from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
     from ..pallas.attention import _count_launch
     _count_launch("grouped_matmul")
-    tiling = tuple(min(t, n) for t, n in zip(
-        _GMM_TILING, (x.shape[0], x.shape[1], w.shape[1])))
+    tiling = _gmm_tiling(x.shape[0], w.shape[0], x.shape[1], w.shape[1])
     with jax.named_scope("pallas.grouped_matmul"):
         return _megablox.gmm(x, w, group_sizes,
                              preferred_element_type=x.dtype, tiling=tiling,
@@ -237,48 +275,117 @@ def grouped_matmul(x, w, group_sizes, impl=None):
                              interpret=impl == "interpret")
 
 
+def _row_buckets(tokens, k, held, num_experts):
+    """The static sizes the sorted rows' buffer may take: the expected
+    and the worst case.  Nothing is dropped whatever the routing, so
+    the larger is a token's every choice held here, ``tokens * min(k,
+    held)``.  But a gather and a scatter-add of that many rows would
+    cost more than the experts when a sixteenth of them is real, so a
+    step whose real count fits takes the smaller: one row a token (the
+    expected count ``tokens * k * held / num_experts`` where that is
+    larger).  Top-1 has the one size."""
+    worst = tokens * min(k, held)
+    size = min(worst, max(tokens, -(-tokens * k * held // num_experts)))
+    return [size] if size == worst else [size, worst]
+
+
+def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
+                          num_experts, held_first=0, impl=None):
+    """SiLU-gated expert FFNs for the experts held here, ``k`` choices a
+    token, nothing dropped.  ``x`` (N, d) tokens; ``experts`` (N, k)
+    int32 among ALL ``num_experts`` and ``weights`` (N, k) float32, a
+    token's choices and what each counts; ``w_gate``/``w_up`` (held,
+    hidden, d), ``w_down`` (held, d, hidden) are experts ``held_first ..
+    held_first + held - 1``.  Returns ``(y (N, d), (token, choice) pairs
+    an expert int32 (num_experts,))`` with ``y = sum over a token's
+    choices e held here of w_e * down_e(silu(gate_e x) * up_e x)``; a
+    choice whose expert is elsewhere adds 0.  The gradient reaches the
+    router through ``weights``.
+
+    The pairs held here are sorted by expert and run through three
+    grouped products.  Their buffer has one of :func:`_row_buckets`'s
+    static sizes, chosen by the step's count (``lax.switch``; with two
+    sizes the forward is computed again in the backward pass, so that
+    the worst case's intermediates are never kept: differentiating
+    through a switch keeps every branch's).
+    ``impl`` is :func:`grouped_matmul`'s (None: chosen once a size for
+    all three products)."""
+    N, d = x.shape
+    k = experts.shape[1]
+    held = w_gate.shape[0]
+    E, first = int(num_experts), int(held_first)
+    f32 = jnp.float32
+    with jax.named_scope("moe.dispatch"):
+        pairs = experts.reshape(N * k)
+        counts = jnp.sum(pairs[:, None] == jnp.arange(E, dtype=jnp.int32),
+                         axis=0, dtype=jnp.int32)
+        local = pairs - first
+        here = (local >= 0) & (local < held)
+        # held pairs first, grouped by expert; the others after them
+        order = jnp.argsort(jnp.where(here, local, held), stable=True)
+        sizes = lax.slice_in_dim(counts, first, first + held)
+        real = jnp.sum(sizes)
+
+    def run(rows, x, weights, wg, wu, wd):
+        """The layer over the first ``rows`` sorted pairs."""
+        how = _grouped_matmul_impl(rows, x.dtype, held) if impl is None \
+            else impl
+        with jax.named_scope("moe.dispatch"):
+            at = lax.slice_in_dim(order, 0, rows)
+            token = at // k if k > 1 else at
+            here_s = (jnp.arange(rows) < real)[:, None]
+            # what a grouped product leaves in the rows of no group is
+            # not defined, forward or backward (it may be NaN): every
+            # operand and result is masked there, and with it its
+            # gradient
+            own = lambda t: jnp.where(here_s, t, 0)
+            xs = own(jnp.take(x, token, axis=0, unique_indices=k == 1))
+        with jax.named_scope("moe.experts"):
+            g = own(grouped_matmul(xs, wg, sizes, how))
+            u = own(grouped_matmul(xs, wu, sizes, how))
+            mid = own((jax.nn.silu(g.astype(f32)) * u.astype(f32))
+                      .astype(x.dtype))
+            ys = own(grouped_matmul(mid, wd, sizes, how))
+        with jax.named_scope("moe.combine"):
+            ys = ys.astype(f32) * jnp.take(weights.reshape(N * k), at)[:, None]
+            if k == 1:      # every token has its one row
+                return jnp.zeros((N, d), x.dtype).at[token].set(
+                    ys.astype(x.dtype), unique_indices=True)
+            return jnp.zeros((N, d), f32).at[token].add(ys).astype(x.dtype)
+
+    buckets = _row_buckets(N, k, held, E)
+    if len(buckets) == 1:
+        return run(buckets[0], x, weights, w_gate, w_up, w_down), counts
+
+    which = (real > buckets[0]).astype(jnp.int32)
+    branches = [_functools.partial(run, rows) for rows in buckets]
+
+    @jax.custom_vjp
+    def sized(x, weights, wg, wu, wd):
+        return lax.switch(which, branches, x, weights, wg, wu, wd)
+
+    def sized_fwd(*operands):
+        return sized(*operands), operands
+
+    def sized_bwd(operands, dy):
+        return lax.switch(
+            which, [lambda ops, dy, f=f: jax.vjp(f, *ops)[1](dy)
+                    for f in branches], operands, dy)
+
+    sized.defvjp(sized_fwd, sized_bwd)
+    return sized(x, weights, w_gate, w_up, w_down), counts
+
+
 def dropless_top1_experts(x, prob, w_gate, w_up, w_down, held_first=0,
                           impl=None):
-    """Top-1 SiLU-gated expert FFNs for the experts held here, nothing
-    dropped.  ``x`` (N, d) tokens, ``prob`` (N, E) float32 over ALL
-    experts; ``w_gate``/``w_up`` (held, hidden, d), ``w_down`` (held, d,
-    hidden) are experts ``held_first .. held_first + held - 1``.
-    Returns ``(y (N, d), tokens an expert int32 (E,))`` with
-    ``y = p_e * down_e(silu(gate_e x) * up_e x)`` for a token whose
-    expert ``e = argmax(prob)`` (ties: the lower index) is held here,
-    0 otherwise.  The gradient reaches the router through ``p_e``.
-    ``impl`` is :func:`grouped_matmul`'s (None: chosen once for all
-    three products)."""
-    N, _ = x.shape
-    E = prob.shape[-1]
-    held = w_gate.shape[0]
-    f32 = jnp.float32
-    if impl is None:
-        impl = _grouped_matmul_impl(N, x.dtype)
+    """:func:`dropless_topk_experts` at ``k = 1`` from probabilities:
+    ``prob`` (N, E) float32 over ALL experts; a token goes to ``e =
+    argmax(prob)`` (ties: the lower index) with weight ``p_e``, so
+    ``y = p_e * down_e(silu(gate_e x) * up_e x)`` where ``e`` is held
+    here and 0 otherwise.  Returns ``(y (N, d), tokens an expert int32
+    (E,))``."""
     with jax.named_scope("moe.dispatch"):
-        e = jnp.argmax(prob, axis=-1).astype(jnp.int32)
-        pe = jnp.take_along_axis(prob, e[:, None], axis=-1)[:, 0]
-        counts = jnp.sum(e[:, None] == jnp.arange(E, dtype=jnp.int32),
-                         axis=0, dtype=jnp.int32)
-        local = e - int(held_first)
-        here = (local >= 0) & (local < held)
-        # held tokens first, grouped by expert; the others after them
-        order = jnp.argsort(jnp.where(here, local, held), stable=True)
-        sizes = lax.slice_in_dim(counts, int(held_first),
-                                 int(held_first) + held)
-        here_s = jnp.take(here, order)[:, None]
-        # what a grouped product leaves in the rows of no group is not
-        # defined, forward or backward (it may be NaN): every operand
-        # and result is masked there, and with it its gradient
-        own = lambda t: jnp.where(here_s, t, 0)
-        xs = own(jnp.take(x, order, axis=0, unique_indices=True))
-    with jax.named_scope("moe.experts"):
-        g = own(grouped_matmul(xs, w_gate, sizes, impl))
-        u = own(grouped_matmul(xs, w_up, sizes, impl))
-        mid = own((jax.nn.silu(g.astype(f32)) * u.astype(f32))
-                  .astype(x.dtype))
-        ys = own(grouped_matmul(mid, w_down, sizes, impl))
-    with jax.named_scope("moe.combine"):
-        ys = (ys.astype(f32) * jnp.take(pe, order)[:, None]).astype(x.dtype)
-        y = jnp.zeros_like(ys).at[order].set(ys, unique_indices=True)
-    return y, counts
+        e = jnp.argmax(prob, axis=-1).astype(jnp.int32)[:, None]
+        pe = jnp.take_along_axis(prob, e, axis=-1)
+    return dropless_topk_experts(x, e, pe, w_gate, w_up, w_down,
+                                 prob.shape[-1], held_first, impl)

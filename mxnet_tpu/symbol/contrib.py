@@ -46,7 +46,7 @@ def _subgraph_eval(entries_sym):
                 vals[(id(node), 0)] = env[node.name]
                 continue
             ins = [vals[(id(i), oi)] for i, oi in node.inputs]
-            raw = node.op.fn(*ins, **node.attrs)
+            raw = node.apply(ins)
             outs = list(raw) if isinstance(raw, (tuple, list)) else [raw]
             for i, v in enumerate(outs):
                 vals[(id(node), i)] = v

@@ -91,6 +91,38 @@ class _Node:
         # match reference multi-output naming: name + suffix per output
         return "%s_output%d" % (self.name, idx)
 
+    def input_names(self):
+        """The declared name of each of this node's inputs.  An op's
+        inputs that its attrs make absent (``unused_inputs``: a bias
+        under ``no_bias``, the router that ``RoutedExperts`` was not
+        asked for) are skipped when the node is composed; where exactly
+        the present ones were given, those are the names, wherever the
+        absent ones stand in the declaration.  (A caller may still pass
+        an input its attrs call unused: then, as before, the inputs are
+        the declaration's first.)"""
+        op = self.op
+        if op.variadic:
+            return [str(i) for i in range(len(self.inputs))]
+        names = op.input_names
+        if op.unused_inputs is not None:
+            absent = op.unused_inputs(self.attrs)
+            present = [n for n in names if n not in absent]
+            if len(present) == len(self.inputs):
+                return present
+        return names[:len(self.inputs)]
+
+    def apply(self, ins):
+        """The op on this node's input values.  Positional where the
+        inputs are the declaration's first; by name where an absent
+        input stands before a present one."""
+        op = self.op
+        if op.variadic or op.unused_inputs is None:
+            return op.fn(*ins, **self.attrs)
+        names = self.input_names()
+        if names == op.input_names[:len(names)]:
+            return op.fn(*ins, **self.attrs)
+        return op.fn(**dict(zip(names, ins)), **self.attrs)
+
     def explicit_attrs(self):
         """The op attrs the caller actually passed, as {name: value} —
         exact when tracked at creation, else the params whose value
@@ -137,8 +169,7 @@ class Symbol:
             if node.is_var or not node.op.mutate_inputs:
                 continue
             mut = {nm for nm, _ in node.op.mutate_inputs}
-            in_names = node.op.input_names
-            for (inp, _), nm in zip(node.inputs, in_names):
+            for (inp, _), nm in zip(node.inputs, node.input_names()):
                 if nm in mut and inp.is_var:
                     aux.add(inp.name)
         return aux
@@ -371,8 +402,7 @@ class Symbol:
                         dtypes[inp.name] = _np.dtype(anchor)
                         del pending_dtype_vars[id(inp)]
 
-            in_names = (node.op.input_names if not node.op.variadic
-                        else [str(i) for i in range(len(node.inputs))])
+            in_names = node.input_names()
             known_in = {}
             for (inp, oi), nm in zip(node.inputs, in_names):
                 sds = env.get((id(inp), oi))
@@ -400,8 +430,7 @@ class Symbol:
                 continue
             with _reg._OpCtxScope(True, None):
                 try:
-                    out = jax.eval_shape(
-                        lambda *xs: node.op.fn(*xs, **node.attrs), *ins)
+                    out = jax.eval_shape(lambda *xs: node.apply(xs), *ins)
                 except Exception as e:  # surface the node for debuggability
                     raise MXNetError("shape inference failed at node %s(%s): %s"
                                      % (node.op.name, node.name, e)) from e

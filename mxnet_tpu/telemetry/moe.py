@@ -1,8 +1,9 @@
 """Per-expert load of the dropless expert layer (docs/OBSERVABILITY.md).
 
 ``sym.contrib.RoutedExperts`` counts, inside the step's program, the
-tokens each expert got; ``models/zaya.py`` hands the counts of every
-layer out of the graph as one output, (layers, experts) int32.  The
+tokens each expert got; ``models/zaya.py`` and ``models/qwen3_next.py``
+hand the counts of every layer out of the graph as one output, (layers,
+experts) int32.  The
 fused fit step keeps a reference to that output's device array after
 each launch (:func:`note`: a reference, no read), so a step costs no
 host sync for it.  The gauges here are filled WHEN READ, from the last
@@ -12,9 +13,11 @@ the numbers now (the benchmark's readers, also after the module is
 gone: the counts are a few hundred bytes and outlive it).
 
 * ``moe_expert_tokens{layer,expert}``: tokens the expert got in the
-  last step, for every expert the router scores;
+  last step, for every expert the router scores.  With ``top_k`` > 1
+  the counts are of (token, choice) pairs, ``top_k`` a token
+  (``models/qwen3_next.py``), and so is every number below;
 * ``moe_tokens_away{layer}``: tokens of that step whose expert is not
-  held by this chip (they get 0 from the expert sublayer);
+  held by this chip (they add 0 to the expert sublayer's result);
 * ``moe_expert_load_max_over_mean``: over the held experts of all
   layers, the fullest expert's tokens over the mean: 1.0 is even
   routing, ``held experts`` is every token on one expert.

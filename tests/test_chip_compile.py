@@ -321,3 +321,66 @@ def test_fit_program_writes_no_float32_probabilities(one_chip, cell, B, S,
 
     returned = _written_types(program(mx.metric.np(lambda l, p: 0.0)))
     assert wide & set(returned)
+
+
+def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
+                                                           monkeypatch):
+    """The fused fit program of the cell ``qwen3next_80b_train_ep16`` at
+    its own sizes (4 layers, 32 of 512 experts held, 18 992 rows of the
+    vocabulary, one sequence of 8192 tokens, bf16 with f32 masters),
+    compiled for the described chip with the kernels the chip would
+    choose: the flash kernel at head_dim 256 with 8 query heads to a
+    key/value head, the Pallas grouped matmul in every size of the
+    sorted rows' buffer.  ``memory_analysis`` (arguments + outputs -
+    aliased + temporaries) stays under 15 GB of the chip's 16: the
+    configuration's ``reduced_why`` quotes the number printed here.  The
+    kernel choices ask ``jax.default_backend()``, which is the CPU here:
+    the test steers them, as the chip would answer."""
+    import json
+    import os
+
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import nn
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(nn, "_use_flash_attention",
+                        lambda *a, **k: "compiled")
+    monkeypatch.setattr(moe, "_grouped_matmul_impl",
+                        lambda *a, **k: "compiled")
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    with open(os.path.join(root, "benchmark", "configs",
+                           "qwen3_next_80b_train.json")) as f:
+        cfg = json.load(f)
+    kw = cfg["kwargs"]
+    S = kw["seq_len"]
+    assert (kw["num_layers"], kw["experts_held"], S) == (4, [0, 32], 8192)
+    mod = mx.Module(mx.models.get_symbol(cfg["model"], **kw),
+                    context=mx.cpu())
+    mod.bind(data_shapes=[("data", (1, S))],
+             label_shapes=[("softmax_label", (S,))])
+    mod.init_params(mx.init.Zero())
+    mod.init_optimizer(optimizer=cfg["optimizer"], optimizer_params=dict(
+        cfg["optimizer_params"], multi_precision=True))
+    tokens = np.arange(S, dtype=np.float32) % kw["num_classes"]
+    batch = mx.io.DataBatch(data=[mx.nd.array(tokens.reshape(1, S))],
+                            label=[mx.nd.array(tokens)])
+    fn, args, _ = mod._get_fused_fit()._prepare(batch,
+                                                mx.metric.create("ce"))
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+    with jax.default_matmul_precision("default"):
+        compiled = fn.lower(*specs).compile()
+    text = compiled.as_text()
+    assert "splash_mha" in text and "gmm" in text and "ragged" not in text
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print("qwen3_next fit program: arguments %.2f GB, outputs %.2f, aliased "
+          "%.2f, temporaries %.2f: %.2f GB"
+          % tuple(b / 1e9 for b in (
+              m.argument_size_in_bytes, m.output_size_in_bytes,
+              m.alias_size_in_bytes, m.temp_size_in_bytes, total)))
+    assert total < 15e9
