@@ -15,6 +15,13 @@ three small products), and every output follows from its chunk's
 incoming state in one batched product.  Plain ``jax.numpy``: the
 backward pass is jax's own (the solve has its closed-form VJP).
 
+:func:`gated_delta_rule` is what the mixer calls: in a one-device TPU
+program whose shapes the kernels take it is ``pallas/delta_rule.py``
+(the same chunks with a chunk's arrays in VMEM, and a backward pass of
+its own that does not run the whole forward again); everywhere else,
+under a mesh and on the CPU, it is :func:`chunk_gated_delta_rule` under
+``jax.checkpoint`` (its forward runs again in the backward pass).
+
 Every exponent taken is <= 0: ``g`` <= 0, its running sum inside a
 chunk only falls, and a difference is exponentiated only where the later
 token's sum stands first (masked BEFORE the exponential).  Nothing is
@@ -163,3 +170,33 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64):
         + mm("bhrnij,nbhrjv->bhrniv", attn, new)
     o = o.astype(low).reshape(B, Hv, n * C, Dv)
     return o[:, :, :S] if pad else o
+
+
+def _delta_rule_impl(q, k, v):
+    """How :func:`gated_delta_rule` runs when not told: the Pallas
+    kernels (``"compiled"``) in a one-device TPU program whose head
+    widths fill whole lane tiles, else the ``jax.numpy`` chunks (False;
+    the fallback is counted in ``pallas_fallbacks{reason}``).  No knob:
+    a test passes ``impl``."""
+    from ..pallas.delta_rule import supported
+    from ..pallas.dispatch import _compiles_here, choose_impl
+    here, why, reason = _compiles_here()
+    fits, shapes = supported(q, k, v)
+    return choose_impl(
+        "gated_delta_rule (no knob)", "auto", "gated_delta_rule",
+        here and fits, why="%s, %s" % (why or "one TPU device", shapes),
+        fallback_reason=reason or "delta-rule-geometry")
+
+
+def gated_delta_rule(q, k, v, g, beta, impl=None):
+    """:func:`chunk_gated_delta_rule` at chunks of 64, rematerialized in
+    the backward pass.  ``impl``: None chooses
+    (:func:`_delta_rule_impl`); ``"compiled"`` / ``"interpret"`` is the
+    Pallas kernels under scope ``pallas.gated_delta_rule``, forward and
+    backward; False is the ``jax.numpy`` path under ``jax.checkpoint``."""
+    if impl is None:
+        impl = _delta_rule_impl(q, k, v)
+    if not impl:
+        return jax.checkpoint(chunk_gated_delta_rule)(q, k, v, g, beta)
+    from ..pallas.delta_rule import gated_delta_rule as kernels
+    return kernels(q, k, v, g, beta, interpret=impl == "interpret")

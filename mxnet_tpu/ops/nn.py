@@ -1110,11 +1110,15 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, A_log,
     v_dim).
 
     Head-major: the projection emits (B, H, S, D).  Convolution,
-    normalisation and gates are rematerialized in the backward pass, and
-    so is the scan (its forward runs again there: a layer saves its
-    projections only).  Scopes: ``gdn.proj``, ``gdn.conv``,
-    ``gdn.scan``, ``gdn.norm``."""
-    from .delta_rule import chunk_gated_delta_rule
+    normalisation and gates are rematerialized in the backward pass.
+    The scan keeps its inputs only (``ops/delta_rule.py``
+    ``gated_delta_rule``): as Pallas kernels (a one-device TPU program)
+    it also keeps the state every run of 8 chunks starts from, and its
+    backward computes a run's chunks again in VMEM; as ``jax.numpy``
+    (everywhere else) its whole forward runs again there.  Scopes:
+    ``gdn.proj``, ``gdn.conv``, ``gdn.scan`` (``pallas.gated_delta_rule``
+    inside it), ``gdn.norm``."""
+    from .delta_rule import gated_delta_rule
     B, S, d = data.shape
     Hk, Hv, Dk, Dv = int(k_heads), int(v_heads), int(k_dim), int(v_dim)
     K = int(conv_kernel)
@@ -1152,7 +1156,7 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, A_log,
         q, k, v, g, beta = mix(qkv, ba, conv_weight, A_log, dt_bias)
 
     with jax.named_scope("gdn.scan"):
-        o = jax.checkpoint(chunk_gated_delta_rule)(q, k, v, g, beta)
+        o = gated_delta_rule(q, k, v, g, beta)
 
     @jax.checkpoint
     def gated_norm(o, z, gain):

@@ -221,6 +221,31 @@ def test_grouped_expert_products_fwd_grad_compile(one_chip):
     assert "ragged" not in compiled.as_text()
 
 
+def test_gated_delta_rule_fwd_grad_compiles(one_chip):
+    """The chunked gated delta rule's kernels at the Qwen3-Next cell's
+    sizes (one sequence of 8192 tokens, 16 key heads serving 32 value
+    heads of 128, bf16): the forward alone, and the gradient's forward
+    (which keeps the float32 state each run of 8 chunks starts from:
+    34 MB a layer, the only residual besides the inputs) and backward
+    kernel."""
+    from mxnet_tpu.ops.delta_rule import gated_delta_rule
+    B, Hk, Hv, S, D = 1, 16, 32, 8192, 128
+    shapes = (((B, Hk, S, D), jnp.bfloat16), ((B, Hk, S, D), jnp.bfloat16),
+              ((B, Hv, S, D), jnp.bfloat16), ((B, Hv, S), jnp.float32),
+              ((B, Hv, S), jnp.float32))
+    rule = lambda *a: gated_delta_rule(*a, impl="compiled")
+    text = _compile(rule, one_chip, *shapes).as_text()
+    assert "tpu_custom_call" in text and "gated_delta_rule_forward" in text
+    grad = _compile(
+        jax.grad(lambda *a: rule(*a).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2, 3, 4)), one_chip, *shapes)
+    text = grad.as_text()
+    assert "tpu_custom_call" in text
+    assert "gated_delta_rule_forward" in text
+    assert "gated_delta_rule_backward" in text
+    assert grad.memory_analysis().temp_size_in_bytes < 50e6
+
+
 def test_compressed_conv_attention_fwd_grad_compiles_with_flash(
         one_chip, monkeypatch):
     """CCA at the ZAYA cell's widths (one sequence of 8192, 8 query to 2
@@ -331,7 +356,8 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
     compiled for the described chip with the kernels the chip would
     choose: the flash kernel at head_dim 256 with 8 query heads to a
     key/value head, the Pallas grouped matmul in every size of the
-    sorted rows' buffer.  ``memory_analysis`` (arguments + outputs -
+    sorted rows' buffer, the gated delta rule's two kernels (forward,
+    backward).  ``memory_analysis`` (arguments + outputs -
     aliased + temporaries) stays under 15 GB of the chip's 16: the
     configuration's ``reduced_why`` quotes the number printed here.  The
     kernel choices ask ``jax.default_backend()``, which is the CPU here:
@@ -342,12 +368,14 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
     import numpy as np
 
     import mxnet_tpu as mx
-    from mxnet_tpu.ops import nn
+    from mxnet_tpu.ops import delta_rule, nn
     from mxnet_tpu.parallel import moe
 
     monkeypatch.setattr(nn, "_use_flash_attention",
                         lambda *a, **k: "compiled")
     monkeypatch.setattr(moe, "_grouped_matmul_impl",
+                        lambda *a, **k: "compiled")
+    monkeypatch.setattr(delta_rule, "_delta_rule_impl",
                         lambda *a, **k: "compiled")
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     with open(os.path.join(root, "benchmark", "configs",
@@ -375,6 +403,8 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
         compiled = fn.lower(*specs).compile()
     text = compiled.as_text()
     assert "splash_mha" in text and "gmm" in text and "ragged" not in text
+    for kernel in ("forward", "backward"):
+        assert "gated_delta_rule_" + kernel in text
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
