@@ -648,7 +648,7 @@ def softmax_activation(data, *, mode="instance"):
 # attention entirely, SURVEY.md §5.7; sequence-parallel forms live in
 # parallel/ring_attention.py)
 # ----------------------------------------------------------------------
-def _use_flash_attention(seq_len, head_dim, dtype):
+def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None):
     """Select the fused Pallas flash kernel.  MXNET_ATTN_IMPL:
     ``auto`` (default) = flash when the backend/geometry supports it,
     ``xla`` = force the materialized-softmax path (A/B runs),
@@ -659,25 +659,35 @@ def _use_flash_attention(seq_len, head_dim, dtype):
 
     The geometry is the same whatever the head counts: the kernel shares
     a key/value head among its query heads itself (``_flash_attention``).
+    ``head_dim`` is the width of queries and keys, ``v_dim`` that of the
+    values where it differs (latent attention: 192 and 128).  Values and
+    the output fill whole lane tiles of 128; queries and keys at least
+    one and then whole halves of one (the contraction of QK^T is padded
+    to the MXU's edge by the compiler, not by the caller).
     It is never interpreted here: only a test passes ``interpret=True``
     to ``_flash_attention``."""
     import os
     from ..pallas.dispatch import _compiles_here, choose_impl
     here, why, reason = _compiles_here()
-    supported = (here and head_dim % 128 == 0 and seq_len % 512 == 0
+    v_dim = head_dim if v_dim is None else v_dim
+    supported = (here and head_dim >= 128 and head_dim % 64 == 0
+                 and v_dim % 128 == 0 and seq_len % 512 == 0
                  and dtype in (jnp.bfloat16, jnp.float32))
     return choose_impl(
         "MXNET_ATTN_IMPL", os.environ.get("MXNET_ATTN_IMPL", "auto"),
         "flash", supported,
         why=f"{why or 'one TPU device'}, head_dim={head_dim}, "
-            f"seq={seq_len}, dtype={dtype}; need a one-device TPU "
-            "program, head_dim%128==0, seq%512==0, bf16/f32",
+            f"v_dim={v_dim}, seq={seq_len}, dtype={dtype}; need a "
+            "one-device TPU program, head_dim>=128, head_dim%64==0, "
+            "v_dim%128==0, seq%512==0, bf16/f32",
         fallback_reason=reason or "flash-geometry")
 
 
-def _flash_block_sizes(seq_len, head_dim):
+def _flash_block_sizes(seq_len, head_dim, v_dim=None):
     """The flash kernel's tiles, from the shapes alone (``seq_len`` is a
-    multiple of 512 and ``head_dim`` of 128: the geometry gate).
+    multiple of 512, ``head_dim`` of 64 and ``v_dim`` of 128: the
+    geometry gate).  The wider of the two widths sizes the resident
+    block; it is the keys' in every geometry so far.
 
     Every block is a whole number of 512-row tiles that divides the
     sequence.  Forward: 1024 query rows against 1024 resident key/value
@@ -708,7 +718,7 @@ def _flash_block_sizes(seq_len, head_dim):
                          if tiles % n == 0)
 
     fwd = rows(2)
-    resident = rows(min(tiles // 4, 4 * 128 // head_dim))
+    resident = rows(min(tiles // 4, 4 * 128 // max(head_dim, v_dim or 0)))
     return BlockSizes(
         block_q=fwd, block_kv=fwd, block_kv_compute=512,
         block_q_dkv=512, block_kv_dkv=resident, block_kv_dkv_compute=512,
@@ -716,10 +726,12 @@ def _flash_block_sizes(seq_len, head_dim):
 
 
 @_functools.lru_cache(maxsize=None)
-def _flash_kernel(q_heads, kv_heads, seq_len, head_dim, interpret):
+def _flash_kernel(q_heads, kv_heads, seq_len, head_dim, interpret,
+                  v_dim=None):
     """jax's splash-attention kernel for one causal sequence, built once
     per geometry: the mask's block tables are host numpy work at trace
-    time, and every layer of a model asks for the same ones."""
+    time, and every layer of a model asks for the same ones.  ``v_dim``
+    is given only where the values' width is not ``head_dim``."""
     import numpy as np
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         CausalMask, MultiHeadMask, make_splash_mha)
@@ -730,14 +742,15 @@ def _flash_kernel(q_heads, kv_heads, seq_len, head_dim, interpret):
     with jax.ensure_compile_time_eval():
         kernel = make_splash_mha(
             mask, head_shards=1, q_seq_shards=1, interpret=interpret,
-            block_sizes=_flash_block_sizes(seq_len, head_dim))
+            block_sizes=_flash_block_sizes(seq_len, head_dim, v_dim))
     return jax.tree_util.tree_map(np.asarray, kernel)
 
 
 def _flash_attention(q, k, v, *, interpret=False):
-    """Causal attention of head-major q (B, Hq, S, D) over k, v
-    (B, Hk, S, D), Hq a multiple of Hk, by jax's splash-attention Pallas
-    kernel: float32 scores, statistics and accumulators whatever the
+    """Causal attention of head-major q (B, Hq, S, D) over k (B, Hk, S,
+    D) and v (B, Hk, S, Dv), Hq a multiple of Hk and Dv = D unless the
+    values are narrower (the result is (B, Hq, S, Dv)), by jax's splash
+    Pallas kernel: float32 scores, statistics and accumulators whatever the
     operands' dtype; a key/value head is shared by its Hq / Hk query
     heads inside the kernel (no repeated K/V, dK and dV summed over the
     group in VMEM); the backward computes every score block once and
@@ -748,8 +761,9 @@ def _flash_attention(q, k, v, *, interpret=False):
     is for the tests; ``_use_flash_attention`` never asks for it."""
     from ..pallas.attention import _count_launch
     _count_launch("flash_attention")
-    kernel = _flash_kernel(q.shape[1], k.shape[1], q.shape[2], q.shape[3],
-                           bool(interpret))
+    D, Dv = q.shape[3], v.shape[3]
+    kernel = _flash_kernel(q.shape[1], k.shape[1], q.shape[2], D,
+                           bool(interpret), None if Dv == D else Dv)
     with jax.named_scope("pallas.flash_attention"):
         return jax.vmap(kernel)(q, k, v)
 
@@ -881,13 +895,14 @@ def fused_causal_self_attention(data, qkv_weight, qkv_bias, proj_weight,
 
 
 def _grouped_causal_attention(q, k, v, scale):
-    """Causal softmax attention of head-major q (B, Hq, S, D) over k, v
-    (B, Hk, S, D) by XLA, each key/value head shared by its Hq / Hk query
-    heads; float32 scores, the probabilities in q's dtype; checkpointed,
-    so the (S, S) scores are not kept for the backward pass.  What the
-    mixers run where the flash kernel cannot."""
+    """Causal softmax attention of head-major q (B, Hq, S, D) over k
+    (B, Hk, S, D) and v (B, Hk, S, Dv) by XLA, each key/value head
+    shared by its Hq / Hk query heads; float32 scores, the probabilities
+    in q's dtype; checkpointed, so the (S, S) scores are not kept for
+    the backward pass.  What the mixers run where the flash kernel
+    cannot."""
     B, Hq, S, D = q.shape
-    Hk = k.shape[1]
+    Hk, Dv = k.shape[1], v.shape[3]
 
     @jax.checkpoint
     def attn(q, k, v):
@@ -896,7 +911,7 @@ def _grouped_causal_attention(q, k, v, scale):
         mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
         s = jnp.where(mask, s.astype(jnp.float32), -1e30)
         p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        return jnp.einsum("bgrqk,bgke->bgrqe", p, v).reshape(B, Hq, S, D)
+        return jnp.einsum("bgrqk,bgke->bgrqe", p, v).reshape(B, Hq, S, Dv)
 
     return attn(q, k, v)
 
@@ -1084,6 +1099,100 @@ def gated_causal_self_attention(data, q_weight, k_weight, v_weight,
 
     with jax.named_scope("gattn.proj"):
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
+
+
+def _rotary_interleaved(x, theta):
+    """Rotary position on ALL of the last axis of a float32 (..., S, R)
+    tensor, neighbours paired (2i with 2i + 1: the layout of a
+    checkpoint whose config says ``rope_interleave``); positions
+    0..S-1.  ``_rotary_half`` pairs halves and turns the first
+    channels."""
+    S, R = x.shape[-2], x.shape[-1]
+    freq = theta ** (-jnp.arange(R // 2, dtype=jnp.float32) * 2.0 / R)
+    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freq[None]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    pairs = x.reshape(x.shape[:-1] + (R // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                     axis=-1).reshape(x.shape)
+
+
+@register("_contrib_LatentAttention", aliases=("LatentAttention",))
+def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
+                     o_weight, *, heads, nope_dim, rope_dim, v_dim, kv_rank,
+                     rope_theta=1e6, eps=1e-6):
+    """Multi-head latent attention (MLA, DeepSeek-V2/V3's, without the
+    query's low-rank path) as one sublayer, (B, S, d) -> (B, S, d), on
+    an already normalised stream; no bias.
+
+    ``q_weight`` (heads * (nope_dim + rope_dim), d) gives each head its
+    query, ``nope_dim`` channels without position and ``rope_dim`` with;
+    ``kva_weight`` (kv_rank + rope_dim, d) the latent ``c`` and ONE
+    rotary key that all heads share; ``kvb_weight`` (heads * (nope_dim +
+    v_dim), kv_rank) turns ``RMSNorm(c) * kv_norm_gamma`` into each
+    head's positionless key and its value.  Rotary position (neighbours
+    paired, ``_rotary_interleaved``) turns the rotary channels of the
+    queries and the shared key; a head's key is its own ``nope_dim``
+    channels beside the shared ``rope_dim``; causal attention at scale
+    ``(nope_dim + rope_dim) ** -0.5`` with values ``v_dim`` wide, then
+    ``o_weight`` (d, heads * v_dim).  Weights are (out, in), as
+    FullyConnected's, in the source's row order.
+
+    Head-major like the other mixers.  Where ``_use_flash_attention``
+    allows it (keys 192 wide and values 128 on one TPU device) the
+    Pallas flash kernel runs (q then carries the softmax scale) and the
+    (S, S) scores never reach memory; the checkpointed XLA path
+    otherwise.  The latent's norm, the rotary and the assembly of q and
+    k are float32 and rematerialized in the backward pass.  Scopes:
+    ``mla.proj``, ``mla.norm``, ``mla.attention``."""
+    B, S, d = data.shape
+    H, Dn, Dr, Dv, C = (int(heads), int(nope_dim), int(rope_dim),
+                        int(v_dim), int(kv_rank))
+    D = Dn + Dr
+    f32 = jnp.float32
+    with jax.named_scope("mla.proj"):
+        q0 = jnp.einsum("bsd,hed->bhse", data, q_weight.reshape(H, D, d))
+        ckr = jnp.einsum("bsd,ed->bse", data, kva_weight)
+
+    sc = D ** -0.5
+    flash = _use_flash_attention(S, D, data.dtype, Dv)
+    theta = float(rope_theta)
+
+    @jax.checkpoint
+    def latent(ckr, gain):
+        c = ckr[..., :C].astype(f32)
+        inv = lax.rsqrt(jnp.mean(jnp.square(c), -1, keepdims=True) + eps)
+        return (c * inv * gain.astype(f32)).astype(ckr.dtype)
+
+    with jax.named_scope("mla.norm"):
+        cn = latent(ckr, kv_norm_gamma)
+
+    with jax.named_scope("mla.proj"):
+        wkv = kvb_weight.reshape(H, Dn + Dv, C)
+        k0 = jnp.einsum("bsc,hec->bhse", cn, wkv[:, :Dn])
+        v = jnp.einsum("bsc,hec->bhse", cn, wkv[:, Dn:])
+
+    @jax.checkpoint
+    def position(q0, k0, ckr):
+        # the flash kernel takes no softmax scale: there q carries it
+        qf = q0.astype(f32) * (sc if flash else 1.0)
+        q = jnp.concatenate(
+            [qf[..., :Dn], _rotary_interleaved(qf[..., Dn:], theta)], -1)
+        kr = _rotary_interleaved(ckr[..., C:].astype(f32), theta)
+        k = jnp.concatenate(
+            [k0, jnp.broadcast_to(kr[:, None].astype(k0.dtype),
+                                  (B, H, S, Dr))], -1)
+        return q.astype(q0.dtype), k
+
+    with jax.named_scope("mla.norm"):
+        q, k = position(q0, k0, ckr)
+
+    with jax.named_scope("mla.attention"):
+        o = _flash_attention(q, k, v) if flash \
+            else _grouped_causal_attention(q, k, v, sc)
+
+    with jax.named_scope("mla.proj"):
+        return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, H, Dv))
 
 
 @register("_contrib_GatedDeltaNet", aliases=("GatedDeltaNet",))
@@ -1486,17 +1595,30 @@ def switch_moe_op(data, router_weight, expert_up_weight, expert_up_bias,
     return y.reshape(data.shape), aux
 
 
+@register("_contrib_GatedFFN", aliases=("GatedFFN",))
+def gated_ffn_op(data, gate_weight, up_weight, down_weight, *, num_hidden):
+    """The dense SiLU-gated feed-forward ``down(silu(gate x) * up x)`` of
+    width ``num_hidden`` on (..., d) (``parallel.moe.gated_ffn``: what a
+    model's dense layers run, and the shared expert of
+    ``RoutedExperts``); weights (out, in), no bias."""
+    from ..parallel.moe import gated_ffn
+    return gated_ffn(data, gate_weight, up_weight, down_weight)
+
+
 @register("_contrib_RoutedExperts", aliases=("RoutedExperts",),
-          num_outputs=3, num_visible_outputs=3)
+          num_outputs=lambda attrs: 4 if attrs.get("router") == "sigmoid"
+          else 3, num_visible_outputs=3,
+          mutate_inputs=(("router_bias", 3),))
 def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
                    router_fc1_weight=None, router_fc2_weight=None,
                    router_out_weight=None, gate_weight=None, up_weight=None,
                    down_weight=None, router_state=None, router_carry=None,
                    router_weight=None, shared_gate_weight=None,
                    shared_up_weight=None, shared_down_weight=None,
-                   shared_sg_weight=None, *, num_experts, held_first=0,
-                   held_count=None, num_hidden, router_hidden=0,
-                   carry_in=True, router="zaya", top_k=1, shared_hidden=0):
+                   shared_sg_weight=None, router_bias=None, *, num_experts,
+                   held_first=0, held_count=None, num_hidden,
+                   router_hidden=0, carry_in=True, router="zaya", top_k=1,
+                   shared_hidden=0, shared_gate=True, route_scale=1.0):
     """The dropless expert sublayer of a chip that holds ``held_count``
     of ``num_experts`` experts (``held_first`` onwards), on an already
     normalised stream (..., d).  A token's ``top_k`` experts are chosen
@@ -1514,15 +1636,24 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
     a softmax; the token's weight is its expert's probability.
     ``router="linear"``: ``softmax(h W^T)`` with ``router_weight``
     (num_experts, d), the ``top_k`` best, weights normalised over all
-    ``top_k`` whether held here or not.  With ``shared_hidden`` one
+    ``top_k`` whether held here or not.  ``router="sigmoid"``:
+    ``sigmoid(h W^T)`` with the same ``router_weight``; the ``top_k``
+    experts with the largest score PLUS ``router_bias`` (num_experts,),
+    an auxiliary state that steers the choice, takes no gradient and
+    leaves the step as it came; the weights are the scores without it,
+    normalised over all ``top_k``, times ``route_scale``
+    (``parallel.moe.sigmoid_router``).  With ``shared_hidden`` one
     shared expert (the dense gated FFN ``parallel.moe.gated_ffn``) runs over
-    every token behind ``sigmoid(h w_sg)`` (``shared_sg_weight`` (1, d)) and
+    every token behind ``sigmoid(h w_sg)`` (``shared_sg_weight`` (1, d);
+    with ``shared_gate=False`` there is no such input and no gate) and
     joins the result, whole on every chip.
 
     Outputs: ``y`` like ``data``; the router's second output: its state
     (..., router_hidden) float32 for the next layer (zaya), the chosen
-    experts (..., top_k) int32 (linear); int32 (num_experts,) (token,
-    choice) pairs an expert, over all experts.  Scopes: ``moe.router``,
+    experts (..., top_k) int32 (linear, sigmoid); int32 (num_experts,)
+    (token, choice) pairs an expert, over all experts; with the sigmoid
+    router a fourth, hidden: the bias, for the executor's aux-state
+    update.  Scopes: ``moe.router``,
     ``moe.dispatch``, ``moe.experts``, ``moe.combine``, ``moe.shared``."""
     from ..parallel import moe
     lead, d = data.shape[:-1], data.shape[-1]
@@ -1544,25 +1675,30 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
         y, counts = moe.dropless_top1_experts(
             x, prob, gate_weight, up_weight, down_weight, int(held_first))
         second = r.reshape(lead + (R,))
-    elif router == "linear":
+    elif router in ("linear", "sigmoid"):
         with jax.named_scope("moe.router"):
-            chosen, weights = moe.linear_router(x, router_weight, k)
+            chosen, weights = moe.linear_router(x, router_weight, k) \
+                if router == "linear" else moe.sigmoid_router(
+                    x, router_weight, router_bias, k, float(route_scale))
         y, counts = moe.dropless_topk_experts(
             x, chosen, weights, gate_weight, up_weight, down_weight, E,
             int(held_first))
         second = lax.stop_gradient(chosen).reshape(lead + (k,))
     else:
-        raise ValueError("router=%r (zaya or linear)" % (router,))
+        raise ValueError("router=%r (zaya, linear or sigmoid)" % (router,))
     if int(shared_hidden):
         with jax.named_scope("moe.shared"):
-            open_ = jax.nn.sigmoid(jnp.einsum(
-                "nd,od->no", x, shared_sg_weight,
-                preferred_element_type=jnp.float32))
             shared = moe.gated_ffn(x, shared_gate_weight, shared_up_weight,
-                                   shared_down_weight)
-            y = (y.astype(jnp.float32)
-                 + open_ * shared.astype(jnp.float32)).astype(data.dtype)
-    return (y.reshape(data.shape), second, lax.stop_gradient(counts))
+                                   shared_down_weight).astype(jnp.float32)
+            if shared_gate:
+                shared = jax.nn.sigmoid(jnp.einsum(
+                    "nd,od->no", x, shared_sg_weight,
+                    preferred_element_type=jnp.float32)) * shared
+            y = (y.astype(jnp.float32) + shared).astype(data.dtype)
+    outs = (y.reshape(data.shape), second, lax.stop_gradient(counts))
+    if router == "sigmoid":
+        outs += (lax.stop_gradient(router_bias),)
+    return outs
 
 
 # ----------------------------------------------------------------------

@@ -128,7 +128,8 @@ def _routed_experts_shapes(known, attrs):
             "gate_weight": (held, h, d), "up_weight": (held, h, d),
             "down_weight": (held, d, h), "router_weight": (E, d),
             "shared_gate_weight": (Fs, d), "shared_up_weight": (Fs, d),
-            "shared_down_weight": (d, Fs), "shared_sg_weight": (1, d)}
+            "shared_down_weight": (d, Fs), "shared_sg_weight": (1, d),
+            "router_bias": (E,)}
 
 
 _ZAYA_ROUTER = {"router_in_weight", "router_norm_gamma", "router_fc1_weight",
@@ -142,7 +143,12 @@ def _routed_experts_unused(attrs):
     """The router's inputs follow ``router``, the shared expert's
     ``shared_hidden``; the zaya router's first layer takes no state."""
     out = set() if int(attrs.get("shared_hidden", 0)) else set(_SHARED_EXPERT)
-    if attrs.get("router", "zaya") == "zaya":
+    if not attrs.get("shared_gate", True):
+        out.add("shared_sg_weight")
+    router = attrs.get("router", "zaya")
+    if router != "sigmoid":
+        out.add("router_bias")
+    if router == "zaya":
         out.add("router_weight")
         if not attrs.get("carry_in", True):
             out |= {"router_state", "router_carry"}
@@ -166,6 +172,33 @@ def _gated_attn_shapes(known, attrs):
 
 
 _set("_contrib_GatedCausalSelfAttention", _gated_attn_shapes)
+
+
+def _latent_attn_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    H, Dn, Dr, Dv, C = (int(attrs[k]) for k in (
+        "heads", "nope_dim", "rope_dim", "v_dim", "kv_rank"))
+    return {"q_weight": (H * (Dn + Dr), d), "kva_weight": (C + Dr, d),
+            "kv_norm_gamma": (C,), "kvb_weight": (H * (Dn + Dv), C),
+            "o_weight": (d, H * Dv)}
+
+
+_set("_contrib_LatentAttention", _latent_attn_shapes)
+
+
+def _gated_ffn_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d, F = int(data[-1]), int(attrs["num_hidden"])
+    return {"gate_weight": (F, d), "up_weight": (F, d),
+            "down_weight": (d, F)}
+
+
+_set("_contrib_GatedFFN", _gated_ffn_shapes)
 
 
 def _gdn_shapes(known, attrs):

@@ -35,7 +35,8 @@ SiLU-gated FFN; ``grouped_matmul``: the Pallas kernel where it compiles,
 ``jax.lax.ragged_dot_general`` elsewhere), and a token whose expert
 lives elsewhere contributes 0.  No capacity, no exchange, and nothing that
 stands in for the absent chips; ``zaya_router`` is the router that
-goes with it (docs/TRAINING.md, "The dropless expert layer").
+goes with it (docs/TRAINING.md, "The dropless expert layer"), and
+``linear_router`` and ``sigmoid_router`` feed its top-k form.
 """
 from __future__ import annotations
 
@@ -47,7 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["switch_moe", "moe_reference", "init_moe_params",
-           "zaya_router", "linear_router", "gated_ffn",
+           "zaya_router", "linear_router", "sigmoid_router", "gated_ffn",
            "dropless_topk_experts", "dropless_top1_experts"]
 
 
@@ -203,6 +204,25 @@ def linear_router(h, w, k):
                    precision=lax.Precision.HIGHEST), axis=-1)
     top, e = lax.top_k(prob, int(k))
     return e.astype(jnp.int32), top / jnp.sum(top, -1, keepdims=True)
+
+
+def sigmoid_router(h, w, bias, k, scale=1.0):
+    """The router with independent scores and a selection bias on tokens
+    ``h`` (N, d), float32 whatever the model's dtype: ``s = sigmoid(h
+    W^T)`` over ALL experts (``w`` (E, d)); the ``k`` experts with the
+    largest ``s + bias`` (``bias`` (E,): a buffer that steers the choice
+    and nothing else; ties: the lower index); their weights are ``s``
+    WITHOUT the bias at the chosen, over their sum, times ``scale``.
+    Returns ``(experts (N, k) int32, weights (N, k) float32)``; the
+    gradient reaches ``w`` through the weights and never ``bias``."""
+    f32 = jnp.float32
+    score = jax.nn.sigmoid(
+        jnp.einsum("nd,ed->ne", h.astype(f32), w.astype(f32),
+                   precision=lax.Precision.HIGHEST))
+    _, e = lax.top_k(lax.stop_gradient(score + bias.astype(f32)), int(k))
+    top = jnp.take_along_axis(score, e, axis=-1)
+    weights = top / (jnp.sum(top, -1, keepdims=True) + 1e-20) * scale
+    return e.astype(jnp.int32), weights
 
 
 def gated_ffn(x, w_gate, w_up, w_down):

@@ -414,3 +414,82 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
               m.argument_size_in_bytes, m.output_size_in_bytes,
               m.alias_size_in_bytes, m.temp_size_in_bytes, total)))
     assert total < 15e9
+
+
+def test_flash_attention_at_192_and_128_fwd_grad_compiles(one_chip):
+    """The flash kernel at latent attention's geometry (the Kanana-2
+    cell): one sequence of 8192, 32 heads, queries and keys 192 wide,
+    values 128, at the tiles ops/nn.py picks for it (1024 rows resident
+    in the fused backward)."""
+    from mxnet_tpu.ops.nn import _flash_attention
+
+    def loss(q, k, v):
+        return _flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    qk = ((1, 32, 8192, 192), jnp.bfloat16)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+             qk, qk, ((1, 32, 8192, 128), jnp.bfloat16))
+
+
+def test_kanana2_fit_program_compiles_and_fits_the_chip(one_chip,
+                                                        monkeypatch):
+    """The fused fit program of the cell ``kanana2_30b_train_ep8`` at
+    its own sizes (the dense layer and 4 expert layers, 16 of 128
+    experts held, 16 032 rows of the vocabulary, one sequence of 8192
+    tokens, bf16 with f32 masters), compiled for the described chip
+    with the kernels the chip would choose: the flash kernel at key
+    width 192 / value width 128 and the Pallas grouped matmul over 16
+    groups of width 768 in both sizes of the sorted rows' buffer.
+    ``memory_analysis`` (arguments + outputs - aliased + temporaries)
+    stays under 15 GB of the chip's 16: the configuration's
+    ``reduced_why`` quotes the number printed here.  The kernel choices
+    ask ``jax.default_backend()``, which is the CPU here: the test
+    steers them, as the chip would answer."""
+    import json
+    import os
+
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import nn
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(nn, "_use_flash_attention",
+                        lambda *a, **k: "compiled")
+    monkeypatch.setattr(moe, "_grouped_matmul_impl",
+                        lambda *a, **k: "compiled")
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kanana2_30b_train.json")) as f:
+        cfg = json.load(f)
+    kw = cfg["kwargs"]
+    S = kw["seq_len"]
+    assert (kw["num_layers"], kw["experts_held"], S) == (5, [0, 16], 8192)
+    mod = mx.Module(mx.models.get_symbol(cfg["model"], **kw),
+                    context=mx.cpu())
+    mod.bind(data_shapes=[("data", (1, S))],
+             label_shapes=[("softmax_label", (S,))])
+    mod.init_params(mx.init.Zero())
+    mod.init_optimizer(optimizer=cfg["optimizer"], optimizer_params=dict(
+        cfg["optimizer_params"], multi_precision=True))
+    tokens = np.arange(S, dtype=np.float32) % kw["num_classes"]
+    batch = mx.io.DataBatch(data=[mx.nd.array(tokens.reshape(1, S))],
+                            label=[mx.nd.array(tokens)])
+    fn, args, _ = mod._get_fused_fit()._prepare(batch,
+                                                mx.metric.create("ce"))
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+    with jax.default_matmul_precision("default"):
+        compiled = fn.lower(*specs).compile()
+    text = compiled.as_text()
+    assert "splash_mha" in text and "gmm" in text and "ragged" not in text
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print("kanana2 fit program: arguments %.2f GB, outputs %.2f, aliased "
+          "%.2f, temporaries %.2f: %.2f GB"
+          % tuple(b / 1e9 for b in (
+              m.argument_size_in_bytes, m.output_size_in_bytes,
+              m.alias_size_in_bytes, m.temp_size_in_bytes, total)))
+    assert total < 15e9
