@@ -1195,6 +1195,60 @@ def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, H, Dv))
 
 
+def gdn_conv(qkv, conv_weight, k_heads):
+    """What Gated DeltaNet's ``[q; k; v]`` (B, 2 k_heads + v_heads, S, D),
+    head-major, passes before the scan, in ``jax.numpy``: a causal
+    depthwise convolution over the sequence (``conv_weight`` (channels,
+    conv_kernel), the last tap the position itself, zeros before
+    position 0), SiLU, and for the q and k heads an L2 norm a head, q
+    scaled by ``D ** -0.5``.  float32 from the widening to the one
+    rounding back to the input's dtype.  Returns (q, k, v)."""
+    _, H, _, D = qkv.shape
+    K = conv_weight.shape[-1]
+    f32 = jnp.float32
+    x = qkv.astype(f32)
+    wc = conv_weight.astype(f32).reshape(1, H, 1, D, K)
+    x = jax.nn.silu(sum(_shift_right(x, K - 1 - j, 2) * wc[..., j]
+                        for j in range(K)))
+    unit = lambda t: t * lax.rsqrt(
+        jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+    q = unit(x[:, :k_heads]) * D ** -0.5
+    k = unit(x[:, k_heads:2 * k_heads])
+    low = qkv.dtype
+    return q.astype(low), k.astype(low), x[:, 2 * k_heads:].astype(low)
+
+
+def _gdn_mix_impl(qkv, k_heads, conv_kernel):
+    """How :func:`gdn_mix` runs when not told: the Pallas kernels
+    (``"compiled"``) in a one-device TPU program whose head width fills
+    whole lane tiles, else :func:`gdn_conv` (False; the fallback is
+    counted in ``pallas_fallbacks{reason}``).  No knob: a test passes
+    ``impl``."""
+    from ..pallas.dispatch import _compiles_here, choose_impl
+    from ..pallas.gdn_mix import supported
+    here, why, reason = _compiles_here()
+    fits, shapes = supported(qkv, k_heads, conv_kernel)
+    return choose_impl(
+        "gdn_mix (no knob)", "auto", "gdn_mix", here and fits,
+        why="%s, %s" % (why or "one TPU device", shapes),
+        fallback_reason=reason or "gdn-mix-geometry")
+
+
+def gdn_mix(qkv, conv_weight, k_heads, impl=None):
+    """:func:`gdn_conv`, rematerialized in the backward pass.  ``impl``:
+    None chooses (:func:`_gdn_mix_impl`); ``"compiled"`` /
+    ``"interpret"`` is the Pallas kernels under scope ``pallas.gdn_mix``
+    (``pallas/gdn_mix.py``), forward and backward; False is
+    :func:`gdn_conv` under ``jax.checkpoint``."""
+    if impl is None:
+        impl = _gdn_mix_impl(qkv, k_heads, conv_weight.shape[-1])
+    if not impl:
+        return jax.checkpoint(gdn_conv, static_argnums=2)(
+            qkv, conv_weight, k_heads)
+    from ..pallas.gdn_mix import gdn_mix as kernels
+    return kernels(qkv, conv_weight, k_heads, interpret=impl == "interpret")
+
+
 @register("_contrib_GatedDeltaNet", aliases=("GatedDeltaNet",))
 def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, A_log,
                     dt_bias, norm_gamma, out_weight, *, k_heads, v_heads,
@@ -1220,17 +1274,22 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, A_log,
 
     Head-major: the projection emits (B, H, S, D).  Convolution,
     normalisation and gates are rematerialized in the backward pass.
+    The convolution, SiLU and L2 norms keep ``qkv`` and the weight only
+    (:func:`gdn_mix`): as Pallas kernels (a one-device TPU program) the
+    backward kernel computes a block's pre-activation again in VMEM;
+    as ``jax.numpy`` (everywhere else) the whole of :func:`gdn_conv`
+    runs again in the backward pass.  The gates ``g`` and ``beta`` are
+    ``jax.numpy`` under ``jax.checkpoint`` on both paths.
     The scan keeps its inputs only (``ops/delta_rule.py``
     ``gated_delta_rule``): as Pallas kernels (a one-device TPU program)
     it also keeps the state every run of 8 chunks starts from, and its
     backward computes a run's chunks again in VMEM; as ``jax.numpy``
     (everywhere else) its whole forward runs again there.  Scopes:
-    ``gdn.proj``, ``gdn.conv``, ``gdn.scan`` (``pallas.gated_delta_rule``
-    inside it), ``gdn.norm``."""
+    ``gdn.proj``, ``gdn.conv`` (``pallas.gdn_mix`` inside it),
+    ``gdn.scan`` (``pallas.gated_delta_rule`` inside it), ``gdn.norm``."""
     from .delta_rule import gated_delta_rule
     B, S, d = data.shape
     Hk, Hv, Dk, Dv = int(k_heads), int(v_heads), int(k_dim), int(v_dim)
-    K = int(conv_kernel)
     if Dk != Dv:
         raise ValueError("the mixed q, k, v projection is head-major over "
                          "one head width: k_dim %d != v_dim %d" % (Dk, Dv))
@@ -1245,24 +1304,15 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, A_log,
                         preferred_element_type=f32)
 
     @jax.checkpoint
-    def mix(qkv, ba, wc, a_log, dt):
-        x = qkv.astype(f32)
-        wc = wc.astype(f32).reshape(1, H, 1, Dk, K)
-        x = jax.nn.silu(sum(_shift_right(x, K - 1 - j, 2) * wc[..., j]
-                            for j in range(K)))
-        unit = lambda t: t * lax.rsqrt(
-            jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
-        q = unit(x[:, :Hk]) * Dk ** -0.5
-        k = unit(x[:, Hk:2 * Hk])
+    def gates(ba, a_log, dt):
         beta = jax.nn.sigmoid(ba[0])
         g = -jnp.exp(a_log.astype(f32)).reshape(1, Hv, 1) \
             * jax.nn.softplus(ba[1] + dt.astype(f32).reshape(1, Hv, 1))
-        low = qkv.dtype
-        return q.astype(low), k.astype(low), x[:, 2 * Hk:].astype(low), \
-            g, beta
+        return g, beta
 
     with jax.named_scope("gdn.conv"):
-        q, k, v, g, beta = mix(qkv, ba, conv_weight, A_log, dt_bias)
+        q, k, v = gdn_mix(qkv, conv_weight, Hk)
+        g, beta = gates(ba, A_log, dt_bias)
 
     with jax.named_scope("gdn.scan"):
         o = gated_delta_rule(q, k, v, g, beta)

@@ -246,6 +246,40 @@ def test_gated_delta_rule_fwd_grad_compiles(one_chip):
     assert grad.memory_analysis().temp_size_in_bytes < 50e6
 
 
+def test_gdn_mix_fwd_grad_compiles(one_chip):
+    """Gated DeltaNet's convolution, SiLU and L2 norms as kernels
+    (``pallas/gdn_mix.py``) at the Qwen3-Next cell's sizes (one sequence
+    of 8192 tokens, 16 + 16 + 32 heads of 128, 4 taps, bf16): the forward
+    alone, and forward + backward, whose temporaries are no larger than
+    the ``jax.numpy`` form's (which writes the float32 activations of all
+    64 heads: 537 MB forward, 1 074 MB with the backward pass)."""
+    from mxnet_tpu.ops.nn import gdn_mix
+    B, Hk, Hv, S, D, K = 1, 16, 32, 8192, 128, 4
+    shapes = (((B, 2 * Hk + Hv, S, D), jnp.bfloat16),
+              (((2 * Hk + Hv) * D, K), jnp.bfloat16))
+
+    def both(impl):
+        def loss(qkv, w):
+            return sum(t.astype(jnp.float32).sum() ** 2
+                       for t in gdn_mix(qkv, w, Hk, impl=impl))
+        return jax.value_and_grad(loss, argnums=(0, 1))
+
+    fwd = _compile(lambda a, b: gdn_mix(a, b, Hk, impl="compiled"),
+                   one_chip, *shapes)
+    assert "gdn_mix_forward" in fwd.as_text()
+    assert fwd.memory_analysis().temp_size_in_bytes < 1e6
+    grad = _compile(both("compiled"), one_chip, *shapes)
+    text = grad.as_text()
+    assert "gdn_mix_forward" in text and "gdn_mix_backward" in text
+    with jax.default_matmul_precision("default"):
+        plain = jax.jit(both(False)).lower(*(
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes)).compile()
+    assert "tpu_custom_call" not in plain.as_text()
+    kernels = grad.memory_analysis().temp_size_in_bytes
+    assert kernels < 300e6 < plain.memory_analysis().temp_size_in_bytes
+
+
 def test_compressed_conv_attention_fwd_grad_compiles_with_flash(
         one_chip, monkeypatch):
     """CCA at the ZAYA cell's widths (one sequence of 8192, 8 query to 2
@@ -357,7 +391,8 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
     choose: the flash kernel at head_dim 256 with 8 query heads to a
     key/value head, the Pallas grouped matmul in every size of the
     sorted rows' buffer, the gated delta rule's two kernels (forward,
-    backward).  ``memory_analysis`` (arguments + outputs -
+    backward) and the two of the convolution, SiLU and L2 norms before
+    it.  ``memory_analysis`` (arguments + outputs -
     aliased + temporaries) stays under 15 GB of the chip's 16: the
     configuration's ``reduced_why`` quotes the number printed here.  The
     kernel choices ask ``jax.default_backend()``, which is the CPU here:
@@ -377,6 +412,7 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
                         lambda *a, **k: "compiled")
     monkeypatch.setattr(delta_rule, "_delta_rule_impl",
                         lambda *a, **k: "compiled")
+    monkeypatch.setattr(nn, "_gdn_mix_impl", lambda *a, **k: "compiled")
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     with open(os.path.join(root, "benchmark", "configs",
                            "qwen3_next_80b_train.json")) as f:
@@ -405,6 +441,7 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
     assert "splash_mha" in text and "gmm" in text and "ragged" not in text
     for kernel in ("forward", "backward"):
         assert "gated_delta_rule_" + kernel in text
+        assert "gdn_mix_" + kernel in text
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
