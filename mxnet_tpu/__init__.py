@@ -11,6 +11,9 @@ Usage mirrors MXNet::
     x = mx.nd.ones((2, 3), ctx=mx.tpu(0))
     net = mx.sym.FullyConnected(mx.sym.Variable('data'), num_hidden=10)
 """
+import time as _time
+_T_IMPORT = _time.perf_counter()    # setup_seconds{phase="import"}
+
 __version__ = "0.1.0"
 
 
@@ -126,6 +129,12 @@ from . import kvstore_tpu
 from . import monitor
 from .monitor import Monitor
 from . import test_utils
+
+# this file's own first line to here: what a process pays for the
+# package before it can bind anything (jax's import too, where the
+# process had not imported jax yet)
+telemetry.tracing.SETUP_SECONDS.labels(phase="import").inc(
+    _time.perf_counter() - _T_IMPORT)
 
 # server/scheduler-role processes enter their loop here, at the END of
 # the package import (reference wires kvstore_server the same way,

@@ -14,6 +14,11 @@ trace + disk-load instead of trace + XLA compile for every program it
 has compiled before (``jit_compile_ms`` collapses to trace time; the
 ``aot_cache_hits`` counter is the witness).
 
+The same listeners that count hits and misses keep jax's own build
+seconds, laid to the dispatch site or set-up span that caused them:
+``program_build_seconds{site, phase}`` (trace, lower, load and, inside
+load, cache_read) and ``program_builds{site}`` (:func:`_on_time_span`).
+
 On top of jax's content-addressed files this module keeps its OWN
 index (``mx_cache_index.json``): the framework's (site, signature,
 mesh-fingerprint) program keys with fn_name / compile_ms / versions,
@@ -35,6 +40,7 @@ import os
 import threading
 
 from .. import telemetry as _telemetry
+from ..telemetry.registry import BUILD_SITE
 
 log = logging.getLogger(__name__)
 
@@ -55,8 +61,37 @@ AOT_INDEX_ERRORS = _telemetry.REGISTRY.counter(
     "aot_index_errors", "persistent-cache index files discarded as "
     "corrupt or version-mismatched (rebuilt; never fatal)")
 
+PROGRAM_BUILD_SECONDS = _telemetry.REGISTRY.counter(
+    "program_build_seconds", "seconds jax spent building programs, "
+    "labeled by `site` (the RetraceSite dispatching, else the open "
+    "set-up span, else outside) and `phase` (trace, lower, load, "
+    "cache_read)", unit="s")
+PROGRAM_BUILDS = _telemetry.REGISTRY.counter(
+    "program_builds", "executables compiled or loaded from the "
+    "persistent cache, labeled by `site`", unit="programs")
+
+# jax's time-span events -> the phase of a program's build
+_BUILD_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "load",
+}
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
 _lock = threading.Lock()
 _STATE = {"dir": None, "listener": False}
+
+
+class _HostIntervals(threading.local):
+    """One thread's trace and lower intervals already counted: disjoint,
+    in the order they ended.  One pair of floats a top-level build,
+    beside the executable jax keeps for it."""
+
+    def __init__(self):
+        self.spans = []
+
+
+_COUNTED = _HostIntervals()
 
 # the one fixed default: <checkout>/.jax_cache (git-ignored)
 DEFAULT_DIR = os.path.join(
@@ -83,11 +118,52 @@ def _on_event(event, **kw):
         AOT_CACHE_MISSES.inc()
 
 
+def _uncounted_seconds(spans, start, end):
+    """The seconds of [start, end] that no interval of ``spans`` holds,
+    and the interval merged in.  jax's trace events nest (the trace of
+    ``step`` reports ``multiply``, ``add``, ... first, then itself) and
+    a lowering rule may trace: summed, the same seconds count many
+    times; counted so, a thread's trace and lower seconds are a time."""
+    fresh = end - start
+    lo, hi = start, end
+    while spans and spans[-1][1] > start:
+        a, b = spans.pop()
+        fresh -= max(0.0, min(b, end) - max(a, start))
+        lo, hi = min(lo, a), max(hi, b)
+    spans.append((lo, hi))
+    return max(fresh, 0.0)
+
+
+def _on_time_span(event, start, end, **kw):
+    # jax reports a build's phases on the thread that dispatched it, so
+    # the thread's BUILD_SITE is what caused this one
+    phase = _BUILD_PHASES.get(event)
+    if phase is None:
+        return
+    site = BUILD_SITE.name
+    if phase == "load":
+        # XLA's compile, or on a cache hit the read and deserialisation
+        PROGRAM_BUILDS.labels(site=site).inc()
+        seconds = end - start
+    else:
+        seconds = _uncounted_seconds(_COUNTED.spans, start, end)
+    PROGRAM_BUILD_SECONDS.labels(site=site, phase=phase).inc(seconds)
+
+
+def _on_duration(event, duration, **kw):
+    # a plain duration, no span: the cache's part of a load
+    if event == _CACHE_READ_EVENT:
+        PROGRAM_BUILD_SECONDS.labels(
+            site=BUILD_SITE.name, phase="cache_read").inc(duration)
+
+
 def _install_listener():
     if _STATE["listener"]:
         return
     import jax
     jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_time_span_listener(_on_time_span)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
     _STATE["listener"] = True
 
 
@@ -173,6 +249,8 @@ def enable(path=None):
     keys agree.  A directory that cannot be created leaves the cache
     off with a warning (a read-only checkout must still import)."""
     import jax
+    # first: a process whose cache stays off still builds programs
+    _install_listener()
     d = path or os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_DIR
     try:
         os.makedirs(d, exist_ok=True)
@@ -188,7 +266,6 @@ def enable(path=None):
     # compile storms make cold starts slow
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _install_listener()
     d = os.path.abspath(d)
     with _lock:
         _STATE["dir"] = d

@@ -9,6 +9,7 @@ that cheap after the first time).
 """
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import warnings
@@ -26,6 +27,26 @@ from .base_module import BaseModule, _check_input_names
 from .executor_group import DataParallelExecutorGroup
 
 __all__ = ["Module"]
+
+_tracing = _telemetry.tracing
+_FIT_BUILD_S = _tracing.SETUP_SECONDS.labels(phase="fit_build")
+
+
+def _setup_span(name, phase):
+    """Run a method a process walks once under set-up span ``name``: an
+    annotation in any running ``jax.profiler`` trace, a ring record
+    when recording is on, and always its wall seconds in
+    ``setup_seconds{phase}`` and the programs its body builds under
+    ``program_build_seconds{site=name}`` (docs/OBSERVABILITY.md)."""
+    seconds = _tracing.SETUP_SECONDS.labels(phase=phase)
+
+    def wrap(method):
+        @functools.wraps(method)
+        def run(self, *args, **kwargs):
+            with _tracing.span(name, seconds_to=seconds):
+                return method(self, *args, **kwargs)
+        return run
+    return wrap
 
 
 def _as_descs(shapes):
@@ -182,6 +203,7 @@ class Module(BaseModule):
                 f"set_params/init_params got extra parameter(s) "
                 f"{sorted(orphans)} (pass allow_extra=True to ignore)")
 
+    @_setup_span("module.init_params", "init_params")
     def init_params(self, initializer=Uniform(0.01), arg_params=None,
                     aux_params=None, allow_missing=False, force_init=False,
                     allow_extra=False):
@@ -238,6 +260,7 @@ class Module(BaseModule):
         self.params_initialized = True
 
     # -- binding --------------------------------------------------------
+    @_setup_span("module.bind", "bind")
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,
              grad_req="write"):
@@ -323,6 +346,7 @@ class Module(BaseModule):
         return {i * n_dev + k: n
                 for i, n in enumerate(names) for k in range(n_dev)}
 
+    @_setup_span("module.init_optimizer", "init_optimizer")
     def init_optimizer(self, kvstore="local", optimizer="sgd",
                        optimizer_params=(("learning_rate", 0.01),),
                        force_init=False):
@@ -483,7 +507,8 @@ class Module(BaseModule):
             if self.binded and self.params_initialized \
                     and self.optimizer_initialized:
                 from .fused_fit import FusedFitStep
-                self._fused_fit = FusedFitStep.build(self)
+                with _tracing.span("fit.build", seconds_to=_FIT_BUILD_S):
+                    self._fused_fit = FusedFitStep.build(self)
         return self._fused_fit
 
     def _fit_sync(self):

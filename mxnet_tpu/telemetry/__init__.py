@@ -24,7 +24,7 @@ One place to read every operational witness the framework emits
 * :mod:`programs` — compiled-program registry: per-program FLOPs /
   bytes / peak HBM / compile time from XLA ``cost_analysis()`` /
   ``memory_analysis()`` for every RetraceSite jit site
-  (``telemetry.programs()``), plus the ``mfu_measured`` gauge.
+  (``telemetry.programs()``).
 * :mod:`health` — pod-scale straggler detection over the coordination-
   service collectives and a hang watchdog (flight note + faulthandler
   stack dump).
@@ -99,9 +99,12 @@ __all__ = [
 # shared compile-time histogram: every dispatch site that detects a
 # retrace (executor, fused fit step, bucketed kvstore) observes the
 # wall time of the dispatching call here — "first-trace wall time",
-# i.e. trace + XLA compile + the first execution of the new program
+# i.e. trace + XLA compile (or cache load) + the first execution of the
+# new program in ONE sample; ``program_build_seconds{site, phase}``
+# (aot/store.py) has the same builds' trace, lowering and load apart
 JIT_COMPILE_MS = REGISTRY.histogram(
     "jit_compile_ms",
     "wall time of dispatches that (re)traced a program "
-    "(trace + compile + first run)", unit="ms",
+    "(trace + compile + first run; split by phase in "
+    "program_build_seconds)", unit="ms",
     bounds=exponential_buckets(1.0, 2.0, 22))

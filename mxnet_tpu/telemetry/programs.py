@@ -22,14 +22,10 @@ math cannot: what does the COMPILER say each live program costs?
   dump path) reports only already-computed analyses — a crash dump
   must never compile.
 
-Exported surfaces: ``telemetry.programs()`` (list of dicts),
-``top_programs(k)`` (by FLOPs — the flight-dump table),
-``mfu_measured(flops_per_step, seconds)`` (gauge ``mfu_measured``:
-compiler-reported model FLOP/s over the chip's peak), and
-``peak_tflops()`` over ``PEAKS`` — the one table of published peaks,
-keyed by exact ``device_kind``.  The benchmark keeps its own copy
-(``benchmark/peaks.json``): it may not import the package's, and the
-package may not import the benchmark.
+Exported surfaces: ``telemetry.programs()`` (list of dicts) and
+``top_programs(k)`` (by FLOPs — the flight-dump table).  The package
+keeps no table of chip peaks: a utilisation is the benchmark's to
+compute, from ``benchmark/peaks.json``.
 """
 from __future__ import annotations
 
@@ -39,9 +35,8 @@ import threading
 from .registry import REGISTRY
 
 __all__ = ["record", "register_compiled", "programs", "top_programs",
-           "analyze", "clear", "peak_tflops", "mfu_measured",
-           "export_signatures", "warming", "is_warming",
-           "note_donation", "MFU_MEASURED"]
+           "analyze", "clear", "export_signatures", "warming",
+           "is_warming", "note_donation"]
 
 PROGRAMS_REGISTERED = REGISTRY.gauge(
     "trace_programs", "distinct compiled programs currently in the "
@@ -50,19 +45,6 @@ PROGRAMS_WARMED = REGISTRY.gauge(
     "trace_programs_warmed", "registered programs compiled (or loaded "
     "from the persistent cache) during an explicit AOT warmup phase "
     "(mx.aot) rather than by live traffic", unit="programs")
-MFU_MEASURED = REGISTRY.gauge(
-    "mfu_measured", "model FLOP utilization from compiler-reported "
-    "FLOPs (cost_analysis) over the chip's peak bf16 throughput — the "
-    "measured counterpart of a hand count from shapes", unit="ratio")
-
-# Published peaks per chip, keyed by the EXACT jax ``device_kind`` — the
-# package's ONE table.  A TPU that is not here is an error
-# where a utilisation is computed, never a neighbour's number.
-# Source: Google Cloud TPU documentation, "TPU v5e" (system
-# architecture): 197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s per chip.
-PEAKS = {
-    "TPU v5 lite": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
-}
 
 _lock = threading.Lock()
 _programs = {}          # key -> entry dict
@@ -114,24 +96,6 @@ def note_donation(fn, argnums):
             _donated[id(fn)] = tuple(int(a) for a in argnums)
     except Exception:
         pass
-
-
-def peak_tflops(device_kind=None):
-    """Peak bf16 TFLOP/s for ``device_kind`` (default: device 0).
-    None on the CPU, which has no published peak; an accelerator that
-    is not in :data:`PEAKS` raises ``KeyError``."""
-    if device_kind is None:
-        import jax
-        device_kind = jax.devices()[0].device_kind
-    if str(device_kind).lower() == "cpu":
-        return None
-    if device_kind not in PEAKS:
-        raise KeyError(
-            "no published peak for device_kind %r in "
-            "telemetry.programs.PEAKS (have %s); add the chip with its "
-            "source before computing a utilisation on it"
-            % (device_kind, sorted(PEAKS)))
-    return PEAKS[device_kind]["bf16_tflops"]
 
 
 def _abstractify(args):
@@ -432,21 +396,6 @@ def top_programs(k=5, analyze=False, by="flops"):
     rows = [r for r in programs(analyze=analyze) if r.get(by)]
     rows.sort(key=lambda r: -r[by])
     return rows[:k]
-
-
-def mfu_measured(flops_per_step, seconds_per_step, device_kind=None):
-    """Set (and return) the ``mfu_measured`` gauge from compiler-
-    reported FLOPs: ``flops/s / peak``.  None (gauge untouched) on
-    the CPU or when inputs are missing; an accelerator without a
-    published peak raises (``peak_tflops``)."""
-    if not flops_per_step or not seconds_per_step:
-        return None
-    peak = peak_tflops(device_kind)
-    if not peak:
-        return None
-    mfu = (flops_per_step / seconds_per_step) / (peak * 1e12)
-    MFU_MEASURED.set(round(mfu, 6))
-    return mfu
 
 
 def clear():

@@ -35,7 +35,7 @@ import threading
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
            "counter", "gauge", "histogram", "enable", "disable", "enabled",
            "sanitize_name", "exponential_buckets", "hist_quantile",
-           "TraceTally", "RetraceSite"]
+           "TraceTally", "RetraceSite", "BUILD_SITE", "OUTSIDE"]
 
 
 class _RetraceSuppress(threading.local):
@@ -50,6 +50,25 @@ class _RetraceSuppress(threading.local):
 
 
 RETRACE_SUPPRESS = _RetraceSuppress()
+
+# what a program build belongs to when nothing of this package is open
+# on the thread: the caller's own jits
+OUTSIDE = "outside"
+
+
+class _BuildSite(threading.local):
+    """Per thread, the innermost thing open that a program build is
+    laid to (``program_build_seconds{site}``, aot/store.py): the
+    ``site`` of a :meth:`RetraceSite.timed` call, else the name of an
+    open set-up span (``tracing.span(..., seconds_to=...)``), else
+    :data:`OUTSIDE`.  jax traces, lowers and loads on the dispatching
+    thread and reports each from there, so the listener reads this."""
+
+    def __init__(self):
+        self.name = OUTSIDE
+
+
+BUILD_SITE = _BuildSite()
 
 
 class TraceTally(threading.local):
@@ -75,7 +94,10 @@ class RetraceSite:
     * dispatch through :meth:`timed` — wall time goes to
       ``dispatch_hist`` (when given), and calls during which THIS
       thread (re)traced also observe into ``compile_hist``
-      (trace + compile + first run), exception or not.
+      (trace + compile + first run in one sample, exception or not;
+      ``program_build_seconds{site, phase}`` has the same call's
+      trace, lowering and load apart, from jax's own events).  For the
+      call the thread's :data:`BUILD_SITE` is this site.
 
     With a ``site`` name, calls that (re)traced a directly-dispatched
     jitted callable also register the program in the compiled-program
@@ -98,10 +120,16 @@ class RetraceSite:
     def timed(self, fn, *args, dispatch_hist=None):
         import time
         r0 = self._tally.count
+        outer = BUILD_SITE.name
+        if self.site is not None:
+            # analyze: ok(threads) a threading.local: every thread writes its own
+            BUILD_SITE.name = self.site
         t0 = time.perf_counter()
         try:
             return fn(*args)
         finally:
+            # analyze: ok(threads) a threading.local: every thread writes its own
+            BUILD_SITE.name = outer
             dt_ms = (time.perf_counter() - t0) * 1e3
             if dispatch_hist is not None:
                 dispatch_hist.observe(dt_ms)

@@ -14,6 +14,14 @@ Design rules (the same overhead contract as the registry):
   ``traceparent``) is OFF by default; every instrumentation site goes
   through :func:`span`/:func:`start_span`, which cost one module-global
   check when disabled.  No clock read, no lock, nothing recorded.
+* **A set-up span keeps its seconds anyway.**  A site that a process
+  walks once (``module.bind``, ``module.init_params``,
+  ``module.init_optimizer``, ``fit.build``) passes a counter's child
+  as ``seconds_to``: the span's wall seconds are added to it on exit,
+  recording on or off (``setup_seconds{phase}``), and while the span is
+  open the thread's program builds are laid to its name
+  (``program_build_seconds{site}``).  Sites that pass none, every site
+  of the steady path, take the path above.
 * **Always on the profiler's clock.**  The context form :func:`span`
   enters a ``jax.profiler.TraceAnnotation`` of the span's name whether
   or not recording is enabled, so any ``jax.profiler`` trace (a
@@ -55,12 +63,12 @@ import threading
 import time
 from collections import deque
 
-from .registry import REGISTRY
+from .registry import BUILD_SITE, REGISTRY
 
 __all__ = ["Span", "SpanContext", "enable", "disable", "enabled",
            "span", "start_span", "current", "traceparent", "extract",
            "spans", "drain_spans", "clear", "chrome_events",
-           "find_trace", "SPAN_CAPACITY"]
+           "find_trace", "SPAN_CAPACITY", "SETUP_SECONDS"]
 
 SPAN_CAPACITY = int(os.environ.get("MXNET_TRACE_CAPACITY", "4096") or 4096)
 
@@ -72,6 +80,12 @@ SPANS_TOTAL = REGISTRY.counter(
 DROPPED = REGISTRY.counter(
     "trace_spans_dropped", "finished spans evicted from the bounded "
     "ring before an export drained them", unit="spans")
+# what a process pays once before its first step, kept with recording
+# off: the set-up spans' sites pass a child of this as ``seconds_to``
+SETUP_SECONDS = REGISTRY.counter(
+    "setup_seconds", "wall seconds spent in a set-up phase of the "
+    "process, labeled by `phase` (import, bind, init_params, "
+    "init_optimizer, fit_build)", unit="s")
 
 _ENABLED = False
 _ring = deque(maxlen=SPAN_CAPACITY)
@@ -138,10 +152,16 @@ class Span:
     a span may be *ended* by a different thread than opened it (a
     serving request settles on the replica thread), but only one thread
     may mutate it at a time — which the single-owner request objects
-    guarantee."""
+    guarantee.
+
+    With ``seconds_to`` (a counter's child) the duration is also added
+    there, and the context form names the thread's program builds for
+    its body.  ``trace_id`` None is such a span while recording is off:
+    it feeds its counter and the ring never sees it."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0",
-                 "t_mono", "attrs", "_ended", "_tid", "_restore", "_ann")
+                 "t_mono", "attrs", "_ended", "_tid", "_restore", "_ann",
+                 "_seconds_to", "_outer_site")
 
     def __init__(self, name, trace_id, parent_id, attrs):
         self.name = name
@@ -155,6 +175,8 @@ class Span:
         self._tid = threading.get_ident()
         self._restore = None
         self._ann = None        # the profiler annotation (span() form)
+        self._seconds_to = None     # set by span(..., seconds_to=)
+        self._outer_site = None
 
     @property
     def context(self):
@@ -173,12 +195,16 @@ class Span:
         if self._ended:
             return self
         self._ended = True
-        dur_ms = (time.perf_counter() - self.t_mono) * 1e3
+        dur = time.perf_counter() - self.t_mono
+        if self._seconds_to is not None:
+            self._seconds_to.inc(dur)
+        if self.trace_id is None:
+            return self
         if attrs:
             self.set(**attrs)
         rec = {"name": self.name, "trace_id": self.trace_id,
                "span_id": self.span_id, "parent_id": self.parent_id,
-               "t0": self.t0, "dur_ms": round(dur_ms, 4),
+               "t0": self.t0, "dur_ms": round(dur * 1e3, 4),
                "tid": self._tid & 0xFFFF}
         if self.attrs:
             rec["attrs"] = self.attrs
@@ -188,8 +214,11 @@ class Span:
     # context-manager form publishes this span as the thread's current
     # so children opened in the body nest under it automatically
     def __enter__(self):
-        self._restore = getattr(_tls, "ctx", None)
-        _tls.ctx = self.context
+        if self.trace_id is not None:
+            self._restore = getattr(_tls, "ctx", None)
+            _tls.ctx = self.context
+        if self._seconds_to is not None:
+            self._outer_site, BUILD_SITE.name = BUILD_SITE.name, self.name
         if self._ann is not None:
             self._ann.__enter__()
         return self
@@ -197,8 +226,11 @@ class Span:
     def __exit__(self, exc_type, exc, tb):
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
-        _tls.ctx = self._restore
-        self._restore = None
+        if self._seconds_to is not None:
+            BUILD_SITE.name = self._outer_site
+        if self.trace_id is not None:
+            _tls.ctx = self._restore
+            self._restore = None
         if exc_type is not None:
             self.set(error=exc_type.__name__)
         self.end()
@@ -288,7 +320,7 @@ def start_span(name, parent="current", **attrs):
     return Span(name, _new_trace_id(), None, attrs or None)
 
 
-def span(name, parent="current", **attrs):
+def span(name, parent="current", seconds_to=None, **attrs):
     """Context-managed span that nests children opened in its body
     (thread-local).  The instrumentation workhorse::
 
@@ -297,11 +329,17 @@ def span(name, parent="current", **attrs):
 
     Its body is always a ``jax.profiler.TraceAnnotation`` of ``name``
     (a running device trace shows it, whoever started the trace); the
-    ring records it only when tracing is enabled."""
+    ring records it only when tracing is enabled.  ``seconds_to`` (a
+    counter's child) gets the span's wall seconds whether or not it is:
+    for the set-up phases, whose one span no trace of a window sees."""
     ann = _annotation(name)
-    if not _ENABLED:
+    if _ENABLED:
+        sp = start_span(name, parent=parent, **attrs)
+    elif seconds_to is None:
         return ann
-    sp = start_span(name, parent=parent, **attrs)
+    else:
+        sp = Span(name, None, None, None)
+    sp._seconds_to = seconds_to
     sp._ann = ann
     return sp
 

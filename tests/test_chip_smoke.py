@@ -130,18 +130,6 @@ def test_explicit_accelerator_context_raises_on_the_cpu():
     assert mx.cpu(0).jax_device.platform == "cpu"
 
 
-def test_peak_table_is_keyed_by_exact_device_kind():
-    from mxnet_tpu.telemetry import programs
-    assert programs.PEAKS["TPU v5 lite"] == {"bf16_tflops": 197.0,
-                                             "hbm_gbps": 819.0}
-    assert programs.peak_tflops(jax.devices()[0].device_kind) is None
-    for kind in ("TPU v5", "TPU v5p", "tpu v5 lite", "TPU v7x"):
-        with pytest.raises(KeyError, match="no published peak"):
-            programs.peak_tflops(kind)
-        with pytest.raises(KeyError):
-            programs.mfu_measured(1e12, 1.0, kind)
-
-
 @pytest.mark.parametrize("from_env", [True, False])
 def test_compile_cache_is_placed_from_outside(tmp_path, from_env):
     """JAX_COMPILATION_CACHE_DIR set: the cache lives there and the

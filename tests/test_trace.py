@@ -14,7 +14,7 @@ Covers the PR 8 contract (docs/OBSERVABILITY.md):
   CONNECTED trace: http span → scheduler → prefill → ≥1 decode-
   iteration spans, visible in both flight and chrome exports;
 * compiled-program registry — every live jit site reports nonzero
-  compiler FLOPs/bytes; ``mfu_measured`` computes from them;
+  compiler FLOPs/bytes;
 * pod health — straggler detector (single-process world: the exchange
   is an identity and never flags) and the hang watchdog.
 """
@@ -202,6 +202,11 @@ def _fit_module(batch=16):
     return mod, mx.io.DataBatch(data=[nd.array(X)], label=[nd.array(y)])
 
 
+def _program_build_seconds():
+    return {c.label_values: c.value for c in
+            telemetry.REGISTRY.get("program_build_seconds").children()}
+
+
 def test_tracing_overhead_guard_fused_fit():
     """Tracing ON must be free where it matters: zero steady-state
     retraces and exactly one device launch per fused fit step."""
@@ -213,11 +218,15 @@ def test_tracing_overhead_guard_fused_fit():
     traced = fused_fit.TRACE_COUNT
     disp = telemetry.REGISTRY.get("device_dispatches")
     d0 = disp.value
+    built = _program_build_seconds()
     for _ in range(4):
         assert mod.fit_step(batch_nd, m)
     assert fused_fit.TRACE_COUNT == traced, \
         "tracing instrumentation caused a fused-step retrace"
     assert disp.value - d0 == 4               # one launch per step
+    # nothing traced, lowered or loaded after the first step: the
+    # steady path adds 0.0 to every child (tests/test_setup_spans.py)
+    assert _program_build_seconds() == built
     assert any(s["name"] == "fit.fused_dispatch"
                for s in tracing.spans())
 
@@ -377,18 +386,6 @@ def test_top_programs_and_flight_table(tmp_path):
     lines = [json.loads(l) for l in open(path)]
     tables = [l["programs"] for l in lines if "programs" in l]
     assert tables and tables[0][0]["flops"] > 0
-
-
-def test_mfu_measured_gauge():
-    from mxnet_tpu.telemetry import programs as programs_mod
-    assert programs_mod.peak_tflops("TPU v5 lite") == 197.0
-    assert programs_mod.peak_tflops("cpu") is None
-    got = programs_mod.mfu_measured(197e12 * 0.5, 1.0, "TPU v5 lite")
-    assert got == pytest.approx(0.5)
-    assert telemetry.REGISTRY.get("mfu_measured").value \
-        == pytest.approx(0.5, abs=1e-5)
-    # unknown chip: no peak, gauge untouched, returns None
-    assert programs_mod.mfu_measured(1e12, 1.0, "cpu") is None
 
 
 # ----------------------------------------------------------------------
