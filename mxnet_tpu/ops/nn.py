@@ -683,55 +683,28 @@ def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None):
         fallback_reason=reason or "flash-geometry")
 
 
-def _flash_block_sizes(seq_len, head_dim, v_dim=None):
-    """The flash kernel's tiles, from the shapes alone (``seq_len`` is a
-    multiple of 512, ``head_dim`` of 64 and ``v_dim`` of 128: the
-    geometry gate).  The wider of the two widths sizes the resident
-    block; it is the keys' in every geometry so far.
-
-    Every block is a whole number of 512-row tiles that divides the
-    sequence.  Forward: 1024 query rows against 1024 resident key/value
-    rows where that divides it, the scores computed 512 columns at a
-    time.
-
-    Backward: ONE kernel walks the key/value sequence in resident blocks
-    of ``block_kv_dkv`` rows, computes each 512 x 512 score block of it
-    once and emits dq, dk and dv from it.  Its dq comes out as one
-    partial per resident block, ``seq_len / block_kv_dkv`` of them in
-    q's dtype, summed afterwards: each a copy of q written and read
-    back.  A larger resident block makes fewer partials, but the kernel
-    skips a resident block only when ALL of it lies above the diagonal:
-    inside one it also computes the 512-blocks the causal mask would
-    drop.  At most a quarter of the sequence balances the two: 4
-    partials, no wasted block at 2048 (the LM cell: 512 rows resident)
-    and 160 blocks computed for 136 at 8192 (the ZAYA cell: 2048 rows);
-    the partials are rounded to q's dtype before their sum.  k, v and
-    the float32 dk, dv of the resident rows live in VMEM: 2048 rows of
-    128 at most (on the v5e 4096 ran out of it beside 2048 query rows,
-    and were slower where they fit: PERF.md, PR 27)."""
+def _flash_block_sizes(seq_len):
+    """The forward flash kernel's tiles, from the sequence alone (a
+    multiple of 512: the geometry gate): 1024 query rows against 1024
+    resident key/value rows where that divides it, else 512, the scores
+    computed 512 columns at a time; no width has asked for other tiles.
+    The backward is the repo's own kernel and takes its shapes itself
+    (``pallas/flash_backward.py`` ``plan``: 512 x 512 score blocks, a
+    key/value head's rows resident, cut into segments where they pass its
+    VMEM budget)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
-    tiles = seq_len // 512
-
-    def rows(at_most):
-        """512 x the largest divisor of ``tiles`` within ``at_most``."""
-        return 512 * max(n for n in range(1, max(1, at_most) + 1)
-                         if tiles % n == 0)
-
-    fwd = rows(2)
-    resident = rows(min(tiles // 4, 4 * 128 // max(head_dim, v_dim or 0)))
-    return BlockSizes(
-        block_q=fwd, block_kv=fwd, block_kv_compute=512,
-        block_q_dkv=512, block_kv_dkv=resident, block_kv_dkv_compute=512,
-        use_fused_bwd_kernel=True)
+    rows = 1024 if seq_len % 1024 == 0 else 512
+    return BlockSizes(block_q=rows, block_kv=rows, block_kv_compute=512)
 
 
 @_functools.lru_cache(maxsize=None)
-def _flash_kernel(q_heads, kv_heads, seq_len, head_dim, interpret,
-                  v_dim=None):
-    """jax's splash-attention kernel for one causal sequence, built once
-    per geometry: the mask's block tables are host numpy work at trace
-    time, and every layer of a model asks for the same ones.  ``v_dim``
-    is given only where the values' width is not ``head_dim``."""
+def _flash_kernel(q_heads, seq_len, interpret, residuals=False):
+    """jax's splash-attention forward kernel for one causal sequence,
+    built once per head count and length (the widths and the key/value
+    head count are the operands' own): the mask's block tables are host
+    numpy work at trace time, and every layer of a model asks for the
+    same ones.  With ``residuals`` the kernel returns ``(o, (lse,))``,
+    the rows' log-sum-exp beside the result, as a backward needs it."""
     import numpy as np
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         CausalMask, MultiHeadMask, make_splash_mha)
@@ -742,30 +715,55 @@ def _flash_kernel(q_heads, kv_heads, seq_len, head_dim, interpret,
     with jax.ensure_compile_time_eval():
         kernel = make_splash_mha(
             mask, head_shards=1, q_seq_shards=1, interpret=interpret,
-            block_sizes=_flash_block_sizes(seq_len, head_dim, v_dim))
+            save_residuals=residuals,
+            block_sizes=_flash_block_sizes(seq_len))
     return jax.tree_util.tree_map(np.asarray, kernel)
+
+
+def _flash_forward(q, k, v, interpret, residuals):
+    kernel = _flash_kernel(q.shape[1], q.shape[2], interpret, residuals)
+    with jax.named_scope("pallas.flash_attention"):
+        return jax.vmap(kernel)(q, k, v)
+
+
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash(q, k, v, interpret):
+    return _flash_forward(q, k, v, interpret, False)
+
+
+def _flash_fwd(q, k, v, interpret):
+    o, (lse,) = _flash_forward(q, k, v, interpret, True)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bwd(interpret, res, do):
+    from ..pallas.flash_backward import flash_attention_backward
+    with jax.named_scope("pallas.flash_attention"):
+        return flash_attention_backward(*res, do, interpret=interpret)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _flash_attention(q, k, v, *, interpret=False):
     """Causal attention of head-major q (B, Hq, S, D) over k (B, Hk, S,
     D) and v (B, Hk, S, Dv), Hq a multiple of Hk and Dv = D unless the
-    values are narrower (the result is (B, Hq, S, Dv)), by jax's splash
-    Pallas kernel: float32 scores, statistics and accumulators whatever the
-    operands' dtype; a key/value head is shared by its Hq / Hk query
-    heads inside the kernel (no repeated K/V, dK and dV summed over the
-    group in VMEM); the backward computes every score block once and
-    emits dq, dk, dv from one kernel (``_flash_block_sizes``).
+    values are narrower (the result is (B, Hq, S, Dv)): float32 scores,
+    statistics and accumulators whatever the operands' dtype, and a
+    key/value head shared by its Hq / Hk query heads inside the kernels
+    (no repeated K/V, dK and dV summed over the group in VMEM).  Forward
+    it is jax's splash Pallas kernel, which keeps the rows' log-sum-exp
+    where a gradient is asked for; backward it is the repo's own kernel
+    (``pallas/flash_backward.py``), which computes every score block on
+    or under the diagonal once and emits dq, dk, dv from it with no
+    partial sums in HBM.
 
-    The kernel takes no softmax scale: ``q`` carries it (a caller scales
+    The kernels take no softmax scale: ``q`` carries it (a caller scales
     q where it is still float32, so q is rounded once).  ``interpret``
     is for the tests; ``_use_flash_attention`` never asks for it."""
     from ..pallas.attention import _count_launch
     _count_launch("flash_attention")
-    D, Dv = q.shape[3], v.shape[3]
-    kernel = _flash_kernel(q.shape[1], k.shape[1], q.shape[2], D,
-                           bool(interpret), None if Dv == D else Dv)
-    with jax.named_scope("pallas.flash_attention"):
-        return jax.vmap(kernel)(q, k, v)
+    return _flash(q, k, v, bool(interpret))
 
 
 def _project_heads(spec, data, weight, bias, scale=None):

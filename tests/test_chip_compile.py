@@ -113,17 +113,18 @@ def test_two_bit_quantize_fused_compiles(one_chip):
 
 
 def test_flash_attention_fwd_grad_compiles(one_chip):
-    """The flash kernel (jax's splash attention, fused dq/dk/dv
-    backward) at the LM cell's geometry and the tiles ops/nn.py picks
-    for it: 2 sequences of 2048, 16 heads of 128."""
+    """The flash kernels (jax's splash attention forward, the repo's
+    backward with a head's key/value rows resident) at the LM cell's
+    geometry: 2 sequences of 2048, 16 heads of 128."""
     from mxnet_tpu.ops.nn import _flash_attention
 
     def loss(q, k, v):
         return _flash_attention(q, k, v).astype(jnp.float32).sum()
 
     qkv = ((2, H, 2048, D), jnp.bfloat16)
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
-             qkv, qkv, qkv)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+                    qkv, qkv, qkv).as_text()
+    assert "flash_attention_backward" in text and "splash_mha_dkv" not in text
 
 
 def test_fit_program_conditional_takes_gradients_narrow(one_chip):
@@ -438,7 +439,8 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
     with jax.default_matmul_precision("default"):
         compiled = fn.lower(*specs).compile()
     text = compiled.as_text()
-    assert "splash_mha" in text and "gmm" in text and "ragged" not in text
+    assert "splash_mha_fwd" in text and "gmm" in text and "ragged" not in text
+    assert "flash_attention_backward" in text and "splash_mha_dkv" not in text
     for kernel in ("forward", "backward"):
         assert "gated_delta_rule_" + kernel in text
         assert "gdn_mix_" + kernel in text
@@ -456,16 +458,17 @@ def test_qwen3_next_fit_program_compiles_and_fits_the_chip(one_chip,
 def test_flash_attention_at_192_and_128_fwd_grad_compiles(one_chip):
     """The flash kernel at latent attention's geometry (the Kanana-2
     cell): one sequence of 8192, 32 heads, queries and keys 192 wide,
-    values 128, at the tiles ops/nn.py picks for it (1024 rows resident
-    in the fused backward)."""
+    values 128; the backward holds a head's 8192 rows of k, v and of the
+    float32 dk, dv in VMEM (38.8 MB: where an overrun would show)."""
     from mxnet_tpu.ops.nn import _flash_attention
 
     def loss(q, k, v):
         return _flash_attention(q, k, v).astype(jnp.float32).sum()
 
     qk = ((1, 32, 8192, 192), jnp.bfloat16)
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
-             qk, qk, ((1, 32, 8192, 128), jnp.bfloat16))
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+                    qk, qk, ((1, 32, 8192, 128), jnp.bfloat16)).as_text()
+    assert "flash_attention_backward" in text and "splash_mha_dkv" not in text
 
 
 def test_kanana2_fit_program_compiles_and_fits_the_chip(one_chip,
@@ -520,7 +523,8 @@ def test_kanana2_fit_program_compiles_and_fits_the_chip(one_chip,
     with jax.default_matmul_precision("default"):
         compiled = fn.lower(*specs).compile()
     text = compiled.as_text()
-    assert "splash_mha" in text and "gmm" in text and "ragged" not in text
+    assert "splash_mha_fwd" in text and "gmm" in text and "ragged" not in text
+    assert "flash_attention_backward" in text and "splash_mha_dkv" not in text
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)

@@ -126,9 +126,12 @@ def test_latent_attention_with_the_flash_kernel_at_192_and_128(
     h, ws = _stream(4, (1, 512, 64)), _mla_weights(ref, kw, scale=4.0)
     w = _stream(5, (1, 512, 64))
     _close(op(h, ws), _mla_ref(ref, h, ws, kw), tol=1e-4)
-    assert seen and set(seen) == {(2, 2, 512, 192, True, 128)}
+    # the value alone, then under the gradient with the rows' log-sum-exp
+    assert set(seen) == {(2, 512, True, False)}
+    got = jax.grad(lambda h, ws: jnp.sum(op(h, ws) * w), (0, 1))(h, ws)
+    assert set(seen) == {(2, 512, True, False), (2, 512, True, True)}
     _grads_close(
-        jax.grad(lambda h, ws: jnp.sum(op(h, ws) * w), (0, 1))(h, ws),
+        got,
         jax.jit(jax.grad(lambda h, ws: jnp.sum(_mla_ref(ref, h, ws, kw) * w),
                          (0, 1)))(h, ws), tol=2e-4)
 
@@ -335,27 +338,40 @@ def test_gated_ffn_operator_is_gated_ffn(ref):
 # ----------------------------------------------------------------------
 # the flash kernel's geometries
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("cell,S_,D,Dv,fwd,resident", [
-    ("cgpt13b_train_s2048", 2048, 128, None, 1024, 512),
-    ("zaya1_8b_train_ep2", 8192, 128, None, 1024, 2048),
-    ("qwen3next_80b_train_ep16", 8192, 256, None, 1024, 1024),
-    ("kanana2_30b_train_ep8", 8192, 192, 128, 1024, 1024),
+@pytest.mark.parametrize("cell,S_,D,Dv,Hq,Hk,held", [
+    ("cgpt13b_train_s2048", 2048, 128, None, 16, 16, 6.3e6),
+    ("zaya1_8b_train_ep2", 8192, 128, None, 8, 2, 25.2e6),
+    ("qwen3next_80b_train_ep16", 8192, 256, None, 16, 2, 50.3e6),
+    ("kanana2_30b_train_ep8", 8192, 192, 128, 32, 32, 38.8e6),
 ])
-def test_flash_geometries_of_the_cells(monkeypatch, cell, S_, D, Dv, fwd,
-                                       resident):
-    """The tiles and the gate's answer for the geometries the four
-    language-model cells use: the first three are what they were before
-    the kernel took two widths, the fourth is latent attention's."""
+def test_flash_geometries_of_the_cells(monkeypatch, cell, S_, D, Dv, Hq, Hk,
+                                       held):
+    """The tiles, the backward's plan and the gate's answer for the
+    geometries the four language-model cells use: the forward's tiles are
+    what they were, the backward holds a key/value head's whole sequence
+    in one segment whatever the head counts (a width of 192 takes the
+    lanes of 256 and has dq and dk summed transposed), and its VMEM
+    limit is what those rows take beside the working room; the program
+    of a gradient at the cell's shapes calls the repo's backward."""
     from mxnet_tpu.ops import nn
-    from mxnet_tpu.pallas import dispatch
-    args = (S_, D) if Dv is None else (S_, D, Dv)
-    bs = nn._flash_block_sizes(*args)
-    assert (bs.block_q, bs.block_kv, bs.block_kv_compute) == (fwd, fwd, 512)
-    assert (bs.block_q_dkv, bs.block_kv_dkv, bs.block_kv_dkv_compute) \
-        == (512, resident, 512)
-    assert bs.use_fused_bwd_kernel
-    if Dv is not None:      # the same tiles when both widths are given
-        assert nn._flash_block_sizes(S_, D, D) == nn._flash_block_sizes(S_, D)
+    from mxnet_tpu.pallas import dispatch, flash_backward as fb
+    bs = nn._flash_block_sizes(S_)
+    assert (bs.block_q, bs.block_kv, bs.block_kv_compute) == (1024, 1024, 512)
+    assert not bs.has_backward_blocks
+    z = fb.plan(S_, D, Dv or D, jnp.bfloat16)
+    assert (z.segments, z.rows, z.transposed) == (1, S_, D == 192)
+    assert abs(z.vmem_limit_bytes - fb._WORKING - held) < 0.05e6
+    assert z.vmem_limit_bytes < 100 << 20      # of the v5e's 128 MiB
+    # float32 operands at the widest geometry pass the budget: two passes
+    assert fb.plan(8192, 256, 256, jnp.float32)[:2] == (2, 4096)
+    q = jax.ShapeDtypeStruct((1, Hq, S_, D), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, Hk, S_, D), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, Hk, S_, Dv or D), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: nn._flash_attention(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(q, k, v)
+    assert "flash_attention_backward" in str(jaxpr)
+    assert "splash_mha_dkv" not in str(jaxpr)
     # the gate, asked as a one-device TPU program would be
     monkeypatch.setattr(dispatch, "_compiles_here",
                         lambda: (True, "", None))

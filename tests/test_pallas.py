@@ -17,9 +17,11 @@ precision).  The decode kernel emits EXACT ZEROS for inactive slots
 (pos < 0) where the XLA path emits masked don't-care values — parity
 is asserted on active slots; both are masked by the engine.
 """
+import functools
 import os
 
 import numpy as np
+
 import pytest
 
 import jax
@@ -414,10 +416,10 @@ def _materialised_causal_attention(q, k, v):
 @pytest.mark.parametrize("Hq,Hk,S", [(4, 4, 512), (8, 2, 1024)],
                          ids=["h4to4_s512", "h8to2_s1024"])
 def test_flash_attention_parity_fwd_bwd(Hq, Hk, S, dtype):
-    """The flash kernel ops/nn.py builds (jax's splash attention: one
-    fused dq/dk/dv backward kernel, key/value heads shared by their
-    query heads inside it), interpreted: value and dq, dk, dv against
-    the materialised-softmax path, equal head counts and ZAYA's 4 : 1
+    """The flash kernels ops/nn.py runs (jax's splash attention forward,
+    the repo's backward behind it, key/value heads shared by their query
+    heads inside both), interpreted: value and dq, dk, dv against the
+    materialised-softmax path, equal head counts and ZAYA's 4 : 1
     grouping with K and V at their own head count, batch 2."""
     from mxnet_tpu.ops.nn import _flash_attention
     B, D = 2, 128
@@ -432,10 +434,13 @@ def test_flash_attention_parity_fwd_bwd(Hq, Hk, S, dtype):
             lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum(),
             argnums=(0, 1, 2)))(q, k, v)
 
-    launches = PALLAS_LAUNCHES.labels(kernel="flash_attention")
-    before = launches.value
+    from mxnet_tpu.pallas.flash_backward import _run_pass
+    _run_pass.clear_cache()     # the backward kernel is built in this test
+    launches = [PALLAS_LAUNCHES.labels(kernel=name)
+                for name in ("flash_attention", "flash_attention_bwd")]
+    before = [c.value for c in launches]
     got = run(lambda q, k, v: _flash_attention(q, k, v, interpret=True))
-    assert launches.value == before + 1
+    assert [c.value for c in launches] == [n + 1 for n in before]
     want = run(_materialised_causal_attention)
     assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
     # float32: the two orders of summation; bf16: one rounding of p and
@@ -447,26 +452,141 @@ def test_flash_attention_parity_fwd_bwd(Hq, Hk, S, dtype):
         assert np.abs(a - b).max() <= tol * np.abs(b).max()
 
 
-@pytest.mark.parametrize("name,S,partials", [
-    ("cgpt13b_train_s2048", 2048, 4),
-    ("zaya1_8b_train_ep2", 8192, 4),
-    ("smallest", 512, 1),
-    ("odd_multiple", 1536, 3),
+def _float32_causal_attention(q, k, v):
+    """Causal attention in float32 whatever the operands' dtype, grouped:
+    the result (B, Hq, S, Dv) and the rows' log-sum-exp (B, Hq, S)."""
+    B, Hq, S, D = q.shape
+    Hk = k.shape[1]
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    s = jnp.einsum("bgrqe,bgke->bgrqk", q.reshape(B, Hk, Hq // Hk, S, D), k)
+    s = jnp.where(jnp.arange(S)[:, None] >= jnp.arange(S)[None, :], s, -1e30)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    o = jnp.einsum("bgrqk,bgke->bgrqe", jnp.exp(s - lse[..., None]), v)
+    return o.reshape(B, Hq, S, -1), lse.reshape(B, Hq, S)
+
+
+def _flash_backward_operands(B, Hq, Hk, S, D, Dv, dtype):
+    """q (scaled), k, v, the float32 attention's o and log-sum-exp, a
+    cotangent, and the float32 attention's dq, dk, dv for it."""
+    ks = jax.random.split(jax.random.PRNGKey(Hq * S + D), 4)
+    q = (jax.random.normal(ks[0], (B, Hq, S, D)) * D ** -0.5).astype(dtype)
+    k = jax.random.normal(ks[1], (B, Hk, S, D)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, Hk, S, Dv)).astype(dtype)
+    do = jax.random.normal(ks[3], (B, Hq, S, Dv)).astype(dtype)
+    (o, lse), vjp = jax.vjp(_float32_causal_attention, q, k, v)
+    want = vjp((do.astype(jnp.float32), jnp.zeros_like(lse)))
+    return (q, k, v, o.astype(dtype), lse, do), want
+
+
+@pytest.mark.parametrize("B,Hq,Hk,S,D,Dv,dtype", [
+    (1, 2, 2, 512, 128, 128, jnp.float32),
+    (2, 4, 1, 1536, 128, 128, jnp.bfloat16),
+    (1, 8, 1, 2048, 256, 256, jnp.bfloat16),
+    (1, 2, 2, 1536, 192, 128, jnp.float32),
+    (2, 2, 2, 512, 256, 256, jnp.bfloat16),
+    (1, 4, 1, 2048, 192, 128, jnp.bfloat16),
+    (2, 8, 1, 512, 128, 128, jnp.float32),
+    (1, 2, 2, 2048, 128, 128, jnp.bfloat16),
+    (1, 4, 1, 512, 192, 128, jnp.float32),
+    (1, 8, 1, 1536, 256, 256, jnp.float32),
+], ids=lambda x: getattr(x, "__name__", str(x)))
+def test_flash_backward_kernel_against_float32_attention(B, Hq, Hk, S, D, Dv,
+                                                         dtype):
+    """The repo's backward kernel alone, interpreted, on the float32
+    attention's own o and log-sum-exp: dq, dk, dv against that
+    attention's gradients, over equal heads and 4 : 1 and 8 : 1 groups
+    (K and V at their own head count), one width and two (192 has dq
+    and dk summed transposed), one block of 512 rows, three and four,
+    one sequence and two.  (One block of 192-wide bfloat16 is left out:
+    there XLA's CPU backend folds the interpreted kernel's slices and
+    transposes into a bfloat16 product it has no routine for.)"""
+    from mxnet_tpu.pallas.flash_backward import flash_attention_backward
+    operands, want = _flash_backward_operands(B, Hq, Hk, S, D, Dv, dtype)
+    got = flash_attention_backward(*operands, interpret=True)
+    # float32: the two orders of summation; bf16: o, p and ds rounded
+    # once, each result once
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for a, b, like in zip(got, want, operands):
+        assert a.shape == like.shape and a.dtype == dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * np.abs(b).max()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_backward_in_two_segments_is_the_unsegmented(monkeypatch,
+                                                           dtype):
+    """A VMEM budget that holds half of a key/value head's 2048 rows cuts
+    them into two passes, the second starting from the first's float32
+    dq: the same sums in the same order, so every bit of dq, dk, dv is
+    the one pass's."""
+    from mxnet_tpu.pallas import flash_backward as fb
+    operands, _ = _flash_backward_operands(1, 4, 2, 2048, 192, 128, dtype)
+    whole = fb.flash_attention_backward(*operands, interpret=True)
+    small = fb.plan(1024, 192, 128, dtype).vmem_limit_bytes - fb._WORKING
+    assert fb.plan(2048, 192, 128, dtype, small)[:2] == (2, 1024)
+    monkeypatch.setattr(fb, "plan",
+                        functools.partial(fb.plan, budget=small))
+    halves = fb.flash_attention_backward(*operands, interpret=True)
+    for a, b in zip(halves, whole):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def _avals(jaxpr):
+    """Every array type a jaxpr names, its sub-jaxprs' included."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield var.aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+def test_flash_gradient_makes_no_dq_partials():
+    """The program of a gradient through ``_flash_attention``: jax's
+    forward kernel, the repo's backward kernel and not jax's, and no
+    array with an axis of partial sums before q's (jax's fused backward
+    wrote S / block copies of dq and summed them afterwards)."""
+    from mxnet_tpu.ops.nn import _flash_attention
+    B, Hq, Hk, S, D = 2, 4, 2, 1024, 128
+    q = jnp.zeros((B, Hq, S, D), jnp.bfloat16)
+    k = v = jnp.zeros((B, Hk, S, D), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: _flash_attention(q, k, v, interpret=True)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
+    text = str(jaxpr)
+    assert "splash_mha_fwd" in text and "flash_attention_backward" in text
+    assert "splash_mha_dkv" not in text
+    shapes = {tuple(a.shape) for a in _avals(jaxpr.jaxpr)
+              if hasattr(a, "shape")}
+    assert (B, Hq, S, D) in shapes
+    assert not [s for s in shapes if len(s) > 4 and s[-3:] == (Hq, S, D)]
+
+
+@pytest.mark.parametrize("name,S,fwd,held", [
+    ("cgpt13b_train_s2048", 2048, 1024, 6.3e6),
+    ("zaya1_8b_train_ep2", 8192, 1024, 25.2e6),
+    ("smallest", 512, 512, 1.6e6),
+    ("odd_multiple", 1536, 512, 4.7e6),
 ])
-def test_flash_block_sizes_divide_the_sequence(name, S, partials):
-    """One function of the shapes gives the kernel's tiles: every block
-    divides S, the compute block divides its resident block, the
-    backward is the fused kernel, and the number of dq partials (one per
-    resident key/value block) is what the docstring states for the two
-    cells' real shapes."""
+def test_flash_block_sizes_divide_the_sequence(name, S, fwd, held):
+    """One function of the shapes gives each kernel its tiles: the
+    forward's blocks divide S and its compute block its resident block,
+    it carries no backward fields (jax's backward is not called); the
+    backward holds a key/value head's whole S rows in one segment, and
+    its VMEM limit is what those rows take (bf16, head_dim 128) beside
+    the working room."""
     from mxnet_tpu.ops.nn import _flash_block_sizes
-    bs = _flash_block_sizes(S, 128)
-    for b in (bs.block_q, bs.block_kv, bs.block_q_dkv, bs.block_kv_dkv):
-        assert S % b == 0, (name, bs)
-    assert bs.block_kv % bs.block_kv_compute == 0
-    assert bs.block_kv_dkv % bs.block_kv_dkv_compute == 0
-    assert bs.use_fused_bwd_kernel and bs.has_backward_blocks
-    assert S // bs.block_kv_dkv == partials, (name, bs)
+    from mxnet_tpu.pallas import flash_backward as fb
+    bs = _flash_block_sizes(S)
+    assert (bs.block_q, bs.block_kv, bs.block_kv_compute) == (fwd, fwd, 512)
+    assert S % bs.block_q == 0 and bs.block_kv % bs.block_kv_compute == 0
+    assert not bs.has_backward_blocks and not bs.use_fused_bwd_kernel
+    z = fb.plan(S, 128, 128, jnp.bfloat16)
+    assert (z.segments, z.rows, z.transposed) == (1, S, False), (name, z)
+    assert z.rows % fb._BLOCK == 0
+    assert abs(z.vmem_limit_bytes - fb._WORKING - held) < 0.05e6, (name, z)
 
 
 def _flash_ops():
@@ -544,11 +664,13 @@ def test_flash_branch_of_each_operator_matches_its_xla_branch(
 
 def test_flash_kernel_is_built_once_per_geometry():
     """The mask's block tables are host work at trace time: every layer
-    of a model asks for the same kernel object."""
+    of a model asks for the same kernel object (one for the value alone,
+    one that keeps the log-sum-exp for a gradient)."""
     from mxnet_tpu.ops.nn import _flash_kernel
-    a = _flash_kernel(4, 2, 512, 128, True)
-    assert _flash_kernel(4, 2, 512, 128, True) is a
-    assert _flash_kernel(4, 4, 512, 128, True) is not a
+    a = _flash_kernel(4, 512, True)
+    assert _flash_kernel(4, 512, True) is a
+    assert _flash_kernel(2, 512, True) is not a
+    assert _flash_kernel(4, 512, True, True) is not a
     # kept on the host: constants of whatever program uses them
     assert all(isinstance(x, np.ndarray)
                for x in jax.tree_util.tree_leaves(a))
