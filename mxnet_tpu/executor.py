@@ -212,7 +212,9 @@ def _build_graph_fn(symbol, collect_taps=False, monitor_all=False,
                 ins = [env[(id(inp), oi)] for inp, oi in node.inputs]
                 with jax.named_scope(node.op.name), \
                         jax.named_scope(node.name):
-                    raw = node.apply(ins)
+                    raw = jax.checkpoint(lambda *a, n=node: n.apply(list(a)))(
+                        *ins) if is_train and _mirrored(node) \
+                        else node.apply(ins)
                 if group_devices:
                     raw = (tuple(_place(node, r) for r in raw)
                            if isinstance(raw, (tuple, list))
@@ -354,6 +356,17 @@ def _monitor_fn(symbol, is_train, monitor_all):
 
         cache["fwd_monitor"][key] = fn
     return fn
+
+
+def _mirrored(node):
+    """The reference's per-node mirroring hint (``force_mirroring=True``
+    on a symbol call, stored as ``__force_mirroring__``): the node's
+    forward is computed again in the backward pass and only its inputs
+    are kept (``jax.checkpoint`` round the one operator), where
+    MXNET_BACKWARD_DO_MIRROR does it to the whole graph.  Not for a
+    node that updates an auxiliary state."""
+    return not node.op.mutate_inputs and str(node.str_attrs.get(
+        "__force_mirroring__", "")).lower() in ("true", "1")
 
 
 def _make_fwd_bwd(graph_fn, diff_names, mirror):

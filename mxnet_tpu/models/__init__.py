@@ -4,14 +4,16 @@ Reference parity: example/image-classification/symbols/ (mlp, lenet,
 alexnet, vgg, resnet, resnext, mobilenet, inception-bn, googlenet,
 squeezenet, densenet). Each module exposes ``get_symbol(num_classes, ...)``
 returning a Symbol ending in SoftmaxOutput, so any of them drops into
-``Module.fit`` / ``benchmark/run.py`` unchanged.  Four language-model
+``Module.fit`` / ``benchmark/run.py`` unchanged.  Five language-model
 families beside them, with the same factory signature: ``transformer``
 (GPT-2's block), ``zaya`` (compressed convolutional attention and a
 dropless top-1 expert sublayer), ``qwen3_next`` (Gated DeltaNet layers
 beside gated attention, a dropless top-k sublayer with a shared expert)
-and ``kanana2`` (latent attention, a leading dense layer, sigmoid scores
-with a selection bias before the top-k sublayer); the second output of
-the last three is the experts' token counts.
+``kanana2`` (latent attention, a leading dense layer, sigmoid scores
+with a selection bias before the top-k sublayer) and ``keye_vl2``
+(attention over the keys a learned index scorer picks, trained by a
+second loss head, before a softmax top-k sublayer); the last four hand
+out the experts' token counts as an output.
 
 These are fresh TPU-first definitions (bf16-friendly: ``dtype`` casts the
 trunk while the final classifier/softmax stays fp32), not translations of
@@ -32,12 +34,14 @@ from . import transformer
 from . import zaya
 from . import qwen3_next
 from . import kanana2
+from . import keye_vl2
 
 _NETWORKS = {
     "transformer": transformer,
     "zaya": zaya,
     "qwen3_next": qwen3_next,
     "kanana2": kanana2,
+    "keye_vl2": keye_vl2,
     "mlp": mlp,
     "lenet": lenet,
     "alexnet": alexnet,

@@ -405,6 +405,9 @@ class FusedFitStep:
         # (output index, held_first, held_count) when the graph hands
         # out its experts' token counts (telemetry.moe), else None
         self._moe_counts = _telemetry.moe.find(module._symbol)
+        # output index of the sparse attention's live-tile counts
+        # (telemetry.dsa), else None
+        self._dsa_tiles = _telemetry.dsa.find(module._symbol)
         self.launches = 0
         self._mem_tracker = _telemetry.StepMemoryTracker() \
             if _MEM_EVERY else None
@@ -932,6 +935,8 @@ class FusedFitStep:
             # telemetry.moe.publish() is asked: no sync in the step
             i, first, held = self._moe_counts
             _telemetry.moe.note(outs[i], first, held)
+        if self._dsa_tiles is not None:
+            _telemetry.dsa.note(outs[self._dsa_tiles])
         exe._pending_train_fwd = False
         exe._train_seed = None
         exe._train_auxs = None

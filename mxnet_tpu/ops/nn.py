@@ -1193,6 +1193,102 @@ def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, H, Dv))
 
 
+@register("_contrib_SparseIndexedAttention",
+          aliases=("SparseIndexedAttention",), num_outputs=3)
+def sparse_indexed_attention(data, q_weight, k_weight, v_weight,
+                             q_norm_gamma, k_norm_gamma, o_weight,
+                             idx_q_weight, idx_k_weight, idx_w_weight,
+                             idx_k_norm_gamma, idx_k_norm_beta, *, q_heads,
+                             kv_heads, head_dim, idx_heads, idx_dim, topk,
+                             rope_theta=1e7, eps=1e-6, q_chunk=512,
+                             kv_chunk=512):
+    """Grouped-query causal attention over the ``topk`` keys that a
+    learned index scorer picks for each query (DeepSeek Sparse
+    Attention), as one sublayer (B, S, d) -> (B, S, d) on an already
+    normalised stream; no bias.
+
+    Main attention: ``q_weight`` (q_heads * head_dim, d), ``k_weight``,
+    ``v_weight`` (kv_heads * head_dim, d); query and key heads
+    RMS-normalised over their channels with a plain gain (one vector for
+    all heads), rotary position on all channels (halves paired), scale
+    ``head_dim ** -0.5``, ``q_heads / kv_heads`` query heads to a
+    key/value head, then ``o_weight`` (d, q_heads * head_dim).
+
+    The scorer reads ``stop_gradient(data)`` in float32 whatever the
+    model's dtype: ``idx_q_weight`` (idx_heads * idx_dim, d) its
+    queries, ``idx_k_weight`` (idx_dim, d) ONE key a token behind a
+    LayerNorm (``idx_k_norm_gamma``, ``idx_k_norm_beta``), rotary on all
+    ``idx_dim`` channels of both, ``idx_w_weight`` (idx_heads, d) a
+    weight a head and token; ``I[t, s] = idx_heads ** -0.5 * idx_dim **
+    -0.5 * sum_j w[t, j] relu(q_j[t] . k[s])``.  Query t attends the
+    ``topk`` keys s <= t with the largest I (a tie to the lower s; all
+    of them while t < topk).
+
+    Outputs: ``y``; the index loss (1,) float32, the mean over tokens of
+    ``KL(p_t || softmax over the chosen of I[t])`` with ``p_t`` the
+    heads' mean probabilities as a constant; int32 (2,): the ``q_chunk``
+    x ``kv_chunk`` score tiles that hold a chosen pair, and the tiles on
+    or under the diagonal.  The gradient of ``y`` reaches the main
+    attention's inputs and never the scorer's; the index loss's reaches
+    the five ``idx_`` inputs and nothing else (``ops/sparse_attention.py``
+    writes both out, and makes q, k, v and the scorer's operands again in
+    the backward pass: between the passes the layer keeps its input, the
+    heads' result, the rows' statistics and the choice as bits).  Scopes: ``dsa.proj``, ``dsa.norm``,
+    ``dsa.indexer``, ``dsa.select``, ``dsa.attention``,
+    ``dsa.index_loss``."""
+    from .sparse_attention import sparse_indexed_attention as core
+    B, S, d = data.shape
+    Hq, Hk, D = int(q_heads), int(kv_heads), int(head_dim)
+    Hi, Di = int(idx_heads), int(idx_dim)
+    if Hq % Hk:
+        raise ValueError("q_heads %d not a multiple of kv_heads %d"
+                         % (Hq, Hk))
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    theta = float(rope_theta)
+
+    def front(data, wq, wk, wv, gq, gk, wiq, wik, wiw, gik, bik):
+        """(q, k, v) of the main attention and (qi, ki, wi) of the
+        scorer from the layer's operands; made again in the backward
+        pass, so nothing of it is kept."""
+        def heads(w, n, gain):
+            with jax.named_scope("dsa.proj"):
+                t = jnp.einsum("bsd,hed->bhse", data, w.reshape(n, D, d))
+            if gain is None:
+                return t
+            with jax.named_scope("dsa.norm"):
+                tf = t.astype(f32)
+                inv = lax.rsqrt(jnp.mean(jnp.square(tf), -1, keepdims=True)
+                                + eps)
+                return _rotary_half(tf * inv * gain.astype(f32), D,
+                                    theta).astype(t.dtype)
+
+        with jax.named_scope("dsa.indexer"):
+            h = lax.stop_gradient(data).astype(f32)
+            qi = _rotary_half(jnp.einsum(
+                "bsd,hed->bhse", h, wiq.astype(f32).reshape(Hi, Di, d),
+                precision=hi), Di, theta)
+            ki = jnp.einsum("bsd,ed->bse", h, wik.astype(f32), precision=hi)
+            mean = jnp.mean(ki, -1, keepdims=True)
+            var = jnp.mean(jnp.square(ki - mean), -1, keepdims=True)
+            ki = (ki - mean) * lax.rsqrt(var + eps) * gik.astype(f32) \
+                + bik.astype(f32)
+            ki = _rotary_half(ki[:, None], Di, theta)[:, 0]
+            wi = jnp.einsum("bsd,hd->bsh", h, wiw.astype(f32),
+                            precision=hi) * (Hi ** -0.5 * Di ** -0.5)
+        return heads(wq, Hq, gq), heads(wk, Hk, gk), heads(wv, Hk, None), \
+            qi, ki, wi
+
+    o, kl, live = core(
+        front, (data, q_weight, k_weight, v_weight, q_norm_gamma,
+                k_norm_gamma, idx_q_weight, idx_k_weight, idx_w_weight,
+                idx_k_norm_gamma, idx_k_norm_beta),
+        topk=int(topk), q_chunk=int(q_chunk), kv_chunk=int(kv_chunk))
+    with jax.named_scope("dsa.proj"):
+        y = jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
+    return (y, (jnp.sum(kl) / (B * S)).reshape(1),
+            lax.stop_gradient(jnp.sum(live, axis=0)))
+
+
 def gdn_conv(qkv, conv_weight, k_heads):
     """What Gated DeltaNet's ``[q; k; v]`` (B, 2 k_heads + v_heads, S, D),
     head-major, passes before the scan, in ``jax.numpy``: a causal

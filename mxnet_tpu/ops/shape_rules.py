@@ -189,6 +189,24 @@ def _latent_attn_shapes(known, attrs):
 _set("_contrib_LatentAttention", _latent_attn_shapes)
 
 
+def _sparse_indexed_attn_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    Hq, Hk, D, Hi, Di = (int(attrs[k]) for k in (
+        "q_heads", "kv_heads", "head_dim", "idx_heads", "idx_dim"))
+    return {"q_weight": (Hq * D, d), "k_weight": (Hk * D, d),
+            "v_weight": (Hk * D, d), "q_norm_gamma": (D,),
+            "k_norm_gamma": (D,), "o_weight": (d, Hq * D),
+            "idx_q_weight": (Hi * Di, d), "idx_k_weight": (Di, d),
+            "idx_w_weight": (Hi, d), "idx_k_norm_gamma": (Di,),
+            "idx_k_norm_beta": (Di,)}
+
+
+_set("_contrib_SparseIndexedAttention", _sparse_indexed_attn_shapes)
+
+
 def _gated_ffn_shapes(known, attrs):
     data = known.get("data")
     if data is None:

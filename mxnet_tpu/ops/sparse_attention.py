@@ -1,0 +1,343 @@
+"""Attention over keys that a learned scorer picks (the published
+DeepSeek Sparse Attention; ``sym.contrib.SparseIndexedAttention`` in
+``ops/nn.py`` makes the operands).
+
+For one sequence: queries ``q`` (Hq, S, D), keys and values ``k``, ``v``
+(Hk, S, D), Hq a multiple of Hk; the scorer's queries ``qi`` (Hi, S, Di),
+its ONE key a token ``ki`` (S, Di) and its head weights ``wi`` (S, Hi),
+float32, the two constant scales already in ``wi``.
+
+    I[t, s] = sum_j wi[t, j] * relu(qi[j, t] . ki[s])        for s <= t
+    S_t     = the ``topk`` keys s <= t with the largest I[t, s], a tie
+              going to the lower s; all of them while t < topk
+    o[h, t] = softmax over s in S_t of (q[h, t] . k[s] * scale) times v
+    L       = sum_t KL(p_t || softmax over S_t of I[t, :]),
+              p_t[s] = mean over the Hq heads of their probabilities
+
+:func:`sparse_indexed_attention` takes the function that makes these
+six from a layer's operands, and returns ``(o, L, live)``.  Its gradient
+is written out (``jax.custom_vjp``): ``o``'s reaches q, k, v and nothing
+else; ``L``'s reaches qi, ki, wi and nothing else (``p_t`` is a
+constant of the scorer's objective, and the choice carries no gradient);
+from the six it goes on through the maker's own.
+
+How the S x S work is done.  Plain XLA, a block of ``q_chunk`` query
+rows at a time (``lax.map`` forward, ``lax.scan`` backward) against
+chunks of keys up to the block's own diagonal (a ``fori_loop`` with the
+block's trip count), scores float32: nothing S x S x heads is ever
+whole.  A block's choice is made from its whole (q_chunk, S) row of
+scorer scores: the ``topk``-th largest by bisection over the scores'
+bit patterns (32 counting passes, no sort), then the tie rule.  Forward
+a block makes three passes over its key chunks: the scorer's scores;
+the heads' row statistics under the chosen mask; then the exact
+probabilities, the result and the head-summed probabilities for ``L``.
+The choice is kept for the backward pass as bits (S x S / 8 bytes), so
+it is never made twice and cannot come out differently; the backward
+pass recomputes the heads' scores and the scorer's once and emits every
+gradient from them.  Every S x S product runs over ALL causal pairs
+under the mask: at 16 384 tokens and 2048 keys 23 % of that work is
+chosen pairs (``live`` counts the ``q_chunk`` x ``kv_chunk`` tiles that
+hold one).
+"""
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+_HI = lax.Precision.HIGHEST
+_LOOP_CHUNKS = 4        # kv_chunk tiles a key-loop iteration takes
+_NEG = -1e30
+
+
+def plan(S, q_chunk, kv_chunk):
+    """``(bq, tile, kc, S_pad)``: rows a query block, keys a counted
+    tile, keys a loop iteration (a few tiles), and the padded length (a
+    multiple of all three and of 8)."""
+    bq = -(-min(int(q_chunk), S) // 8) * 8
+    tile = -(-min(int(kv_chunk), S) // 8) * 8
+    S_pad = -(-S // math.lcm(bq, tile)) * math.lcm(bq, tile)
+    for m in (_LOOP_CHUNKS, 2):
+        if S_pad % (tile * m) == 0:
+            return bq, tile, tile * m, S_pad
+    return bq, tile, tile, S_pad
+
+
+def _sortable(x):
+    """float32 -> uint32, order-preserving (-0.0 as +0.0)."""
+    bits = lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def choose(scores, causal, topk):
+    """The chosen mask (rows, S) bool of float32 ``scores`` (rows, S):
+    per row the ``topk`` largest among ``causal``, a tie to the lower
+    column; every causal column of a row that has no more than ``topk``.
+    No sort: the ``topk``-th largest key by bisection over its 32 bits."""
+    key = jnp.where(causal, _sortable(scores), jnp.uint32(0))
+
+    def bit(i, tau):
+        cand = tau | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        n = jnp.sum(key >= cand[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= topk, cand, tau)
+
+    tau = lax.fori_loop(0, 32, bit, jnp.zeros(key.shape[:1], jnp.uint32))
+    above = key > tau[:, None]
+    ties = key == tau[:, None]
+    room = topk - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    n_ties = jnp.sum(ties, axis=-1, dtype=jnp.int32)
+    first = lax.cond(
+        jnp.all(n_ties <= room), lambda: ties,
+        lambda: ties & (jnp.cumsum(ties.astype(jnp.int32), axis=-1)
+                        <= room[:, None]))
+    return causal & (above | first)
+
+
+def _pack(mask, kc):
+    """(rows, S) bool -> (rows, S / 8) uint8, a loop chunk of ``kc``
+    columns into ``kc / 8`` bytes: bit b of byte j is column b * kc/8 + j
+    of the chunk."""
+    rows, S = mask.shape
+    m = mask.reshape(rows, S // kc, 8, kc // 8).astype(jnp.uint8)
+    shifts = jnp.arange(8, dtype=jnp.uint8)[None, None, :, None]
+    return jnp.sum(m << shifts, axis=2, dtype=jnp.uint8).reshape(rows, S // 8)
+
+
+def _unpack(bits):
+    """One loop chunk's bytes (rows, kc / 8) -> (rows, kc) bool."""
+    rows, n = bits.shape
+    shifts = jnp.arange(8, dtype=jnp.uint8)[None, :, None]
+    return ((bits[:, None, :] >> shifts) & 1).astype(bool) \
+        .reshape(rows, 8 * n)
+
+
+def _scorer_chunk(qi, ki_c, wi):
+    """(z, I) of a block's scorer queries (Hi, bq, Di) on a chunk of
+    keys (kc, Di): ``z`` (Hi, bq, kc) the heads' products, ``I`` (bq,
+    kc) their weighted sum behind the ReLU."""
+    z = jnp.einsum("hqd,kd->hqk", qi, ki_c, precision=_HI,
+                   preferred_element_type=jnp.float32)
+    return z, jnp.sum(wi.T[:, :, None] * jax.nn.relu(z), axis=0)
+
+
+def _head_scores(q, k_c, scale):
+    """float32 scores (Hk, R, bq, kc) of a block's grouped queries
+    (Hk, R, bq, D) on a chunk of keys (Hk, kc, D)."""
+    return jnp.einsum("grqd,gkd->grqk", q, k_c,
+                      preferred_element_type=jnp.float32) * scale
+
+
+def _rows(x, start, size, axis):
+    return lax.dynamic_slice_in_dim(x, start, size, axis)
+
+
+def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile):
+    """Padded operands in, ``(o, L, live)`` out; ``S`` is the real
+    length, ``tile`` the key width of a counted tile."""
+    Hq, Sp, D = q.shape
+    Hk = k.shape[0]
+    R, nq = Hq // Hk, Sp // bq
+    scale = D ** -0.5
+    col = jnp.arange(Sp, dtype=jnp.int32)
+
+    def block(xs):
+        i, qb = xs
+        r0 = i * bq
+        row = r0 + jnp.arange(bq, dtype=jnp.int32)
+        n_c = (r0 + bq + kc - 1) // kc          # key chunks to the diagonal
+        qib, wib = _rows(qi, r0, bq, 1), _rows(wi, r0, bq, 0)
+        causal = col[None, :] <= row[:, None]
+
+        # 1. the scorer's row, and the choice
+        def score(c, acc):
+            _, ic = _scorer_chunk(qib, _rows(ki, c * kc, kc, 0), wib)
+            return lax.dynamic_update_slice_in_dim(acc, ic, c * kc, 1)
+
+        with jax.named_scope("dsa.indexer"):
+            ib = lax.fori_loop(0, n_c, score,
+                               jnp.zeros((bq, Sp), jnp.float32))
+        with jax.named_scope("dsa.select"):
+            chosen = lax.cond(r0 + bq > topk,
+                              lambda: choose(ib, causal, topk),
+                              lambda: causal)
+            bits = _pack(chosen, kc)
+            real = chosen & (row < S)[:, None]
+            live = jnp.sum(jnp.any(real.reshape(bq, Sp // tile, tile),
+                                   axis=(0, 2)), dtype=jnp.int32)
+        with jax.named_scope("dsa.index_loss"):
+            lse_i = jax.nn.logsumexp(jnp.where(chosen, ib, _NEG), axis=-1)
+
+        # 2. the heads' row statistics under the choice
+        def stats(c, ml):
+            m, l = ml
+            s = _head_scores(qb, _rows(k, c * kc, kc, 1), scale)
+            s = jnp.where(_rows(chosen, c * kc, kc, 1), s, _NEG)
+            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
+            return m2, l * jnp.exp(m - m2) + jnp.sum(
+                jnp.exp(s - m2[..., None]), axis=-1)
+
+        # 3. exact probabilities: the result, and their sum over heads
+        def result(c, acc):
+            o, kl = acc
+            on = _rows(chosen, c * kc, kc, 1)
+            s = _head_scores(qb, _rows(k, c * kc, kc, 1), scale)
+            p = jnp.where(on, jnp.exp(s - lse[..., None]), 0.0)
+            o = o + jnp.einsum("grqk,gkd->grqd", p.astype(v.dtype),
+                               _rows(v, c * kc, kc, 1),
+                               preferred_element_type=jnp.float32)
+            with jax.named_scope("dsa.index_loss"):
+                pt = jnp.sum(p, axis=(0, 1)) * (1.0 / Hq)
+                logq = _rows(ib, c * kc, kc, 1) - lse_i[:, None]
+                kl = kl + jnp.sum(jnp.where(
+                    on & (pt > 0), pt * (jnp.log(jnp.where(pt > 0, pt, 1.0))
+                                         - logq), 0.0), axis=-1)
+            return o, kl
+
+        with jax.named_scope("dsa.attention"):
+            m, l = lax.fori_loop(
+                0, n_c, stats, (jnp.full((Hk, R, bq), _NEG, jnp.float32),
+                                jnp.zeros((Hk, R, bq), jnp.float32)))
+            lse = m + jnp.log(l)
+            o, kl = lax.fori_loop(
+                0, n_c, result, (jnp.zeros((Hk, R, bq, D), jnp.float32),
+                                 jnp.zeros((bq,), jnp.float32)))
+        kl = jnp.sum(jnp.where(row < S, kl, 0.0))
+        return o.astype(q.dtype), lse, lse_i, bits, kl, live
+
+    o, lse, lse_i, bits, kl, live = lax.map(
+        block, (jnp.arange(nq),
+                q.reshape(Hk, R, nq, bq, D).transpose(2, 0, 1, 3, 4)))
+    # (nq, Hk, R, bq, D) -> (Hq, Sp, D)
+    o = o.transpose(1, 2, 0, 3, 4).reshape(Hq, Sp, D)
+    return (o, jnp.sum(kl), jnp.sum(live)), (lse, lse_i, bits)
+
+
+def _core_bwd(q, k, v, qi, ki, wi, o, lse, lse_i, bits, do, dl, S, bq, kc):
+    """The gradients to q, k, v (from ``do``) and to qi, ki, wi (from
+    ``dl``) of one padded sequence, from what the forward kept."""
+    Hq, Sp, D = q.shape
+    Hk, Hi, Di = k.shape[0], qi.shape[0], qi.shape[2]
+    R, nq = Hq // Hk, Sp // bq
+    scale = D ** -0.5
+    f32 = jnp.float32
+    do = do.astype(q.dtype)
+    blocks = lambda x: x.reshape(Hk, R, nq, bq, D).transpose(2, 0, 1, 3, 4)
+    with jax.named_scope("dsa.attention"):
+        delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1) \
+            .reshape(Hk, R, nq, bq).transpose(2, 0, 1, 3)
+
+    def block(carry, xs):
+        dk, dv, dki = carry
+        i, qb, dob, delta_b, lse_b, lse_ib, bits_b = xs
+        r0 = i * bq
+        row = r0 + jnp.arange(bq, dtype=jnp.int32)
+        n_c = (r0 + bq + kc - 1) // kc
+        qib, wib = _rows(qi, r0, bq, 1), _rows(wi, r0, bq, 0)
+        valid = (row < S)[:, None]
+
+        def chunk(c, acc):
+            dq, dqi, dwi, dk, dv, dki = acc
+            k_c, v_c = _rows(k, c * kc, kc, 1), _rows(v, c * kc, kc, 1)
+            on = _unpack(_rows(bits_b, c * (kc // 8), kc // 8, 1))
+            with jax.named_scope("dsa.attention"):
+                s = _head_scores(qb, k_c, scale)
+                p = jnp.where(on, jnp.exp(s - lse_b[..., None]), 0.0)
+                pl = p.astype(q.dtype)
+                dv_c = jnp.einsum("grqk,grqd->gkd", pl, dob,
+                                  preferred_element_type=f32)
+                dp = jnp.einsum("grqd,gkd->grqk", dob, v_c,
+                                preferred_element_type=f32)
+                ds = (p * (dp - delta_b[..., None]) * scale).astype(q.dtype)
+                dq = dq + jnp.einsum("grqk,gkd->grqd", ds, k_c,
+                                     preferred_element_type=f32)
+                dk_c = jnp.einsum("grqk,grqd->gkd", ds, qb,
+                                  preferred_element_type=f32)
+            with jax.named_scope("dsa.index_loss"):
+                ki_c = _rows(ki, c * kc, kc, 0)
+                z, ic = _scorer_chunk(qib, ki_c, wib)
+                pt = jnp.sum(p, axis=(0, 1)) * (1.0 / Hq)
+                di = jnp.where(on & valid,
+                               dl * (jnp.exp(ic - lse_ib[:, None]) - pt), 0.0)
+                dwi = dwi + jnp.sum(di[None] * jax.nn.relu(z), axis=-1).T
+                dz = jnp.where(z > 0, di[None] * wib.T[:, :, None], 0.0)
+                dqi = dqi + jnp.einsum("hqk,kd->hqd", dz, ki_c,
+                                       precision=_HI,
+                                       preferred_element_type=f32)
+                dki_c = jnp.einsum("hqk,hqd->kd", dz, qib, precision=_HI,
+                                   preferred_element_type=f32)
+            add = lambda t, u, ax: lax.dynamic_update_slice_in_dim(
+                t, _rows(t, c * kc, kc, ax) + u, c * kc, ax)
+            return (dq, dqi, dwi, add(dk, dk_c, 1), add(dv, dv_c, 1),
+                    add(dki, dki_c, 0))
+
+        dq, dqi, dwi, dk, dv, dki = lax.fori_loop(
+            0, n_c, chunk, (jnp.zeros((Hk, R, bq, D), f32),
+                            jnp.zeros((Hi, bq, Di), f32),
+                            jnp.zeros((bq, Hi), f32), dk, dv, dki))
+        return (dk, dv, dki), (dq.astype(q.dtype), dqi, dwi)
+
+    xs = (jnp.arange(nq), blocks(q), blocks(do), delta,
+          lse, lse_i, bits)
+    (dk, dv, dki), (dq, dqi, dwi) = lax.scan(
+        block, (jnp.zeros((Hk, Sp, D), f32), jnp.zeros((Hk, Sp, D), f32),
+                jnp.zeros((Sp, Di), f32)), xs)
+    dq = dq.transpose(1, 2, 0, 3, 4).reshape(Hq, Sp, D)
+    dqi = dqi.transpose(1, 0, 2, 3).reshape(Hi, Sp, Di)
+    return (dq, dk.astype(k.dtype), dv.astype(v.dtype), dqi, dki,
+            dwi.reshape(Sp, Hi))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 2))
+def _attend(front, operands, static):
+    return _attend_fwd(front, operands, static)[0]
+
+
+def _attend_fwd(front, operands, static):
+    S, topk, bq, kc, tile = static
+    out, (lse, lse_i, bits) = jax.vmap(
+        lambda *made: _core_fwd(*made, S, topk, bq, kc, tile))(
+            *front(*operands))
+    return out, (operands, out[0], lse, lse_i, bits)
+
+
+def _attend_bwd(front, static, res, grads):
+    S, _, bq, kc, _ = static
+    operands, o, lse, lse_i, bits = res
+    do, dl, _ = grads
+    made, pull = jax.vjp(front, *operands)
+    d = jax.vmap(lambda *a: _core_bwd(*a, S, bq, kc))(
+        *made, o, lse, lse_i, bits, do, dl)
+    return (pull(d),)
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def sparse_indexed_attention(front, operands, *, topk, q_chunk=512,
+                             kv_chunk=512):
+    """``front(*operands)`` makes a batch's ``(q, k, v, qi, ki, wi)``
+    (module docstring, a leading batch axis on each).  Returns ``(o (B,
+    Hq, S, D) in q's dtype, L (B,) float32, live int32 (B, 2))``,
+    ``live`` the ``q_chunk`` x ``kv_chunk`` score tiles with a chosen
+    pair and the tiles on or under the diagonal.  What ``front`` makes
+    is made again in the backward pass and not kept: between the passes
+    a layer holds ``operands``, ``o``, the rows' statistics and the
+    choice as bits."""
+    S = jax.eval_shape(front, *operands)[0].shape[2]
+    bq, tile, kc, Sp = plan(S, q_chunk, kv_chunk)
+
+    def padded(*operands):
+        q, k, v, qi, ki, wi = front(*operands)
+        pad = lambda x, axis: jnp.pad(x, [
+            (0, Sp - S) if a == axis else (0, 0) for a in range(x.ndim)])
+        return (pad(q, 2), pad(k, 2), pad(v, 2), pad(qi, 2), pad(ki, 1),
+                pad(wi, 1))
+
+    o, kl, live = _attend(padded, tuple(operands),
+                          (S, int(topk), bq, kc, tile))
+    # tiles on or under the diagonal that hold a real row, by block
+    under = sum(-(-min((i + 1) * bq, S) // tile)
+                for i in range(Sp // bq) if i * bq < S)
+    return o[:, :, :S], kl, jnp.stack(
+        [live, jnp.full_like(live, under)], axis=-1)

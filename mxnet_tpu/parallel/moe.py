@@ -301,11 +301,14 @@ def _row_buckets(tokens, k, held, num_experts):
     the larger is a token's every choice held here, ``tokens * min(k,
     held)``.  But a gather and a scatter-add of that many rows would
     cost more than the experts when a sixteenth of them is real, so a
-    step whose real count fits takes the smaller: one row a token (the
-    expected count ``tokens * k * held / num_experts`` where that is
-    larger).  Top-1 has the one size."""
+    step whose real count fits takes the smaller: one row a token, or
+    five quarters of the expected count ``tokens * k * held /
+    num_experts`` where that is larger (a size AT the expected count
+    would send every other step to the worst case).  Top-1 has the one
+    size."""
     worst = tokens * min(k, held)
-    size = min(worst, max(tokens, -(-tokens * k * held // num_experts)))
+    expected = -(-tokens * k * held // num_experts)
+    size = min(worst, max(tokens, -(-5 * expected // 4)))
     return [size] if size == worst else [size, worst]
 
 
@@ -323,11 +326,13 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
     router through ``weights``.
 
     The pairs held here are sorted by expert and run through three
-    grouped products.  Their buffer has one of :func:`_row_buckets`'s
-    static sizes, chosen by the step's count (``lax.switch``; with two
-    sizes the forward is computed again in the backward pass, so that
-    the worst case's intermediates are never kept: differentiating
-    through a switch keeps every branch's).
+    grouped products.  Their buffer has the smaller of
+    :func:`_row_buckets`'s static sizes; a step whose count passes it
+    (``lax.switch``) runs its pairs a slab of that size at a time,
+    forward and backward, so the worst case holds one slab's
+    intermediates (with two arms the forward is computed again in the
+    backward pass: differentiating through a switch keeps every
+    branch's).
     ``impl`` is :func:`grouped_matmul`'s (None: chosen once a size for
     all three products)."""
     N, d = x.shape
@@ -346,14 +351,26 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
         sizes = lax.slice_in_dim(counts, first, first + held)
         real = jnp.sum(sizes)
 
-    def run(rows, x, weights, wg, wu, wd):
-        """The layer over the first ``rows`` sorted pairs."""
+    def run(rows, x, weights, wg, wu, wd, start=None):
+        """The layer over the first ``rows`` sorted pairs; with
+        ``start``, over the ``rows`` pairs from there (a slab of the
+        worst case: its part of the result, float32)."""
         how = _grouped_matmul_impl(rows, x.dtype, held) if impl is None \
             else impl
         with jax.named_scope("moe.dispatch"):
-            at = lax.slice_in_dim(order, 0, rows)
+            if start is None:
+                at = lax.slice_in_dim(order, 0, rows)
+                sizes_here = sizes
+                here_s = (jnp.arange(rows) < real)[:, None]
+            else:
+                # the slab may pass the end of the pairs: rows of no
+                # group, masked like the others past ``real``
+                at = lax.dynamic_slice_in_dim(
+                    jnp.pad(order, (0, rows)), start, rows)
+                ends = jnp.clip(jnp.cumsum(sizes), start, start + rows)
+                sizes_here = jnp.diff(ends, prepend=start)
+                here_s = (start + jnp.arange(rows) < real)[:, None]
             token = at // k if k > 1 else at
-            here_s = (jnp.arange(rows) < real)[:, None]
             # what a grouped product leaves in the rows of no group is
             # not defined, forward or backward (it may be NaN): every
             # operand and result is masked there, and with it its
@@ -361,36 +378,61 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
             own = lambda t: jnp.where(here_s, t, 0)
             xs = own(jnp.take(x, token, axis=0, unique_indices=k == 1))
         with jax.named_scope("moe.experts"):
-            g = own(grouped_matmul(xs, wg, sizes, how))
-            u = own(grouped_matmul(xs, wu, sizes, how))
+            g = own(grouped_matmul(xs, wg, sizes_here, how))
+            u = own(grouped_matmul(xs, wu, sizes_here, how))
             mid = own((jax.nn.silu(g.astype(f32)) * u.astype(f32))
                       .astype(x.dtype))
-            ys = own(grouped_matmul(mid, wd, sizes, how))
+            ys = own(grouped_matmul(mid, wd, sizes_here, how))
         with jax.named_scope("moe.combine"):
             ys = ys.astype(f32) * jnp.take(weights.reshape(N * k), at)[:, None]
             if k == 1:      # every token has its one row
                 return jnp.zeros((N, d), x.dtype).at[token].set(
                     ys.astype(x.dtype), unique_indices=True)
-            return jnp.zeros((N, d), f32).at[token].add(ys).astype(x.dtype)
+            y = jnp.zeros((N, d), f32).at[token].add(ys)
+            return y.astype(x.dtype) if start is None else y
 
     buckets = _row_buckets(N, k, held, E)
     if len(buckets) == 1:
         return run(buckets[0], x, weights, w_gate, w_up, w_down), counts
 
-    which = (real > buckets[0]).astype(jnp.int32)
-    branches = [_functools.partial(run, rows) for rows in buckets]
+    # a step whose pairs pass the smaller size runs them a slab of that
+    # size at a time, as many slabs as hold the real pairs: the worst
+    # case costs what its pairs cost, and holds one slab's intermediates
+    size = buckets[0]
+    which = (real > size).astype(jnp.int32)
+    slabs = (real + size - 1) // size
+
+    def slab(j, *operands):
+        return run(size, *operands, start=j * size)
+
+    def worst(*operands):
+        return lax.fori_loop(
+            0, slabs, lambda j, y: y + slab(j, *operands),
+            jnp.zeros((N, d), f32)).astype(x.dtype)
+
+    def worst_bwd(operands, dy):
+        def more(j, acc):
+            got = jax.vjp(_functools.partial(slab, j), *operands)[1](
+                dy.astype(f32))
+            return tuple(a + g.astype(f32) for a, g in zip(acc, got))
+
+        acc = lax.fori_loop(0, slabs, more, tuple(
+            jnp.zeros(o.shape, f32) for o in operands))
+        return tuple(a.astype(o.dtype) for a, o in zip(acc, operands))
+
+    small = _functools.partial(run, size)
 
     @jax.custom_vjp
     def sized(x, weights, wg, wu, wd):
-        return lax.switch(which, branches, x, weights, wg, wu, wd)
+        return lax.switch(which, [small, worst], x, weights, wg, wu, wd)
 
     def sized_fwd(*operands):
         return sized(*operands), operands
 
     def sized_bwd(operands, dy):
         return lax.switch(
-            which, [lambda ops, dy, f=f: jax.vjp(f, *ops)[1](dy)
-                    for f in branches], operands, dy)
+            which, [lambda ops, dy: jax.vjp(small, *ops)[1](dy), worst_bwd],
+            operands, dy)
 
     sized.defvjp(sized_fwd, sized_bwd)
     return sized(x, weights, w_gate, w_up, w_down), counts

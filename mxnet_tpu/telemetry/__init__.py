@@ -41,6 +41,9 @@ One place to read every operational witness the framework emits
   (``moe_expert_tokens{layer,expert}``, ``moe_tokens_away{layer}``,
   ``moe_expert_load_max_over_mean``), read from the last step's count
   outputs at sync boundaries, never in the step.
+* :mod:`dsa` — live score tiles of the sparse indexed attention
+  (``dsa_live_block_share``), read from the last step's count output
+  when asked, never in the step.
 
 This package is stdlib-only at import (jax is touched lazily inside
 :mod:`memory`/:mod:`programs`), so the registry is safe to import from
@@ -67,6 +70,7 @@ from . import aggregate
 from .aggregate import PodMetricsAggregator
 from . import sentinel
 from . import moe
+from . import dsa
 
 
 class _ProgramsFacade:
@@ -85,7 +89,7 @@ programs = _ProgramsFacade()
 
 __all__ = [
     "registry", "export", "flight", "memory", "chrome", "tracing",
-    "health", "programs", "aggregate", "sentinel", "moe",
+    "health", "programs", "aggregate", "sentinel", "moe", "dsa",
     "PodMetricsAggregator",
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
     "counter", "gauge", "histogram", "enable", "disable", "enabled",

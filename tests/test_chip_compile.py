@@ -534,3 +534,100 @@ def test_kanana2_fit_program_compiles_and_fits_the_chip(one_chip,
               m.argument_size_in_bytes, m.output_size_in_bytes,
               m.alias_size_in_bytes, m.temp_size_in_bytes, total)))
     assert total < 15e9
+
+
+def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
+                                                         monkeypatch):
+    """The fused fit program of the cell ``keyevl2_30b_train_ep8`` at
+    its own sizes (4 layers, 16 of 128 experts held, 18 992 rows of the
+    vocabulary, one sequence of 16 384 tokens, bf16 with f32 masters and
+    a float32 index scorer), compiled for the described chip with the
+    kernel the chip would choose for the expert layer (the Pallas
+    grouped matmul); the sparse indexed attention is plain XLA and
+    takes no kernel.  ``memory_analysis`` (arguments + outputs - aliased
+    + temporaries) stays under 15 GB of the chip's 16: the
+    configuration's ``reduced_why`` quotes the number printed here."""
+    import json
+    import os
+
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_grouped_matmul_impl",
+                        lambda *a, **k: "compiled")
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye_vl2_30b_train.json")) as f:
+        cfg = json.load(f)
+    kw = cfg["kwargs"]
+    S = kw["seq_len"]
+    assert (kw["num_layers"], kw["experts_held"], kw["topk"]) \
+        == (4, [0, 16], 2048)
+    assert S in (16384, 8192)
+    mod = mx.Module(mx.models.get_symbol(cfg["model"], **kw),
+                    context=mx.cpu())
+    mod.bind(data_shapes=[("data", (1, S))],
+             label_shapes=[("softmax_label", (S,))])
+    mod.init_params(mx.init.Zero())
+    mod.init_optimizer(optimizer=cfg["optimizer"], optimizer_params=dict(
+        cfg["optimizer_params"], multi_precision=True))
+    tokens = np.arange(S, dtype=np.float32) % kw["num_classes"]
+    batch = mx.io.DataBatch(data=[mx.nd.array(tokens.reshape(1, S))],
+                            label=[mx.nd.array(tokens)])
+    fn, args, _ = mod._get_fused_fit()._prepare(batch,
+                                                mx.metric.create("ce"))
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+    with jax.default_matmul_precision("default"):
+        compiled = fn.lower(*specs).compile()
+    text = compiled.as_text()
+    assert "gmm" in text and "ragged" not in text
+    assert "dsa.select" in text and "dsa.index_loss" in text
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print("keye_vl2 fit program: arguments %.2f GB, outputs %.2f, aliased "
+          "%.2f, temporaries %.2f: %.2f GB"
+          % tuple(b / 1e9 for b in (
+              m.argument_size_in_bytes, m.output_size_in_bytes,
+              m.alias_size_in_bytes, m.temp_size_in_bytes, total)))
+    assert total < 15e9
+
+
+@pytest.mark.parametrize("cell,S_,D,Dv,rows,buckets", [
+    ("cgpt13b_train_s2048", 2048, 128, None, None, None),
+    ("zaya1_8b_train_ep2", 8192, 128, None, (8192, 1, 8, 16), [8192]),
+    ("qwen3next_80b_train_ep16", 8192, 256, None, (8192, 10, 32, 512),
+     [8192, 81920]),
+    ("kanana2_30b_train_ep8", 8192, 192, 128, (8192, 6, 16, 128),
+     [8192, 49152]),
+    ("keyevl2_30b_train_ep8", 16384, None, None, (16384, 8, 16, 128),
+     [20480, 131072]),
+])
+def test_accepted_cells_geometries_give_what_they_gave(cell, S_, D, Dv, rows,
+                                                       buckets):
+    """What PR 38 must not have moved for the four accepted
+    language-model cells: the flash forward's tiles and the backward's
+    plan from their sequence and head widths, and the sorted rows'
+    sizes of their expert layers (the smaller size is five quarters of
+    the expected count only where that passes one row a token: in the
+    Keye cell, whose expected count IS one row a token).  No topology
+    is described here."""
+    from mxnet_tpu.ops import nn
+    from mxnet_tpu.parallel import moe
+    from mxnet_tpu.pallas import flash_backward as fb
+    if D is not None:
+        bs = nn._flash_block_sizes(S_)
+        assert (bs.block_q, bs.block_kv, bs.block_kv_compute) \
+            == (1024, 1024, 512)
+        z = fb.plan(S_, D, Dv or D, jnp.bfloat16)
+        assert (z.segments, z.rows, z.transposed) == (1, S_, D == 192)
+    if rows is not None:
+        assert moe._row_buckets(*rows) == buckets
+        # top-1 and an expected count under a row a token: as before PR 38
+        tokens, k, held, E = rows
+        if tokens * k * held < tokens * E:
+            assert buckets[0] == tokens
