@@ -21,23 +21,42 @@ else; ``L``'s reaches qi, ki, wi and nothing else (``p_t`` is a
 constant of the scorer's objective, and the choice carries no gradient);
 from the six it goes on through the maker's own.
 
-How the S x S work is done.  Plain XLA, a block of ``q_chunk`` query
-rows at a time (``lax.map`` forward, ``lax.scan`` backward) against
-chunks of keys up to the block's own diagonal (a ``fori_loop`` with the
-block's trip count), scores float32: nothing S x S x heads is ever
-whole.  A block's choice is made from its whole (q_chunk, S) row of
-scorer scores: the ``topk``-th largest by bisection over the scores'
-bit patterns (32 counting passes, no sort), then the tie rule.  Forward
-a block makes three passes over its key chunks: the scorer's scores;
-the heads' row statistics under the chosen mask; then the exact
-probabilities, the result and the head-summed probabilities for ``L``.
-The choice is kept for the backward pass as bits (S x S / 8 bytes), so
-it is never made twice and cannot come out differently; the backward
-pass recomputes the heads' scores and the scorer's once and emits every
-gradient from them.  Every S x S product runs over ALL causal pairs
-under the mask: at 16 384 tokens and 2048 keys 23 % of that work is
-chosen pairs (``live`` counts the ``q_chunk`` x ``kv_chunk`` tiles that
-hold one).
+How the S x S work is done.  A block of ``q_chunk`` query rows at a
+time (``lax.map`` forward, ``lax.scan`` backward), because a block's
+choice is made from its whole (q_chunk, S) row of scorer scores before
+its cores can run: nothing S x S x heads is ever whole.  The scorer's
+row is made a chunk of keys at a time up to the block's own diagonal (a
+``fori_loop`` with the block's trip count, float32 at ``HIGHEST``); the
+choice is the ``topk``-th largest by bisection over the scores' bit
+patterns (32 counting passes, no sort), then the tie rule.  It is kept
+for the backward pass as bits (S x S / 8 bytes), so it is never made
+twice and cannot come out differently.
+
+The heads' cores (scope ``dsa.attention``) have two forms that share
+everything else (``plan``, ``choose``, the bits, the scorer and the
+index loss) and are chosen from the program, not by a user
+(:func:`_cores_impl`; a test passes ``impl``):
+
+* in a one-device TPU program whose shapes they take, the kernels of
+  ``pallas/sparse_attention.py``: the block's mask goes in as an int8
+  operand, every (kv_chunk x q_chunk) score tile up to the diagonal
+  lives in VMEM only, forward two walks over the key tiles in one call
+  (the heads' row statistics; then the exact probabilities, ``p v`` and
+  the head-mean probabilities ``pt`` as a (q_chunk, S) row), backward
+  one walk that emits dq, adds into the float32 dk and dv the scan
+  carries (in place) and hands back the same ``pt`` row.  The index loss
+  reads ``pt`` in XLA: forward the KL as one pass over the block's row,
+  backward ``dI`` a chunk of keys at a time beside the scorer's
+  recomputed products;
+* everywhere else (the CPU, a mesh, other shapes), plain XLA: forward
+  two passes over chunks of keys (statistics, then probabilities, result
+  and the KL terms), backward one pass that recomputes the heads' scores
+  and the scorer's and emits every gradient, float32 score arrays
+  (heads, q_chunk, chunk) in memory.
+
+Either way every S x S product runs over ALL causal pairs under the
+mask: at 16 384 tokens and 2048 keys 23 % of that work is chosen pairs
+(``live`` counts the ``q_chunk`` x ``kv_chunk`` tiles that hold one).
 """
 import functools
 import math
@@ -45,6 +64,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..pallas import sparse_attention as kernels
 
 _HI = lax.Precision.HIGHEST
 _LOOP_CHUNKS = 4        # kv_chunk tiles a key-loop iteration takes
@@ -104,12 +125,14 @@ def _pack(mask, kc):
     return jnp.sum(m << shifts, axis=2, dtype=jnp.uint8).reshape(rows, S // 8)
 
 
-def _unpack(bits):
-    """One loop chunk's bytes (rows, kc / 8) -> (rows, kc) bool."""
+def _unpack(bits, kc=None):
+    """Whole loop chunks' bytes (rows, n) -> (rows, 8 n) bool; one chunk
+    of ``8 n`` columns unless ``kc`` says how many a chunk holds."""
     rows, n = bits.shape
-    shifts = jnp.arange(8, dtype=jnp.uint8)[None, :, None]
-    return ((bits[:, None, :] >> shifts) & 1).astype(bool) \
-        .reshape(rows, 8 * n)
+    per = n if kc is None else kc // 8
+    shifts = jnp.arange(8, dtype=jnp.uint8)[None, None, :, None]
+    return ((bits.reshape(rows, n // per, 1, per) >> shifts) & 1) \
+        .astype(bool).reshape(rows, 8 * n)
 
 
 def _scorer_chunk(qi, ki_c, wi):
@@ -132,9 +155,17 @@ def _rows(x, start, size, axis):
     return lax.dynamic_slice_in_dim(x, start, size, axis)
 
 
-def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile):
+def _kl_rows(on, pt, logq):
+    """Per row, ``sum over the chosen of pt (log pt - logq)``."""
+    return jnp.sum(jnp.where(
+        on & (pt > 0), pt * (jnp.log(jnp.where(pt > 0, pt, 1.0)) - logq),
+        0.0), axis=-1)
+
+
+def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile, impl):
     """Padded operands in, ``(o, L, live)`` out; ``S`` is the real
-    length, ``tile`` the key width of a counted tile."""
+    length, ``tile`` the key width of a counted tile, ``impl`` how the
+    heads' cores run (:func:`_cores_impl`)."""
     Hq, Sp, D = q.shape
     Hk = k.shape[0]
     R, nq = Hq // Hk, Sp // bq
@@ -188,20 +219,27 @@ def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile):
                                preferred_element_type=jnp.float32)
             with jax.named_scope("dsa.index_loss"):
                 pt = jnp.sum(p, axis=(0, 1)) * (1.0 / Hq)
-                logq = _rows(ib, c * kc, kc, 1) - lse_i[:, None]
-                kl = kl + jnp.sum(jnp.where(
-                    on & (pt > 0), pt * (jnp.log(jnp.where(pt > 0, pt, 1.0))
-                                         - logq), 0.0), axis=-1)
+                kl = kl + _kl_rows(
+                    on, pt, _rows(ib, c * kc, kc, 1) - lse_i[:, None])
             return o, kl
 
-        with jax.named_scope("dsa.attention"):
-            m, l = lax.fori_loop(
-                0, n_c, stats, (jnp.full((Hk, R, bq), _NEG, jnp.float32),
-                                jnp.zeros((Hk, R, bq), jnp.float32)))
-            lse = m + jnp.log(l)
-            o, kl = lax.fori_loop(
-                0, n_c, result, (jnp.zeros((Hk, R, bq, D), jnp.float32),
-                                 jnp.zeros((bq,), jnp.float32)))
+        if impl:        # 2. and 3. in VMEM: pallas/sparse_attention.py
+            with jax.named_scope("dsa.attention"):
+                o, lse, pt = kernels.forward(
+                    qb, k, v, chosen.astype(jnp.int8),
+                    (r0 + bq + tile - 1) // tile, tile,
+                    interpret=impl == "interpret")
+            with jax.named_scope("dsa.index_loss"):
+                kl = _kl_rows(chosen, pt, ib - lse_i[:, None])
+        else:
+            with jax.named_scope("dsa.attention"):
+                m, l = lax.fori_loop(
+                    0, n_c, stats, (jnp.full((Hk, R, bq), _NEG, jnp.float32),
+                                    jnp.zeros((Hk, R, bq), jnp.float32)))
+                lse = m + jnp.log(l)
+                o, kl = lax.fori_loop(
+                    0, n_c, result, (jnp.zeros((Hk, R, bq, D), jnp.float32),
+                                     jnp.zeros((bq,), jnp.float32)))
         kl = jnp.sum(jnp.where(row < S, kl, 0.0))
         return o.astype(q.dtype), lse, lse_i, bits, kl, live
 
@@ -213,7 +251,8 @@ def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile):
     return (o, jnp.sum(kl), jnp.sum(live)), (lse, lse_i, bits)
 
 
-def _core_bwd(q, k, v, qi, ki, wi, o, lse, lse_i, bits, do, dl, S, bq, kc):
+def _core_bwd(q, k, v, qi, ki, wi, o, lse, lse_i, bits, do, dl, S, bq, kc,
+              tile, impl):
     """The gradients to q, k, v (from ``do``) and to qi, ki, wi (from
     ``dl``) of one padded sequence, from what the forward kept."""
     Hq, Sp, D = q.shape
@@ -235,28 +274,49 @@ def _core_bwd(q, k, v, qi, ki, wi, o, lse, lse_i, bits, do, dl, S, bq, kc):
         n_c = (r0 + bq + kc - 1) // kc
         qib, wib = _rows(qi, r0, bq, 1), _rows(wi, r0, bq, 0)
         valid = (row < S)[:, None]
+        add = lambda t, u, c, ax: lax.dynamic_update_slice_in_dim(
+            t, _rows(t, c * kc, kc, ax) + u, c * kc, ax)
+
+        def attend(c, on, main):
+            """A chunk's part of dq, dk, dv, and its probabilities."""
+            dq, dk, dv = main
+            k_c, v_c = _rows(k, c * kc, kc, 1), _rows(v, c * kc, kc, 1)
+            s = _head_scores(qb, k_c, scale)
+            p = jnp.where(on, jnp.exp(s - lse_b[..., None]), 0.0)
+            pl = p.astype(q.dtype)
+            dv_c = jnp.einsum("grqk,grqd->gkd", pl, dob,
+                              preferred_element_type=f32)
+            dp = jnp.einsum("grqd,gkd->grqk", dob, v_c,
+                            preferred_element_type=f32)
+            ds = (p * (dp - delta_b[..., None]) * scale).astype(q.dtype)
+            dq = dq + jnp.einsum("grqk,gkd->grqd", ds, k_c,
+                                 preferred_element_type=f32)
+            dk_c = jnp.einsum("grqk,grqd->gkd", ds, qb,
+                              preferred_element_type=f32)
+            return (dq, add(dk, dk_c, c, 1), add(dv, dv_c, c, 1)), p
+
+        if impl:        # the whole block's, in VMEM
+            with jax.named_scope("dsa.attention"):
+                dq, dk, dv, pt_b = kernels.backward(
+                    qb, dob, lse_b, delta_b,
+                    _unpack(bits_b, kc).astype(jnp.int8), k, v, dk, dv,
+                    (r0 + bq + tile - 1) // tile, tile,
+                    interpret=impl == "interpret")
+            main = ()
+        else:
+            main = (jnp.zeros((Hk, R, bq, D), f32), dk, dv)
 
         def chunk(c, acc):
-            dq, dqi, dwi, dk, dv, dki = acc
-            k_c, v_c = _rows(k, c * kc, kc, 1), _rows(v, c * kc, kc, 1)
+            main, (dqi, dwi, dki) = acc
             on = _unpack(_rows(bits_b, c * (kc // 8), kc // 8, 1))
-            with jax.named_scope("dsa.attention"):
-                s = _head_scores(qb, k_c, scale)
-                p = jnp.where(on, jnp.exp(s - lse_b[..., None]), 0.0)
-                pl = p.astype(q.dtype)
-                dv_c = jnp.einsum("grqk,grqd->gkd", pl, dob,
-                                  preferred_element_type=f32)
-                dp = jnp.einsum("grqd,gkd->grqk", dob, v_c,
-                                preferred_element_type=f32)
-                ds = (p * (dp - delta_b[..., None]) * scale).astype(q.dtype)
-                dq = dq + jnp.einsum("grqk,gkd->grqd", ds, k_c,
-                                     preferred_element_type=f32)
-                dk_c = jnp.einsum("grqk,grqd->gkd", ds, qb,
-                                  preferred_element_type=f32)
+            if not impl:
+                with jax.named_scope("dsa.attention"):
+                    main, p = attend(c, on, main)
             with jax.named_scope("dsa.index_loss"):
                 ki_c = _rows(ki, c * kc, kc, 0)
                 z, ic = _scorer_chunk(qib, ki_c, wib)
-                pt = jnp.sum(p, axis=(0, 1)) * (1.0 / Hq)
+                pt = _rows(pt_b, c * kc, kc, 1) if impl \
+                    else jnp.sum(p, axis=(0, 1)) * (1.0 / Hq)
                 di = jnp.where(on & valid,
                                dl * (jnp.exp(ic - lse_ib[:, None]) - pt), 0.0)
                 dwi = dwi + jnp.sum(di[None] * jax.nn.relu(z), axis=-1).T
@@ -266,15 +326,13 @@ def _core_bwd(q, k, v, qi, ki, wi, o, lse, lse_i, bits, do, dl, S, bq, kc):
                                        preferred_element_type=f32)
                 dki_c = jnp.einsum("hqk,hqd->kd", dz, qib, precision=_HI,
                                    preferred_element_type=f32)
-            add = lambda t, u, ax: lax.dynamic_update_slice_in_dim(
-                t, _rows(t, c * kc, kc, ax) + u, c * kc, ax)
-            return (dq, dqi, dwi, add(dk, dk_c, 1), add(dv, dv_c, 1),
-                    add(dki, dki_c, 0))
+            return main, (dqi, dwi, add(dki, dki_c, c, 0))
 
-        dq, dqi, dwi, dk, dv, dki = lax.fori_loop(
-            0, n_c, chunk, (jnp.zeros((Hk, R, bq, D), f32),
-                            jnp.zeros((Hi, bq, Di), f32),
-                            jnp.zeros((bq, Hi), f32), dk, dv, dki))
+        main, (dqi, dwi, dki) = lax.fori_loop(
+            0, n_c, chunk, (main, (jnp.zeros((Hi, bq, Di), f32),
+                                   jnp.zeros((bq, Hi), f32), dki)))
+        if not impl:
+            dq, dk, dv = main
         return (dk, dv, dki), (dq.astype(q.dtype), dqi, dwi)
 
     xs = (jnp.arange(nq), blocks(q), blocks(do), delta,
@@ -294,19 +352,17 @@ def _attend(front, operands, static):
 
 
 def _attend_fwd(front, operands, static):
-    S, topk, bq, kc, tile = static
     out, (lse, lse_i, bits) = jax.vmap(
-        lambda *made: _core_fwd(*made, S, topk, bq, kc, tile))(
-            *front(*operands))
+        lambda *made: _core_fwd(*made, *static))(*front(*operands))
     return out, (operands, out[0], lse, lse_i, bits)
 
 
 def _attend_bwd(front, static, res, grads):
-    S, _, bq, kc, _ = static
+    S, _, bq, kc, tile, impl = static
     operands, o, lse, lse_i, bits = res
     do, dl, _ = grads
     made, pull = jax.vjp(front, *operands)
-    d = jax.vmap(lambda *a: _core_bwd(*a, S, bq, kc))(
+    d = jax.vmap(lambda *a: _core_bwd(*a, S, bq, kc, tile, impl))(
         *made, o, lse, lse_i, bits, do, dl)
     return (pull(d),)
 
@@ -314,8 +370,23 @@ def _attend_bwd(front, static, res, grads):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
+def _cores_impl(q, k, bq, tile, S_pad):
+    """How the heads' cores run when not told: the Pallas kernels
+    (``"compiled"``) in a one-device TPU program whose shapes they take
+    (``pallas/sparse_attention.py`` ``supported``), else the XLA loops
+    (False; counted in ``pallas_fallbacks{reason}``).  No knob: a test
+    passes ``impl``."""
+    from ..pallas.dispatch import _compiles_here, choose_impl
+    here, why, reason = _compiles_here()
+    fits, shapes = kernels.supported(q, k, bq, tile, S_pad)
+    return choose_impl(
+        "sparse_indexed_attention (no knob)", "auto", "sparse_attention",
+        here and fits, why="%s, %s" % (why or "one TPU device", shapes),
+        fallback_reason=reason or "sparse-attention-geometry")
+
+
 def sparse_indexed_attention(front, operands, *, topk, q_chunk=512,
-                             kv_chunk=512):
+                             kv_chunk=512, impl=None):
     """``front(*operands)`` makes a batch's ``(q, k, v, qi, ki, wi)``
     (module docstring, a leading batch axis on each).  Returns ``(o (B,
     Hq, S, D) in q's dtype, L (B,) float32, live int32 (B, 2))``,
@@ -324,8 +395,11 @@ def sparse_indexed_attention(front, operands, *, topk, q_chunk=512,
     is made again in the backward pass and not kept: between the passes
     a layer holds ``operands``, ``o``, the rows' statistics and the
     choice as bits."""
-    S = jax.eval_shape(front, *operands)[0].shape[2]
+    q, k = jax.eval_shape(front, *operands)[:2]
+    S = q.shape[2]
     bq, tile, kc, Sp = plan(S, q_chunk, kv_chunk)
+    if impl is None:
+        impl = _cores_impl(q, k, bq, tile, Sp)
 
     def padded(*operands):
         q, k, v, qi, ki, wi = front(*operands)
@@ -335,7 +409,7 @@ def sparse_indexed_attention(front, operands, *, topk, q_chunk=512,
                 pad(wi, 1))
 
     o, kl, live = _attend(padded, tuple(operands),
-                          (S, int(topk), bq, kc, tile))
+                          (S, int(topk), bq, kc, tile, impl))
     # tiles on or under the diagonal that hold a real row, by block
     under = sum(-(-min((i + 1) * bq, S) // tile)
                 for i in range(Sp // bq) if i * bq < S)

@@ -542,21 +542,29 @@ def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
     its own sizes (4 layers, 16 of 128 experts held, 18 992 rows of the
     vocabulary, one sequence of 16 384 tokens, bf16 with f32 masters and
     a float32 index scorer), compiled for the described chip with the
-    kernel the chip would choose for the expert layer (the Pallas
-    grouped matmul); the sparse indexed attention is plain XLA and
-    takes no kernel.  ``memory_analysis`` (arguments + outputs - aliased
-    + temporaries) stays under 15 GB of the chip's 16: the
-    configuration's ``reduced_why`` quotes the number printed here."""
+    kernels the chip would choose: the Pallas grouped matmul for the
+    expert layer, and for the sparse indexed attention's cores the pair
+    of ``pallas/sparse_attention.py`` (both custom calls are in the
+    compiled text, and nothing fell back).  ``memory_analysis``
+    (arguments + outputs - aliased + temporaries) stays under 15 GB of
+    the chip's 16; PR 38's program, whose cores were XLA loops, read
+    13.54 GB (the configuration's ``reduced_why`` quotes that one)."""
     import json
     import os
 
     import numpy as np
 
     import mxnet_tpu as mx
+    from mxnet_tpu.pallas import dispatch
     from mxnet_tpu.parallel import moe
 
     monkeypatch.setattr(moe, "_grouped_matmul_impl",
                         lambda *a, **k: "compiled")
+    # `auto` as on the chip: a one-device TPU program
+    monkeypatch.setattr(dispatch, "_compiles_here", lambda: (True, "", None))
+    fallbacks = lambda: sum(c.value
+                            for c in dispatch.PALLAS_FALLBACKS.children())
+    before = fallbacks()
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     with open(os.path.join(root, "benchmark", "configs",
                            "keye_vl2_30b_train.json")) as f:
@@ -586,6 +594,9 @@ def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
     text = compiled.as_text()
     assert "gmm" in text and "ragged" not in text
     assert "dsa.select" in text and "dsa.index_loss" in text
+    assert "sparse_attention_forward" in text
+    assert "sparse_attention_backward" in text
+    assert fallbacks() == before
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)

@@ -1,8 +1,9 @@
 """Keye-VL-2.0's language model on the CPU at tiny widths, float32: the
 sparse indexed attention against the plain reference
 (benchmark/reference/keye_vl2.py), forward, the index loss and every
-gradient, over several query blocks and at a padded length; the choice
-against a sort on the host, planted ties included; with ``topk >= S`` the
+gradient, over several query blocks and at a padded length, with the
+heads' cores as the XLA loops and once more as the Pallas kernels in
+interpret mode (``cores``); the choice against a sort on the host, planted ties included; with ``topk >= S`` the
 operator is dense grouped causal attention; the two objectives keep to
 their own leaves EXACTLY (the cross-entropy gives the scorer's leaves
 zero, the index loss gives every other leaf zero); the share test of the
@@ -47,6 +48,18 @@ def ref(monkeypatch):
     from reference import keye_vl2, train
     keye_vl2.train = train
     return keye_vl2
+
+
+@pytest.fixture(params=["xla", "kernels"])
+def cores(request, monkeypatch):
+    """The heads' cores as the CPU chooses them (the XLA loops), and as
+    ``pallas/sparse_attention.py``'s kernels in interpret mode, through
+    the operator's whole ``custom_vjp``."""
+    if request.param == "kernels":
+        from mxnet_tpu.ops import sparse_attention
+        monkeypatch.setattr(sparse_attention, "_cores_impl",
+                            lambda *a: "interpret")
+    return request.param
 
 
 def _params(ref, kw=KW, seed=7):
@@ -102,7 +115,8 @@ def _ref_layer(ref, h, ws, kw=KW, want_chosen=False):
 # the operator against the reference
 # ----------------------------------------------------------------------
 @pytest.mark.parametrize("seq,topk", [(40, 12), (37, 9), (40, 40)])
-def test_operator_matches_reference_forward_and_every_gradient(ref, seq, topk):
+def test_operator_matches_reference_forward_and_every_gradient(ref, cores, seq,
+                                                               topk):
     """Five query blocks of 8 (and a padded length, 37): the result,
     the index loss, and the gradient of ``sum(y * w) + 3 L`` to the
     stream and to all eleven leaves."""
@@ -190,7 +204,7 @@ def test_live_tiles_are_the_tiles_of_the_references_choice(ref):
     assert 0 < live < tiles[1]
 
 
-def test_with_topk_at_least_the_length_it_is_dense_causal_attention(ref):
+def test_with_topk_at_least_the_length_it_is_dense_causal_attention(ref, cores):
     """``topk >= S``: every causal key is chosen, whatever the scorer
     says, and the result is ``_grouped_causal_attention`` of the same
     projections."""
@@ -211,7 +225,7 @@ def test_with_topk_at_least_the_length_it_is_dense_causal_attention(ref):
     _close(y, jnp.einsum("bhse,dhe->bsd", o, ws[5].reshape(d, Hq, D)))
 
 
-def test_each_objective_reaches_its_own_leaves_and_no_other(ref):
+def test_each_objective_reaches_its_own_leaves_and_no_other(ref, cores):
     """EXACT zeros: the gradient of the result to the scorer's five
     leaves, and of the index loss to the stream and the main attention's
     six; in the program and in the reference."""
@@ -232,7 +246,7 @@ def test_each_objective_reaches_its_own_leaves_and_no_other(ref):
         assert float(jnp.abs(of_y[0]).max()) > 0.0
 
 
-def test_operator_is_causal(ref):
+def test_operator_is_causal(ref, cores):
     """Changing position t changes nothing before it."""
     h, ws = _stream(4, (1, S, KW["d_model"])), _attn_weights(ref)
     t = 23
