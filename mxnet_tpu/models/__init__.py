@@ -4,15 +4,17 @@ Reference parity: example/image-classification/symbols/ (mlp, lenet,
 alexnet, vgg, resnet, resnext, mobilenet, inception-bn, googlenet,
 squeezenet, densenet). Each module exposes ``get_symbol(num_classes, ...)``
 returning a Symbol ending in SoftmaxOutput, so any of them drops into
-``Module.fit`` / ``benchmark/run.py`` unchanged.  Five language-model
+``Module.fit`` / ``benchmark/run.py`` unchanged.  Six language-model
 families beside them, with the same factory signature: ``transformer``
 (GPT-2's block), ``zaya`` (compressed convolutional attention and a
 dropless top-1 expert sublayer), ``qwen3_next`` (Gated DeltaNet layers
 beside gated attention, a dropless top-k sublayer with a shared expert)
 ``kanana2`` (latent attention, a leading dense layer, sigmoid scores
-with a selection bias before the top-k sublayer) and ``keye_vl2``
+with a selection bias before the top-k sublayer), ``keye_vl2``
 (attention over the keys a learned index scorer picks, trained by a
-second loss head, before a softmax top-k sublayer); the last four hand
+second loss head, before a softmax top-k sublayer) and ``smallthinker``
+(window and full grouped-query attention layers in one model, a router
+that reads the layer's input, ReLU-gated experts); the last five hand
 out the experts' token counts as an output.
 
 These are fresh TPU-first definitions (bf16-friendly: ``dtype`` casts the
@@ -35,6 +37,7 @@ from . import zaya
 from . import qwen3_next
 from . import kanana2
 from . import keye_vl2
+from . import smallthinker
 
 _NETWORKS = {
     "transformer": transformer,
@@ -42,6 +45,7 @@ _NETWORKS = {
     "qwen3_next": qwen3_next,
     "kanana2": kanana2,
     "keye_vl2": keye_vl2,
+    "smallthinker": smallthinker,
     "mlp": mlp,
     "lenet": lenet,
     "alexnet": alexnet,

@@ -648,7 +648,7 @@ def softmax_activation(data, *, mode="instance"):
 # attention entirely, SURVEY.md §5.7; sequence-parallel forms live in
 # parallel/ring_attention.py)
 # ----------------------------------------------------------------------
-def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None):
+def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None, window=None):
     """Select the fused Pallas flash kernel.  MXNET_ATTN_IMPL:
     ``auto`` (default) = flash when the backend/geometry supports it,
     ``xla`` = force the materialized-softmax path (A/B runs),
@@ -663,24 +663,30 @@ def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None):
     values where it differs (latent attention: 192 and 128).  Values and
     the output fill whole lane tiles of 128; queries and keys at least
     one and then whole halves of one (the contraction of QK^T is padded
-    to the MXU's edge by the compiler, not by the caller).
+    to the MXU's edge by the compiler, not by the caller).  A band
+    (``window``, None for plain causal attention) is whole score blocks
+    of 512; one that is not is the only refusal counted under reason
+    ``flash-window``.
     It is never interpreted here: only a test passes ``interpret=True``
     to ``_flash_attention``."""
     import os
     from ..pallas.dispatch import _compiles_here, choose_impl
     here, why, reason = _compiles_here()
     v_dim = head_dim if v_dim is None else v_dim
-    supported = (here and head_dim >= 128 and head_dim % 64 == 0
-                 and v_dim % 128 == 0 and seq_len % 512 == 0
-                 and dtype in (jnp.bfloat16, jnp.float32))
+    causal = (here and head_dim >= 128 and head_dim % 64 == 0
+              and v_dim % 128 == 0 and seq_len % 512 == 0
+              and dtype in (jnp.bfloat16, jnp.float32))
+    band = window is None or (window > 0 and window % 512 == 0)
     return choose_impl(
         "MXNET_ATTN_IMPL", os.environ.get("MXNET_ATTN_IMPL", "auto"),
-        "flash", supported,
+        "flash", causal and band,
         why=f"{why or 'one TPU device'}, head_dim={head_dim}, "
-            f"v_dim={v_dim}, seq={seq_len}, dtype={dtype}; need a "
-            "one-device TPU program, head_dim>=128, head_dim%64==0, "
-            "v_dim%128==0, seq%512==0, bf16/f32",
-        fallback_reason=reason or "flash-geometry")
+            f"v_dim={v_dim}, seq={seq_len}, dtype={dtype}, "
+            f"window={window}; need a one-device TPU program, "
+            "head_dim>=128, head_dim%64==0, v_dim%128==0, seq%512==0, "
+            "window%512==0, bf16/f32",
+        fallback_reason=reason or ("flash-geometry" if not causal
+                                   else "flash-window"))
 
 
 def _flash_block_sizes(seq_len):
@@ -698,17 +704,24 @@ def _flash_block_sizes(seq_len):
 
 
 @_functools.lru_cache(maxsize=None)
-def _flash_kernel(q_heads, seq_len, interpret, residuals=False):
-    """jax's splash-attention forward kernel for one causal sequence,
-    built once per head count and length (the widths and the key/value
-    head count are the operands' own): the mask's block tables are host
-    numpy work at trace time, and every layer of a model asks for the
-    same ones.  With ``residuals`` the kernel returns ``(o, (lse,))``,
-    the rows' log-sum-exp beside the result, as a backward needs it."""
+def _flash_kernel(q_heads, seq_len, interpret, residuals=False, window=None):
+    """jax's splash-attention forward kernel for one causal, optionally
+    banded, sequence, built once per head count, length and window (the
+    widths and the key/value head count are the operands' own): the
+    mask's block tables are host numpy work at trace time, and every
+    layer of a model asks for the same ones.  With ``window`` the mask
+    is jax's ``LocalMask`` (``t - (window - 1) <= s <= t``) and its
+    tables drop the blocks left of the band: at 16 384 rows and a window
+    of 4096, 70 of the 136 causal 1024-row blocks.  With ``residuals``
+    the kernel returns ``(o, (lse,))``, the rows' log-sum-exp beside the
+    result, as a backward needs it."""
     import numpy as np
     from jax.experimental.pallas.ops.tpu.splash_attention import (
-        CausalMask, MultiHeadMask, make_splash_mha)
-    mask = MultiHeadMask([CausalMask((seq_len, seq_len))] * q_heads)
+        CausalMask, LocalMask, MultiHeadMask, make_splash_mha)
+    shape = (seq_len, seq_len)
+    one = CausalMask(shape) if window is None \
+        else LocalMask(shape, window_size=(window - 1, 0), offset=0)
+    mask = MultiHeadMask([one] * q_heads)
     # the kernel keeps its tables as arrays: made outside whatever trace
     # asks first, and kept on the host, they are constants of every
     # program that uses them
@@ -720,50 +733,107 @@ def _flash_kernel(q_heads, seq_len, interpret, residuals=False):
     return jax.tree_util.tree_map(np.asarray, kernel)
 
 
-def _flash_forward(q, k, v, interpret, residuals):
-    kernel = _flash_kernel(q.shape[1], q.shape[2], interpret, residuals)
-    with jax.named_scope("pallas.flash_attention"):
+def _flash_scope(window):
+    """The scope, and the launch counter's label, of the flash pair:
+    the band's kernels are counted apart from the causal ones."""
+    return "flash_attention" if window is None else "flash_attention_window"
+
+
+def _flash_forward(q, k, v, interpret, residuals, window=None):
+    # one cache entry a geometry: the causal kernel is asked for as ever
+    kernel = _flash_kernel(q.shape[1], q.shape[2], interpret, residuals,
+                           *(() if window is None else (window,)))
+    with jax.named_scope("pallas." + _flash_scope(window)):
         return jax.vmap(kernel)(q, k, v)
 
 
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash(q, k, v, interpret):
-    return _flash_forward(q, k, v, interpret, False)
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, interpret, window):
+    return _flash_forward(q, k, v, interpret, False, window)
 
 
-def _flash_fwd(q, k, v, interpret):
-    o, (lse,) = _flash_forward(q, k, v, interpret, True)
+def _flash_fwd(q, k, v, interpret, window):
+    o, (lse,) = _flash_forward(q, k, v, interpret, True, window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(interpret, res, do):
+def _flash_bwd(interpret, window, res, do):
     from ..pallas.flash_backward import flash_attention_backward
-    with jax.named_scope("pallas.flash_attention"):
-        return flash_attention_backward(*res, do, interpret=interpret)
+    with jax.named_scope("pallas." + _flash_scope(window)):
+        return flash_attention_backward(*res, do, window=window,
+                                        interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_attention(q, k, v, *, interpret=False):
-    """Causal attention of head-major q (B, Hq, S, D) over k (B, Hk, S,
-    D) and v (B, Hk, S, Dv), Hq a multiple of Hk and Dv = D unless the
-    values are narrower (the result is (B, Hq, S, Dv)): float32 scores,
-    statistics and accumulators whatever the operands' dtype, and a
-    key/value head shared by its Hq / Hk query heads inside the kernels
-    (no repeated K/V, dK and dV summed over the group in VMEM).  Forward
-    it is jax's splash Pallas kernel, which keeps the rows' log-sum-exp
-    where a gradient is asked for; backward it is the repo's own kernel
-    (``pallas/flash_backward.py``), which computes every score block on
-    or under the diagonal once and emits dq, dk, dv from it with no
-    partial sums in HBM.
+def _count_flash_blocks(batch, q_heads, seq_len, window, interpret):
+    """Books, at trace time, the 512 x 512 score blocks one banded flash
+    pair executes beside those its causal twin would
+    (``flash_blocks_walked`` / ``flash_blocks_causal``, by kernel):
+    forward what the kernel's own tables keep (its blocks counted in
+    512s) against the triangle of such blocks, backward the walk."""
+    import numpy as np
+    from ..pallas.dispatch import (FLASH_BLOCKS_CAUSAL, FLASH_BLOCKS_WALKED,
+                                   RETRACE_SUPPRESS)
+    from ..pallas.flash_backward import _BLOCK, blocks_walked
+    if RETRACE_SUPPRESS.on:     # a program-registry re-lower, as launches
+        return
+    table = _flash_kernel(q_heads, seq_len, interpret, True,
+                          window).fwd_mask_info.block_mask
+    bs = _flash_block_sizes(seq_len)
+    per = (bs.block_q // _BLOCK) * (bs.block_kv // _BLOCK)
+    rows = seq_len // bs.block_q
+    # one table serves all heads where they share a mask
+    kept = int(np.count_nonzero(table)) * (q_heads // table.shape[0])
+    for kernel, walked, causal in (
+            ("flash_attention_window", kept * per,
+             q_heads * per * rows * (rows + 1) // 2),
+            ("flash_attention_window_bwd",
+             q_heads * blocks_walked(seq_len, window),
+             q_heads * blocks_walked(seq_len))):
+        FLASH_BLOCKS_WALKED.labels(kernel=kernel).inc(batch * walked)
+        FLASH_BLOCKS_CAUSAL.labels(kernel=kernel).inc(batch * causal)
+
+
+def _flash_attention(q, k, v, *, window=None, interpret=False):
+    """Causal, optionally banded, attention of head-major q (B, Hq, S,
+    D) over k (B, Hk, S, D) and v (B, Hk, S, Dv), Hq a multiple of Hk
+    and Dv = D unless the values are narrower (the result is (B, Hq, S,
+    Dv)): float32 scores, statistics and accumulators whatever the
+    operands' dtype, and a key/value head shared by its Hq / Hk query
+    heads inside the kernels (no repeated K/V, dK and dV summed over the
+    group in VMEM).  Forward it is jax's splash Pallas kernel, which
+    keeps the rows' log-sum-exp where a gradient is asked for; backward
+    it is the repo's own kernel (``pallas/flash_backward.py``), which
+    computes every score block on or under the diagonal once (528 a head
+    at 16 384 rows) and emits dq, dk, dv from it with no partial sums in
+    HBM.
+
+    ``window`` (static; a multiple of 512, anything else raises): a
+    query attends the ``window`` keys that end with its own (``query -
+    key < window``), and neither kernel computes a block left of that
+    band (252 of the 528 at a window of 4096 backward; the forward's
+    1024-row tables keep 70 of 136).  ``None``, or a window no shorter
+    than the sequence, builds exactly the causal kernels.
 
     The kernels take no softmax scale: ``q`` carries it (a caller scales
     q where it is still float32, so q is rounded once).  ``interpret``
     is for the tests; ``_use_flash_attention`` never asks for it."""
     from ..pallas.attention import _count_launch
-    _count_launch("flash_attention")
-    return _flash(q, k, v, bool(interpret))
+    S = q.shape[2]
+    if window is not None:
+        window = int(window)
+        if window <= 0 or window % 512:
+            raise ValueError("flash attention: window=%d is not a positive "
+                             "multiple of 512" % window)
+        if window >= S:
+            window = None
+    _count_launch(_flash_scope(window))
+    if window is not None:
+        _count_flash_blocks(q.shape[0], q.shape[1], S, window,
+                            bool(interpret))
+    return _flash(q, k, v, bool(interpret), window)
 
 
 def _project_heads(spec, data, weight, bias, scale=None):
@@ -892,13 +962,14 @@ def fused_causal_self_attention(data, qkv_weight, qkv_bias, proj_weight,
                       proj_weight.reshape(d, H, D)) + proj_bias
 
 
-def _grouped_causal_attention(q, k, v, scale):
+def _grouped_causal_attention(q, k, v, scale, window=None):
     """Causal softmax attention of head-major q (B, Hq, S, D) over k
     (B, Hk, S, D) and v (B, Hk, S, Dv) by XLA, each key/value head
     shared by its Hq / Hk query heads; float32 scores, the probabilities
     in q's dtype; checkpointed, so the (S, S) scores are not kept for
-    the backward pass.  What the mixers run where the flash kernel
-    cannot."""
+    the backward pass.  With ``window`` a query attends the ``window``
+    keys that end with its own (``query - key < window``), any width.
+    What the mixers run where the flash kernel cannot."""
     B, Hq, S, D = q.shape
     Hk, Dv = k.shape[1], v.shape[3]
 
@@ -906,7 +977,9 @@ def _grouped_causal_attention(q, k, v, scale):
     def attn(q, k, v):
         s = jnp.einsum("bgrqe,bgke->bgrqk",
                        q.reshape(B, Hk, Hq // Hk, S, D), k) * scale
-        mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+        back = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+        mask = back >= 0 if window is None \
+            else (back >= 0) & (back < window)
         s = jnp.where(mask, s.astype(jnp.float32), -1e30)
         p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
         return jnp.einsum("bgrqk,bgke->bgrqe", p, v).reshape(B, Hq, S, Dv)
@@ -1096,6 +1169,68 @@ def gated_causal_self_attention(data, q_weight, k_weight, v_weight,
             .astype(data.dtype)
 
     with jax.named_scope("gattn.proj"):
+        return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
+
+
+@register("_contrib_GroupedQueryAttention",
+          aliases=("GroupedQueryAttention",))
+def grouped_query_attention(data, q_weight, k_weight, v_weight, o_weight, *,
+                            q_heads, kv_heads, head_dim, window=0,
+                            rotary=True, rope_theta=1e6):
+    """Plain grouped-query causal attention as one sublayer (B, S, d) ->
+    (B, S, d) on an already normalised stream: no bias, no q/k norm, no
+    gate.  ``q_weight`` (q_heads * head_dim, d), ``k_weight``,
+    ``v_weight`` (kv_heads * head_dim, d), ``q_heads / kv_heads`` query
+    heads to a key/value head (head j reads key/value head ``j //
+    (q_heads / kv_heads)``), scale ``head_dim ** -0.5``, then
+    ``o_weight`` (d, q_heads * head_dim).
+
+    ``rotary``: rotary position on all ``head_dim`` channels of queries
+    and keys (halves paired, ``rope_theta``, positions 0..S-1); False is
+    a layer with no position at all.  ``window`` > 0: a query attends
+    the ``window`` keys that end with its own (``query - key < window``);
+    0, or a window no shorter than the sequence, is full causal
+    attention.  A model mixes both kinds of layer by giving each its own
+    attributes.
+
+    Head-major like GatedCausalSelfAttention.  Where
+    ``_use_flash_attention`` allows it the Pallas flash pair runs the
+    core, banded where the layer is (q then carries the softmax scale:
+    from the projection's float32 accumulator on a layer without
+    position, from the rotation's float32 on one with); the checkpointed
+    XLA path with the band's mask otherwise (the CPU, a mesh, other
+    shapes; a window that is not whole blocks of 512 is counted under
+    ``pallas_fallbacks{reason="flash-window"}``).  The rotation is
+    linear: its backward needs the angles alone, which are made again
+    from the positions, and nothing of its input is kept.  Scopes:
+    ``gqa.proj``, ``gqa.rope``, and the core under ``gqa.window`` (a
+    band that bites) or ``gqa.full``."""
+    B, S, d = data.shape
+    Hq, Hk, D = int(q_heads), int(kv_heads), int(head_dim)
+    if Hq % Hk:
+        raise ValueError("q_heads %d not a multiple of kv_heads %d"
+                         % (Hq, Hk))
+    band = int(window) if 0 < int(window) < S else None
+    flash = _use_flash_attention(S, D, data.dtype, window=band)
+    sc = D ** -0.5
+    f32 = jnp.float32
+    turned = bool(rotary)
+    with jax.named_scope("gqa.proj"):
+        # the flash kernel takes no softmax scale: there q carries it
+        heads = lambda w, n, **kw: jnp.einsum(
+            "bsd,hed->bhse", data, w.reshape(n, D, d), **kw)
+        q = heads(q_weight, Hq) if turned or not flash else (heads(
+            q_weight, Hq, preferred_element_type=f32) * sc).astype(data.dtype)
+        k, v = heads(k_weight, Hk), heads(v_weight, Hk)
+    if turned:
+        with jax.named_scope("gqa.rope"):
+            turn = lambda t, scale: (_rotary_half(
+                t.astype(f32), D, float(rope_theta)) * scale).astype(t.dtype)
+            q, k = turn(q, sc if flash else 1.0), turn(k, 1.0)
+    with jax.named_scope("gqa.window" if band else "gqa.full"):
+        o = _flash_attention(q, k, v, window=band) if flash \
+            else _grouped_causal_attention(q, k, v, sc, band)
+    with jax.named_scope("gqa.proj"):
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
 
 
@@ -1759,17 +1894,19 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
                    down_weight=None, router_state=None, router_carry=None,
                    router_weight=None, shared_gate_weight=None,
                    shared_up_weight=None, shared_down_weight=None,
-                   shared_sg_weight=None, router_bias=None, *, num_experts,
-                   held_first=0, held_count=None, num_hidden,
+                   shared_sg_weight=None, router_bias=None, router_data=None,
+                   *, num_experts, held_first=0, held_count=None, num_hidden,
                    router_hidden=0, carry_in=True, router="zaya", top_k=1,
-                   shared_hidden=0, shared_gate=True, route_scale=1.0):
+                   shared_hidden=0, shared_gate=True, route_scale=1.0,
+                   router_stream=False, act="silu"):
     """The dropless expert sublayer of a chip that holds ``held_count``
     of ``num_experts`` experts (``held_first`` onwards), on an already
     normalised stream (..., d).  A token's ``top_k`` experts are chosen
     among ALL experts in float32; the (token, choice) pairs whose expert
     is held here are sorted and run through three grouped matrix
-    products (SiLU-gated FFN of width ``num_hidden``; stacks (held, out,
-    in)), each weighted by what the router gives it.  A pair whose
+    products (a gated FFN of width ``num_hidden``, ``down(act(gate x) *
+    up x)`` with ``act`` ``silu`` or ``relu``; stacks (held, out, in)),
+    each weighted by what the router gives it.  A pair whose
     expert lives on another chip adds 0.  No capacity, no dropped token
     (``SwitchMoE`` keeps its capacity semantics).
 
@@ -1780,7 +1917,13 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
     a softmax; the token's weight is its expert's probability.
     ``router="linear"``: ``softmax(h W^T)`` with ``router_weight``
     (num_experts, d), the ``top_k`` best, weights normalised over all
-    ``top_k`` whether held here or not.  ``router="sigmoid"``:
+    ``top_k`` whether held here or not; with ``router_stream=True`` it
+    reads the extra input ``router_data`` (the leading shape of ``data``:
+    another place of the residual stream, such as the layer's input
+    before attention) while the experts read ``data``, and the router's
+    gradient goes to ``router_data`` and never to ``data`` (the flag
+    says the input is there: a graph tells an input's presence from the
+    attributes).  ``router="sigmoid"``:
     ``sigmoid(h W^T)`` with the same ``router_weight``; the ``top_k``
     experts with the largest score PLUS ``router_bias`` (num_experts,),
     an auxiliary state that steers the choice, takes no gradient and
@@ -1807,6 +1950,9 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
         raise ValueError("expert stacks hold %d experts, held_count=%d"
                          % (gate_weight.shape[0], held))
     x = data.reshape(-1, d)
+    if (router_stream or act != "silu") and router != "linear":
+        raise ValueError("router_stream and act belong to router='linear', "
+                         "not %r" % (router,))
     if router == "zaya":
         if k != 1:
             raise ValueError("the zaya router is top-1, top_k=%d" % k)
@@ -1821,12 +1967,14 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
         second = r.reshape(lead + (R,))
     elif router in ("linear", "sigmoid"):
         with jax.named_scope("moe.router"):
-            chosen, weights = moe.linear_router(x, router_weight, k) \
+            chosen, weights = moe.linear_router(
+                router_data.reshape(-1, router_data.shape[-1])
+                if router_stream else x, router_weight, k) \
                 if router == "linear" else moe.sigmoid_router(
                     x, router_weight, router_bias, k, float(route_scale))
         y, counts = moe.dropless_topk_experts(
             x, chosen, weights, gate_weight, up_weight, down_weight, E,
-            int(held_first))
+            int(held_first), act=act)
         second = lax.stop_gradient(chosen).reshape(lead + (k,))
     else:
         raise ValueError("router=%r (zaya, linear or sigmoid)" % (router,))

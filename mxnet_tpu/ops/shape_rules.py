@@ -129,7 +129,7 @@ def _routed_experts_shapes(known, attrs):
             "down_weight": (held, d, h), "router_weight": (E, d),
             "shared_gate_weight": (Fs, d), "shared_up_weight": (Fs, d),
             "shared_down_weight": (d, Fs), "shared_sg_weight": (1, d),
-            "router_bias": (E,)}
+            "router_bias": (E,), "router_data": tuple(data)}
 
 
 _ZAYA_ROUTER = {"router_in_weight", "router_norm_gamma", "router_fc1_weight",
@@ -146,6 +146,8 @@ def _routed_experts_unused(attrs):
     if not attrs.get("shared_gate", True):
         out.add("shared_sg_weight")
     router = attrs.get("router", "zaya")
+    if not attrs.get("router_stream", False):
+        out.add("router_data")
     if router != "sigmoid":
         out.add("router_bias")
     if router == "zaya":
@@ -172,6 +174,19 @@ def _gated_attn_shapes(known, attrs):
 
 
 _set("_contrib_GatedCausalSelfAttention", _gated_attn_shapes)
+
+
+def _gqa_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    Hq, Hk, D = (int(attrs[k]) for k in ("q_heads", "kv_heads", "head_dim"))
+    return {"q_weight": (Hq * D, d), "k_weight": (Hk * D, d),
+            "v_weight": (Hk * D, d), "o_weight": (d, Hq * D)}
+
+
+_set("_contrib_GroupedQueryAttention", _gqa_shapes)
 
 
 def _latent_attn_shapes(known, attrs):

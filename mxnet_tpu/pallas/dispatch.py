@@ -38,6 +38,14 @@ PALLAS_FALLBACKS = _telemetry.REGISTRY.counter(
     "pallas_fallbacks",
     "auto-mode kernel selections that fell back to the XLA reference "
     "path, labeled by `reason`")
+FLASH_BLOCKS_WALKED = _telemetry.REGISTRY.counter(
+    "flash_blocks_walked",
+    "512 x 512 score blocks the banded flash kernels built into traced "
+    "programs execute a step, labeled by `kernel`")
+FLASH_BLOCKS_CAUSAL = _telemetry.REGISTRY.counter(
+    "flash_blocks_causal",
+    "512 x 512 score blocks the causal kernels would execute in the "
+    "banded ones' place, labeled by `kernel`")
 PALLAS_RETRACES = _telemetry.REGISTRY.counter(
     "pallas_kernel_retraces",
     "pallas kernel (re)builds — nonzero growth after warmup means a "

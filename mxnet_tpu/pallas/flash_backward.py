@@ -1,14 +1,23 @@
 """The training flash attention's backward as one Pallas kernel
 (docs/KERNELS.md).
 
-Causal attention of head-major q (B, Hq, S, D) over k (B, Hk, S, D) and
-v (B, Hk, S, Dv), Hq a multiple of Hk, q carrying the softmax scale: from
-the forward's result ``o``, its row log-sum-exp and the cotangent ``do``
-the kernel emits dq, dk and dv.  Every 512 x 512 score block on or under
-the diagonal is computed once (136 a head at 8192 rows, none above the
-diagonal), float32 scores, statistics and accumulators whatever the
-operands' dtype; p and ds are rounded to the operands' dtype for the
-products.
+Causal, optionally banded, attention of head-major q (B, Hq, S, D) over
+k (B, Hk, S, D) and v (B, Hk, S, Dv), Hq a multiple of Hk, q carrying the
+softmax scale: from the forward's result ``o``, its row log-sum-exp and
+the cotangent ``do`` the kernel emits dq, dk and dv.  Every 512 x 512
+score block on or under the diagonal is computed once (136 a head at
+8192 rows, 528 at 16 384, none above the diagonal), float32 scores,
+statistics and accumulators whatever the operands' dtype; p and ds are
+rounded to the operands' dtype for the products.
+
+**The band.**  With ``window`` (whole blocks: a query attends the
+``window`` keys that end with its own, ``query - key < window``) a query
+block ``i`` walks key blocks ``i - window / 512 .. i`` and nothing left
+of them: the leftmost under the band's mask (its keys right of the
+block's own diagonal), the ones between unmasked, its own under the
+causal mask; at 16 384 rows and a window of 4096 that is 252 of the 528
+causal blocks a head (:func:`blocks_walked`).  ``window=None`` is the
+causal kernel, line for line what it was.
 
 **No partial sums.**  A key/value head's rows stay in VMEM while its
 query heads' blocks pass by: k and v as they are, dk and dv as float32
@@ -90,10 +99,20 @@ def plan(seq_len, head_dim, v_dim, dtype, budget=_BUDGET):
     return Plan(segments, rows, transposed, row * rows + _WORKING)
 
 
-def _kernel(first, tiles, chained, transposed):
+def blocks_walked(seq_len, window=None):
+    """Score blocks the backward computes for one query head: every
+    block on or under the diagonal, or with ``window`` those of the
+    band, ``min(i, window / 512) + 1`` for query block ``i``."""
+    tiles = seq_len // _BLOCK
+    reach = tiles if window is None else window // _BLOCK
+    return sum(min(i, reach) + 1 for i in range(tiles))
+
+
+def _kernel(first, tiles, chained, transposed, reach=None):
     """The kernel of the pass that holds key/value blocks ``first ..
     first + tiles - 1``; ``chained`` where it starts from an earlier
-    pass's float32 dq; ``transposed`` as ``plan`` says."""
+    pass's float32 dq; ``transposed`` as ``plan`` says; ``reach`` the
+    band's width in blocks (None: every block under the diagonal)."""
     def kernel(*refs):
         q_ref, do_ref, lse_ref, di_ref, k_ref, v_ref = refs[:6]
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[6 + chained:][:6]
@@ -120,14 +139,15 @@ def _kernel(first, tiles, chained, transposed):
         # are (1, 512) rows that the sublanes share
         lse, di = lse_ref[...], di_ref[...]
 
-        def block(j, diagonal):
+        def block(j, allowed=None):
+            """Key/value block ``j``; ``allowed(key, query)`` masks it."""
             rows = block_of(j - first)
             k, v = k_ref[rows, :], v_ref[rows, :]
             s = lax.dot_general(k, q, _NT, preferred_element_type=_F32)
-            if diagonal:
+            if allowed is not None:
                 key = lax.broadcasted_iota(jnp.int32, s.shape, 0)
                 query = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                s = jnp.where(key <= query, s, _MASKED)
+                s = jnp.where(allowed(key, query), s, _MASKED)
             p = jnp.exp(s - lse)
             dv_acc[rows, :] += lax.dot(p.astype(do.dtype), do,
                                        preferred_element_type=_F32)
@@ -144,11 +164,22 @@ def _kernel(first, tiles, chained, transposed):
                 dq_acc[...] += lax.dot(ds.T, k, preferred_element_type=_F32)
 
         def before(j, carry):
-            block(j, False)
+            block(j)
             return carry
 
-        lax.fori_loop(first, jnp.minimum(i, first + tiles), before, 0)
-        pl.when((i >= first) & (i < first + tiles))(lambda: block(i, True))
+        causal = lambda key, query: key <= query
+
+        if reach is None:
+            lax.fori_loop(first, jnp.minimum(i, first + tiles), before, 0)
+        else:
+            # the band's leftmost block lies ``reach`` blocks back: there
+            # query - key < window is key > query inside the block
+            left = i - reach
+            pl.when((left >= first) & (left < first + tiles))(
+                lambda: block(left, lambda key, query: key > query))
+            lax.fori_loop(jnp.maximum(first, left + 1),
+                          jnp.minimum(i, first + tiles), before, 0)
+        pl.when((i >= first) & (i < first + tiles))(lambda: block(i, causal))
         dq_ref[...] = turned(dq_acc[...]).astype(dq_ref.dtype)
 
         @pl.when((g == pl.num_programs(2) - 1)
@@ -168,11 +199,12 @@ def _kernel(first, tiles, chained, transposed):
 
 # Jitted on its own, as the delta rule's: a model's layers of one
 # geometry share ONE trace and ONE lowering of the kernel.
-@functools.partial(jax.jit, static_argnums=(7, 8, 9))
-def _run_pass(q, k, v, do, lse, di, dq, segment, z, interpret):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _run_pass(q, k, v, do, lse, di, dq, segment, z, interpret, window=None):
     """One pass of the ``Plan`` ``z``: (dq, dk, dv) of key/value segment
     ``segment``; ``dq`` is the earlier passes' float32 sum or None, and
-    the result's dq is float32 unless the pass is the last."""
+    the result's dq is float32 unless the pass is the last.  ``window``
+    (whole blocks, or None) is the band."""
     B, Hq, S, D = q.shape
     Hk, Dv = k.shape[1], v.shape[3]
     G, tiles = Hq // Hk, z.rows // _BLOCK
@@ -186,9 +218,11 @@ def _run_pass(q, k, v, do, lse, di, dq, segment, z, interpret):
     of_kv = lambda width, at: pl.BlockSpec(
         (None, None, z.rows, width), lambda b, h, g, i: (b, h, at, 0))
     across = lambda shape: shape[::-1] if z.transposed else shape
-    _count_launch("flash_attention_bwd")
+    _count_launch("flash_attention_bwd" if window is None
+                  else "flash_attention_window_bwd")
     return pl.pallas_call(
-        _kernel(segment * tiles, tiles, chained, z.transposed),
+        _kernel(segment * tiles, tiles, chained, z.transposed,
+                None if window is None else window // _BLOCK),
         grid=(B, Hk, G, S // _BLOCK),
         in_specs=[of_q(D), of_q(Dv), stat, stat,
                   of_kv(D, segment), of_kv(Dv, segment)]
@@ -212,12 +246,20 @@ def _run_pass(q, k, v, do, lse, di, dq, segment, z, interpret):
     )(q, do, lse, di, k, v, *([dq] if chained else []))
 
 
-def flash_attention_backward(q, k, v, o, lse, do, *, interpret=False):
-    """(dq, dk, dv) of causal attention: ``o`` (B, Hq, S, Dv) and ``lse``
-    (B, Hq, S) float32 are the forward's result and row log-sum-exp,
-    ``do`` the result's cotangent.  S is whole score blocks of 512, D and
-    Dv what the forward's gate admits."""
+def flash_attention_backward(q, k, v, o, lse, do, *, window=None,
+                             interpret=False):
+    """(dq, dk, dv) of causal, optionally banded, attention: ``o`` (B,
+    Hq, S, Dv) and ``lse`` (B, Hq, S) float32 are the forward's result
+    and row log-sum-exp, ``do`` the result's cotangent.  S is whole score
+    blocks of 512 (528 on or under the diagonal a head at 16 384 rows),
+    D and Dv what the forward's gate admits.  ``window`` (a multiple of
+    512, or None): a query attends the ``window`` keys that end with its
+    own, and the blocks left of that band are never computed (252 of the
+    528 at a window of 4096)."""
     B, Hq, S, D = q.shape
+    if window is not None and (window <= 0 or window % _BLOCK):
+        raise ValueError("pallas flash backward: window=%r is not whole "
+                         "blocks of %d" % (window, _BLOCK))
     if S % _BLOCK or Hq % k.shape[1]:
         raise ValueError("pallas flash backward: S=%d is not whole blocks "
                          "of %d, or %d query heads are not whole groups of "
@@ -231,7 +273,7 @@ def flash_attention_backward(q, k, v, o, lse, do, *, interpret=False):
     dq, dks, dvs = None, [], []
     for segment in range(z.segments):
         dq, dk, dv = _run_pass(q, k, v, do, as_rows(lse), as_rows(di), dq,
-                               segment, z, bool(interpret))
+                               segment, z, bool(interpret), window)
         dks.append(dk)
         dvs.append(dv)
     if z.segments == 1:

@@ -312,16 +312,20 @@ def _row_buckets(tokens, k, held, num_experts):
     return [size] if size == worst else [size, worst]
 
 
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
-                          num_experts, held_first=0, impl=None):
-    """SiLU-gated expert FFNs for the experts held here, ``k`` choices a
+                          num_experts, held_first=0, impl=None, act="silu"):
+    """Gated expert FFNs for the experts held here (``act`` the gate's
+    activation, ``silu`` or ``relu``), ``k`` choices a
     token, nothing dropped.  ``x`` (N, d) tokens; ``experts`` (N, k)
     int32 among ALL ``num_experts`` and ``weights`` (N, k) float32, a
     token's choices and what each counts; ``w_gate``/``w_up`` (held,
     hidden, d), ``w_down`` (held, d, hidden) are experts ``held_first ..
     held_first + held - 1``.  Returns ``(y (N, d), (token, choice) pairs
     an expert int32 (num_experts,))`` with ``y = sum over a token's
-    choices e held here of w_e * down_e(silu(gate_e x) * up_e x)``; a
+    choices e held here of w_e * down_e(act(gate_e x) * up_e x)``; a
     choice whose expert is elsewhere adds 0.  The gradient reaches the
     router through ``weights``.
 
@@ -339,6 +343,9 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
     k = experts.shape[1]
     held = w_gate.shape[0]
     E, first = int(num_experts), int(held_first)
+    if act not in _GATES:
+        raise ValueError("act=%r (one of %s)" % (act, sorted(_GATES)))
+    gate = _GATES[act]
     f32 = jnp.float32
     with jax.named_scope("moe.dispatch"):
         pairs = experts.reshape(N * k)
@@ -380,7 +387,7 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
         with jax.named_scope("moe.experts"):
             g = own(grouped_matmul(xs, wg, sizes_here, how))
             u = own(grouped_matmul(xs, wu, sizes_here, how))
-            mid = own((jax.nn.silu(g.astype(f32)) * u.astype(f32))
+            mid = own((gate(g.astype(f32)) * u.astype(f32))
                       .astype(x.dtype))
             ys = own(grouped_matmul(mid, wd, sizes_here, how))
         with jax.named_scope("moe.combine"):

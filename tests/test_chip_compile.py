@@ -608,6 +608,110 @@ def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
     assert total < 15e9
 
 
+def test_banded_flash_pair_fwd_grad_compiles(one_chip):
+    """The flash pair with a band at the SmallThinker cell's geometry:
+    one sequence of 16 384, 28 query heads to 4 key/value heads of 128,
+    window 4096.  Both kernels are in the program (the splash forward
+    under a ``LocalMask``, the repo's backward walking the band), and
+    the backward holds a key/value head's whole 16 384 rows of k, v and
+    of the float32 dk, dv in VMEM (50.3 MB in one segment: where an
+    overrun would show).  The causal pair at the same geometry beside
+    it: the model's full layers run it at a length no cell had."""
+    from mxnet_tpu.ops.nn import _flash_attention
+    from mxnet_tpu.pallas import flash_backward as fb
+    q = ((1, 28, 16384, 128), jnp.bfloat16)
+    kv = ((1, 4, 16384, 128), jnp.bfloat16)
+    z = fb.plan(16384, 128, 128, jnp.bfloat16)
+    assert (z.segments, z.rows) == (1, 16384)
+    for window in (4096, None):
+        def loss(q, k, v):
+            return _flash_attention(q, k, v, window=window) \
+                .astype(jnp.float32).sum()
+
+        text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                        one_chip, q, kv, kv).as_text()
+        assert "splash_mha_fwd" in text
+        assert "flash_attention_backward" in text
+        assert "splash_mha_dkv" not in text
+
+
+def test_smallthinker_fit_program_compiles_and_fits_the_chip(one_chip,
+                                                             monkeypatch):
+    """The fused fit program of the cell ``smallthinker_21b_train_s16k``
+    at its own sizes (4 layers: one full without position, three of
+    window 4096 with rotary; 16 of 64 experts held, 18 992 rows of the
+    vocabulary, one sequence of 16 384 tokens, bf16 with f32 masters),
+    compiled for the described chip with the kernels the chip would
+    choose: the flash pair, banded in three layers, and the Pallas
+    grouped matmul (nothing fell back).  ``memory_analysis`` (arguments
+    + outputs - aliased + temporaries) stays under 15 GB of the chip's
+    16: the configuration's ``reduced_why`` quotes the number printed
+    here."""
+    import json
+    import os
+
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.pallas import dispatch
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_grouped_matmul_impl",
+                        lambda *a, **k: "compiled")
+    # `auto` as on the chip: a one-device TPU program
+    monkeypatch.setattr(dispatch, "_compiles_here", lambda: (True, "", None))
+    fallbacks = lambda: sum(c.value
+                            for c in dispatch.PALLAS_FALLBACKS.children())
+    launches = lambda name: dispatch.PALLAS_LAUNCHES.labels(kernel=name).value
+    before = fallbacks()
+    banded, causal = (launches("flash_attention_window"),
+                      launches("flash_attention"))
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    with open(os.path.join(root, "benchmark", "configs",
+                           "smallthinker_21b_train.json")) as f:
+        cfg = json.load(f)
+    kw = cfg["kwargs"]
+    S = kw["seq_len"]
+    assert (kw["num_layers"], kw["experts_held"], kw["window"], S) \
+        == (4, [0, 16], 4096, 16384)
+    mod = mx.Module(mx.models.get_symbol(cfg["model"], **kw),
+                    context=mx.cpu())
+    mod.bind(data_shapes=[("data", (1, S))],
+             label_shapes=[("softmax_label", (S,))])
+    mod.init_params(mx.init.Zero())
+    mod.init_optimizer(optimizer=cfg["optimizer"], optimizer_params=dict(
+        cfg["optimizer_params"], multi_precision=True))
+    tokens = np.arange(S, dtype=np.float32) % kw["num_classes"]
+    batch = mx.io.DataBatch(data=[mx.nd.array(tokens.reshape(1, S))],
+                            label=[mx.nd.array(tokens)])
+    fn, args, _ = mod._get_fused_fit()._prepare(batch,
+                                                mx.metric.create("ce"))
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+    with jax.default_matmul_precision("default"):
+        compiled = fn.lower(*specs).compile()
+    text = compiled.as_text()
+    assert "gmm" in text and "ragged" not in text
+    assert "splash_mha_fwd" in text and "flash_attention_backward" in text
+    for scope in ("gqa.proj", "gqa.rope", "gqa.window", "gqa.full"):
+        assert scope in text, scope
+    assert fallbacks() == before
+    # three banded layers to one full, however often the step is traced
+    banded, causal = (launches("flash_attention_window") - banded,
+                      launches("flash_attention") - causal)
+    assert causal >= 1 and banded == 3 * causal
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print("smallthinker fit program: arguments %.2f GB, outputs %.2f, "
+          "aliased %.2f, temporaries %.2f: %.2f GB"
+          % tuple(b / 1e9 for b in (
+              m.argument_size_in_bytes, m.output_size_in_bytes,
+              m.alias_size_in_bytes, m.temp_size_in_bytes, total)))
+    assert total < 15e9
+
+
 @pytest.mark.parametrize("cell,S_,D,Dv,rows,buckets", [
     ("cgpt13b_train_s2048", 2048, 128, None, None, None),
     ("zaya1_8b_train_ep2", 8192, 128, None, (8192, 1, 8, 16), [8192]),
@@ -617,6 +721,8 @@ def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
      [8192, 49152]),
     ("keyevl2_30b_train_ep8", 16384, None, None, (16384, 8, 16, 128),
      [20480, 131072]),
+    ("smallthinker_21b_train_s16k", 16384, 128, None, (16384, 6, 16, 64),
+     [30720, 98304]),
 ])
 def test_accepted_cells_geometries_give_what_they_gave(cell, S_, D, Dv, rows,
                                                        buckets):
