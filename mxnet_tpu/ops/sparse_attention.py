@@ -24,35 +24,44 @@ from the six it goes on through the maker's own.
 How the S x S work is done.  A block of ``q_chunk`` query rows at a
 time (``lax.map`` forward, ``lax.scan`` backward), because a block's
 choice is made from its whole (q_chunk, S) row of scorer scores before
-its cores can run: nothing S x S x heads is ever whole.  The scorer's
-row is made a chunk of keys at a time up to the block's own diagonal (a
-``fori_loop`` with the block's trip count, float32 at ``HIGHEST``); the
-choice is the ``topk``-th largest by bisection over the scores' bit
+its cores can run: nothing S x S x heads is ever whole.  The choice is
+the ``topk``-th largest of that row by bisection over the scores' bit
 patterns (32 counting passes, no sort), then the tie rule.  It is kept
 for the backward pass as bits (S x S / 8 bytes), so it is never made
 twice and cannot come out differently.
 
-The heads' cores (scope ``dsa.attention``) have two forms that share
-everything else (``plan``, ``choose``, the bits, the scorer and the
-index loss) and are chosen from the program, not by a user
-(:func:`_cores_impl`; a test passes ``impl``):
+The scorer's row and its gradients (scopes ``dsa.indexer``,
+``dsa.index_loss``) and the heads' cores (scope ``dsa.attention``) have
+two forms that share everything else (``plan``, ``choose``, the bits,
+the log-sum-exp and the KL of the index loss) and are chosen together
+from the program, not by a user (:func:`_cores_impl`; a test passes
+``impl``):
 
-* in a one-device TPU program whose shapes they take, the kernels of
-  ``pallas/sparse_attention.py``: the block's mask goes in as an int8
-  operand, every (kv_chunk x q_chunk) score tile up to the diagonal
-  lives in VMEM only, forward two walks over the key tiles in one call
-  (the heads' row statistics; then the exact probabilities, ``p v`` and
-  the head-mean probabilities ``pt`` as a (q_chunk, S) row), backward
-  one walk that emits dq, adds into the float32 dk and dv the scan
-  carries (in place) and hands back the same ``pt`` row.  The index loss
-  reads ``pt`` in XLA: forward the KL as one pass over the block's row,
-  backward ``dI`` a chunk of keys at a time beside the scorer's
-  recomputed products;
-* everywhere else (the CPU, a mesh, other shapes), plain XLA: forward
-  two passes over chunks of keys (statistics, then probabilities, result
-  and the KL terms), backward one pass that recomputes the heads' scores
-  and the scorer's and emits every gradient, float32 score arrays
-  (heads, q_chunk, chunk) in memory.
+* in a one-device TPU program whose shapes they take, two Pallas kernel
+  pairs in which no array with a head axis and a key axis reaches HBM.
+  ``pallas/index_scorer.py``: forward a block's row ``I`` a 512-key tile
+  at a time up to the diagonal, the heads' products behind the ReLU
+  summed in VMEM; backward the products again, kept in VMEM beside
+  ``dI = on * (exp(I - lse_i) - pt)``, and from them ``g`` (which gives
+  ``dqi`` and ``dwi``) and the keys' gradient, added in place into the
+  (Di, S) float32 sum the scan carries.  Its products are float32 as
+  ``HIGHEST`` makes them, the six bfloat16 terms of each laid side by
+  side so the MXU runs full.  ``pallas/sparse_attention.py``: the
+  block's mask goes in as an int8 operand, every (kv_chunk x q_chunk)
+  score tile up to the diagonal lives in VMEM only, forward two walks
+  over the key tiles in one call (the heads' row statistics; then the
+  exact probabilities, ``p v`` and the head-mean probabilities ``pt`` as
+  a (q_chunk, S) row), backward one walk that emits dq, adds into the
+  float32 dk and dv the scan carries (in place) and hands back the same
+  ``pt`` row, which the scorer's backward kernel reads.  XLA keeps the
+  choice, ``lse_i`` and the KL, each one pass over the block's rows;
+* everywhere else (the CPU, a mesh, other shapes), plain XLA: the
+  scorer's row a chunk of keys at a time up to the block's diagonal (a
+  ``fori_loop`` with the block's trip count, float32 at ``HIGHEST``);
+  the cores forward two passes over chunks of keys (statistics, then
+  probabilities, result and the KL terms), backward one pass that
+  recomputes the heads' scores and the scorer's and emits every
+  gradient, float32 score arrays (heads, q_chunk, chunk) in memory.
 
 Either way every S x S product runs over ALL causal pairs under the
 mask: at 16 384 tokens and 2048 keys 23 % of that work is chosen pairs
@@ -65,6 +74,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..pallas import index_scorer as scorer
 from ..pallas import sparse_attention as kernels
 
 _HI = lax.Precision.HIGHEST
@@ -165,18 +175,23 @@ def _kl_rows(on, pt, logq):
 def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile, impl):
     """Padded operands in, ``(o, L, live)`` out; ``S`` is the real
     length, ``tile`` the key width of a counted tile, ``impl`` how the
-    heads' cores run (:func:`_cores_impl`)."""
+    S x S work runs (:func:`_cores_impl`)."""
     Hq, Sp, D = q.shape
     Hk = k.shape[0]
     R, nq = Hq // Hk, Sp // bq
     scale = D ** -0.5
     col = jnp.arange(Sp, dtype=jnp.int32)
+    interpret = impl == "interpret"
+    if impl:        # the scorer's keys as bfloat16 parts, split once
+        with jax.named_scope("dsa.indexer"):
+            kcat = scorer.keys(ki)
 
     def block(xs):
         i, qb = xs
         r0 = i * bq
         row = r0 + jnp.arange(bq, dtype=jnp.int32)
         n_c = (r0 + bq + kc - 1) // kc          # key chunks to the diagonal
+        tiles = (r0 + bq + tile - 1) // tile    # key tiles to the diagonal
         qib, wib = _rows(qi, r0, bq, 1), _rows(wi, r0, bq, 0)
         causal = col[None, :] <= row[:, None]
 
@@ -186,8 +201,12 @@ def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile, impl):
             return lax.dynamic_update_slice_in_dim(acc, ic, c * kc, 1)
 
         with jax.named_scope("dsa.indexer"):
-            ib = lax.fori_loop(0, n_c, score,
-                               jnp.zeros((bq, Sp), jnp.float32))
+            if impl:    # in VMEM: pallas/index_scorer.py
+                ib = scorer.forward(qib, wib, kcat, tiles, tile,
+                                    interpret=interpret)
+            else:
+                ib = lax.fori_loop(0, n_c, score,
+                                   jnp.zeros((bq, Sp), jnp.float32))
         with jax.named_scope("dsa.select"):
             chosen = lax.cond(r0 + bq > topk,
                               lambda: choose(ib, causal, topk),
@@ -226,9 +245,8 @@ def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile, impl):
         if impl:        # 2. and 3. in VMEM: pallas/sparse_attention.py
             with jax.named_scope("dsa.attention"):
                 o, lse, pt = kernels.forward(
-                    qb, k, v, chosen.astype(jnp.int8),
-                    (r0 + bq + tile - 1) // tile, tile,
-                    interpret=impl == "interpret")
+                    qb, k, v, chosen.astype(jnp.int8), tiles, tile,
+                    interpret=interpret)
             with jax.named_scope("dsa.index_loss"):
                 kl = _kl_rows(chosen, pt, ib - lse_i[:, None])
         else:
@@ -265,6 +283,35 @@ def _core_bwd(q, k, v, qi, ki, wi, o, lse, lse_i, bits, do, dl, S, bq, kc,
     with jax.named_scope("dsa.attention"):
         delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1) \
             .reshape(Hk, R, nq, bq).transpose(2, 0, 1, 3)
+    interpret = impl == "interpret"
+    if impl:        # the scorer's keys as bfloat16 parts, split once
+        with jax.named_scope("dsa.index_loss"):
+            kcat, kg = scorer.keys(ki), scorer.gradient_keys(ki)
+
+    def block_in_vmem(carry, xs):
+        """A block's gradients with every S x S array in VMEM: the
+        cores' kernel, then the scorer's on the ``pt`` row it hands
+        back.  The keys' scorer gradient is carried transposed, for a
+        loss cotangent of 1."""
+        dk, dv, dki_t = carry
+        i, qb, dob, delta_b, lse_b, lse_ib, bits_b = xs
+        r0 = i * bq
+        tiles = (r0 + bq + tile - 1) // tile
+        qib, wib = _rows(qi, r0, bq, 1), _rows(wi, r0, bq, 0)
+        on = _unpack(bits_b, kc)
+        with jax.named_scope("dsa.attention"):
+            dq, dk, dv, pt_b = kernels.backward(
+                qb, dob, lse_b, delta_b, on.astype(jnp.int8), k, v, dk, dv,
+                tiles, tile, interpret=interpret)
+        with jax.named_scope("dsa.index_loss"):
+            if S < Sp:      # the padded rows carry no loss
+                on = on & (r0 + jnp.arange(bq) < S)[:, None]
+            g, dki_t = scorer.backward(
+                qib, wib, lse_ib, on.astype(jnp.int8), pt_b, kcat, kg,
+                dki_t, tiles, tile, interpret=interpret)
+            dqi = dl * wib.T[:, :, None] * g
+            dwi = dl * jnp.sum(qib * g, axis=-1).T
+        return (dk, dv, dki_t), (dq, dqi, dwi)
 
     def block(carry, xs):
         dk, dv, dki = carry
@@ -295,28 +342,15 @@ def _core_bwd(q, k, v, qi, ki, wi, o, lse, lse_i, bits, do, dl, S, bq, kc,
                               preferred_element_type=f32)
             return (dq, add(dk, dk_c, c, 1), add(dv, dv_c, c, 1)), p
 
-        if impl:        # the whole block's, in VMEM
-            with jax.named_scope("dsa.attention"):
-                dq, dk, dv, pt_b = kernels.backward(
-                    qb, dob, lse_b, delta_b,
-                    _unpack(bits_b, kc).astype(jnp.int8), k, v, dk, dv,
-                    (r0 + bq + tile - 1) // tile, tile,
-                    interpret=impl == "interpret")
-            main = ()
-        else:
-            main = (jnp.zeros((Hk, R, bq, D), f32), dk, dv)
-
         def chunk(c, acc):
             main, (dqi, dwi, dki) = acc
             on = _unpack(_rows(bits_b, c * (kc // 8), kc // 8, 1))
-            if not impl:
-                with jax.named_scope("dsa.attention"):
-                    main, p = attend(c, on, main)
+            with jax.named_scope("dsa.attention"):
+                main, p = attend(c, on, main)
             with jax.named_scope("dsa.index_loss"):
                 ki_c = _rows(ki, c * kc, kc, 0)
                 z, ic = _scorer_chunk(qib, ki_c, wib)
-                pt = _rows(pt_b, c * kc, kc, 1) if impl \
-                    else jnp.sum(p, axis=(0, 1)) * (1.0 / Hq)
+                pt = jnp.sum(p, axis=(0, 1)) * (1.0 / Hq)
                 di = jnp.where(on & valid,
                                dl * (jnp.exp(ic - lse_ib[:, None]) - pt), 0.0)
                 dwi = dwi + jnp.sum(di[None] * jax.nn.relu(z), axis=-1).T
@@ -328,18 +362,20 @@ def _core_bwd(q, k, v, qi, ki, wi, o, lse, lse_i, bits, do, dl, S, bq, kc,
                                    preferred_element_type=f32)
             return main, (dqi, dwi, add(dki, dki_c, c, 0))
 
-        main, (dqi, dwi, dki) = lax.fori_loop(
-            0, n_c, chunk, (main, (jnp.zeros((Hi, bq, Di), f32),
-                                   jnp.zeros((bq, Hi), f32), dki)))
-        if not impl:
-            dq, dk, dv = main
+        (dq, dk, dv), (dqi, dwi, dki) = lax.fori_loop(
+            0, n_c, chunk, ((jnp.zeros((Hk, R, bq, D), f32), dk, dv),
+                            (jnp.zeros((Hi, bq, Di), f32),
+                             jnp.zeros((bq, Hi), f32), dki)))
         return (dk, dv, dki), (dq.astype(q.dtype), dqi, dwi)
 
     xs = (jnp.arange(nq), blocks(q), blocks(do), delta,
           lse, lse_i, bits)
     (dk, dv, dki), (dq, dqi, dwi) = lax.scan(
-        block, (jnp.zeros((Hk, Sp, D), f32), jnp.zeros((Hk, Sp, D), f32),
-                jnp.zeros((Sp, Di), f32)), xs)
+        block_in_vmem if impl else block,
+        (jnp.zeros((Hk, Sp, D), f32), jnp.zeros((Hk, Sp, D), f32),
+         jnp.zeros((Di, Sp) if impl else (Sp, Di), f32)), xs)
+    if impl:
+        dki = dl * dki.T
     dq = dq.transpose(1, 2, 0, 3, 4).reshape(Hq, Sp, D)
     dqi = dqi.transpose(1, 0, 2, 3).reshape(Hi, Sp, Di)
     return (dq, dk.astype(k.dtype), dv.astype(v.dtype), dqi, dki,
@@ -370,18 +406,21 @@ def _attend_bwd(front, static, res, grads):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
-def _cores_impl(q, k, bq, tile, S_pad):
-    """How the heads' cores run when not told: the Pallas kernels
-    (``"compiled"``) in a one-device TPU program whose shapes they take
-    (``pallas/sparse_attention.py`` ``supported``), else the XLA loops
-    (False; counted in ``pallas_fallbacks{reason}``).  No knob: a test
-    passes ``impl``."""
+def _cores_impl(q, k, qi, bq, tile, S_pad):
+    """How the S x S work (the heads' cores and the index scorer) runs
+    when not told: the Pallas kernels (``"compiled"``) in a one-device
+    TPU program whose shapes both pairs take (``supported`` of
+    ``pallas/sparse_attention.py`` and of ``pallas/index_scorer.py``),
+    else the XLA loops (False; counted in ``pallas_fallbacks{reason}``).
+    One decision and no knob: a test passes ``impl``."""
     from ..pallas.dispatch import _compiles_here, choose_impl
     here, why, reason = _compiles_here()
     fits, shapes = kernels.supported(q, k, bq, tile, S_pad)
+    fits_i, shapes_i = scorer.supported(qi, bq, tile, S_pad)
     return choose_impl(
         "sparse_indexed_attention (no knob)", "auto", "sparse_attention",
-        here and fits, why="%s, %s" % (why or "one TPU device", shapes),
+        here and fits and fits_i,
+        why="%s, %s %s" % (why or "one TPU device", shapes, shapes_i),
         fallback_reason=reason or "sparse-attention-geometry")
 
 
@@ -395,11 +434,11 @@ def sparse_indexed_attention(front, operands, *, topk, q_chunk=512,
     is made again in the backward pass and not kept: between the passes
     a layer holds ``operands``, ``o``, the rows' statistics and the
     choice as bits."""
-    q, k = jax.eval_shape(front, *operands)[:2]
+    q, k, _, qi = jax.eval_shape(front, *operands)[:4]
     S = q.shape[2]
     bq, tile, kc, Sp = plan(S, q_chunk, kv_chunk)
     if impl is None:
-        impl = _cores_impl(q, k, bq, tile, Sp)
+        impl = _cores_impl(q, k, qi, bq, tile, Sp)
 
     def padded(*operands):
         q, k, v, qi, ki, wi = front(*operands)

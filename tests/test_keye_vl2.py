@@ -2,8 +2,8 @@
 sparse indexed attention against the plain reference
 (benchmark/reference/keye_vl2.py), forward, the index loss and every
 gradient, over several query blocks and at a padded length, with the
-heads' cores as the XLA loops and once more as the Pallas kernels in
-interpret mode (``cores``); the choice against a sort on the host, planted ties included; with ``topk >= S`` the
+heads' cores and the scorer as the XLA loops and once more as the Pallas
+kernels in interpret mode (``cores``); the choice against a sort on the host, planted ties included; with ``topk >= S`` the
 operator is dense grouped causal attention; the two objectives keep to
 their own leaves EXACTLY (the cross-entropy gives the scorer's leaves
 zero, the index loss gives every other leaf zero); the share test of the
@@ -52,8 +52,9 @@ def ref(monkeypatch):
 
 @pytest.fixture(params=["xla", "kernels"])
 def cores(request, monkeypatch):
-    """The heads' cores as the CPU chooses them (the XLA loops), and as
-    ``pallas/sparse_attention.py``'s kernels in interpret mode, through
+    """The S x S work as the CPU chooses it (the XLA loops), and as the
+    kernels of ``pallas/sparse_attention.py`` (the heads' cores) and
+    ``pallas/index_scorer.py`` (the scorer) in interpret mode, through
     the operator's whole ``custom_vjp``."""
     if request.param == "kernels":
         from mxnet_tpu.ops import sparse_attention

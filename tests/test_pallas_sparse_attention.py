@@ -175,6 +175,10 @@ def _cell_shapes(D=128, dtype=jnp.bfloat16, S=16384):
             jax.ShapeDtypeStruct((1, 4, S, D), dtype))
 
 
+def _scorer_shape(Di=64, Hi=16, S=16384):
+    return jax.ShapeDtypeStruct((1, Hi, S, Di), jnp.float32)
+
+
 @pytest.mark.parametrize("why,kw,plan", [
     ("narrow heads", dict(D=64), (512, 512, 16384)),
     ("half precision of another kind", dict(dtype=jnp.float16),
@@ -229,15 +233,25 @@ def test_the_choice_is_counted_and_has_no_knob(monkeypatch):
     assert dict(os.environ) == environ
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert sa._cores_impl(*_cell_shapes(), 512, 512, 16384) == "compiled"
+    cell = (512, 512, 16384)
+    assert sa._cores_impl(*_cell_shapes(), _scorer_shape(), *cell) \
+        == "compiled"
     before = count("sparse-attention-geometry")
-    assert sa._cores_impl(*_cell_shapes(D=192), 512, 512, 16384) is False
-    assert sa._cores_impl(*_cell_shapes(), 8, 8, 16384) is False
-    assert count("sparse-attention-geometry") == before + 2
+    assert sa._cores_impl(*_cell_shapes(D=192), _scorer_shape(),
+                          *cell) is False
+    assert sa._cores_impl(*_cell_shapes(), _scorer_shape(), 8, 8,
+                          16384) is False
+    # the scorer's kernels take a width of 64 and what VMEM holds
+    assert sa._cores_impl(*_cell_shapes(), _scorer_shape(Di=128),
+                          *cell) is False
+    assert sa._cores_impl(*_cell_shapes(), _scorer_shape(Hi=32),
+                          *cell) is False
+    assert count("sparse-attention-geometry") == before + 4
     before = count("mesh")
     mx.sharding.set_mesh({"dp": 4, "mp": 2})
     try:
-        assert sa._cores_impl(*_cell_shapes(), 512, 512, 16384) is False
+        assert sa._cores_impl(*_cell_shapes(), _scorer_shape(),
+                              *cell) is False
     finally:
         mx.sharding.set_mesh(None)
     assert count("mesh") == before + 1
