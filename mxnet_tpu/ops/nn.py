@@ -1908,7 +1908,12 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
     up x)`` with ``act`` ``silu`` or ``relu``; stacks (held, out, in)),
     each weighted by what the router gives it.  A pair whose
     expert lives on another chip adds 0.  No capacity, no dropped token
-    (``SwitchMoE`` keeps its capacity semantics).
+    (``SwitchMoE`` keeps its capacity semantics).  With ``top_k > 1`` a
+    token's weighted rows are added in token order, in float32 (the
+    combine; the gradient of the gather that fetched the token's rows is
+    the same sum): beside the kernels of the grouped products the
+    token-ordered sum of ``pallas/token_sum.py``, scope
+    ``pallas.token_sum``, else XLA's ``scatter-add``.
 
     ``router="zaya"`` (top-1 only): the state ``r = h W_in + carry *
     r_prev`` takes the previous layer's state (``router_state`` and the

@@ -295,13 +295,99 @@ def grouped_matmul(x, w, group_sizes, impl=None):
                              interpret=impl == "interpret")
 
 
+def _token_sum_impl(how, rows, tokens, width, dtype):
+    """How the sums over a token's rows run where ``k > 1``, given the
+    grouped products' decision ``how``: with the compiled kernels, the
+    token-ordered sum of ``pallas/token_sum.py`` where it takes the
+    shapes (whole tiles of 256 tokens, row blocks and rows of whole
+    lane tiles, bf16 or float32), else XLA's ``scatter-add`` (False;
+    counted in ``pallas_fallbacks{reason}``).  A test's ``"interpret"``
+    runs it at any whole tiles of tokens."""
+    from ..pallas import token_sum as _ts
+    from ..pallas.dispatch import _compiles_here, choose_impl
+    if how == "interpret":
+        return how if tokens % _ts.TOKENS == 0 else False
+    ok, why = _ts.supported(rows, tokens, width, dtype)
+    return choose_impl(
+        "token_sum (no knob)", "auto", "token_sum", how == "compiled" and ok,
+        why=f"grouped products {how or 'by XLA'}, {why}; need the compiled "
+            "grouped products, tokens%256==0, rows%128==0, width%128==0, "
+            "bf16/f32",
+        fallback_reason=_compiles_here()[2] or "token-sum-geometry")
+
+
+def _rows(x, at):
+    """``x[at]`` along the first axis for indices that lie inside it by
+    construction (a permutation, a pair's token): XLA's own gather, with
+    no fill of the wide rows."""
+    return jnp.take(x, at, axis=0, mode="clip")
+
+
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _summed(c, v, key, perm, sorted_key, tokens, out_dtype, how):
+    """``out[n] = sum over rows r with key[r] == n of c[r] * v[r]``
+    (``c`` None: 1), float32 sums rounded once to ``out_dtype``, by the
+    token-ordered sum: ``perm`` sorts ``key`` into ``sorted_key``, the
+    rows are permuted once, and a tile of 256 tokens then owns a run of
+    them (``pallas/token_sum.py``).  A row keyed ``tokens`` adds
+    nothing.  The other direction is what XLA derives from the
+    ``scatter-add`` this stands for: the gather ``dy[key] * c`` and the
+    row dot for ``c``."""
+    from ..pallas.token_sum import token_sum
+    return token_sum(
+        _rows(v, perm), sorted_key, None if c is None else _rows(c, perm),
+        tokens=tokens, out_dtype=out_dtype, interpret=how == "interpret")
+
+
+def _summed_fwd(c, v, key, perm, sorted_key, tokens, out_dtype, how):
+    return (_summed(c, v, key, perm, sorted_key, tokens, out_dtype, how),
+            (c, v, key))
+
+
+def _summed_bwd(tokens, out_dtype, how, kept, dy):
+    c, v, key = kept
+    f32 = jnp.float32
+    got = jnp.where((key < tokens)[:, None],
+                    _rows(dy, jnp.minimum(key, tokens - 1)).astype(f32), 0)
+    if c is None:
+        return None, got.astype(v.dtype), None, None, None
+    return (jnp.sum(got * v.astype(f32), axis=-1),
+            (got * c[:, None]).astype(v.dtype), None, None, None)
+
+
+_summed.defvjp(_summed_fwd, _summed_bwd)
+
+
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _taken(x, token, key, perm, sorted_key, how):
+    """``x[token]``, whose gradient is the token-ordered sum of the
+    rows' cotangents (:func:`_summed` with no weight) in place of the
+    ``scatter-add`` XLA derives."""
+    return _rows(x, token)
+
+
+def _taken_fwd(x, token, key, perm, sorted_key, how):
+    return _rows(x, token), (key, perm, sorted_key, x.shape[0])
+
+
+def _taken_bwd(how, kept, dxs):
+    key, perm, sorted_key, tokens = kept
+    return (_summed(None, dxs, key, perm, sorted_key, tokens, dxs.dtype, how),
+            None, None, None, None)
+
+
+_taken.defvjp(_taken_fwd, _taken_bwd)
+
+
 def _row_buckets(tokens, k, held, num_experts):
     """The static sizes the sorted rows' buffer may take: the expected
     and the worst case.  Nothing is dropped whatever the routing, so
     the larger is a token's every choice held here, ``tokens * min(k,
-    held)``.  But a gather and a scatter-add of that many rows would
-    cost more than the experts when a sixteenth of them is real, so a
-    step whose real count fits takes the smaller: one row a token, or
+    held)``.  But a gather and a sum over the tokens of that many rows
+    (the combine: the token-ordered sum of ``pallas/token_sum.py``
+    beside the products' kernels, else a scatter-add) would cost more
+    than the experts when a sixteenth of them is real, so a step whose
+    real count fits takes the smaller: one row a token, or
     five quarters of the expected count ``tokens * k * held /
     num_experts`` where that is larger (a size AT the expected count
     would send every other step to the worst case).  Top-1 has the one
@@ -330,7 +416,14 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
     router through ``weights``.
 
     The pairs held here are sorted by expert and run through three
-    grouped products.  Their buffer has the smaller of
+    grouped products; with ``k > 1`` the weighted rows of a token are
+    then added in float32 and rounded once (``moe.combine``), and the
+    gradient of the gather that fetched them (``moe.dispatch``) is the
+    same sum with no weight: beside the products' kernels both run as
+    the token-ordered sum (:func:`_token_sum_impl`: the held pairs
+    sorted by token once, the rows permuted, a one-hot product a tile
+    of 256 tokens), else as XLA's ``scatter-add``.  Their buffer has
+    the smaller of
     :func:`_row_buckets`'s static sizes; a step whose count passes it
     (``lax.switch``) runs its pairs a slab of that size at a time,
     forward and backward, so the worst case holds one slab's
@@ -338,7 +431,7 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
     backward pass: differentiating through a switch keeps every
     branch's).
     ``impl`` is :func:`grouped_matmul`'s (None: chosen once a size for
-    all three products)."""
+    all three products, and with them for the sums)."""
     N, d = x.shape
     k = experts.shape[1]
     held = w_gate.shape[0]
@@ -383,7 +476,16 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
             # operand and result is masked there, and with it its
             # gradient
             own = lambda t: jnp.where(here_s, t, 0)
-            xs = own(jnp.take(x, token, axis=0, unique_indices=k == 1))
+            summed = k > 1 and _token_sum_impl(how, rows, N, d, x.dtype)
+            if summed:
+                # the held pairs in token order, once for both sums over
+                # a token's rows: the combine, and the gather's gradient
+                key = jnp.where(here_s[:, 0], token, N)
+                perm = jnp.argsort(key)
+                by_token = (key, perm, _rows(key, perm))
+                xs = own(_taken(x, token, *by_token, summed))
+            else:
+                xs = own(jnp.take(x, token, axis=0, unique_indices=k == 1))
         with jax.named_scope("moe.experts"):
             g = own(grouped_matmul(xs, wg, sizes_here, how))
             u = own(grouped_matmul(xs, wu, sizes_here, how))
@@ -391,7 +493,11 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
                       .astype(x.dtype))
             ys = own(grouped_matmul(mid, wd, sizes_here, how))
         with jax.named_scope("moe.combine"):
-            ys = ys.astype(f32) * jnp.take(weights.reshape(N * k), at)[:, None]
+            w = jnp.take(weights.reshape(N * k), at)
+            if summed:
+                return _summed(w, ys, *by_token, N,
+                               x.dtype if start is None else f32, summed)
+            ys = ys.astype(f32) * w[:, None]
             if k == 1:      # every token has its one row
                 return jnp.zeros((N, d), x.dtype).at[token].set(
                     ys.astype(x.dtype), unique_indices=True)

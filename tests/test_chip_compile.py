@@ -222,6 +222,37 @@ def test_grouped_expert_products_fwd_grad_compile(one_chip):
     assert "ragged" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("cell, N, k, held, E, d, F", [
+    ("smallthinker", 16384, 6, 16, 64, 2560, 768),
+    ("keye", 16384, 8, 16, 128, 2048, 768),
+    ("qwen3next", 8192, 10, 32, 512, 2048, 512)])
+def test_topk_expert_layer_with_the_token_ordered_sum_compiles(
+        one_chip, cell, N, k, held, E, d, F):
+    """The dropless top-k layer at three cells' shapes (Kanana-2's is
+    Qwen3-Next's with other experts), forward and backward with the
+    kernels the chip would choose: beside the grouped products the
+    token-ordered sum, twice (the combine, weighted: three bfloat16
+    passes; the gather's gradient), in both arms of the sized buffer,
+    and no wide ``scatter`` is left in the program."""
+    from mxnet_tpu.parallel.moe import _row_buckets, dropless_topk_experts
+    assert len(_row_buckets(N, k, held, E)) == 2
+
+    def loss(x, experts, weights, wg, wu, wd):
+        y, counts = dropless_topk_experts(
+            x, experts, weights, wg, wu, wd, E, 0, impl="compiled")
+        return y.astype(jnp.float32).sum() + counts.sum()
+
+    stack = ((held, F, d), jnp.bfloat16)
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 2, 3, 4, 5)), one_chip,
+        ((N, d), jnp.bfloat16), ((N, k), jnp.int32), ((N, k), jnp.float32),
+        stack, stack, ((held, d, F), jnp.bfloat16)).as_text()
+    assert "token_sum" in text and "gmm" in text
+    wide = [line for line in text.splitlines()
+            if " scatter(" in line and "%d]" % d in line.split("scatter(")[0]]
+    assert not wide, wide[:2]
+
+
 def test_gated_delta_rule_fwd_grad_compiles(one_chip):
     """The chunked gated delta rule's kernels at the Qwen3-Next cell's
     sizes (one sequence of 8192 tokens, 16 key heads serving 32 value
@@ -597,6 +628,7 @@ def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
         compiled = fn.lower(*specs).compile()
     text = compiled.as_text()
     assert "gmm" in text and "ragged" not in text
+    assert "token_sum" in text
     assert "dsa.select" in text and "dsa.index_loss" in text
     assert "sparse_attention_forward" in text
     assert "sparse_attention_backward" in text
@@ -705,6 +737,7 @@ def test_smallthinker_fit_program_compiles_and_fits_the_chip(one_chip,
         compiled = fn.lower(*specs).compile()
     text = compiled.as_text()
     assert "gmm" in text and "ragged" not in text
+    assert "token_sum" in text
     assert "splash_mha_fwd" in text and "flash_attention_backward" in text
     for scope in ("gqa.proj", "gqa.rope", "gqa.window", "gqa.full"):
         assert scope in text, scope

@@ -245,3 +245,152 @@ def test_ep_sharded_grads_match_unsharded():
         # the expert-dim sharding survived the grad transpose
         if params[k].shape[0] == 4 and params[k].ndim >= 2:
             assert "ep" in str(g_ep[k].sharding)
+
+
+# ----------------------------------------------------------------------
+# the dropless layer's sums over a token's rows, in token order
+# ----------------------------------------------------------------------
+def _sum_cases():
+    """(k, experts an expert held, dtype, routing): the four cells' k
+    and shares and ZAYA-free extremes, then the routings the issue
+    names, each at one k."""
+    cases = [(k, share, dtype, "even") for dtype in ("float32", "bfloat16")
+             for share in (4, 8, 16, 1) for k in (2, 6, 8, 10)]
+    cases += [(6, 4, dtype, routing) for dtype in ("float32", "bfloat16")
+              for routing in ("empty-tile", "all-held-token", "none-held",
+                              "collapsed")]
+    return cases
+
+
+def _routing(rng, routing, N, k, held, E, first):
+    """(N, k) expert choices: ``even`` any k distinct experts;
+    ``empty-tile`` the second tile of 256 tokens chooses none held here;
+    ``all-held-token`` every 32nd token chooses k held ones;
+    ``none-held`` nobody does; ``collapsed`` everybody's k are held (the
+    slab arm)."""
+    inside = np.arange(first, first + held)
+    outside = np.setdiff1d(np.arange(E), inside)
+    draw = lambda pool: rng.permutation(pool)[:k]
+    e = np.stack([draw(np.arange(E)) for _ in range(N)])
+    if routing == "empty-tile":
+        e[256:512] = np.stack([draw(outside) for _ in range(256)])
+    if routing == "all-held-token":
+        e[::32] = np.stack([draw(inside) for _ in range(len(e[::32]))])
+    if routing == "none-held":
+        e = np.stack([draw(outside) for _ in range(N)])
+    if routing == "collapsed":
+        e = np.stack([draw(inside) for _ in range(N)])
+    return jnp.asarray(e, jnp.int32)
+
+
+@pytest.mark.parametrize("k, share, dtype, routing", _sum_cases())
+def test_token_ordered_sum_is_the_scatter_add(k, share, dtype, routing):
+    """Where ``k > 1`` the combine and the gather's gradient are sums
+    over a token's rows; with the kernels (interpreted here) they run as
+    ``pallas/token_sum.py``'s token-ordered sum.  Against the layer as
+    XLA runs it, the sums as ``.at[token].add``: the result and the
+    gradients for
+    ``x``, ``weights`` and the three expert stacks, at the four cells' k
+    and shares of experts held, with rows past the real ones, a tile of
+    256 tokens that holds no pair, tokens whose every choice is held,
+    nothing held at all, and a routing that takes the slab arm."""
+    from mxnet_tpu.parallel import moe
+    from mxnet_tpu.pallas.dispatch import PALLAS_LAUNCHES
+    from mxnet_tpu.pallas.token_sum import token_sum
+    rng = np.random.default_rng(k * 100 + share)
+    d, F = 128, 32
+    # sizes the interpreted grouped products take: rows in whole tiles
+    def whole_tiles(N, held):
+        rows = moe._row_buckets(N, k, held, held * share)[0]
+        return k <= held and rows % moe._gmm_tiling(rows, held, 1, 1)[0] == 0
+
+    N, held = next((N, held) for N in (1024, 2048)
+                   for held in (16, 8, 32, 4, 64) if whole_tiles(N, held))
+    E, first = held * share, held * (share > 1)
+    buckets = moe._row_buckets(N, k, held, E)
+    e = _routing(rng, routing, N, k, held, E, first)
+    real = int(((e >= first) & (e < first + held)).sum())
+    if routing == "collapsed":
+        assert len(buckets) == 2 and real == N * k > buckets[0]
+    elif share > 1:
+        assert real < buckets[0]        # rows past the real ones
+    draw = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    w = jax.nn.softmax(draw(N, k), -1)
+    x = draw(N, d).astype(dtype)
+    wg, wu = (draw(held, F, d) * d ** -0.5).astype(dtype), \
+        (draw(held, F, d) * d ** -0.5).astype(dtype)
+    wd = (draw(held, d, F) * F ** -0.5).astype(dtype)
+    cot = draw(N, d)
+
+    def both(impl, x, w, wg, wu, wd):
+        def loss(*a):
+            y = moe.dropless_topk_experts(a[0], e, *a[1:], E, first,
+                                          impl=impl)[0]
+            return jnp.sum(y.astype(jnp.float32) * cot), y
+        (_, y), grads = jax.value_and_grad(loss, (0, 1, 2, 3, 4),
+                                           has_aux=True)(x, w, wg, wu, wd)
+        return (y,) + grads
+
+    launches = PALLAS_LAUNCHES.labels(kernel="token_sum")
+    token_sum.clear_cache()     # a launch is counted where a kernel is built
+    before = launches.value
+    got = jax.jit(lambda *a: both("interpret", *a))(x, w, wg, wu, wd)
+    assert launches.value > before
+    before = launches.value
+    want = jax.jit(lambda *a: both(False, *a))(x, w, wg, wu, wd)
+    assert launches.value == before
+    # float32: the same products summed in another order; bfloat16: the
+    # grouped products round apart, and the gather's gradient is rounded
+    # once where the scatter-add rounds every addition (the sums alone,
+    # to float32's last bits: the next test)
+    tol = 2e-6 if dtype == "float32" else 2e-2
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert a.dtype == b.dtype and np.isfinite(a).all()
+        if routing == "none-held":
+            assert np.abs(a).max() == 0.0
+        assert np.abs(a - b).max() <= tol * max(np.abs(b).max(), 1e-30)
+    if routing == "empty-tile":
+        assert np.abs(np.asarray(got[0][256:512], np.float64)).max() == 0.0
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("weighted", [True, False])
+def test_token_sum_keeps_float32_weights_and_sums(weighted, dtype):
+    """The sum alone against ``.at[token].add`` of the float32 products
+    ``c * v``: bfloat16 rows under float32 weights come out to float32's
+    last bits (the weight's three bfloat16 parts, no rounding of the
+    weight or of the weighted row), a row keyed past the last token adds
+    nothing, an empty tile of 256 tokens is zeros, and the other
+    direction is the gather and the row dot."""
+    from mxnet_tpu.parallel import moe
+    rng = np.random.default_rng(7)
+    rows, N, d = 1152, 1024, 128
+    key = rng.integers(0, N, rows)
+    key = np.where((key >= 512) & (key < 768), key - 512, key)  # no row
+    key[rng.random(rows) < 0.2] = N                             # no token
+    key = jnp.asarray(key, jnp.int32)
+    v = jnp.asarray(rng.standard_normal((rows, d)), dtype)
+    c = jnp.asarray(rng.random(rows) + 0.5, jnp.float32) if weighted else None
+    cot = jnp.asarray(rng.standard_normal((N, d)), jnp.float32)
+    perm = jnp.argsort(key)
+
+    def kernel(c, v):
+        return moe._summed(c, v, key, perm, jnp.take(key, perm), N,
+                           jnp.float32, "interpret")
+
+    def scatter(c, v):
+        v = v.astype(jnp.float32) * (1 if c is None else c[:, None])
+        return jnp.zeros((N, d), jnp.float32).at[key].add(v, mode="drop")
+
+    got, pull = jax.vjp(kernel, c, v)
+    want, pull_want = jax.vjp(scatter, c, v)
+    assert float(jnp.abs(got[512:768]).max()) == 0.0
+    tol = 1e-6 * float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) <= tol
+    for a, b in zip(pull(cot), pull_want(cot)):
+        if b is not None:
+            assert a.dtype == b.dtype
+            assert float(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32)).max()) \
+                <= 1e-6 * float(jnp.abs(b.astype(jnp.float32)).max())
