@@ -395,9 +395,11 @@ class KVStoreDistAsync(KVStore):
         import zlib
         return zlib.crc32(str(key).encode()) % len(self._servers)
 
-    def _request(self, sidx, msg, retries=240):
+    def _request(self, sidx, msg, retries=240, timeout=120):
         # generous connect retries: the server process imports the full
-        # package before listening (~seconds on a loaded host)
+        # package before listening (~seconds on a loaded host).
+        # ``timeout``: seconds to wait for the connection and for the
+        # reply of one attempt
         # fresh copy per (request, shard): callers (and _all_servers)
         # reuse msg dicts, and a seq stamped for one shard must never
         # leak to another — each server dedupes on its own counter line
@@ -412,7 +414,7 @@ class KVStoreDistAsync(KVStore):
                 if sock is None:
                     try:
                         sock = socket.create_connection(
-                            self._servers[sidx], timeout=120)
+                            self._servers[sidx], timeout=timeout)
                         sock.setsockopt(socket.IPPROTO_TCP,
                                         socket.TCP_NODELAY, 1)
                         self._socks[sidx] = sock
@@ -420,6 +422,7 @@ class KVStoreDistAsync(KVStore):
                         time.sleep(0.25)
                         continue
                 try:
+                    sock.settimeout(timeout)
                     _send_msg(sock, msg, self._secret)
                     resp = _recv_msg(sock, self._secret)
                 except (ConnectionError, OSError):
@@ -564,10 +567,13 @@ class KVStoreDistAsync(KVStore):
         self._request(0, {"op": "barrier", "count": self._nworkers})
 
     def get_num_dead_node(self, node_id=0, timeout=60):
+        """Servers that do not answer a ping within ``timeout`` seconds
+        (reference kvstore.h:341: a node silent for ``timeout`` seconds
+        is presumed dead)."""
         dead = 0
         for i in range(len(self._servers)):
             try:
-                self._request(i, {"op": "ping"}, retries=2)
+                self._request(i, {"op": "ping"}, retries=2, timeout=timeout)
             except MXNetError:
                 dead += 1
         return dead

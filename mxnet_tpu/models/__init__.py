@@ -15,7 +15,10 @@ with a selection bias before the top-k sublayer), ``keye_vl2``
 second loss head, before a softmax top-k sublayer) and ``smallthinker``
 (window and full grouped-query attention layers in one model, a router
 that reads the layer's input, ReLU-gated experts); the last five hand
-out the experts' token counts as an output.
+out the experts' token counts as an output, and share one frame
+(``_decoder.py``: ``experts_held``, the embedding, the closing norm, the
+head, the loss and the counts' output), so that a family's file is its
+mixer, its expert sublayer's attributes and its layer schedule.
 
 These are fresh TPU-first definitions (bf16-friendly: ``dtype`` casts the
 trunk while the final classifier/softmax stays fp32), not translations of

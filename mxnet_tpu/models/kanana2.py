@@ -31,7 +31,7 @@ the embedding and in the head.
 """
 from .. import initializer as _init
 from .. import symbol as sym
-from ..telemetry.moe import COUNTS_NODE     # the counts' node (output 1)
+from ._decoder import F32, Decoder, weight
 
 
 def get_symbol(num_classes=16032, num_layers=5, d_model=2048, heads=32,
@@ -42,46 +42,20 @@ def get_symbol(num_classes=16032, num_layers=5, d_model=2048, heads=32,
                dtype="float32", **kwargs):
     """``seq_len`` is accepted for factory-signature parity with the
     transformer (positions are rotary: nothing is sized by it)."""
-    vocab, d = int(num_classes), int(d_model)
     E, F, Fs = int(num_experts), int(expert_dim), int(shared_dim)
-    if experts_held is None:
-        first, held = 0, E
-    elif isinstance(experts_held, int):
-        first, held = 0, int(experts_held)
-    else:
-        first, held = (int(v) for v in experts_held)
-    if not (0 <= first and 0 < held and first + held <= E):
-        raise ValueError("experts_held=%r is no part of %d experts"
-                         % (experts_held, E))
+    eps = 1e-6
+    frame = Decoder(num_classes, d_model, E, experts_held, dtype, eps=eps)
+    norm = frame.norm
     if not 0 <= int(dense_layers) < int(num_layers):
         raise ValueError("dense_layers=%r of %r layers leaves no expert "
                          "layer" % (dense_layers, num_layers))
-    low = dtype in ("float16", "bfloat16")
-    std = _init.Normal(0.02)
-    f32 = {"dtype": "float32"}      # the router, whatever dtype
-    eps = 1e-6
 
-    def weight(name, init=std, **kw):
-        return sym.Variable(name, init=init, **kw)
-
-    def norm(x, name):
-        return sym.RMSNorm(x, gamma=weight(name + "_gamma", _init.One()),
-                           eps=eps, name=name)
-
-    data = sym.Variable("data")                      # (B, S) token ids
-    embed = weight("tok_embed_weight", _init.Normal(1.0),
-                   shape=(vocab, d), **f32)
-    x = sym.Embedding(data, weight=embed, input_dim=vocab, output_dim=d,
-                      name="tok_embed")
-    if low:
-        x = sym.Cast(data=x, dtype=dtype, name="cast_embed")
-
+    x = frame.embed()
     counts = []
     for i in range(int(num_layers)):
         pre = "layer%d_" % i
-        h = norm(x, pre + "in_norm")
         x = x + sym.contrib.LatentAttention(
-            h, weight(pre + "attn_q_weight"),
+            norm(x, pre + "in_norm"), weight(pre + "attn_q_weight"),
             weight(pre + "attn_kva_weight"),
             weight(pre + "attn_kv_norm_gamma", _init.One()),
             weight(pre + "attn_kvb_weight"), weight(pre + "attn_o_weight"),
@@ -103,27 +77,15 @@ def get_symbol(num_classes=16032, num_layers=5, d_model=2048, heads=32,
             gate_weight=weight(pre + "moe_gate_weight"),
             up_weight=weight(pre + "moe_up_weight"),
             down_weight=weight(pre + "moe_down_weight"),
-            router_weight=weight(pre + "moe_router_weight", **f32),
+            router_weight=weight(pre + "moe_router_weight", **F32),
             shared_gate_weight=weight(pre + "moe_shared_gate_weight"),
             shared_up_weight=weight(pre + "moe_shared_up_weight"),
             shared_down_weight=weight(pre + "moe_shared_down_weight"),
-            router_bias=weight(pre + "moe_router_bias", _init.Zero(), **f32),
+            router_bias=weight(pre + "moe_router_bias", _init.Zero(), **F32),
             router="sigmoid", top_k=int(top_k),
             route_scale=float(route_scale), num_experts=E,
-            held_first=first, held_count=held, num_hidden=F,
+            held_first=frame.first, held_count=frame.held, num_hidden=F,
             shared_hidden=Fs, shared_gate=False, name=pre + "moe")
         x = x + moe[0]
         counts.append(moe[2])
-
-    x = norm(x, "final_norm")
-    logits = sym.FullyConnected(data=x, weight=weight("lm_head_weight"),
-                                no_bias=True, num_hidden=vocab,
-                                flatten=False, name="lm_head")
-    if low:
-        logits = sym.Cast(data=logits, dtype="float32", name="cast_out")
-    flat = sym.Reshape(data=logits, shape=(-1, vocab), name="logits_2d")
-    out = sym.SoftmaxOutput(data=flat, name="softmax",
-                            normalization="batch")
-    tokens = sym.BlockGrad(sym.stack(*counts, axis=0, name="moe_tokens_all"),
-                           name=COUNTS_NODE)
-    return sym.Group([out, tokens])
+    return sym.Group(frame.close(x, counts))

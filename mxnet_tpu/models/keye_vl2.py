@@ -35,7 +35,7 @@ slice of the vocabulary held here, in the embedding and in the head.
 from .. import initializer as _init
 from .. import symbol as sym
 from ..telemetry.dsa import TILES_NODE      # the tiles' node (output 3)
-from ..telemetry.moe import COUNTS_NODE     # the counts' node (output 2)
+from ._decoder import F32, Decoder, weight
 
 INDEX_LOSS_NODE = "index_loss"              # the second loss head (output 1)
 
@@ -47,54 +47,30 @@ def get_symbol(num_classes=18992, num_layers=4, d_model=2048, q_heads=32,
                seq_len=16384, dtype="float32", **kwargs):
     """``seq_len`` is accepted for factory-signature parity with the
     transformer (positions are rotary: nothing is sized by it)."""
-    vocab, d = int(num_classes), int(d_model)
     E, F = int(num_experts), int(expert_dim)
-    if experts_held is None:
-        first, held = 0, E
-    elif isinstance(experts_held, int):
-        first, held = 0, int(experts_held)
-    else:
-        first, held = (int(v) for v in experts_held)
-    if not (0 <= first and 0 < held and first + held <= E):
-        raise ValueError("experts_held=%r is no part of %d experts"
-                         % (experts_held, E))
-    low = dtype in ("float16", "bfloat16")
-    std = _init.Normal(0.02)
-    f32 = {"dtype": "float32"}      # the router and the scorer, whatever dtype
     eps = 1e-6
+    # norms mirrored: at 16 384 tokens a norm's float32 intermediates
+    # are 0.4 GB that the backward pass can make again from the stream
+    frame = Decoder(num_classes, d_model, E, experts_held, dtype, eps=eps,
+                    force_mirroring=True)
+    norm = frame.norm
 
-    def weight(name, init=std, **kw):
-        return sym.Variable(name, init=init, **kw)
-
-    def norm(x, name):
-        # mirrored: at 16 384 tokens a norm's float32 intermediates are
-        # 0.4 GB that the backward pass can make again from the stream
-        return sym.RMSNorm(x, gamma=weight(name + "_gamma", _init.One()),
-                           eps=eps, name=name, force_mirroring=True)
-
-    data = sym.Variable("data")                      # (B, S) token ids
-    embed = weight("tok_embed_weight", _init.Normal(1.0),
-                   shape=(vocab, d), **f32)
-    x = sym.Embedding(data, weight=embed, input_dim=vocab, output_dim=d,
-                      name="tok_embed")
-    if low:
-        x = sym.Cast(data=x, dtype=dtype, name="cast_embed")
-
+    x = frame.embed()
     counts, tiles, index_losses = [], [], []
     for i in range(int(num_layers)):
         pre = "layer%d_" % i
-        h = norm(x, pre + "in_norm")
         attn = sym.contrib.SparseIndexedAttention(
-            h, weight(pre + "attn_q_weight"), weight(pre + "attn_k_weight"),
+            norm(x, pre + "in_norm"),
+            weight(pre + "attn_q_weight"), weight(pre + "attn_k_weight"),
             weight(pre + "attn_v_weight"),
             weight(pre + "attn_q_norm_gamma", _init.One()),
             weight(pre + "attn_k_norm_gamma", _init.One()),
             weight(pre + "attn_o_weight"),
-            weight(pre + "attn_idx_q_weight", **f32),
-            weight(pre + "attn_idx_k_weight", **f32),
-            weight(pre + "attn_idx_w_weight", **f32),
-            weight(pre + "attn_idx_k_norm_gamma", _init.One(), **f32),
-            weight(pre + "attn_idx_k_norm_beta", _init.Zero(), **f32),
+            weight(pre + "attn_idx_q_weight", **F32),
+            weight(pre + "attn_idx_k_weight", **F32),
+            weight(pre + "attn_idx_w_weight", **F32),
+            weight(pre + "attn_idx_k_norm_gamma", _init.One(), **F32),
+            weight(pre + "attn_idx_k_norm_beta", _init.Zero(), **F32),
             q_heads=int(q_heads), kv_heads=int(kv_heads),
             head_dim=int(head_dim), idx_heads=int(idx_heads),
             idx_dim=int(idx_dim), topk=int(topk),
@@ -104,34 +80,23 @@ def get_symbol(num_classes=18992, num_layers=4, d_model=2048, q_heads=32,
         index_losses.append(attn[1])
         tiles.append(attn[2])
 
-        h = norm(x, pre + "post_norm")
         moe = sym.contrib.RoutedExperts(
-            h,
+            norm(x, pre + "post_norm"),
             # 3-D stacks (held, out, in): Xavier would misread their fans
             gate_weight=weight(pre + "moe_gate_weight"),
             up_weight=weight(pre + "moe_up_weight"),
             down_weight=weight(pre + "moe_down_weight"),
-            router_weight=weight(pre + "moe_router_weight", **f32),
+            router_weight=weight(pre + "moe_router_weight", **F32),
             router="linear", top_k=int(top_k), num_experts=E,
-            held_first=first, held_count=held, num_hidden=F,
+            held_first=frame.first, held_count=frame.held, num_hidden=F,
             name=pre + "moe")
         x = x + moe[0]
         counts.append(moe[2])
 
-    x = norm(x, "final_norm")
-    logits = sym.FullyConnected(data=x, weight=weight("lm_head_weight"),
-                                no_bias=True, num_hidden=vocab,
-                                flatten=False, name="lm_head")
-    if low:
-        logits = sym.Cast(data=logits, dtype="float32", name="cast_out")
-    flat = sym.Reshape(data=logits, shape=(-1, vocab), name="logits_2d")
-    out = sym.SoftmaxOutput(data=flat, name="softmax",
-                            normalization="batch")
+    out, tokens = frame.close(x, counts)
     index_loss = sym.MakeLoss(
         sym.add_n(*index_losses, name="index_loss_sum"),
         name=INDEX_LOSS_NODE)
-    tokens = sym.BlockGrad(sym.stack(*counts, axis=0, name="moe_tokens_all"),
-                           name=COUNTS_NODE)
     live = sym.BlockGrad(sym.stack(*tiles, axis=0, name="dsa_tiles_all"),
                          name=TILES_NODE)
     return sym.Group([out, index_loss, tokens, live])

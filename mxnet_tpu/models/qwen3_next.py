@@ -20,7 +20,7 @@ of the vocabulary held here, in the embedding and in the head.
 """
 from .. import initializer as _init
 from .. import symbol as sym
-from ..telemetry.moe import COUNTS_NODE     # the counts' node (output 1)
+from ._decoder import F32, Decoder, weight
 
 
 def layer_kinds(num_layers, full_attention_interval=4):
@@ -42,41 +42,17 @@ def get_symbol(num_classes=18992, num_layers=4, d_model=2048,
                dtype="float32", **kwargs):
     """``seq_len`` is accepted for factory-signature parity with the
     transformer (positions are rotary: nothing is sized by it)."""
-    vocab, d = int(num_classes), int(d_model)
     E, F, Fs = int(num_experts), int(expert_dim), int(shared_dim)
-    if experts_held is None:
-        first, held = 0, E
-    elif isinstance(experts_held, int):
-        first, held = 0, int(experts_held)
-    else:
-        first, held = (int(v) for v in experts_held)
-    if not (0 <= first and 0 < held and first + held <= E):
-        raise ValueError("experts_held=%r is no part of %d experts"
-                         % (experts_held, E))
-    kinds = layer_kinds(num_layers, full_attention_interval)
-    low = dtype in ("float16", "bfloat16")
-    std = _init.Normal(0.02)
     zero, one = _init.Zero(), _init.One()
-    f32 = {"dtype": "float32"}      # the router and the decay, whatever dtype
     eps = 1e-6
+    frame = Decoder(num_classes, d_model, E, experts_held, dtype,
+                    norm_init=zero, eps=eps, zero_centered=True)
+    norm = frame.norm
 
-    def weight(name, init=std, **kw):
-        return sym.Variable(name, init=init, **kw)
-
-    def norm(x, name):
-        return sym.RMSNorm(x, gamma=weight(name + "_gamma", zero), eps=eps,
-                           zero_centered=True, name=name)
-
-    data = sym.Variable("data")                      # (B, S) token ids
-    embed = weight("tok_embed_weight", _init.Normal(1.0),
-                   shape=(vocab, d), **f32)
-    x = sym.Embedding(data, weight=embed, input_dim=vocab, output_dim=d,
-                      name="tok_embed")
-    if low:
-        x = sym.Cast(data=x, dtype=dtype, name="cast_embed")
-
+    x = frame.embed()
     counts = []
-    for i, kind in enumerate(kinds):
+    for i, kind in enumerate(layer_kinds(num_layers,
+                                         full_attention_interval)):
         pre = "layer%d_" % i
         h = norm(x, pre + "in_norm")
         if kind == "linear":
@@ -86,8 +62,8 @@ def get_symbol(num_classes=18992, num_layers=4, d_model=2048,
                 weight(pre + "gdn_conv_weight"),
                 # the Gated DeltaNet reference draws A in (0, 16) and the
                 # step dt log-uniformly; a seeded run sets both by name
-                weight(pre + "gdn_A_log", zero, **f32),
-                weight(pre + "gdn_dt_bias", one, **f32),
+                weight(pre + "gdn_A_log", zero, **F32),
+                weight(pre + "gdn_dt_bias", one, **F32),
                 weight(pre + "gdn_norm_gamma", one),
                 weight(pre + "gdn_out_weight"),
                 k_heads=int(gdn_k_heads), v_heads=int(gdn_v_heads),
@@ -105,33 +81,20 @@ def get_symbol(num_classes=18992, num_layers=4, d_model=2048,
                 rope_theta=float(rope_theta), eps=eps, name=pre + "attn")
         x = x + mixed
 
-        h = norm(x, pre + "post_norm")
         moe = sym.contrib.RoutedExperts(
-            h,
+            norm(x, pre + "post_norm"),
             # 3-D stacks (held, out, in): Xavier would misread their fans
             gate_weight=weight(pre + "moe_gate_weight"),
             up_weight=weight(pre + "moe_up_weight"),
             down_weight=weight(pre + "moe_down_weight"),
-            router_weight=weight(pre + "moe_router_weight", **f32),
+            router_weight=weight(pre + "moe_router_weight", **F32),
             shared_gate_weight=weight(pre + "moe_shared_gate_weight"),
             shared_up_weight=weight(pre + "moe_shared_up_weight"),
             shared_down_weight=weight(pre + "moe_shared_down_weight"),
             shared_sg_weight=weight(pre + "moe_shared_sg_weight"),
             router="linear", top_k=int(top_k), num_experts=E,
-            held_first=first, held_count=held, num_hidden=F,
+            held_first=frame.first, held_count=frame.held, num_hidden=F,
             shared_hidden=Fs, name=pre + "moe")
         x = x + moe[0]
         counts.append(moe[2])
-
-    x = norm(x, "final_norm")
-    logits = sym.FullyConnected(data=x, weight=weight("lm_head_weight"),
-                                no_bias=True, num_hidden=vocab,
-                                flatten=False, name="lm_head")
-    if low:
-        logits = sym.Cast(data=logits, dtype="float32", name="cast_out")
-    flat = sym.Reshape(data=logits, shape=(-1, vocab), name="logits_2d")
-    out = sym.SoftmaxOutput(data=flat, name="softmax",
-                            normalization="batch")
-    tokens = sym.BlockGrad(sym.stack(*counts, axis=0, name="moe_tokens_all"),
-                           name=COUNTS_NODE)
-    return sym.Group([out, tokens])
+    return sym.Group(frame.close(x, counts))

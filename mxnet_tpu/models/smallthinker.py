@@ -27,9 +27,8 @@ from expert 0, or ``[first, count]``): the router still scores all
 and a choice whose expert is elsewhere adds 0.  ``num_classes`` is the
 slice of the vocabulary held here, in the embedding and in the head.
 """
-from .. import initializer as _init
 from .. import symbol as sym
-from ..telemetry.moe import COUNTS_NODE     # the counts' node (output 1)
+from ._decoder import F32, Decoder, weight
 
 
 def get_symbol(num_classes=18992, num_layers=4, d_model=2560, q_heads=28,
@@ -41,43 +40,18 @@ def get_symbol(num_classes=18992, num_layers=4, d_model=2560, q_heads=28,
     are as long as the model is deep.  ``seq_len`` is accepted for
     factory-signature parity with the transformer (positions are rotary
     or absent: nothing is sized by it)."""
-    vocab, d, L = int(num_classes), int(d_model), int(num_layers)
-    E, F = int(num_experts), int(expert_dim)
+    L, E, F = int(num_layers), int(num_experts), int(expert_dim)
     if len(window_layout) != L or len(rope_layout) != L:
         raise ValueError("window_layout (%d) and rope_layout (%d) each name "
                          "every one of the %d layers"
                          % (len(window_layout), len(rope_layout), L))
-    if experts_held is None:
-        first, held = 0, E
-    elif isinstance(experts_held, int):
-        first, held = 0, int(experts_held)
-    else:
-        first, held = (int(v) for v in experts_held)
-    if not (0 <= first and 0 < held and first + held <= E):
-        raise ValueError("experts_held=%r is no part of %d experts"
-                         % (experts_held, E))
-    low = dtype in ("float16", "bfloat16")
-    std = _init.Normal(0.02)
-    f32 = {"dtype": "float32"}      # the router, whatever dtype
-    eps = 1e-6
+    # norms mirrored: at 16 384 tokens a norm's float32 intermediates
+    # are 0.5 GB that the backward pass can make again from the stream
+    frame = Decoder(num_classes, d_model, E, experts_held, dtype, eps=1e-6,
+                    force_mirroring=True)
+    norm = frame.norm
 
-    def weight(name, init=std, **kw):
-        return sym.Variable(name, init=init, **kw)
-
-    def norm(x, name):
-        # mirrored: at 16 384 tokens a norm's float32 intermediates are
-        # 0.5 GB that the backward pass can make again from the stream
-        return sym.RMSNorm(x, gamma=weight(name + "_gamma", _init.One()),
-                           eps=eps, name=name, force_mirroring=True)
-
-    data = sym.Variable("data")                      # (B, S) token ids
-    embed = weight("tok_embed_weight", _init.Normal(1.0),
-                   shape=(vocab, d), **f32)
-    x = sym.Embedding(data, weight=embed, input_dim=vocab, output_dim=d,
-                      name="tok_embed")
-    if low:
-        x = sym.Cast(data=x, dtype=dtype, name="cast_embed")
-
+    x = frame.embed()
     counts = []
     for i in range(L):
         pre = "layer%d_" % i
@@ -98,23 +72,11 @@ def get_symbol(num_classes=18992, num_layers=4, d_model=2560, q_heads=28,
             gate_weight=weight(pre + "moe_gate_weight"),
             up_weight=weight(pre + "moe_up_weight"),
             down_weight=weight(pre + "moe_down_weight"),
-            router_weight=weight(pre + "moe_router_weight", **f32),
+            router_weight=weight(pre + "moe_router_weight", **F32),
             router_data=layer_in, router_stream=True,
             router="linear", act="relu", top_k=int(top_k), num_experts=E,
-            held_first=first, held_count=held, num_hidden=F,
+            held_first=frame.first, held_count=frame.held, num_hidden=F,
             name=pre + "moe")
         x = x + moe[0]
         counts.append(moe[2])
-
-    x = norm(x, "final_norm")
-    logits = sym.FullyConnected(data=x, weight=weight("lm_head_weight"),
-                                no_bias=True, num_hidden=vocab,
-                                flatten=False, name="lm_head")
-    if low:
-        logits = sym.Cast(data=logits, dtype="float32", name="cast_out")
-    flat = sym.Reshape(data=logits, shape=(-1, vocab), name="logits_2d")
-    out = sym.SoftmaxOutput(data=flat, name="softmax",
-                            normalization="batch")
-    tokens = sym.BlockGrad(sym.stack(*counts, axis=0, name="moe_tokens_all"),
-                           name=COUNTS_NODE)
-    return sym.Group([out, tokens])
+    return sym.Group(frame.close(x, counts))

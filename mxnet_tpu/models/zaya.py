@@ -21,7 +21,7 @@ here: one matrix, used by ``Embedding`` and by the head.
 """
 from .. import initializer as _init
 from .. import symbol as sym
-from ..telemetry.moe import COUNTS_NODE     # the counts' node (output 1)
+from ._decoder import F32, Decoder, weight
 
 
 def get_symbol(num_classes=32784, num_layers=4, d_model=2048, q_heads=8,
@@ -31,42 +31,21 @@ def get_symbol(num_classes=32784, num_layers=4, d_model=2048, q_heads=8,
                dtype="float32", **kwargs):
     """``seq_len`` is accepted for factory-signature parity with the
     transformer (positions are rotary: nothing is sized by it)."""
-    vocab, d = int(num_classes), int(d_model)
+    d = int(d_model)
     E, R, F = int(num_experts), int(router_hidden), int(expert_dim)
-    if experts_held is None:
-        first, held = 0, E
-    elif isinstance(experts_held, int):
-        first, held = 0, int(experts_held)
-    else:
-        first, held = (int(v) for v in experts_held)
-    if not (0 <= first and 0 < held and first + held <= E):
-        raise ValueError("experts_held=%r is no part of %d experts"
-                         % (experts_held, E))
-    low = dtype in ("float16", "bfloat16")
-    std = _init.Normal(0.02)
+    frame = Decoder(num_classes, d, E, experts_held, dtype)
+    norm = frame.norm
     one = _init.One()
     fan = lambda n: _init.Normal(float(n) ** -0.5)
-    f32 = {"dtype": "float32"}              # the router, whatever dtype
 
-    def weight(name, init=std, **kw):
-        return sym.Variable(name, init=init, **kw)
-
-    data = sym.Variable("data")                      # (B, S) token ids
-    # float32, as the transformer's: the lookup and the gradient's
-    # scatter-add stay exact; the head reads a copy in the trunk's dtype
-    embed = weight("tok_embed_weight", shape=(vocab, d), dtype="float32")
-    x = sym.Embedding(data, weight=embed, input_dim=vocab, output_dim=d,
-                      name="tok_embed")
-    if low:
-        x = sym.Cast(data=x, dtype=dtype, name="cast_embed")
-
+    # one matrix, the head's too: drawn as the other weights are
+    x = frame.embed(_init.Normal(0.02))
     state, counts = None, []
     for i in range(int(num_layers)):
         pre = "layer%d_" % i
-        h = sym.RMSNorm(x, gamma=weight(pre + "attn_norm_gamma", one),
-                        name=pre + "attn_norm")
         attn = sym.contrib.CompressedConvAttention(
-            h, weight(pre + "attn_q_weight"), weight(pre + "attn_k_weight"),
+            norm(x, pre + "attn_norm"),
+            weight(pre + "attn_q_weight"), weight(pre + "attn_k_weight"),
             weight(pre + "attn_v_weight"),
             # taps sized to their fan-in, so that both convolutions
             # weigh as much as the query-key mean they are added to
@@ -81,42 +60,26 @@ def get_symbol(num_classes=32784, num_layers=4, d_model=2048, q_heads=8,
             attn, weight(pre + "attn_scale", one, shape=(d,)),
             name=pre + "attn_scaled")
 
-        h = sym.RMSNorm(x, gamma=weight(pre + "moe_norm_gamma", one),
-                        name=pre + "moe_norm")
         carry = {} if state is None else {
             "router_state": state,
             "router_carry": weight(pre + "moe_router_carry",
-                                   _init.Constant(0.5), **f32)}
+                                   _init.Constant(0.5), **F32)}
         moe = sym.contrib.RoutedExperts(
-            h, weight(pre + "moe_router_in_weight", **f32),
-            weight(pre + "moe_router_norm_gamma", one, **f32),
-            weight(pre + "moe_router_fc1_weight", **f32),
-            weight(pre + "moe_router_fc2_weight", **f32),
-            weight(pre + "moe_router_out_weight", **f32),
+            norm(x, pre + "moe_norm"),
+            weight(pre + "moe_router_in_weight", **F32),
+            weight(pre + "moe_router_norm_gamma", one, **F32),
+            weight(pre + "moe_router_fc1_weight", **F32),
+            weight(pre + "moe_router_fc2_weight", **F32),
+            weight(pre + "moe_router_out_weight", **F32),
             # 3-D stacks (held, out, in): Xavier would misread their fans
             weight(pre + "moe_gate_weight"), weight(pre + "moe_up_weight"),
             weight(pre + "moe_down_weight"),
-            num_experts=E, held_first=first, held_count=held, num_hidden=F,
-            router_hidden=R, carry_in=state is not None, name=pre + "moe",
-            **carry)
+            num_experts=E, held_first=frame.first, held_count=frame.held,
+            num_hidden=F, router_hidden=R, carry_in=state is not None,
+            name=pre + "moe", **carry)
         x = x + sym.broadcast_mul(
             moe[0], weight(pre + "moe_scale", one, shape=(d,)),
             name=pre + "moe_scaled")
         state = moe[1]
         counts.append(moe[2])
-
-    x = sym.RMSNorm(x, gamma=weight("final_norm_gamma", one),
-                    name="final_norm")
-    tied = sym.Cast(data=embed, dtype=dtype, name="cast_head") if low \
-        else embed
-    logits = sym.FullyConnected(data=x, weight=tied, no_bias=True,
-                                num_hidden=vocab, flatten=False,
-                                name="lm_head")
-    if low:
-        logits = sym.Cast(data=logits, dtype="float32", name="cast_out")
-    flat = sym.Reshape(data=logits, shape=(-1, vocab), name="logits_2d")
-    out = sym.SoftmaxOutput(data=flat, name="softmax",
-                            normalization="batch")
-    tokens = sym.BlockGrad(sym.stack(*counts, axis=0, name="moe_tokens_all"),
-                           name=COUNTS_NODE)
-    return sym.Group([out, tokens])
+    return sym.Group(frame.close(x, counts, tied=True))

@@ -1,54 +1,10 @@
 """Name manager (reference python/mxnet/name.py): deterministic auto-name
 scopes for symbols. ``with mx.name.NameManager():`` resets the counter
 scope so generated names ("fullyconnected0"...) restart — what the
-reference's fluent-API tests rely on for reproducible graphs."""
+reference's fluent-API tests rely on for reproducible graphs.  The
+managers are ``base``'s, the ones symbol creation asks."""
 from __future__ import annotations
 
-import threading
+from .base import NameManager, Prefix, current_name_manager as current
 
 __all__ = ["NameManager", "Prefix", "current"]
-
-_TLS = threading.local()
-
-
-class NameManager:
-    """Assigns `hint + running index` names within its scope."""
-
-    def __init__(self):
-        self._counter = {}
-        self._old = None
-
-    def get(self, name, hint):
-        if name:
-            return name
-        idx = self._counter.get(hint, 0)
-        self._counter[hint] = idx + 1
-        return "%s%d" % (hint, idx)
-
-    def __enter__(self):
-        self._old = getattr(_TLS, "manager", None)
-        _TLS.manager = self
-        return self
-
-    def __exit__(self, *exc):
-        _TLS.manager = self._old
-        return False
-
-
-class Prefix(NameManager):
-    """NameManager that prepends a fixed prefix to every generated name."""
-
-    def __init__(self, prefix):
-        super().__init__()
-        self._prefix = prefix
-
-    def get(self, name, hint):
-        return self._prefix + super().get(name, hint)
-
-
-def current():
-    """The innermost active manager (a fresh default if none entered)."""
-    mgr = getattr(_TLS, "manager", None)
-    if mgr is None:
-        mgr = _TLS.manager = NameManager()
-    return mgr

@@ -649,13 +649,12 @@ def softmax_activation(data, *, mode="instance"):
 # parallel/ring_attention.py)
 # ----------------------------------------------------------------------
 def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None, window=None):
-    """Select the fused Pallas flash kernel.  MXNET_ATTN_IMPL:
-    ``auto`` (default) = flash when the backend/geometry supports it,
-    ``xla`` = force the materialized-softmax path (A/B runs),
-    ``flash`` = require the kernel — raise instead of silently measuring
-    the wrong path when it cannot run.  The selection semantics live in
-    ``pallas.dispatch.choose_impl``, shared with the paged-attention
-    and quantize knobs so the three contracts cannot drift.
+    """Whether the fused Pallas flash pair runs the causal core
+    (``"compiled"``) or XLA does (False).  No knob: the choice is where
+    the program runs (``pallas.dispatch._compiles_here``: one TPU
+    device) and the geometry, and a refusal is counted under
+    ``pallas_fallbacks{reason}``: ``backend``, ``mesh``,
+    ``flash-geometry``, ``flash-window``.
 
     The geometry is the same whatever the head counts: the kernel shares
     a key/value head among its query heads itself (``_flash_attention``).
@@ -669,24 +668,19 @@ def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None, window=None):
     ``flash-window``.
     It is never interpreted here: only a test passes ``interpret=True``
     to ``_flash_attention``."""
-    import os
-    from ..pallas.dispatch import _compiles_here, choose_impl
-    here, why, reason = _compiles_here()
+    from ..pallas.dispatch import (PALLAS_FALLBACKS, RETRACE_SUPPRESS,
+                                   _compiles_here)
+    here, _, reason = _compiles_here()
     v_dim = head_dim if v_dim is None else v_dim
     causal = (here and head_dim >= 128 and head_dim % 64 == 0
               and v_dim % 128 == 0 and seq_len % 512 == 0
               and dtype in (jnp.bfloat16, jnp.float32))
-    band = window is None or (window > 0 and window % 512 == 0)
-    return choose_impl(
-        "MXNET_ATTN_IMPL", os.environ.get("MXNET_ATTN_IMPL", "auto"),
-        "flash", causal and band,
-        why=f"{why or 'one TPU device'}, head_dim={head_dim}, "
-            f"v_dim={v_dim}, seq={seq_len}, dtype={dtype}, "
-            f"window={window}; need a one-device TPU program, "
-            "head_dim>=128, head_dim%64==0, v_dim%128==0, seq%512==0, "
-            "window%512==0, bf16/f32",
-        fallback_reason=reason or ("flash-geometry" if not causal
-                                   else "flash-window"))
+    if causal and (window is None or (window > 0 and window % 512 == 0)):
+        return "compiled"
+    if not RETRACE_SUPPRESS.on:         # not a program-registry re-lower
+        PALLAS_FALLBACKS.labels(reason=reason or (
+            "flash-window" if causal else "flash-geometry")).inc()
+    return False
 
 
 def _flash_block_sizes(seq_len):
@@ -836,16 +830,65 @@ def _flash_attention(q, k, v, *, window=None, interpret=False):
     return _flash(q, k, v, bool(interpret), window)
 
 
-def _project_heads(spec, data, weight, bias, scale=None):
-    """``data`` times a head-major ``weight`` plus ``bias``.  With
-    ``scale`` the float32 accumulator is scaled before it is rounded to
-    ``data``'s dtype: how a query gets the softmax scale that the flash
-    kernel does not take, with one rounding."""
-    if scale is None:
+def _grouped_causal_attention(q, k, v, scale, window=None):
+    """Causal softmax attention of head-major q (B, Hq, S, D) over k
+    (B, Hk, S, D) and v (B, Hk, S, Dv) by XLA, each key/value head
+    shared by its Hq / Hk query heads; float32 scores, the probabilities
+    in q's dtype; checkpointed, so the (S, S) scores are not kept for
+    the backward pass.  With ``window`` a query attends the ``window``
+    keys that end with its own (``query - key < window``), any width.
+    What the core runs where the flash pair cannot; equal head counts
+    are the case of one query head a group."""
+    B, Hq, S, D = q.shape
+    Hk, Dv = k.shape[1], v.shape[3]
+
+    @jax.checkpoint
+    def attn(q, k, v):
+        s = jnp.einsum("bgrqe,bgke->bgrqk",
+                       q.reshape(B, Hk, Hq // Hk, S, D), k) * scale
+        back = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+        mask = back >= 0 if window is None \
+            else (back >= 0) & (back < window)
+        s = jnp.where(mask, s.astype(jnp.float32), -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        return jnp.einsum("bgrqk,bgke->bgrqe", p, v).reshape(B, Hq, S, Dv)
+
+    return attn(q, k, v)
+
+
+def _causal_attention_core(seq_len, head_dim, dtype, scale, v_dim=None,
+                           window=None):
+    """The one place that knows which causal softmax core a mixer runs,
+    who carries the softmax scale, and what runs otherwise.  A front
+    asks once a call with what the choice depends on and gets ``(fold,
+    attend)``:
+
+    ``fold``: the factor q takes in the front's last float32 stage, so
+    that q is rounded once: ``scale`` where the chosen core takes none
+    (the flash pair), 1.0 where the core applies the scale itself.
+    Multiplying by it is always right; a front that would open a float32
+    stage for it alone skips the stage at 1.0.
+
+    ``attend(q, k, v)``: head-major q (B, Hq, S, D) over k (B, Hk, S, D)
+    and v (B, Hk, S, Dv) -> (B, Hq, S, Dv), under the front's own scope:
+    ``_flash_attention`` where ``_use_flash_attention`` says so, else
+    ``_grouped_causal_attention``, either with the band."""
+    if _use_flash_attention(seq_len, head_dim, dtype, v_dim, window):
+        return scale, _functools.partial(_flash_attention, window=window)
+    return 1.0, lambda q, k, v: _grouped_causal_attention(q, k, v, scale,
+                                                          window)
+
+
+def _project_heads(spec, data, weight, bias, fold=1.0):
+    """``data`` times a head-major ``weight`` plus ``bias``.  With a
+    ``fold`` other than 1 (``_causal_attention_core``'s, for a query)
+    the float32 accumulator takes it before it is rounded to ``data``'s
+    dtype: one rounding."""
+    if fold == 1.0:
         return jnp.einsum(spec, data, weight) + bias
     acc = jnp.einsum(spec, data, weight,
                      preferred_element_type=jnp.float32)
-    return ((acc + bias) * scale).astype(data.dtype)
+    return ((acc + bias) * fold).astype(data.dtype)
 
 
 @register("_contrib_CausalSelfAttention", aliases=("CausalSelfAttention",))
@@ -853,11 +896,10 @@ def causal_self_attention(qkv, *, num_heads, scale=None):
     """Fused causal multi-head self-attention over a packed QKV tensor:
     (B, S, 3*d_model) -> (B, S, d_model).
 
-    TPU-first schedule: QK^T and PV are two MXU einsums (bf16 inputs,
-    fp32 accumulation on the MXU); softmax statistics run in fp32 inside
-    the fusion; the whole op is rematerialized in backward
-    (``jax.checkpoint``) so no (S, S) attention matrix is ever saved as
-    a residual — live memory stays O(S·d) per layer.
+    Head-major through ``_causal_attention_core``: one kernel pair
+    whose (S, S) scores never reach HBM, or two MXU einsums round a
+    float32 softmax, rematerialized in backward; either way no (S, S)
+    matrix is saved as a residual and live memory stays O(S·d) a layer.
     """
     B, S, d3 = qkv.shape
     d = d3 // 3
@@ -867,34 +909,14 @@ def causal_self_attention(qkv, *, num_heads, scale=None):
     D = d // H
     sc = (1.0 / D ** 0.5) if scale is None else float(scale)
 
-    if _use_flash_attention(S, D, qkv.dtype):
-        # Pallas flash kernel: QK^T -> online softmax -> PV in ONE kernel,
-        # blocks resident in VMEM — the (S, S) score tensor never touches
-        # HBM in forward OR backward (the kernel brings its own
-        # recomputing VJP, so no jax.checkpoint wrapper here; wrapping
-        # would re-pay the whole kernel a third time).
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-        # the kernel takes no scale and q arrives rounded: scaled in
-        # float32 and rounded once more
-        q = (q.astype(jnp.float32) * sc).astype(qkv.dtype)
-        o = _flash_attention(to_heads(q), to_heads(k), to_heads(v))
-        return o.transpose(0, 2, 1, 3).reshape(B, S, d)
-
-    @jax.checkpoint
-    def attn(qkv):
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, S, H, D)
-        k = k.reshape(B, S, H, D)
-        v = v.reshape(B, S, H, D)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * sc
-        mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-        s = jnp.where(mask, s.astype(jnp.float32), -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(qkv.dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        return o.reshape(B, S, d)
-
-    return attn(qkv)
+    fold, attend = _causal_attention_core(S, D, qkv.dtype, sc)
+    q, k, v = jnp.split(qkv, 3, axis=-1)
+    to_heads = lambda t: t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
+    if fold != 1.0:
+        # q arrives rounded: scaled in float32 and rounded once more
+        q = (q.astype(jnp.float32) * fold).astype(qkv.dtype)
+    o = attend(to_heads(q), to_heads(k), to_heads(v))
+    return o.transpose(0, 2, 1, 3).reshape(B, S, d)
 
 
 @register("_contrib_FusedCausalSelfAttention",
@@ -907,9 +929,10 @@ def fused_causal_self_attention(data, qkv_weight, qkv_bias, proj_weight,
 
     TPU-first layout trick: the projections are dot_generals that emit /
     consume the HEAD-MAJOR (B, H, S, D) layout directly, so no transpose
-    ever materialises between the matmuls and the fused Pallas flash
-    kernel (a separate (B,S,H,D)->(B,H,S,D) copy costs ~0.5 ms/layer
-    fwd+bwd at d2048/S1024 on v5e, builders' measurement).  Weight
+    ever materialises between the matmuls and the causal core
+    (``_causal_attention_core``; a separate (B,S,H,D)->(B,H,S,D) copy
+    costs ~0.5 ms/layer fwd+bwd at d2048/S1024 on v5e, builders'
+    measurement).  Weight
     layouts match the reference FullyConnected convention ((3d, d) /
     (d, d) row-major), so checkpoints from the unfused pair load
     unchanged.
@@ -939,52 +962,14 @@ def fused_causal_self_attention(data, qkv_weight, qkv_bias, proj_weight,
 
     Wqkv = qkv_weight.reshape(3, H, D, d)
     bqkv = qkv_bias.reshape(3, H, 1, D)
-    flash = _use_flash_attention(S, D, data.dtype)
+    fold, attend = _causal_attention_core(S, D, data.dtype, sc)
     proj = _functools.partial(_project_heads, "bsd,hed->bhse", data)
-    q = _shard_heads(proj(Wqkv[0], bqkv[0], sc if flash else None))
+    q = _shard_heads(proj(Wqkv[0], bqkv[0], fold))
     k = _shard_heads(proj(Wqkv[1], bqkv[1]))
     v = _shard_heads(proj(Wqkv[2], bqkv[2]))
-
-    if flash:
-        o = _flash_attention(q, k, v)
-    else:
-        @jax.checkpoint
-        def attn(q, k, v):
-            s = jnp.einsum("bhqe,bhke->bhqk", q, k) * sc
-            mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-            s = jnp.where(mask, s.astype(jnp.float32), -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-            return jnp.einsum("bhqk,bhke->bhqe", p, v)
-        o = attn(q, k, v)
-
-    o = _shard_heads(o)
+    o = _shard_heads(attend(q, k, v))
     return jnp.einsum("bhse,dhe->bsd", o,
                       proj_weight.reshape(d, H, D)) + proj_bias
-
-
-def _grouped_causal_attention(q, k, v, scale, window=None):
-    """Causal softmax attention of head-major q (B, Hq, S, D) over k
-    (B, Hk, S, D) and v (B, Hk, S, Dv) by XLA, each key/value head
-    shared by its Hq / Hk query heads; float32 scores, the probabilities
-    in q's dtype; checkpointed, so the (S, S) scores are not kept for
-    the backward pass.  With ``window`` a query attends the ``window``
-    keys that end with its own (``query - key < window``), any width.
-    What the mixers run where the flash kernel cannot."""
-    B, Hq, S, D = q.shape
-    Hk, Dv = k.shape[1], v.shape[3]
-
-    @jax.checkpoint
-    def attn(q, k, v):
-        s = jnp.einsum("bgrqe,bgke->bgrqk",
-                       q.reshape(B, Hk, Hq // Hk, S, D), k) * scale
-        back = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
-        mask = back >= 0 if window is None \
-            else (back >= 0) & (back < window)
-        s = jnp.where(mask, s.astype(jnp.float32), -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        return jnp.einsum("bgrqk,bgke->bgrqe", p, v).reshape(B, Hq, S, Dv)
-
-    return attn(q, k, v)
 
 
 def _shift_right(x, n, axis):
@@ -1038,13 +1023,11 @@ def compressed_conv_attention(data, q_weight, k_weight, v_weight,
     FullyConnected's.
 
     Head-major like FusedCausalSelfAttention: the projections emit and
-    consume (B, H, S, D).  Where ``_use_flash_attention`` allows it the
-    Pallas flash kernel takes K and V at their own ``kv_heads`` and
-    shares each among its query heads itself (q then carries the softmax
-    scale: it is given length 1, not sqrt(head_dim)); the checkpointed
-    XLA path otherwise.  Everything between the
-    projections and the attention is cheap and rematerialized in the
-    backward pass, so a layer saves its latents and no float32 copy of
+    consume (B, H, S, D), and ``_causal_attention_core`` takes K and V
+    at their own ``kv_heads`` (its fold joins the length q is given:
+    1 where q carries the softmax scale, not sqrt(head_dim)).
+    Everything between the projections and the attention is cheap and
+    rematerialized in the backward pass, so a layer saves its latents and no float32 copy of
     them.  Scopes for a reader of the raw trace: ``cca.proj``,
     ``cca.conv``, ``cca.attention``."""
     B, S, d = data.shape
@@ -1067,11 +1050,10 @@ def compressed_conv_attention(data, q_weight, k_weight, v_weight,
                        .reshape(H, D, d))
         v2 = jnp.einsum("bsd,hed->bhse", data, v_weight.reshape(2, D, d))
 
-    sc = 1.0 / D ** 0.5
-    flash = _use_flash_attention(S, D, data.dtype)
-    # the flash kernel takes no softmax scale: there q carries it, in
-    # the float32 length it is given before its one rounding
-    q_length = 1.0 if flash else D ** 0.5
+    fold, attend = _causal_attention_core(S, D, data.dtype, 1.0 / D ** 0.5)
+    # the fold joins the float32 length q is given before its one
+    # rounding: 1 where q carries the softmax scale, sqrt(head_dim) else
+    q_length = D ** 0.5 * fold
 
     @jax.checkpoint
     def mix(z, v2, w0, w1, temp):
@@ -1098,8 +1080,7 @@ def compressed_conv_attention(data, q_weight, k_weight, v_weight,
         q, k, v = mix(z, v2, conv0_weight, conv1_weight, temp)
 
     with jax.named_scope("cca.attention"):
-        o = _flash_attention(q, k, v) if flash \
-            else _grouped_causal_attention(q, k, v, sc)
+        o = attend(q, k, v)
 
     with jax.named_scope("cca.proj"):
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
@@ -1124,11 +1105,10 @@ def gated_causal_self_attention(data, q_weight, k_weight, v_weight,
     key/value head; the result times ``sigmoid(gate)``, then
     ``o_weight`` (d, q_heads * head_dim).
 
-    Head-major like CompressedConvAttention.  Where
-    ``_use_flash_attention`` allows it the Pallas flash kernel takes K
-    and V at their own ``kv_heads`` (q then carries the softmax scale);
-    the checkpointed XLA path otherwise.  Norms and rotary are
-    rematerialized in the backward pass.  Scopes: ``gattn.proj``,
+    Head-major like CompressedConvAttention; ``_causal_attention_core``
+    takes K and V at their own ``kv_heads`` (its fold joins the query
+    norm's float32 gain).  Norms and rotary are rematerialized in the
+    backward pass.  Scopes: ``gattn.proj``,
     ``gattn.norm``, ``gattn.attention``."""
     B, S, d = data.shape
     Hq, Hk, D = int(q_heads), int(kv_heads), int(head_dim)
@@ -1144,8 +1124,7 @@ def gated_causal_self_attention(data, q_weight, k_weight, v_weight,
         k0 = jnp.einsum("bsd,hed->bhse", data, k_weight.reshape(Hk, D, d))
         v = jnp.einsum("bsd,hed->bhse", data, v_weight.reshape(Hk, D, d))
 
-    sc = D ** -0.5
-    flash = _use_flash_attention(S, D, data.dtype)
+    fold, attend = _causal_attention_core(S, D, data.dtype, D ** -0.5)
 
     @jax.checkpoint
     def prepare(q0, k0, gq, gk):
@@ -1153,9 +1132,7 @@ def gated_causal_self_attention(data, q_weight, k_weight, v_weight,
             t = t.astype(f32)
             inv = lax.rsqrt(jnp.mean(jnp.square(t), -1, keepdims=True) + eps)
             return t * inv * ((1.0 + gain.astype(f32)) * scale)
-        # the flash kernel takes no softmax scale: there q carries it
-        q = _rotary_half(norm(q0, gq, sc if flash else 1.0), rot,
-                         float(rope_theta))
+        q = _rotary_half(norm(q0, gq, fold), rot, float(rope_theta))
         k = _rotary_half(norm(k0, gk, 1.0), rot, float(rope_theta))
         return q.astype(q0.dtype), k.astype(k0.dtype)
 
@@ -1163,8 +1140,7 @@ def gated_causal_self_attention(data, q_weight, k_weight, v_weight,
         q, k = prepare(q0, k0, q_norm_gamma, k_norm_gamma)
 
     with jax.named_scope("gattn.attention"):
-        o = _flash_attention(q, k, v) if flash \
-            else _grouped_causal_attention(q, k, v, sc)
+        o = attend(q, k, v)
         o = (o.astype(f32) * jax.nn.sigmoid(gate.astype(f32))) \
             .astype(data.dtype)
 
@@ -1193,14 +1169,10 @@ def grouped_query_attention(data, q_weight, k_weight, v_weight, o_weight, *,
     attention.  A model mixes both kinds of layer by giving each its own
     attributes.
 
-    Head-major like GatedCausalSelfAttention.  Where
-    ``_use_flash_attention`` allows it the Pallas flash pair runs the
-    core, banded where the layer is (q then carries the softmax scale:
-    from the projection's float32 accumulator on a layer without
-    position, from the rotation's float32 on one with); the checkpointed
-    XLA path with the band's mask otherwise (the CPU, a mesh, other
-    shapes; a window that is not whole blocks of 512 is counted under
-    ``pallas_fallbacks{reason="flash-window"}``).  The rotation is
+    Head-major like GatedCausalSelfAttention; ``_causal_attention_core``
+    runs the core, banded where the layer is (its fold joins the
+    projection's float32 accumulator on a layer without position, the
+    rotation's float32 on one with).  The rotation is
     linear: its backward needs the angles alone, which are made again
     from the positions, and nothing of its input is kept.  Scopes:
     ``gqa.proj``, ``gqa.rope``, and the core under ``gqa.window`` (a
@@ -1211,25 +1183,26 @@ def grouped_query_attention(data, q_weight, k_weight, v_weight, o_weight, *,
         raise ValueError("q_heads %d not a multiple of kv_heads %d"
                          % (Hq, Hk))
     band = int(window) if 0 < int(window) < S else None
-    flash = _use_flash_attention(S, D, data.dtype, window=band)
-    sc = D ** -0.5
+    fold, attend = _causal_attention_core(S, D, data.dtype, D ** -0.5,
+                                          window=band)
     f32 = jnp.float32
     turned = bool(rotary)
     with jax.named_scope("gqa.proj"):
-        # the flash kernel takes no softmax scale: there q carries it
+        # q takes the fold in float32: the rotation's where the layer
+        # turns, else the projection's accumulator
         heads = lambda w, n, **kw: jnp.einsum(
             "bsd,hed->bhse", data, w.reshape(n, D, d), **kw)
-        q = heads(q_weight, Hq) if turned or not flash else (heads(
-            q_weight, Hq, preferred_element_type=f32) * sc).astype(data.dtype)
+        q = heads(q_weight, Hq) if turned or fold == 1.0 else (heads(
+            q_weight, Hq, preferred_element_type=f32) * fold) \
+            .astype(data.dtype)
         k, v = heads(k_weight, Hk), heads(v_weight, Hk)
     if turned:
         with jax.named_scope("gqa.rope"):
             turn = lambda t, scale: (_rotary_half(
                 t.astype(f32), D, float(rope_theta)) * scale).astype(t.dtype)
-            q, k = turn(q, sc if flash else 1.0), turn(k, 1.0)
+            q, k = turn(q, fold), turn(k, 1.0)
     with jax.named_scope("gqa.window" if band else "gqa.full"):
-        o = _flash_attention(q, k, v, window=band) if flash \
-            else _grouped_causal_attention(q, k, v, sc, band)
+        o = attend(q, k, v)
     with jax.named_scope("gqa.proj"):
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
 
@@ -1271,11 +1244,9 @@ def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
     ``o_weight`` (d, heads * v_dim).  Weights are (out, in), as
     FullyConnected's, in the source's row order.
 
-    Head-major like the other mixers.  Where ``_use_flash_attention``
-    allows it (keys 192 wide and values 128 on one TPU device) the
-    Pallas flash kernel runs (q then carries the softmax scale) and the
-    (S, S) scores never reach memory; the checkpointed XLA path
-    otherwise.  The latent's norm, the rotary and the assembly of q and
+    Head-major like the other mixers; ``_causal_attention_core`` runs
+    the core at keys 192 wide and values 128 (its fold joins q's float32
+    before the rotation).  The latent's norm, the rotary and the assembly of q and
     k are float32 and rematerialized in the backward pass.  Scopes:
     ``mla.proj``, ``mla.norm``, ``mla.attention``."""
     B, S, d = data.shape
@@ -1287,8 +1258,7 @@ def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
         q0 = jnp.einsum("bsd,hed->bhse", data, q_weight.reshape(H, D, d))
         ckr = jnp.einsum("bsd,ed->bse", data, kva_weight)
 
-    sc = D ** -0.5
-    flash = _use_flash_attention(S, D, data.dtype, Dv)
+    fold, attend = _causal_attention_core(S, D, data.dtype, D ** -0.5, Dv)
     theta = float(rope_theta)
 
     @jax.checkpoint
@@ -1307,8 +1277,7 @@ def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
 
     @jax.checkpoint
     def position(q0, k0, ckr):
-        # the flash kernel takes no softmax scale: there q carries it
-        qf = q0.astype(f32) * (sc if flash else 1.0)
+        qf = q0.astype(f32) * fold
         q = jnp.concatenate(
             [qf[..., :Dn], _rotary_interleaved(qf[..., Dn:], theta)], -1)
         kr = _rotary_interleaved(ckr[..., C:].astype(f32), theta)
@@ -1321,8 +1290,7 @@ def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
         q, k = position(q0, k0, ckr)
 
     with jax.named_scope("mla.attention"):
-        o = _flash_attention(q, k, v) if flash \
-            else _grouped_causal_attention(q, k, v, sc)
+        o = attend(q, k, v)
 
     with jax.named_scope("mla.proj"):
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, H, Dv))
@@ -1668,8 +1636,8 @@ def paged_prefill_attention(data, qkv_weight, qkv_bias, proj_weight,
     data (B, S, d) is the padded prompt batch; lengths (B,) the real
     prompt lengths; block_table (B, M) the destination blocks.  The
     attention itself is the same head-major causal MHA as
-    FusedCausalSelfAttention (flash kernel when the TPU geometry
-    allows, fp32-softmax XLA path otherwise); additionally K/V rows for
+    FusedCausalSelfAttention (``_causal_attention_core``); additionally
+    K/V rows for
     positions < length are scattered into the cache so decode can
     continue the sequence.  Outputs (hidden (B, S, d), new_k_cache,
     new_v_cache)."""
@@ -1703,20 +1671,12 @@ def paged_prefill_attention(data, qkv_weight, qkv_bias, proj_weight,
 
     Wqkv = qkv_weight.reshape(3, H, D, d)
     bqkv = qkv_bias.reshape(3, H, 1, D)
-    flash = _use_flash_attention(S, D, data.dtype)
+    fold, attend = _causal_attention_core(S, D, data.dtype, sc)
     proj = _functools.partial(_project_heads, "bsd,hed->bhse", data)
-    q = proj(Wqkv[0], bqkv[0], sc if flash else None)
+    q = proj(Wqkv[0], bqkv[0], fold)
     k = proj(Wqkv[1], bqkv[1])
     v = proj(Wqkv[2], bqkv[2])
-
-    if flash:
-        o = _flash_attention(q, k, v)
-    else:
-        s = jnp.einsum("bhqe,bhke->bhqk", q, k) * sc
-        mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-        s = jnp.where(mask, s.astype(jnp.float32), -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhqk,bhke->bhqe", p, v)
+    o = attend(q, k, v)
     out = jnp.einsum("bhse,dhe->bsd", o,
                      proj_weight.reshape(d, H, D)) + proj_bias
 

@@ -14,8 +14,8 @@ this package holds the custom TPU kernels behind the framework's
   forward/backward for the transformer symbol path (``MXNET_LN_IMPL``;
   the ISSUE 17 registry-ranked kernel).
 * :mod:`dispatch` — the one ``auto|<kernel>|xla`` selection contract
-  shared by every kernel knob (``MXNET_ATTN_IMPL``,
-  ``MXNET_PAGED_ATTN_IMPL``, ``MXNET_Q2BIT_IMPL``, ``MXNET_LN_IMPL``),
+  shared by every kernel knob (``MXNET_PAGED_ATTN_IMPL``,
+  ``MXNET_Q2BIT_IMPL``, ``MXNET_LN_IMPL``),
   plus the ``pallas_kernel_launches`` / ``pallas_fallbacks``
   witnesses.
 

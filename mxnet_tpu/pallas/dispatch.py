@@ -1,8 +1,10 @@
 """Kernel-impl selection — one ``auto|<kernel>|xla`` contract.
 
-``MXNET_ATTN_IMPL`` (flash), ``MXNET_PAGED_ATTN_IMPL`` (paged
-decode/prefill) and ``MXNET_Q2BIT_IMPL`` (kvstore 2-bit quantize) all
-route through :func:`choose_impl`, so the three knobs cannot drift:
+``MXNET_PAGED_ATTN_IMPL`` (paged decode/prefill), ``MXNET_LN_IMPL``
+(LayerNorm) and ``MXNET_Q2BIT_IMPL`` (kvstore 2-bit quantize) all
+route through :func:`choose_impl`, so the knobs cannot drift (the
+training kernels have none: they choose from :func:`_compiles_here` and
+their shapes, and count a refusal the same way):
 
 * ``auto`` (default) — the kernel when the backend/geometry supports
   it profitably, the XLA reference path otherwise (the fallback bumps
@@ -19,7 +21,7 @@ route through :func:`choose_impl`, so the three knobs cannot drift:
 
 The decisions here run at TRACE time (inside the enclosing jitted
 program's Python), so they are per-program-construction, not
-per-launch — same contract as ``_use_flash_attention`` always had.
+per-launch.
 """
 import os
 

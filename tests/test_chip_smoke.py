@@ -39,10 +39,12 @@ def kernels_forced(monkeypatch):
     """On a chip ``auto`` picks the compiled kernels.  Here the knobs
     force the same kernels (interpret mode) so the phases' "the kernel
     is in the program, and nothing fell back" checks see what they would
-    see there; flash has no interpret path, so it is switched off."""
+    see there; the flash pair has no knob and no interpret path: its
+    choice answers no here without booking the fallback it would."""
+    from mxnet_tpu.ops import nn
     monkeypatch.setenv("MXNET_LN_IMPL", "pallas")
     monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "pallas")
-    monkeypatch.setenv("MXNET_ATTN_IMPL", "xla")
+    monkeypatch.setattr(nn, "_use_flash_attention", lambda *a, **k: False)
 
 
 def _lines(capsys):

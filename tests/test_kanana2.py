@@ -116,8 +116,8 @@ def test_latent_attention_with_the_flash_kernel_at_192_and_128(
     monkeypatch.setattr(nn, "_use_flash_attention",
                         lambda *a, **k: "interpret")
     flash = nn._flash_attention
-    monkeypatch.setattr(nn, "_flash_attention",
-                        lambda q, k, v: flash(q, k, v, interpret=True))
+    monkeypatch.setattr(nn, "_flash_attention", lambda q, k, v, window=None:
+                        flash(q, k, v, window=window, interpret=True))
     seen = []
     kernel = nn._flash_kernel
     monkeypatch.setattr(nn, "_flash_kernel",
@@ -375,7 +375,6 @@ def test_flash_geometries_of_the_cells(monkeypatch, cell, S_, D, Dv, Hq, Hk,
     # the gate, asked as a one-device TPU program would be
     monkeypatch.setattr(dispatch, "_compiles_here",
                         lambda: (True, "", None))
-    monkeypatch.delenv("MXNET_ATTN_IMPL", raising=False)
     gate = lambda *a: nn._use_flash_attention(*a)
     assert gate(S_, D, jnp.bfloat16, *(() if Dv is None else (Dv,)))
     assert not gate(S_, 96, jnp.bfloat16)           # under a lane tile

@@ -370,20 +370,36 @@ def test_choose_impl_semantics():
 
 
 def test_flash_and_paged_knobs_share_one_contract(monkeypatch):
-    """Satellite 6: MXNET_ATTN_IMPL and MXNET_PAGED_ATTN_IMPL route
-    through the same helper — same error shape, same auto/force/off
-    semantics."""
+    """The flash pair's choice has no knob and is counted: on the CPU it
+    is the XLA core under ``pallas_fallbacks{reason="backend"}`` whatever
+    the environment says, in a one-device TPU program the kernels, in one
+    partitioned over a selected mesh ``mesh``, at a shape they refuse
+    ``flash-geometry`` (only a test hands ``_flash_attention``
+    ``interpret=True``).  The paged knob keeps ``choose_impl``'s
+    contract: forced off-TPU it runs via interpret mode."""
     from mxnet_tpu.ops.nn import _use_flash_attention
     from mxnet_tpu.pallas.dispatch import use_paged_pallas
-    monkeypatch.setenv("MXNET_ATTN_IMPL", "bogus")
-    with pytest.raises(ValueError, match=r"use auto\|flash\|xla"):
-        _use_flash_attention(512, 128, jnp.float32)
-    # flash forced off-TPU raises (the knob never interprets it: only a
-    # test hands _flash_attention interpret=True); paged forced off-TPU
-    # runs via interpret mode
-    monkeypatch.setenv("MXNET_ATTN_IMPL", "flash")
-    with pytest.raises(ValueError, match="cannot run here"):
-        _use_flash_attention(512, 128, jnp.float32)
+    count = lambda reason: PALLAS_FALLBACKS.labels(reason=reason).value
+    before = count("backend")
+    for value in ("flash", "xla", "bogus"):     # the name the knob had
+        monkeypatch.setenv("MXNET_ATTN_IMPL", value)
+        assert _use_flash_attention(512, 128, jnp.float32) is False
+    assert count("backend") == before + 3
+    monkeypatch.delenv("MXNET_ATTN_IMPL")
+    environ = dict(os.environ)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        assert _use_flash_attention(512, 128, jnp.float32) == "compiled"
+        before = count("flash-geometry"), count("mesh")
+        assert _use_flash_attention(512, 96, jnp.float32) is False
+        mx.sharding.set_mesh({"dp": 4, "mp": 2})
+        try:
+            assert _use_flash_attention(512, 128, jnp.float32) is False
+        finally:
+            mx.sharding.set_mesh(None)
+        assert (count("flash-geometry"), count("mesh")) \
+            == (before[0] + 1, before[1] + 1)
+    assert dict(os.environ) == environ
     monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "pallas")
     assert use_paged_pallas() == "interpret"
     monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "xla")
@@ -391,6 +407,52 @@ def test_flash_and_paged_knobs_share_one_contract(monkeypatch):
     monkeypatch.setenv("MXNET_PAGED_ATTN_IMPL", "bogus")
     with pytest.raises(ValueError, match=r"use auto\|pallas\|xla"):
         use_paged_pallas()
+
+
+@pytest.mark.parametrize("window", [None, 4], ids=["causal", "band4"])
+@pytest.mark.parametrize("Hq,Hk", [(4, 4), (4, 2)], ids=["h4to4", "h4to2"])
+@pytest.mark.parametrize("chosen", ["xla", "flash"])
+def test_the_causal_core_hands_a_front_its_fold_and_its_attend(
+        monkeypatch, chosen, Hq, Hk, window):
+    """``_causal_attention_core`` is asked once a call and answers with
+    ``(fold, attend)``.  Where the XLA core runs (the CPU, a mesh, a
+    shape the kernels refuse) it applies the scale itself: the fold is
+    1.0 and ``attend`` is ``_grouped_causal_attention`` with the scale
+    and the band, to the bit.  Where the flash pair runs the fold IS the
+    scale (the kernels take none) and ``attend`` hands q, k, v on
+    untouched, the band by keyword: with q scaled by the fold the two
+    answers agree."""
+    from mxnet_tpu.ops import nn
+    B, S, D, scale = 2, 12, 8, 0.3
+    ks = jax.random.split(jax.random.PRNGKey(Hq * 10 + Hk), 3)
+    q = jax.random.normal(ks[0], (B, Hq, S, D))
+    k = jax.random.normal(ks[1], (B, Hk, S, D))
+    v = jax.random.normal(ks[2], (B, Hk, S, D))
+    want = nn._grouped_causal_attention(q, k, v, scale, window)
+    asked, seen = [], []
+    if chosen == "flash":
+        def gate(*a):
+            asked.append(a)
+            return "compiled"
+
+        def kernels(q, k, v, *, window=None):
+            # what the pair computes: q already carries the scale
+            seen.append(window)
+            return nn._grouped_causal_attention(q, k, v, 1.0, window)
+
+        monkeypatch.setattr(nn, "_use_flash_attention", gate)
+        monkeypatch.setattr(nn, "_flash_attention", kernels)
+    fold, attend = nn._causal_attention_core(S, D, q.dtype, scale, None,
+                                             window)
+    if chosen == "xla":
+        assert fold == 1.0
+        assert np.array_equal(np.asarray(attend(q, k, v)), np.asarray(want))
+    else:
+        assert fold == scale and asked == [(S, D, q.dtype, None, window)]
+        got = attend(q * fold, k, v)
+        assert seen == [window]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-6)
 
 
 def _materialised_causal_attention(q, k, v):
@@ -653,7 +715,8 @@ def test_flash_branch_of_each_operator_matches_its_xla_branch(
     kernel = nn._flash_attention
     monkeypatch.setattr(
         nn, "_flash_attention",
-        lambda q, k, v: kernel(q, k, v, interpret=True))
+        lambda q, k, v, window=None: kernel(q, k, v, window=window,
+                                            interpret=True))
     got = run()
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):

@@ -247,7 +247,6 @@ def test_a_window_that_is_not_whole_blocks_is_refused_and_counted(
     with pytest.raises(ValueError, match="whole blocks"):
         flash_attention_backward(q, k, v, q, q[..., 0], w, window=300,
                                  interpret=True)
-    monkeypatch.delenv("MXNET_ATTN_IMPL", raising=False)
     monkeypatch.setattr(dispatch, "_compiles_here", lambda: (True, "", None))
     band = dispatch.PALLAS_FALLBACKS.labels(reason="flash-window")
     shape = dispatch.PALLAS_FALLBACKS.labels(reason="flash-geometry")
