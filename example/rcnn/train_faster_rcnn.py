@@ -152,6 +152,9 @@ def main():
               "roi_label": (B * POST_NMS,)}
     ex = net.simple_bind(ctx=mx.cpu(), grad_req="write", **shapes)
     rng = np.random.RandomState(0)
+    # Xavier draws from numpy's global stream: unseeded, the first
+    # accuracy moved 0.45 .. 0.88 run to run and the check below with it
+    np.random.seed(0)
     init = mx.initializer.Xavier()
     for name, arr in ex.arg_dict.items():
         if name in shapes:

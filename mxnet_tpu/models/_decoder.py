@@ -1,8 +1,8 @@
 """The frame of the expert-layer decoders (``zaya``, ``qwen3_next``,
-``kanana2``, ``keye_vl2``, ``smallthinker``): everything such a model
-is apart from its layers.  A family's file says its mixer, its expert
-sublayer's attributes and its layer schedule; token ids enter and
-logits leave here:
+``kanana2``, ``keye_vl2``, ``smallthinker``, ``sdar_moe``): everything
+such a model is apart from its layers.  A family's file says its mixer,
+its expert sublayer's attributes and its layer schedule; token ids enter
+and logits leave here:
 
 * ``experts_held``, the chip's share of a layer's experts (how many,
   from expert 0, or ``[first, count]``), parsed and checked;
@@ -12,7 +12,10 @@ logits leave here:
 * the closing norm, the head (its own matrix, or the embedding's in the
   trunk's dtype), float32 logits flattened to (tokens, vocab) and the
   ``SoftmaxOutput`` normalised by the batch: output 0, what ``ce``
-  reads;
+  reads; a family whose objective is not the plain mean over all rows
+  (the seventh, ``sdar_moe``: a masked, weighted denoising loss) hands
+  ``close`` a hook that stands between ``lm_head`` and the cast, so
+  that the head's chain stays the deferred one (``loss_head.py``);
 * the experts' token counts of every expert sublayer, stacked to
   (expert layers, num_experts) int32 behind ``BlockGrad`` under
   ``telemetry.moe.COUNTS_NODE``.
@@ -60,22 +63,27 @@ class Decoder:
         return sym.RMSNorm(x, gamma=weight(name + "_gamma", self.norm_init),
                            name=name, **self.norm_attrs)
 
-    def embed(self, init=_init.Normal(1.0)):
-        """(B, S) token ids (``data``) -> the stream (B, S, d_model) in
-        the trunk's dtype."""
+    def embed(self, init=_init.Normal(1.0), ids=None, cast=True):
+        """(B, S) token ids (``data``, or ``ids`` where the family cuts
+        them out of ``data`` itself) -> the stream (B, S, d_model) in the
+        trunk's dtype; with ``cast=False`` in the table's float32 (a
+        family that keeps its residual stream float32: ``sdar_moe``)."""
         self.table = weight("tok_embed_weight", init,
                             shape=(self.vocab, self.d), **F32)
-        x = sym.Embedding(sym.Variable("data"), weight=self.table,
+        x = sym.Embedding(sym.Variable("data") if ids is None else ids,
+                          weight=self.table,
                           input_dim=self.vocab, output_dim=self.d,
                           name="tok_embed")
         return sym.Cast(data=x, dtype=self.dtype, name="cast_embed") \
-            if self.low else x
+            if self.low and cast else x
 
-    def close(self, x, counts, tied=False):
+    def close(self, x, counts, tied=False, rows=None):
         """The stream after the last layer and the expert sublayers'
         counts -> ``[softmax, counts]``, which the family groups (with
         what it appends).  ``tied``: the head is the embedding, read in
-        the trunk's dtype."""
+        the trunk's dtype.  ``rows``: the family's hook on the logits as
+        ``lm_head`` wrote them (trunk's dtype, (B, S, vocab)), before
+        the cast: what it returns is the loss head's stem."""
         if not tied:
             head = weight("lm_head_weight")
         elif self.low:
@@ -87,6 +95,8 @@ class Decoder:
                                     weight=head, no_bias=True,
                                     num_hidden=self.vocab, flatten=False,
                                     name="lm_head")
+        if rows is not None:
+            logits = rows(logits)
         if self.low:
             logits = sym.Cast(data=logits, dtype="float32", name="cast_out")
         flat = sym.Reshape(data=logits, shape=(-1, self.vocab),

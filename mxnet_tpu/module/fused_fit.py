@@ -408,6 +408,9 @@ class FusedFitStep:
         # output index of the sparse attention's live-tile counts
         # (telemetry.dsa), else None
         self._dsa_tiles = _telemetry.dsa.find(module._symbol)
+        # output index of a diffusion head's masked-row counts
+        # (telemetry.diffusion), else None
+        self._diffusion_rows = _telemetry.diffusion.find(module._symbol)
         self.launches = 0
         self._mem_tracker = _telemetry.StepMemoryTracker() \
             if _MEM_EVERY else None
@@ -937,6 +940,8 @@ class FusedFitStep:
             _telemetry.moe.note(outs[i], first, held)
         if self._dsa_tiles is not None:
             _telemetry.dsa.note(outs[self._dsa_tiles])
+        if self._diffusion_rows is not None:
+            _telemetry.diffusion.note(outs[self._diffusion_rows])
         exe._pending_train_fwd = False
         exe._train_seed = None
         exe._train_auxs = None

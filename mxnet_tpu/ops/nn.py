@@ -643,18 +643,70 @@ def softmax_activation(data, *, mode="instance"):
     return jax.nn.softmax(data.reshape(data.shape[0], -1), axis=-1).reshape(data.shape)
 
 
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _diffusion_rows(logits, noised, weight, mask_id):
+    masked = (noised == mask_id)[..., None]
+    kept = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1) \
+        == noised.astype(jnp.int32)[..., None]
+    low = -0.7 * float(jnp.finfo(logits.dtype).max)
+    return jnp.where(masked, logits,
+                     jnp.where(kept, 0.0, low).astype(logits.dtype))
+
+
+def _diffusion_rows_fwd(logits, noised, weight, mask_id):
+    return _diffusion_rows(logits, noised, weight, mask_id), (noised, weight)
+
+
+def _diffusion_rows_bwd(mask_id, res, g):
+    noised, weight = res
+    scale = jnp.where(noised == mask_id, weight.astype(jnp.float32), 0.0)
+    return ((g.astype(jnp.float32) * scale[..., None]).astype(g.dtype),
+            jnp.zeros_like(noised), jnp.zeros_like(weight))
+
+
+_diffusion_rows.defvjp(_diffusion_rows_fwd, _diffusion_rows_bwd)
+
+
+@register("_contrib_DiffusionHead", aliases=("DiffusionHead",),
+          num_outputs=2)
+def diffusion_head(data, noised, weight, *, mask_id):
+    """The masked, weighted rows of a masked-diffusion loss, put between
+    a model's logits and its normalising loss head: ``data`` (..., V)
+    the logits of the noised rows, ``noised`` (...) their input ids
+    ``xt`` (float or int) and ``weight`` (...) a row's loss weight.
+
+    Forward, a row whose ``xt`` is ``mask_id`` passes; any other row is
+    replaced by one that puts all its mass on ``xt`` itself, so that its
+    cross-entropy at the label (the token it shows) reads 0: the
+    carry-over of the masked-diffusion parameterisation.  Backward, a
+    masked row's cotangent is multiplied by its weight (in float32,
+    rounded once) and every other row's is 0.  So a ``SoftmaxOutput``
+    behind it reads ``(1 / rows) sum_i m_i ce_i`` through ``ce`` and
+    sends the gradient of ``(1 / rows) sum_i m_i w_i ce_i``, and the
+    operator is that head's stem (``loss_head.py``): the probabilities
+    stay deferred.
+
+    Output 1 is ``(masked rows, rows)`` int32, for
+    ``telemetry.diffusion``.  Scope ``head.diffusion``."""
+    with jax.named_scope("head.diffusion"):
+        masked = noised == mask_id
+        rows = jnp.stack([jnp.sum(masked), masked.size]).astype(jnp.int32)
+        return _diffusion_rows(data, noised, weight, float(mask_id)), rows
+
+
 # ----------------------------------------------------------------------
 # Attention (new TPU-native capability — the reference predates
 # attention entirely, SURVEY.md §5.7; sequence-parallel forms live in
 # parallel/ring_attention.py)
 # ----------------------------------------------------------------------
-def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None, window=None):
-    """Whether the fused Pallas flash pair runs the causal core
+def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None, window=None,
+                         blocks=None):
+    """Whether the fused Pallas flash pair runs the masked softmax core
     (``"compiled"``) or XLA does (False).  No knob: the choice is where
     the program runs (``pallas.dispatch._compiles_here``: one TPU
     device) and the geometry, and a refusal is counted under
     ``pallas_fallbacks{reason}``: ``backend``, ``mesh``,
-    ``flash-geometry``, ``flash-window``.
+    ``flash-geometry``, ``flash-window``, ``flash-blocks``.
 
     The geometry is the same whatever the head counts: the kernel shares
     a key/value head among its query heads itself (``_flash_attention``).
@@ -665,7 +717,10 @@ def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None, window=None):
     to the MXU's edge by the compiler, not by the caller).  A band
     (``window``, None for plain causal attention) is whole score blocks
     of 512; one that is not is the only refusal counted under reason
-    ``flash-window``.
+    ``flash-window``.  A block-diffusion mask (``blocks``, the block
+    length; ``seq_len`` is the clean and the noised half together) is a
+    block length that divides 512 and halves of whole score blocks; one
+    that is not is counted under ``flash-blocks``.
     It is never interpreted here: only a test passes ``interpret=True``
     to ``_flash_attention``."""
     from ..pallas.dispatch import (PALLAS_FALLBACKS, RETRACE_SUPPRESS,
@@ -675,11 +730,16 @@ def _use_flash_attention(seq_len, head_dim, dtype, v_dim=None, window=None):
     causal = (here and head_dim >= 128 and head_dim % 64 == 0
               and v_dim % 128 == 0 and seq_len % 512 == 0
               and dtype in (jnp.bfloat16, jnp.float32))
-    if causal and (window is None or (window > 0 and window % 512 == 0)):
+    if blocks is not None:
+        masked = blocks > 0 and 512 % blocks == 0 and seq_len % 1024 == 0
+    else:
+        masked = window is None or (window > 0 and window % 512 == 0)
+    if causal and masked:
         return "compiled"
     if not RETRACE_SUPPRESS.on:         # not a program-registry re-lower
         PALLAS_FALLBACKS.labels(reason=reason or (
-            "flash-window" if causal else "flash-geometry")).inc()
+            "flash-geometry" if not causal else
+            "flash-window" if blocks is None else "flash-blocks")).inc()
     return False
 
 
@@ -697,23 +757,87 @@ def _flash_block_sizes(seq_len):
     return BlockSizes(block_q=rows, block_kv=rows, block_kv_compute=512)
 
 
+def _block_diffusion_allowed(seq_len, blocks):
+    """``allowed(q_ids, kv_ids)`` of the block-diffusion mask over
+    ``seq_len`` rows, a clean sequence of ``L = seq_len / 2`` rows (whole
+    blocks of ``blocks``) and its noised copy side by side.  With
+    ``pos(r) = r mod L`` and ``blk(r) = pos(r) // blocks``: a clean
+    query sees the clean keys of ``blk(s) <= blk(t)``, a noised query
+    the clean keys of ``blk(s) < blk(t)`` and the noised keys of
+    ``blk(s) == blk(t)``; a clean query never sees a noised key.
+    Operators alone (numpy blocks for a kernel's tables, int32 tiles
+    inside a kernel, index arrays for XLA), and few: a partial block
+    pays them a cell."""
+    half = seq_len // 2
+    if blocks & (blocks - 1):           # any length: XLA's path alone
+        blk = lambda r: r // blocks
+    else:
+        shift = blocks.bit_length() - 1
+        blk = lambda r: r >> shift
+    # blocks counted over all 2 L rows: a noised row's lies ``ahead``
+    # past its clean twin's
+    ahead = half // blocks
+
+    def allowed(q_ids, kv_ids):
+        # Same block: a clean row's own block, a noised row's own noised
+        # block.  Else a clean query takes the blocks before its own,
+        # and a noised one those before its clean twin's: ``ahead + 1``
+        # back, which no noised key's block is.
+        bq, bk = blk(q_ids), blk(kv_ids)
+        return (bk == bq) | (bk <= bq - (ahead + 1) * (q_ids >= half))
+
+    return allowed
+
+
+def _block_diffusion_mask(seq_len, blocks):
+    """The block-diffusion mask (``_block_diffusion_allowed``) as a
+    splash-attention ``Mask`` that is never an array: the kernel's
+    tables ask it for a block at a time (``__getitem__``) and the kernel
+    computes a partial block's cells from the row numbers."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import \
+        splash_attention_mask as _masks
+
+    class BlockDiffusionMask(_masks._ComputableMask):
+        def __init__(self, seq_len, blocks):
+            self.blocks = int(blocks)
+            super().__init__((seq_len, seq_len),
+                             _block_diffusion_allowed(seq_len, blocks))
+
+        # two masks are one where length AND block length agree: the
+        # kernel's cached tables are keyed by the mask
+        def __eq__(self, other):
+            return isinstance(other, type(self)) \
+                and (self.shape, self.blocks) == (other.shape, other.blocks)
+
+        def __hash__(self):
+            return hash((type(self).__name__, self.shape, self.blocks))
+
+    return BlockDiffusionMask(seq_len, blocks)
+
+
 @_functools.lru_cache(maxsize=None)
-def _flash_kernel(q_heads, seq_len, interpret, residuals=False, window=None):
-    """jax's splash-attention forward kernel for one causal, optionally
-    banded, sequence, built once per head count, length and window (the
-    widths and the key/value head count are the operands' own): the
-    mask's block tables are host numpy work at trace time, and every
-    layer of a model asks for the same ones.  With ``window`` the mask
-    is jax's ``LocalMask`` (``t - (window - 1) <= s <= t``) and its
-    tables drop the blocks left of the band: at 16 384 rows and a window
-    of 4096, 70 of the 136 causal 1024-row blocks.  With ``residuals``
-    the kernel returns ``(o, (lse,))``, the rows' log-sum-exp beside the
-    result, as a backward needs it."""
+def _flash_kernel(q_heads, seq_len, interpret, residuals=False, window=None,
+                  blocks=None):
+    """jax's splash-attention forward kernel for one sequence under one
+    of the three static masks, built once per head count, length and
+    mask (the widths and the key/value head count are the operands'
+    own): the mask's block tables are host numpy work at trace time, and
+    every layer of a model asks for the same ones.  Causal (neither
+    ``window`` nor ``blocks``): jax's ``CausalMask``, 136 blocks of 1024
+    rows at 16 384 rows.  With ``window`` the mask is jax's
+    ``LocalMask`` (``t - (window - 1) <= s <= t``) and its tables drop
+    the blocks left of the band: at a window of 4096, 70 of the 136.
+    With ``blocks`` it is ``_block_diffusion_mask`` and the tables drop
+    the dead quadrant and everything above the two block-triangles: 80
+    of the 256 blocks (36 + 36 + 8).  With ``residuals`` the kernel
+    returns ``(o, (lse,))``, the rows' log-sum-exp beside the result, as
+    a backward needs it."""
     import numpy as np
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         CausalMask, LocalMask, MultiHeadMask, make_splash_mha)
     shape = (seq_len, seq_len)
-    one = CausalMask(shape) if window is None \
+    one = _block_diffusion_mask(seq_len, blocks) if blocks is not None \
+        else CausalMask(shape) if window is None \
         else LocalMask(shape, window_size=(window - 1, 0), offset=0)
     mask = MultiHeadMask([one] * q_heads)
     # the kernel keeps its tables as arrays: made outside whatever trace
@@ -727,71 +851,81 @@ def _flash_kernel(q_heads, seq_len, interpret, residuals=False, window=None):
     return jax.tree_util.tree_map(np.asarray, kernel)
 
 
-def _flash_scope(window):
+def _flash_scope(window, blocks=None):
     """The scope, and the launch counter's label, of the flash pair:
-    the band's kernels are counted apart from the causal ones."""
-    return "flash_attention" if window is None else "flash_attention_window"
+    the band's and the block-diffusion mask's kernels are counted apart
+    from the causal ones."""
+    return "flash_attention_blocks" if blocks is not None \
+        else "flash_attention" if window is None else "flash_attention_window"
 
 
-def _flash_forward(q, k, v, interpret, residuals, window=None):
+def _flash_kernel_of(q_heads, seq_len, interpret, residuals, window, blocks):
     # one cache entry a geometry: the causal kernel is asked for as ever
-    kernel = _flash_kernel(q.shape[1], q.shape[2], interpret, residuals,
-                           *(() if window is None else (window,)))
-    with jax.named_scope("pallas." + _flash_scope(window)):
+    return _flash_kernel(q_heads, seq_len, interpret, residuals,
+                         *(() if window is None else (window,)),
+                         **({} if blocks is None else {"blocks": blocks}))
+
+
+def _flash_forward(q, k, v, interpret, residuals, window=None, blocks=None):
+    kernel = _flash_kernel_of(q.shape[1], q.shape[2], interpret, residuals,
+                              window, blocks)
+    with jax.named_scope("pallas." + _flash_scope(window, blocks)):
         return jax.vmap(kernel)(q, k, v)
 
 
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, interpret, window):
-    return _flash_forward(q, k, v, interpret, False, window)
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, interpret, window, blocks):
+    return _flash_forward(q, k, v, interpret, False, window, blocks)
 
 
-def _flash_fwd(q, k, v, interpret, window):
-    o, (lse,) = _flash_forward(q, k, v, interpret, True, window)
+def _flash_fwd(q, k, v, interpret, window, blocks):
+    o, (lse,) = _flash_forward(q, k, v, interpret, True, window, blocks)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(interpret, window, res, do):
+def _flash_bwd(interpret, window, blocks, res, do):
     from ..pallas.flash_backward import flash_attention_backward
-    with jax.named_scope("pallas." + _flash_scope(window)):
+    with jax.named_scope("pallas." + _flash_scope(window, blocks)):
         return flash_attention_backward(*res, do, window=window,
-                                        interpret=interpret)
+                                        blocks=blocks, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _count_flash_blocks(batch, q_heads, seq_len, window, interpret):
-    """Books, at trace time, the 512 x 512 score blocks one banded flash
-    pair executes beside those its causal twin would
-    (``flash_blocks_walked`` / ``flash_blocks_causal``, by kernel):
-    forward what the kernel's own tables keep (its blocks counted in
-    512s) against the triangle of such blocks, backward the walk."""
+def _count_flash_blocks(batch, q_heads, seq_len, window, interpret,
+                        blocks=None):
+    """Books, at trace time, the 512 x 512 score blocks one banded or
+    block-diffusion flash pair executes beside those the causal pair
+    would over the same rows (``flash_blocks_walked`` /
+    ``flash_blocks_causal``, by kernel): forward what the kernel's own
+    tables keep (its blocks counted in 512s) against the triangle of
+    such blocks, backward the walk."""
     import numpy as np
     from ..pallas.dispatch import (FLASH_BLOCKS_CAUSAL, FLASH_BLOCKS_WALKED,
                                    RETRACE_SUPPRESS)
     from ..pallas.flash_backward import _BLOCK, blocks_walked
     if RETRACE_SUPPRESS.on:     # a program-registry re-lower, as launches
         return
-    table = _flash_kernel(q_heads, seq_len, interpret, True,
-                          window).fwd_mask_info.block_mask
+    table = _flash_kernel_of(q_heads, seq_len, interpret, True, window,
+                             blocks).fwd_mask_info.block_mask
     bs = _flash_block_sizes(seq_len)
     per = (bs.block_q // _BLOCK) * (bs.block_kv // _BLOCK)
     rows = seq_len // bs.block_q
     # one table serves all heads where they share a mask
     kept = int(np.count_nonzero(table)) * (q_heads // table.shape[0])
+    scope = _flash_scope(window, blocks)
     for kernel, walked, causal in (
-            ("flash_attention_window", kept * per,
-             q_heads * per * rows * (rows + 1) // 2),
-            ("flash_attention_window_bwd",
-             q_heads * blocks_walked(seq_len, window),
+            (scope, kept * per, q_heads * per * rows * (rows + 1) // 2),
+            (scope + "_bwd", q_heads * blocks_walked(seq_len, window, blocks),
              q_heads * blocks_walked(seq_len))):
         FLASH_BLOCKS_WALKED.labels(kernel=kernel).inc(batch * walked)
         FLASH_BLOCKS_CAUSAL.labels(kernel=kernel).inc(batch * causal)
 
 
-def _flash_attention(q, k, v, *, window=None, interpret=False):
-    """Causal, optionally banded, attention of head-major q (B, Hq, S,
+def _flash_attention(q, k, v, *, window=None, blocks=None, interpret=False):
+    """Softmax attention under one of three static masks (causal,
+    causal and banded, block diffusion) of head-major q (B, Hq, S,
     D) over k (B, Hk, S, D) and v (B, Hk, S, Dv), Hq a multiple of Hk
     and Dv = D unless the values are narrower (the result is (B, Hq, S,
     Dv)): float32 scores, statistics and accumulators whatever the
@@ -800,9 +934,9 @@ def _flash_attention(q, k, v, *, window=None, interpret=False):
     group in VMEM).  Forward it is jax's splash Pallas kernel, which
     keeps the rows' log-sum-exp where a gradient is asked for; backward
     it is the repo's own kernel (``pallas/flash_backward.py``), which
-    computes every score block on or under the diagonal once (528 a head
-    at 16 384 rows) and emits dq, dk, dv from it with no partial sums in
-    HBM.
+    computes every live score block once (causal: those on or under the
+    diagonal, 528 a head at 16 384 rows) and emits dq, dk, dv from it
+    with no partial sums in HBM.
 
     ``window`` (static; a multiple of 512, anything else raises): a
     query attends the ``window`` keys that end with its own (``query -
@@ -810,6 +944,15 @@ def _flash_attention(q, k, v, *, window=None, interpret=False):
     band (252 of the 528 at a window of 4096 backward; the forward's
     1024-row tables keep 70 of 136).  ``None``, or a window no shorter
     than the sequence, builds exactly the causal kernels.
+
+    ``blocks`` (static; the block length ``Bk``, a divisor of 512, with S
+    two halves of whole score blocks: anything else raises, as does a
+    window beside it): the rows are a clean sequence and its noised copy
+    side by side, and the mask is ``_block_diffusion_mask``'s: keys right
+    of the query are attended inside a block, the clean-noised quadrant
+    is dead.  At 16 384 rows (two halves of 8192) the backward walks 288
+    of the 1024 blocks a head (136 clean-clean, 136 noised-clean, 16 on
+    the noised diagonal), the forward's 1024-row tables keep 80 of 256.
 
     The kernels take no softmax scale: ``q`` carries it (a caller scales
     q where it is still float32, so q is rounded once).  ``interpret``
@@ -823,22 +966,31 @@ def _flash_attention(q, k, v, *, window=None, interpret=False):
                              "multiple of 512" % window)
         if window >= S:
             window = None
-    _count_launch(_flash_scope(window))
-    if window is not None:
+    if blocks is not None:
+        blocks = int(blocks)
+        if blocks <= 0 or 512 % blocks or S % 1024 or window is not None:
+            raise ValueError(
+                "flash attention: blocks=%d does not divide 512, S=%d is "
+                "not two halves of whole blocks of 512, or a window was "
+                "given beside it" % (blocks, S))
+    _count_launch(_flash_scope(window, blocks))
+    if window is not None or blocks is not None:
         _count_flash_blocks(q.shape[0], q.shape[1], S, window,
-                            bool(interpret))
-    return _flash(q, k, v, bool(interpret), window)
+                            bool(interpret), blocks)
+    return _flash(q, k, v, bool(interpret), window, blocks)
 
 
-def _grouped_causal_attention(q, k, v, scale, window=None):
-    """Causal softmax attention of head-major q (B, Hq, S, D) over k
+def _grouped_causal_attention(q, k, v, scale, window=None, blocks=None):
+    """Masked softmax attention of head-major q (B, Hq, S, D) over k
     (B, Hk, S, D) and v (B, Hk, S, Dv) by XLA, each key/value head
     shared by its Hq / Hk query heads; float32 scores, the probabilities
     in q's dtype; checkpointed, so the (S, S) scores are not kept for
-    the backward pass.  With ``window`` a query attends the ``window``
-    keys that end with its own (``query - key < window``), any width.
-    What the core runs where the flash pair cannot; equal head counts
-    are the case of one query head a group."""
+    the backward pass.  Causal; with ``window`` a query attends the
+    ``window`` keys that end with its own (``query - key < window``),
+    any width; with ``blocks`` the mask is the block-diffusion one over
+    a clean and a noised half (``_block_diffusion_allowed``), any block
+    length that divides a half.  What the core runs where the flash pair cannot; equal head
+    counts are the case of one query head a group."""
     B, Hq, S, D = q.shape
     Hk, Dv = k.shape[1], v.shape[3]
 
@@ -846,9 +998,13 @@ def _grouped_causal_attention(q, k, v, scale, window=None):
     def attn(q, k, v):
         s = jnp.einsum("bgrqe,bgke->bgrqk",
                        q.reshape(B, Hk, Hq // Hk, S, D), k) * scale
-        back = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
-        mask = back >= 0 if window is None \
-            else (back >= 0) & (back < window)
+        t, u = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+        if blocks is not None:
+            mask = _block_diffusion_allowed(S, blocks)(t, u)
+        else:
+            back = t - u
+            mask = back >= 0 if window is None \
+                else (back >= 0) & (back < window)
         s = jnp.where(mask, s.astype(jnp.float32), -1e30)
         p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
         return jnp.einsum("bgrqk,bgke->bgrqe", p, v).reshape(B, Hq, S, Dv)
@@ -857,11 +1013,16 @@ def _grouped_causal_attention(q, k, v, scale, window=None):
 
 
 def _causal_attention_core(seq_len, head_dim, dtype, scale, v_dim=None,
-                           window=None):
-    """The one place that knows which causal softmax core a mixer runs,
-    who carries the softmax scale, and what runs otherwise.  A front
-    asks once a call with what the choice depends on and gets ``(fold,
-    attend)``:
+                           window=None, blocks=None):
+    """The one place that knows which masked softmax core a mixer runs,
+    who carries the softmax scale, and what runs otherwise.  The mask is
+    one of three static kinds: causal (528 score blocks of 512 a head at
+    16 384 rows), causal and banded (``window``: 252 at a window of
+    4096), or the block-diffusion mask (``blocks``, the block length;
+    ``seq_len`` is then the clean and the noised half together: 288).
+    ``window=None, blocks=None`` builds exactly the causal kernels.  A
+    front asks once a call with what the choice depends on and gets
+    ``(fold, attend)``:
 
     ``fold``: the factor q takes in the front's last float32 stage, so
     that q is rounded once: ``scale`` where the chosen core takes none
@@ -872,11 +1033,16 @@ def _causal_attention_core(seq_len, head_dim, dtype, scale, v_dim=None,
     ``attend(q, k, v)``: head-major q (B, Hq, S, D) over k (B, Hk, S, D)
     and v (B, Hk, S, Dv) -> (B, Hq, S, Dv), under the front's own scope:
     ``_flash_attention`` where ``_use_flash_attention`` says so, else
-    ``_grouped_causal_attention``, either with the band."""
-    if _use_flash_attention(seq_len, head_dim, dtype, v_dim, window):
-        return scale, _functools.partial(_flash_attention, window=window)
-    return 1.0, lambda q, k, v: _grouped_causal_attention(q, k, v, scale,
-                                                          window)
+    ``_grouped_causal_attention``, either under the same mask."""
+    # ``blocks`` goes on only where it is set: the causal and banded
+    # callers, and what a test puts in the kernels' place, see what
+    # they saw
+    kind = {} if blocks is None else {"blocks": int(blocks)}
+    if _use_flash_attention(seq_len, head_dim, dtype, v_dim, window, **kind):
+        return scale, _functools.partial(_flash_attention, window=window,
+                                         **kind)
+    return 1.0, lambda q, k, v: _grouped_causal_attention(
+        q, k, v, scale, window, **kind)
 
 
 def _project_heads(spec, data, weight, bias, fold=1.0):
@@ -1150,12 +1316,14 @@ def gated_causal_self_attention(data, q_weight, k_weight, v_weight,
 
 @register("_contrib_GroupedQueryAttention",
           aliases=("GroupedQueryAttention",))
-def grouped_query_attention(data, q_weight, k_weight, v_weight, o_weight, *,
+def grouped_query_attention(data, q_weight, k_weight, v_weight, o_weight,
+                            q_norm_gamma=None, k_norm_gamma=None, *,
                             q_heads, kv_heads, head_dim, window=0,
-                            rotary=True, rope_theta=1e6):
-    """Plain grouped-query causal attention as one sublayer (B, S, d) ->
-    (B, S, d) on an already normalised stream: no bias, no q/k norm, no
-    gate.  ``q_weight`` (q_heads * head_dim, d), ``k_weight``,
+                            rotary=True, rope_theta=1e6, qk_norm=False,
+                            eps=1e-6, blocks=0):
+    """Plain grouped-query attention as one sublayer (B, S, d) ->
+    (B, S, d) on an already normalised stream: no bias, no gate.
+    ``q_weight`` (q_heads * head_dim, d), ``k_weight``,
     ``v_weight`` (kv_heads * head_dim, d), ``q_heads / kv_heads`` query
     heads to a key/value head (head j reads key/value head ``j //
     (q_heads / kv_heads)``), scale ``head_dim ** -0.5``, then
@@ -1169,39 +1337,84 @@ def grouped_query_attention(data, q_weight, k_weight, v_weight, o_weight, *,
     attention.  A model mixes both kinds of layer by giving each its own
     attributes.
 
+    ``qk_norm`` brings the two inputs ``q_norm_gamma`` and
+    ``k_norm_gamma`` (head_dim each; absent without it): query and key
+    heads are RMS-normalised over their channels in float32 with a plain
+    gain, one vector for all heads (``eps``), before the rotation.
+
+    ``blocks`` > 0 (the block length) is a block-diffusion training
+    pass: the S rows are a clean sequence of ``L = S / 2`` rows and its
+    noised copy side by side, the rotation turns each half by positions
+    ``0 .. L - 1`` (row ``L + i`` stands where row ``i`` does), and the
+    mask is the block-diffusion one (``_causal_attention_core``): a
+    clean row attends the clean blocks up to its own, a noised row the
+    clean blocks before its own and the noised rows of its own block.
+    0 is causal attention.
+
     Head-major like GatedCausalSelfAttention; ``_causal_attention_core``
-    runs the core, banded where the layer is (its fold joins the
-    projection's float32 accumulator on a layer without position, the
-    rotation's float32 on one with).  The rotation is
-    linear: its backward needs the angles alone, which are made again
-    from the positions, and nothing of its input is kept.  Scopes:
-    ``gqa.proj``, ``gqa.rope``, and the core under ``gqa.window`` (a
-    band that bites) or ``gqa.full``."""
+    runs the core under the layer's mask (its fold joins the
+    projection's float32 accumulator on a layer without position or
+    norm, the rotation's or the norm's float32 on one with).  The
+    rotation is linear: its backward needs the angles alone, which are
+    made again from the positions, and nothing of its input is kept;
+    with the norms, both are made again in the backward pass from the
+    projections' results.  Scopes: ``gqa.proj``, ``gqa.norm`` (the norms
+    and the rotation behind them), ``gqa.rope``, and the core under
+    ``gqa.window`` (a band that bites), ``gqa.blockdiff`` or
+    ``gqa.full``."""
     B, S, d = data.shape
     Hq, Hk, D = int(q_heads), int(kv_heads), int(head_dim)
     if Hq % Hk:
         raise ValueError("q_heads %d not a multiple of kv_heads %d"
                          % (Hq, Hk))
+    Bk = int(blocks) or None
+    if Bk and (S % (2 * Bk) or int(window)):
+        raise ValueError("GroupedQueryAttention: blocks=%d takes a clean "
+                         "and a noised half of whole blocks (S=%d) and no "
+                         "window" % (Bk, S))
     band = int(window) if 0 < int(window) < S else None
     fold, attend = _causal_attention_core(S, D, data.dtype, D ** -0.5,
-                                          window=band)
+                                          window=band, blocks=Bk)
     f32 = jnp.float32
-    turned = bool(rotary)
+    turned, normed = bool(rotary), bool(qk_norm)
     with jax.named_scope("gqa.proj"):
-        # q takes the fold in float32: the rotation's where the layer
-        # turns, else the projection's accumulator
+        # q takes the fold in float32: the norm's or the rotation's
+        # where the layer has one, else the projection's accumulator
         heads = lambda w, n, **kw: jnp.einsum(
             "bsd,hed->bhse", data, w.reshape(n, D, d), **kw)
-        q = heads(q_weight, Hq) if turned or fold == 1.0 else (heads(
-            q_weight, Hq, preferred_element_type=f32) * fold) \
+        q = heads(q_weight, Hq) if turned or normed or fold == 1.0 else (
+            heads(q_weight, Hq, preferred_element_type=f32) * fold) \
             .astype(data.dtype)
         k, v = heads(k_weight, Hk), heads(v_weight, Hk)
-    if turned:
+
+    def turn(t):
+        # a block-diffusion pass turns each half by its own positions:
+        # the halves as heads of their own
+        if not turned:
+            return t
+        halves = t.reshape(B, -1, S // 2, D) if Bk else t
+        return _rotary_half(halves, D, float(rope_theta)).reshape(t.shape)
+
+    if normed:
+        @jax.checkpoint
+        def prepare(q0, k0, gq, gk):
+            def norm(t, gain, scale):
+                t = t.astype(f32)
+                inv = lax.rsqrt(jnp.mean(jnp.square(t), -1, keepdims=True)
+                                + eps)
+                return t * inv * (gain.astype(f32) * scale)
+            return (turn(norm(q0, gq, fold)).astype(q0.dtype),
+                    turn(norm(k0, gk, 1.0)).astype(k0.dtype))
+
+        with jax.named_scope("gqa.norm"):
+            q, k = prepare(q, k, q_norm_gamma, k_norm_gamma)
+    elif turned:
         with jax.named_scope("gqa.rope"):
-            turn = lambda t, scale: (_rotary_half(
-                t.astype(f32), D, float(rope_theta)) * scale).astype(t.dtype)
-            q, k = turn(q, fold), turn(k, 1.0)
-    with jax.named_scope("gqa.window" if band else "gqa.full"):
+            spin = lambda t, scale: (turn(t.astype(f32)) * scale) \
+                .astype(t.dtype)
+            q, k = spin(q, fold), spin(k, 1.0)
+    with jax.named_scope("gqa.blockdiff" if Bk else
+                         "gqa.window" if band else "gqa.full"):
         o = attend(q, k, v)
     with jax.named_scope("gqa.proj"):
         return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, Hq, D))
@@ -1858,7 +2071,7 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
                    *, num_experts, held_first=0, held_count=None, num_hidden,
                    router_hidden=0, carry_in=True, router="zaya", top_k=1,
                    shared_hidden=0, shared_gate=True, route_scale=1.0,
-                   router_stream=False, act="silu"):
+                   router_stream=False, act="silu", rows_slack=1.25):
     """The dropless expert sublayer of a chip that holds ``held_count``
     of ``num_experts`` experts (``held_first`` onwards), on an already
     normalised stream (..., d).  A token's ``top_k`` experts are chosen
@@ -1873,7 +2086,11 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
     combine; the gradient of the gather that fetched the token's rows is
     the same sum): beside the kernels of the grouped products the
     token-ordered sum of ``pallas/token_sum.py``, scope
-    ``pallas.token_sum``, else XLA's ``scatter-add``.
+    ``pallas.token_sum``, else XLA's ``scatter-add``.  ``rows_slack``
+    (``top_k > 1``): the sorted rows' buffer holds that many times the
+    pairs an even routing sends here; a step with more runs them in
+    slabs (``parallel.moe._row_buckets``: five quarters, or more where
+    a model's rows choose alike).
 
     ``router="zaya"`` (top-1 only): the state ``r = h W_in + carry *
     r_prev`` takes the previous layer's state (``router_state`` and the
@@ -1939,7 +2156,7 @@ def routed_experts(data, router_in_weight=None, router_norm_gamma=None,
                     x, router_weight, router_bias, k, float(route_scale))
         y, counts = moe.dropless_topk_experts(
             x, chosen, weights, gate_weight, up_weight, down_weight, E,
-            int(held_first), act=act)
+            int(held_first), act=act, slack=float(rows_slack))
         second = lax.stop_gradient(chosen).reshape(lead + (k,))
     else:
         raise ValueError("router=%r (zaya, linear or sigmoid)" % (router,))

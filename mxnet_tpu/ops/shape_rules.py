@@ -183,10 +183,14 @@ def _gqa_shapes(known, attrs):
     d = int(data[-1])
     Hq, Hk, D = (int(attrs[k]) for k in ("q_heads", "kv_heads", "head_dim"))
     return {"q_weight": (Hq * D, d), "k_weight": (Hk * D, d),
-            "v_weight": (Hk * D, d), "o_weight": (d, Hq * D)}
+            "v_weight": (Hk * D, d), "o_weight": (d, Hq * D),
+            "q_norm_gamma": (D,), "k_norm_gamma": (D,)}
 
 
-_set("_contrib_GroupedQueryAttention", _gqa_shapes)
+_set("_contrib_GroupedQueryAttention", _gqa_shapes,
+     # the two gains come with ``qk_norm``
+     unused_inputs=lambda attrs: set() if attrs.get("qk_norm")
+     else {"q_norm_gamma", "k_norm_gamma"})
 
 
 def _latent_attn_shapes(known, attrs):

@@ -1,23 +1,44 @@
 """The training flash attention's backward as one Pallas kernel
 (docs/KERNELS.md).
 
-Causal, optionally banded, attention of head-major q (B, Hq, S, D) over
-k (B, Hk, S, D) and v (B, Hk, S, Dv), Hq a multiple of Hk, q carrying the
-softmax scale: from the forward's result ``o``, its row log-sum-exp and
-the cotangent ``do`` the kernel emits dq, dk and dv.  Every 512 x 512
-score block on or under the diagonal is computed once (136 a head at
-8192 rows, 528 at 16 384, none above the diagonal), float32 scores,
-statistics and accumulators whatever the operands' dtype; p and ds are
-rounded to the operands' dtype for the products.
+Masked softmax attention of head-major q (B, Hq, S, D) over k (B, Hk, S,
+D) and v (B, Hk, S, Dv), Hq a multiple of Hk, q carrying the softmax
+scale: from the forward's result ``o``, its row log-sum-exp and the
+cotangent ``do`` the kernel emits dq, dk and dv.  Every live 512 x 512
+score block is computed once, float32 scores, statistics and
+accumulators whatever the operands' dtype; p and ds are rounded to the
+operands' dtype for the products.  Three static mask families, one
+kernel body: which key blocks a query block visits, and under which
+rule for their cells, is a small table a query block (:func:`walk`), and
+a family is a filling of it.
+
+**Causal** (``window=None, blocks=None``).  Query block ``i`` walks key
+blocks ``0 .. i - 1`` unmasked and its own under ``key <= query``: every
+block on or under the diagonal (136 a head at 8192 rows, 528 at 16 384,
+none above it).
 
 **The band.**  With ``window`` (whole blocks: a query attends the
 ``window`` keys that end with its own, ``query - key < window``) a query
 block ``i`` walks key blocks ``i - window / 512 .. i`` and nothing left
-of them: the leftmost under the band's mask (its keys right of the
+of them: the leftmost under the band's rule (its keys right of the
 block's own diagonal), the ones between unmasked, its own under the
-causal mask; at 16 384 rows and a window of 4096 that is 252 of the 528
-causal blocks a head (:func:`blocks_walked`).  ``window=None`` is the
-causal kernel, line for line what it was.
+causal rule; at 16 384 rows and a window of 4096 that is 252 of the 528
+causal blocks a head (:func:`blocks_walked`).
+
+**Block diffusion.**  With ``blocks`` (the block length ``Bk``, which
+divides 512) the S rows are a clean sequence of ``L = S / 2`` rows and
+its noised copy side by side, ``pos(r) = r mod L``, ``blk(r) = pos(r) //
+Bk``: a clean row attends the clean rows of blocks up to and including
+its own, a noised row the clean rows of the blocks strictly before its
+own and the NOISED rows of its own block (both directions: keys right of
+the query are attended), and nothing else.  Of the ``T = L / 512`` score
+blocks a half, a clean query block ``i`` walks clean key blocks ``0 ..
+i - 1`` unmasked and its own under ``blk(key) <= blk(query)``; a noised
+one (``c = i - T``) clean key blocks ``0 .. c - 1`` unmasked, clean
+block ``c`` under ``blk(key) < blk(query)`` and noised block ``i`` under
+``blk(key) == blk(query)``; the clean-noised quadrant is never visited.
+At 16 384 rows (L 8192, T 16) that is 136 + 136 + 16 = 288 of the 1024
+blocks a head, where the causal walk of the same rows would be 528.
 
 **No partial sums.**  A key/value head's rows stay in VMEM while its
 query heads' blocks pass by: k and v as they are, dk and dv as float32
@@ -99,20 +120,77 @@ def plan(seq_len, head_dim, v_dim, dtype, budget=_BUDGET):
     return Plan(segments, rows, transposed, row * rows + _WORKING)
 
 
-def blocks_walked(seq_len, window=None):
-    """Score blocks the backward computes for one query head: every
-    block on or under the diagonal, or with ``window`` those of the
-    band, ``min(i, window / 512) + 1`` for query block ``i``."""
+def _pick(cond, a, b):
+    """``a if cond else b`` for a query block index that is a Python
+    int (:func:`blocks_walked`) or the kernel's ``program_id``."""
+    return (a if cond else b) if isinstance(cond, bool) \
+        else jnp.where(cond, a, b)
+
+
+def walk(seq_len, window=None, blocks=None):
+    """The walk of a query block, as ``table(i)``: it yields the steps of
+    query block ``i`` in the order the kernel takes them, each ``("run", lo,
+    hi)`` (key blocks ``lo .. hi - 1``, unmasked) or ``("one", j,
+    rule)`` (key block ``j`` with ``rule(key, query)`` over the block's
+    own 0 .. 511 saying which cells live; a ``j`` outside the key blocks
+    is no step).  ``i`` is a Python int or a traced scalar.  The three
+    fillings (the module's docstring): causal, the band (``window``, a
+    multiple of 512) and block diffusion (``blocks``, which divides 512;
+    ``seq_len`` is then the clean and the noised half)."""
+    causal = lambda key, query: key <= query
+    if blocks is not None:
+        half = seq_len // _BLOCK // 2
+        shift = blocks.bit_length() - 1         # blocks is a power of two
+        blk = lambda t: t >> shift
+
+        def table(i):
+            noised = i >= half
+            own = _pick(noised, i - half, i)    # the clean block at pos(i)
+            yield "run", 0, own
+            yield ("one", _pick(noised, -1, own),
+                   lambda key, query: blk(key) <= blk(query))
+            yield ("one", _pick(noised, own, -1),
+                   lambda key, query: blk(key) < blk(query))
+            yield ("one", _pick(noised, i, -1),
+                   lambda key, query: blk(key) == blk(query))
+    elif window is not None:
+        reach = window // _BLOCK
+
+        def table(i):
+            # the band's leftmost block lies ``reach`` blocks back: there
+            # query - key < window is key > query inside the block
+            left = i - reach
+            yield "one", left, lambda key, query: key > query
+            yield "run", left + 1, i
+            yield "one", i, causal
+    else:
+        def table(i):
+            yield "run", 0, i
+            yield "one", i, causal
+    return table
+
+
+def blocks_walked(seq_len, window=None, blocks=None):
+    """Score blocks the backward computes for one query head, counted
+    from the walk's table: every block on or under the diagonal (528 at
+    16 384 rows); with ``window`` those of the band, ``min(i, window /
+    512) + 1`` for query block ``i`` (252 at a window of 4096); with
+    ``blocks`` the two block-triangles and the noised diagonal (288)."""
     tiles = seq_len // _BLOCK
-    reach = tiles if window is None else window // _BLOCK
-    return sum(min(i, reach) + 1 for i in range(tiles))
+    table = walk(seq_len, window, blocks)
+    n = 0
+    for i in range(tiles):
+        for kind, a, b in table(i):
+            n += max(0, min(b, tiles) - max(a, 0)) if kind == "run" \
+                else 0 <= a < tiles
+    return n
 
 
-def _kernel(first, tiles, chained, transposed, reach=None):
+def _kernel(first, tiles, chained, transposed, table):
     """The kernel of the pass that holds key/value blocks ``first ..
     first + tiles - 1``; ``chained`` where it starts from an earlier
-    pass's float32 dq; ``transposed`` as ``plan`` says; ``reach`` the
-    band's width in blocks (None: every block under the diagonal)."""
+    pass's float32 dq; ``transposed`` as ``plan`` says; ``table`` the
+    walk of a query block (:func:`walk`)."""
     def kernel(*refs):
         q_ref, do_ref, lse_ref, di_ref, k_ref, v_ref = refs[:6]
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[6 + chained:][:6]
@@ -167,19 +245,15 @@ def _kernel(first, tiles, chained, transposed, reach=None):
             block(j)
             return carry
 
-        causal = lambda key, query: key <= query
-
-        if reach is None:
-            lax.fori_loop(first, jnp.minimum(i, first + tiles), before, 0)
-        else:
-            # the band's leftmost block lies ``reach`` blocks back: there
-            # query - key < window is key > query inside the block
-            left = i - reach
-            pl.when((left >= first) & (left < first + tiles))(
-                lambda: block(left, lambda key, query: key > query))
-            lax.fori_loop(jnp.maximum(first, left + 1),
-                          jnp.minimum(i, first + tiles), before, 0)
-        pl.when((i >= first) & (i < first + tiles))(lambda: block(i, causal))
+        # a step's part inside this pass's key/value blocks
+        for kind, a, b in table(i):
+            if kind == "run":
+                lax.fori_loop(max(first, a) if isinstance(a, int)
+                              else jnp.maximum(first, a),
+                              jnp.minimum(b, first + tiles), before, 0)
+            else:
+                pl.when((a >= first) & (a < first + tiles))(
+                    lambda a=a, b=b: block(a, b))
         dq_ref[...] = turned(dq_acc[...]).astype(dq_ref.dtype)
 
         @pl.when((g == pl.num_programs(2) - 1)
@@ -199,12 +273,14 @@ def _kernel(first, tiles, chained, transposed, reach=None):
 
 # Jitted on its own, as the delta rule's: a model's layers of one
 # geometry share ONE trace and ONE lowering of the kernel.
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
-def _run_pass(q, k, v, do, lse, di, dq, segment, z, interpret, window=None):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11))
+def _run_pass(q, k, v, do, lse, di, dq, segment, z, interpret, window=None,
+              blocks=None):
     """One pass of the ``Plan`` ``z``: (dq, dk, dv) of key/value segment
     ``segment``; ``dq`` is the earlier passes' float32 sum or None, and
     the result's dq is float32 unless the pass is the last.  ``window``
-    (whole blocks, or None) is the band."""
+    (whole blocks) is the band, ``blocks`` the block diffusion's block
+    length; both None is the causal walk."""
     B, Hq, S, D = q.shape
     Hk, Dv = k.shape[1], v.shape[3]
     G, tiles = Hq // Hk, z.rows // _BLOCK
@@ -218,11 +294,12 @@ def _run_pass(q, k, v, do, lse, di, dq, segment, z, interpret, window=None):
     of_kv = lambda width, at: pl.BlockSpec(
         (None, None, z.rows, width), lambda b, h, g, i: (b, h, at, 0))
     across = lambda shape: shape[::-1] if z.transposed else shape
-    _count_launch("flash_attention_bwd" if window is None
+    _count_launch("flash_attention_blocks_bwd" if blocks is not None
+                  else "flash_attention_bwd" if window is None
                   else "flash_attention_window_bwd")
     return pl.pallas_call(
         _kernel(segment * tiles, tiles, chained, z.transposed,
-                None if window is None else window // _BLOCK),
+                walk(S, window, blocks)),
         grid=(B, Hk, G, S // _BLOCK),
         in_specs=[of_q(D), of_q(Dv), stat, stat,
                   of_kv(D, segment), of_kv(Dv, segment)]
@@ -247,19 +324,27 @@ def _run_pass(q, k, v, do, lse, di, dq, segment, z, interpret, window=None):
 
 
 def flash_attention_backward(q, k, v, o, lse, do, *, window=None,
-                             interpret=False):
-    """(dq, dk, dv) of causal, optionally banded, attention: ``o`` (B,
-    Hq, S, Dv) and ``lse`` (B, Hq, S) float32 are the forward's result
-    and row log-sum-exp, ``do`` the result's cotangent.  S is whole score
-    blocks of 512 (528 on or under the diagonal a head at 16 384 rows),
-    D and Dv what the forward's gate admits.  ``window`` (a multiple of
-    512, or None): a query attends the ``window`` keys that end with its
-    own, and the blocks left of that band are never computed (252 of the
-    528 at a window of 4096)."""
+                             blocks=None, interpret=False):
+    """(dq, dk, dv) of masked softmax attention under one of the three
+    static masks: ``o`` (B, Hq, S, Dv) and ``lse`` (B, Hq, S) float32 are
+    the forward's result and row log-sum-exp, ``do`` the result's
+    cotangent.  S is whole score blocks of 512, D and Dv what the
+    forward's gate admits.  Causal (neither keyword): the 528 blocks on
+    or under the diagonal a head at 16 384 rows.  ``window`` (a multiple
+    of 512): a query attends the ``window`` keys that end with its own,
+    and the blocks left of that band are never computed (252 of the 528
+    at a window of 4096).  ``blocks`` (the block length, a divisor of
+    512; S is a clean and a noised half of whole score blocks each): the
+    block-diffusion mask of the module's docstring, 288 blocks."""
     B, Hq, S, D = q.shape
     if window is not None and (window <= 0 or window % _BLOCK):
         raise ValueError("pallas flash backward: window=%r is not whole "
                          "blocks of %d" % (window, _BLOCK))
+    if blocks is not None and (window is not None or blocks <= 0
+                               or _BLOCK % blocks or S % (2 * _BLOCK)):
+        raise ValueError("pallas flash backward: blocks=%r does not divide "
+                         "%d, S=%d is not two halves of whole blocks, or a "
+                         "window was given beside it" % (blocks, _BLOCK, S))
     if S % _BLOCK or Hq % k.shape[1]:
         raise ValueError("pallas flash backward: S=%d is not whole blocks "
                          "of %d, or %d query heads are not whole groups of "
@@ -273,7 +358,8 @@ def flash_attention_backward(q, k, v, o, lse, do, *, window=None,
     dq, dks, dvs = None, [], []
     for segment in range(z.segments):
         dq, dk, dv = _run_pass(q, k, v, do, as_rows(lse), as_rows(di), dq,
-                               segment, z, bool(interpret), window)
+                               segment, z, bool(interpret), window,
+                               *(() if blocks is None else (blocks,)))
         dks.append(dk)
         dvs.append(dv)
     if z.segments == 1:

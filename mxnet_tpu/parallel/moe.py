@@ -379,7 +379,7 @@ def _taken_bwd(how, kept, dxs):
 _taken.defvjp(_taken_fwd, _taken_bwd)
 
 
-def _row_buckets(tokens, k, held, num_experts):
+def _row_buckets(tokens, k, held, num_experts, slack=1.25):
     """The static sizes the sorted rows' buffer may take: the expected
     and the worst case.  Nothing is dropped whatever the routing, so
     the larger is a token's every choice held here, ``tokens * min(k,
@@ -387,14 +387,16 @@ def _row_buckets(tokens, k, held, num_experts):
     (the combine: the token-ordered sum of ``pallas/token_sum.py``
     beside the products' kernels, else a scatter-add) would cost more
     than the experts when a sixteenth of them is real, so a step whose
-    real count fits takes the smaller: one row a token, or
-    five quarters of the expected count ``tokens * k * held /
+    real count fits takes the smaller: one row a token, or ``slack``
+    (five quarters) of the expected count ``tokens * k * held /
     num_experts`` where that is larger (a size AT the expected count
-    would send every other step to the worst case).  Top-1 has the one
-    size."""
+    would send every other step to the worst case; a model whose rows
+    choose ALIKE, such as a diffusion pass's masked rows, says a larger
+    ``slack``: its count moves by whole shares of the rows with which
+    experts they favour).  Top-1 has the one size."""
     worst = tokens * min(k, held)
     expected = -(-tokens * k * held // num_experts)
-    size = min(worst, max(tokens, -(-5 * expected // 4)))
+    size = min(worst, max(tokens, int(math.ceil(slack * expected))))
     return [size] if size == worst else [size, worst]
 
 
@@ -402,7 +404,8 @@ _GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
-                          num_experts, held_first=0, impl=None, act="silu"):
+                          num_experts, held_first=0, impl=None, act="silu",
+                          slack=1.25):
     """Gated expert FFNs for the experts held here (``act`` the gate's
     activation, ``silu`` or ``relu``), ``k`` choices a
     token, nothing dropped.  ``x`` (N, d) tokens; ``experts`` (N, k)
@@ -424,7 +427,8 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
     sorted by token once, the rows permuted, a one-hot product a tile
     of 256 tokens), else as XLA's ``scatter-add``.  Their buffer has
     the smaller of
-    :func:`_row_buckets`'s static sizes; a step whose count passes it
+    :func:`_row_buckets`'s static sizes (``slack`` times the expected
+    count); a step whose count passes it
     (``lax.switch``) runs its pairs a slab of that size at a time,
     forward and backward, so the worst case holds one slab's
     intermediates (with two arms the forward is computed again in the
@@ -504,7 +508,7 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
             y = jnp.zeros((N, d), f32).at[token].add(ys)
             return y.astype(x.dtype) if start is None else y
 
-    buckets = _row_buckets(N, k, held, E)
+    buckets = _row_buckets(N, k, held, E, slack)
     if len(buckets) == 1:
         return run(buckets[0], x, weights, w_gate, w_up, w_down), counts
 

@@ -44,6 +44,8 @@ One place to read every operational witness the framework emits
 * :mod:`dsa` — live score tiles of the sparse indexed attention
   (``dsa_live_block_share``), read from the last step's count output
   when asked, never in the step.
+* :mod:`diffusion` — the masked rows of a block-diffusion step
+  (``diffusion_masked_row_share``), read the same way.
 
 This package is stdlib-only at import (jax is touched lazily inside
 :mod:`memory`/:mod:`programs`), so the registry is safe to import from
@@ -71,6 +73,7 @@ from .aggregate import PodMetricsAggregator
 from . import sentinel
 from . import moe
 from . import dsa
+from . import diffusion
 
 
 class _ProgramsFacade:
@@ -90,6 +93,7 @@ programs = _ProgramsFacade()
 __all__ = [
     "registry", "export", "flight", "memory", "chrome", "tracing",
     "health", "programs", "aggregate", "sentinel", "moe", "dsa",
+    "diffusion",
     "PodMetricsAggregator",
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
     "counter", "gauge", "histogram", "enable", "disable", "enabled",

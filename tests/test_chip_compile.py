@@ -81,10 +81,12 @@ def cell_config(name):
         return json.load(f)
 
 
-def fit_program(cfg, one_chip):
+def fit_program(cfg, one_chip, planes=None):
     """The fused fit program of a training cell at its own sizes (one
     sequence of ``seq_len`` tokens, the configuration's optimizer with
     float32 masters, ``ce`` folded), compiled for the described chip.
+    ``planes``: the family's ``data`` is (1, planes, seq_len), not
+    (1, seq_len) (a block-diffusion pass: ids, noised ids, weights).
     The kernel choices ask ``jax.default_backend()``, which is the CPU
     here: the caller steers them first, as the chip would answer.  The
     parameters stay the zeros they were bound as: the program is
@@ -97,14 +99,16 @@ def fit_program(cfg, one_chip):
     S = kw["seq_len"]
     mod = mx.Module(mx.models.get_symbol(cfg["model"], **kw),
                     context=mx.cpu())
-    mod.bind(data_shapes=[("data", (1, S))],
+    shape = (1, S) if planes is None else (1, planes, S)
+    mod.bind(data_shapes=[("data", shape)],
              label_shapes=[("softmax_label", (S,))])
     mod.init_params(initializer=None)
     mod.init_optimizer(optimizer=cfg["optimizer"], optimizer_params=dict(
         cfg["optimizer_params"], multi_precision=True))
     tokens = np.arange(S, dtype=np.float32) % kw["num_classes"]
-    batch = mx.io.DataBatch(data=[mx.nd.array(tokens.reshape(1, S))],
-                            label=[mx.nd.array(tokens)])
+    batch = mx.io.DataBatch(
+        data=[mx.nd.array(np.broadcast_to(tokens, shape))],
+        label=[mx.nd.array(tokens)])
     fn, args, _ = mod._get_fused_fit()._prepare(batch,
                                                 mx.metric.create("ce"))
     with jax.default_matmul_precision("default"):
@@ -691,6 +695,77 @@ def test_smallthinker_fit_program_compiles_and_fits_the_chip(one_chip,
     assert device_bytes(compiled, "smallthinker") < 15e9
 
 
+def test_block_diffusion_flash_pair_fwd_grad_compiles(one_chip):
+    """The flash pair under the block-diffusion mask at the SDAR cell's
+    geometry: a clean and a noised half of 8192 rows (16 384 in all), 32
+    query heads to 4 key/value heads of 128, block length 4.  Both
+    kernels are in the program: the splash forward over the lazy mask
+    (its cells computed in the kernel from the row numbers), the repo's
+    backward walking the table's four steps (a run and three masked
+    blocks with shifts and compares Mosaic has to take), a key/value
+    head's 16 384 rows resident in one segment."""
+    from mxnet_tpu.ops.nn import _flash_attention
+    from mxnet_tpu.pallas import flash_backward as fb
+    q = ((1, 32, 16384, 128), jnp.bfloat16)
+    kv = ((1, 4, 16384, 128), jnp.bfloat16)
+    z = fb.plan(16384, 128, 128, jnp.bfloat16)
+    assert (z.segments, z.rows) == (1, 16384)
+
+    def loss(q, k, v):
+        return _flash_attention(q, k, v, blocks=4).astype(jnp.float32).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    one_chip, q, kv, kv).as_text()
+    assert "splash_mha_fwd" in text
+    assert "flash_attention_backward" in text
+    assert "splash_mha_dkv" not in text
+
+
+def test_sdar_fit_program_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """The fused fit program of the cell ``sdar_30b_train_bd4_s8k`` at
+    its own sizes (4 layers, 16 of 128 experts held, 18 992 rows of the
+    vocabulary, one sequence of 8192 tokens as 16 384 rows, block length
+    4, bf16 with f32 masters), compiled for the described chip with the
+    kernels the chip would choose: the flash pair under the
+    block-diffusion mask in every layer and the Pallas grouped matmul
+    (nothing fell back).  ``memory_analysis`` (arguments + outputs -
+    aliased + temporaries) stays under 15 GB of the chip's 16: the
+    configuration's ``reduced_why`` quotes the number printed here.  The
+    head's stem is the bfloat16 rows of the noised half: no float32
+    (8192, 18 992) array is written."""
+    from mxnet_tpu.pallas import dispatch
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_grouped_matmul_impl",
+                        lambda *a, **k: "compiled")
+    # `auto` as on the chip: a one-device TPU program
+    monkeypatch.setattr(dispatch, "_compiles_here", lambda: (True, "", None))
+    fallbacks = lambda: sum(c.value
+                            for c in dispatch.PALLAS_FALLBACKS.children())
+    launches = lambda name: dispatch.PALLAS_LAUNCHES.labels(kernel=name).value
+    before = fallbacks()
+    masked, causal = (launches("flash_attention_blocks"),
+                      launches("flash_attention"))
+    cfg = cell_config("sdar_30b_a3b_train")
+    kw = cfg["kwargs"]
+    assert (kw["num_layers"], kw["experts_held"], kw["block_length"],
+            kw["seq_len"]) == (4, [0, 16], 4, 8192)
+    compiled = fit_program(cfg, one_chip, planes=3)
+    text = compiled.as_text()
+    assert "gmm" in text and "ragged" not in text
+    assert "token_sum" in text
+    assert "splash_mha_fwd" in text and "flash_attention_backward" in text
+    for scope in ("gqa.proj", "gqa.norm", "gqa.blockdiff",
+                  "head.diffusion"):
+        assert scope in text, scope
+    assert "gqa.full" not in text and "gqa.rope" not in text
+    assert fallbacks() == before
+    assert launches("flash_attention_blocks") > masked
+    assert launches("flash_attention") == causal
+    assert "f32[8192,18992]" not in _written_types(text)
+    assert device_bytes(compiled, "sdar_moe") < 15e9
+
+
 @pytest.mark.parametrize("cell,S_,D,Dv,rows,buckets", [
     ("cgpt13b_train_s2048", 2048, 128, None, None, None),
     ("zaya1_8b_train_ep2", 8192, 128, None, (8192, 1, 8, 16), [8192]),
@@ -702,6 +777,9 @@ def test_smallthinker_fit_program_compiles_and_fits_the_chip(one_chip,
      [20480, 131072]),
     ("smallthinker_21b_train_s16k", 16384, 128, None, (16384, 6, 16, 64),
      [30720, 98304]),
+    # rows that choose alike: twice the even share (``rows_slack``)
+    ("sdar_30b_train_bd4_s8k", 16384, 128, None, (16384, 8, 16, 128, 2.0),
+     [32768, 131072]),
 ])
 def test_accepted_cells_geometries_give_what_they_gave(cell, S_, D, Dv, rows,
                                                        buckets):
@@ -724,6 +802,6 @@ def test_accepted_cells_geometries_give_what_they_gave(cell, S_, D, Dv, rows,
     if rows is not None:
         assert moe._row_buckets(*rows) == buckets
         # top-1 and an expected count under a row a token: as before PR 38
-        tokens, k, held, E = rows
+        tokens, k, held, E = rows[:4]
         if tokens * k * held < tokens * E:
             assert buckets[0] == tokens

@@ -90,6 +90,11 @@ DECODER_GRAPHS = {
     ("smallthinker", "smallthinker_21b_train"): (
         "f14da0925abf9e134d97a5a7f65afea7534e554dedbc627824e2ba67b2f5c573",
         "1fbbefe52c3082be398d3116ae384c4a8373d88ff99fc816d124da3bbfbbb3fc"),
+    # the seventh family, as PR 44 built it on the frame (its review
+    # round: a float32 stream, the routers on float32 rows, rows_slack)
+    ("sdar_moe", "sdar_30b_a3b_train"): (
+        "dcd0ecf0e73bd67504aa181928423d5f31d7a2031c709979af1bdcfeea6365da",
+        "09e4fd547b4f4c4963d5562384de8e2881e2424d2c68954ba9576fefb03359b2"),
 }
 
 
@@ -146,8 +151,10 @@ def test_the_frame_reads_experts_held_for_every_family(family, config, held,
         assert (int(n["attrs"]["held_first"]),
                 int(n["attrs"]["held_count"])) == (first, count)
     S = kw["seq_len"]
+    # the seventh family's data is ids, noised ids and weights
+    data = (1, 3, S) if family == "sdar_moe" else (1, S)
     shapes = dict(zip(net.list_arguments(),
-                      net.infer_shape(data=(1, S))[0]))
+                      net.infer_shape(data=data)[0]))
     stacks = [s for a, s in shapes.items()
               if a.endswith(("moe_gate_weight", "moe_up_weight",
                              "moe_down_weight"))]
