@@ -31,14 +31,15 @@ for the backward pass as bits (S x S / 8 bytes), so it is never made
 twice and cannot come out differently.
 
 The scorer's row and its gradients (scopes ``dsa.indexer``,
-``dsa.index_loss``) and the heads' cores (scope ``dsa.attention``) have
-two forms that share everything else (``plan``, ``choose``, the bits,
-the log-sum-exp and the KL of the index loss) and are chosen together
-from the program, not by a user (:func:`_cores_impl`; a test passes
-``impl``):
+``dsa.index_loss``), the choice (scope ``dsa.select``) and the heads'
+cores (scope ``dsa.attention``) have two forms that share everything
+else (``plan``, the bits, the log-sum-exp and the KL of the index loss)
+and are chosen together from the program, not by a user
+(:func:`_cores_impl`; a test passes ``impl``):
 
 * in a one-device TPU program whose shapes they take, two Pallas kernel
-  pairs in which no array with a head axis and a key axis reaches HBM.
+  pairs in which no array with a head axis and a key axis reaches HBM,
+  and the choice's kernel between them.
   ``pallas/index_scorer.py``: forward a block's row ``I`` a 512-key tile
   at a time up to the diagonal, the heads' products behind the ReLU
   summed in VMEM; backward the products again, kept in VMEM beside
@@ -46,7 +47,13 @@ from the program, not by a user (:func:`_cores_impl`; a test passes
   ``dqi`` and ``dwi``) and the keys' gradient, added in place into the
   (Di, S) float32 sum the scan carries.  Its products are float32 as
   ``HIGHEST`` makes them, the six bfloat16 terms of each laid side by
-  side so the MXU runs full.  ``pallas/sparse_attention.py``: the
+  side so the MXU runs full.
+  ``pallas/topk_choice.py``: :func:`choose`'s set to the bit, from the
+  block's row ``I`` held in VMEM as integer keys: the 32 counting passes
+  and the two walks after them over the tiles up to the diagonal only,
+  the tie rule in the last walk, the mask written once as the int8 the
+  cores read and as the bits the backward pass keeps.
+  ``pallas/sparse_attention.py``: the
   block's mask goes in as an int8 operand, every (kv_chunk x q_chunk)
   score tile up to the diagonal lives in VMEM only, forward two walks
   over the key tiles in one call (the heads' row statistics; then the
@@ -54,11 +61,13 @@ from the program, not by a user (:func:`_cores_impl`; a test passes
   a (q_chunk, S) row), backward one walk that emits dq, adds into the
   float32 dk and dv the scan carries (in place) and hands back the same
   ``pt`` row, which the scorer's backward kernel reads.  XLA keeps the
-  choice, ``lse_i`` and the KL, each one pass over the block's rows;
+  live tiles, ``lse_i`` and the KL, each one pass over the block's
+  rows;
 * everywhere else (the CPU, a mesh, other shapes), plain XLA: the
   scorer's row a chunk of keys at a time up to the block's diagonal (a
   ``fori_loop`` with the block's trip count, float32 at ``HIGHEST``);
-  the cores forward two passes over chunks of keys (statistics, then
+  the choice :func:`choose` over the block's whole row; the cores
+  forward two passes over chunks of keys (statistics, then
   probabilities, result and the KL terms), backward one pass that
   recomputes the heads' scores and the scorer's and emits every
   gradient, float32 score arrays (heads, q_chunk, chunk) in memory.
@@ -76,6 +85,7 @@ from jax import lax
 
 from ..pallas import index_scorer as scorer
 from ..pallas import sparse_attention as kernels
+from ..pallas import topk_choice
 
 _HI = lax.Precision.HIGHEST
 _LOOP_CHUNKS = 4        # kv_chunk tiles a key-loop iteration takes
@@ -208,10 +218,18 @@ def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile, impl):
                 ib = lax.fori_loop(0, n_c, score,
                                    jnp.zeros((bq, Sp), jnp.float32))
         with jax.named_scope("dsa.select"):
-            chosen = lax.cond(r0 + bq > topk,
-                              lambda: choose(ib, causal, topk),
-                              lambda: causal)
-            bits = _pack(chosen, kc)
+            if impl:    # in VMEM, as int8 and as bits: pallas/topk_choice.py
+                on8, bits = lax.cond(
+                    r0 + bq > topk,
+                    lambda: topk_choice.choose(ib, r0, tiles, topk, tile, kc,
+                                               interpret=interpret),
+                    lambda: (causal.astype(jnp.int8), _pack(causal, kc)))
+                chosen = on8 != 0
+            else:
+                chosen = lax.cond(r0 + bq > topk,
+                                  lambda: choose(ib, causal, topk),
+                                  lambda: causal)
+                bits = _pack(chosen, kc)
             real = chosen & (row < S)[:, None]
             live = jnp.sum(jnp.any(real.reshape(bq, Sp // tile, tile),
                                    axis=(0, 2)), dtype=jnp.int32)
@@ -245,8 +263,7 @@ def _core_fwd(q, k, v, qi, ki, wi, S, topk, bq, kc, tile, impl):
         if impl:        # 2. and 3. in VMEM: pallas/sparse_attention.py
             with jax.named_scope("dsa.attention"):
                 o, lse, pt = kernels.forward(
-                    qb, k, v, chosen.astype(jnp.int8), tiles, tile,
-                    interpret=interpret)
+                    qb, k, v, on8, tiles, tile, interpret=interpret)
             with jax.named_scope("dsa.index_loss"):
                 kl = _kl_rows(chosen, pt, ib - lse_i[:, None])
         else:
@@ -407,20 +424,24 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def _cores_impl(q, k, qi, bq, tile, S_pad):
-    """How the S x S work (the heads' cores and the index scorer) runs
-    when not told: the Pallas kernels (``"compiled"``) in a one-device
-    TPU program whose shapes both pairs take (``supported`` of
-    ``pallas/sparse_attention.py`` and of ``pallas/index_scorer.py``),
-    else the XLA loops (False; counted in ``pallas_fallbacks{reason}``).
-    One decision and no knob: a test passes ``impl``."""
+    """How the S x S work (the heads' cores, the index scorer and the
+    choice between them) runs when not told: the Pallas kernels
+    (``"compiled"``) in a one-device TPU program whose shapes all of
+    them take (``supported`` of ``pallas/sparse_attention.py``, of
+    ``pallas/index_scorer.py`` and of ``pallas/topk_choice.py``, whose
+    scores are the scorer's: its queries' dtype), else the XLA loops
+    (False; counted in ``pallas_fallbacks{reason}``).  One decision and
+    no knob: a test passes ``impl``."""
     from ..pallas.dispatch import _compiles_here, choose_impl
     here, why, reason = _compiles_here()
     fits, shapes = kernels.supported(q, k, bq, tile, S_pad)
     fits_i, shapes_i = scorer.supported(qi, bq, tile, S_pad)
+    fits_c, shapes_c = topk_choice.supported(qi.dtype, bq, tile, S_pad)
     return choose_impl(
         "sparse_indexed_attention (no knob)", "auto", "sparse_attention",
-        here and fits and fits_i,
-        why="%s, %s %s" % (why or "one TPU device", shapes, shapes_i),
+        here and fits and fits_i and fits_c,
+        why="%s, %s %s %s" % (why or "one TPU device", shapes, shapes_i,
+                              shapes_c),
         fallback_reason=reason or "sparse-attention-geometry")
 
 

@@ -581,7 +581,8 @@ def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
     kernels the chip would choose: the Pallas grouped matmul for the
     expert layer, and for the sparse indexed attention the pairs of
     ``pallas/sparse_attention.py`` (the heads' cores) and of
-    ``pallas/index_scorer.py`` (the scorer's S x S work): all four
+    ``pallas/index_scorer.py`` (the scorer's S x S work) and the kernel
+    of ``pallas/topk_choice.py`` (the choice between them): all five
     custom calls are in the compiled text, and nothing fell back.
     ``memory_analysis`` (arguments + outputs - aliased + temporaries)
     stays under 15 GB of the chip's 16; PR 38's program, whose cores
@@ -615,6 +616,7 @@ def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
     assert "sparse_attention_backward" in text
     assert "index_scorer_forward" in text
     assert "index_scorer_backward" in text
+    assert "topk_choice" in text.replace("pallas.topk_choice", "")
     assert fallbacks() == before
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
     wide = jax.ShapeDtypeStruct((1, 16, S, 128), jnp.float32)
@@ -622,6 +624,22 @@ def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
         shape(1, 32, S, 128), shape(1, 4, S, 128), wide, 512, 512, S) is False
     assert fallbacks() == before + 1
     assert device_bytes(compiled, "keye_vl2") < 15e9
+
+
+@pytest.mark.parametrize("S", [16384, 8192])
+def test_topk_choice_compiles(one_chip, S):
+    """The choice's kernel alone at the Keye cell's sizes (a block of
+    512 rows on a row of 16 384 float32 scores, ``topk`` 2048) and at
+    half the length: Mosaic takes the dynamic lane slices, the int8
+    mask, its packed bits and the tie rule's bfloat16 product, and the
+    call holds no memory of its own beside its operands."""
+    from mxnet_tpu.pallas import topk_choice
+    assert topk_choice.supported(jnp.float32, 512, 512, S)[0]
+    compiled = _compile(
+        lambda ib, r0, n: topk_choice.choose(ib, r0, n, 2048, 512, 2048),
+        one_chip, ((512, S), jnp.float32), ((), jnp.int32), ((), jnp.int32))
+    assert "topk_choice" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
 def test_banded_flash_pair_fwd_grad_compiles(one_chip):

@@ -257,6 +257,50 @@ def test_operator_is_causal(ref, cores):
     assert float(jnp.abs(y1[0, t:] - y0[0, t:]).max()) > 0.0
 
 
+@pytest.mark.parametrize("scores,seq,topk", [
+    ("distinct", 40, 12), ("distinct", 37, 9), ("tied", 40, 12),
+    ("tied", 48, 20)])
+def test_kernels_and_loops_agree_and_keep_the_same_bits(scores, seq, topk):
+    """``ops/sparse_attention.py`` end to end with ``impl="interpret"``
+    (the scorer's, the choice's and the cores' kernels) against
+    ``impl=False`` (the XLA loops and ``choose``): the result, the index
+    loss, the live tiles, the six gradients, and the SAME bits kept for
+    the backward pass.  ``tied``: the scorer's keys are drawn from three
+    vectors, so a row's scores tie in bulk and nearly every row's ties
+    overflow its room (the tie rule runs inside the choice's kernel)."""
+    from mxnet_tpu.ops import sparse_attention as sa
+    Hq, Hk, D, Hi, Di = 4, 2, 8, 2, 8
+    n = lambda i, *shape: _stream(20 + i, (B,) + shape)
+    ki = n(4, seq, Di)
+    if scores == "tied":
+        ki = ki[:, :3][:, jnp.arange(seq) % 3]
+    ops = (n(0, Hq, seq, D) * 2, n(1, Hk, seq, D), n(2, Hk, seq, D),
+           n(3, Hi, seq, Di), ki, n(5, seq, Hi) * 0.3)
+    w = n(6, Hq, seq, D)
+    front = lambda *made: made
+    got = {}
+    for impl in (False, "interpret"):
+        run = lambda *a, impl=impl: sa.sparse_indexed_attention(
+            front, a, topk=topk, q_chunk=8, kv_chunk=8, impl=impl)
+        o, L, live = jax.jit(run)(*ops)
+        grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(run(*a)[0] * w) + 3.0 * jnp.sum(run(*a)[1]),
+            argnums=tuple(range(6))))(*ops)
+        bq, tile, kc, Sp = sa.plan(seq, 8, 8)
+        padded = tuple(jnp.pad(x, [(0, Sp - seq) if a == axis else (0, 0)
+                                   for a in range(x.ndim)])
+                       for x, axis in zip(ops, (2, 2, 2, 2, 1, 1)))
+        bits = jax.jit(lambda *a, impl=impl: sa._attend_fwd(
+            front, a, (seq, topk, bq, kc, tile, impl))[1][4])(*padded)
+        got[impl] = (o, L) + tuple(grads), (live, bits)
+    for a, b in zip(got[False][1], got["interpret"][1]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert int(np.asarray(got[False][1][1]).sum()) > 0
+    for want, have in zip(got[False][0], got["interpret"][0]):
+        assert float(jnp.abs(want).max()) > 0
+        _close(have, want, 5e-5)
+
+
 # ----------------------------------------------------------------------
 # the expert layer's shares
 # ----------------------------------------------------------------------
