@@ -402,8 +402,9 @@ class FusedFitStep:
         # the cumulative non-finite count already pushed to the registry
         self._sent_state = None
         self._published_nonfinite = 0.0
-        # (output index, held_first, held_count) when the graph hands
-        # out its experts' token counts (telemetry.moe), else None
+        # (output index, held_first, held_count, top_k, rows_slack) when
+        # the graph hands out its experts' token counts (telemetry.moe),
+        # else None
         self._moe_counts = _telemetry.moe.find(module._symbol)
         # output index of the sparse attention's live-tile counts
         # (telemetry.dsa), else None
@@ -936,8 +937,8 @@ class FusedFitStep:
         if self._moe_counts is not None:
             # a reference to the counts' device array, read only when
             # telemetry.moe.publish() is asked: no sync in the step
-            i, first, held = self._moe_counts
-            _telemetry.moe.note(outs[i], first, held)
+            i, *sizing = self._moe_counts
+            _telemetry.moe.note(outs[i], *sizing)
         if self._dsa_tiles is not None:
             _telemetry.dsa.note(outs[self._dsa_tiles])
         if self._diffusion_rows is not None:

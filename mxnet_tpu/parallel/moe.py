@@ -295,6 +295,31 @@ def grouped_matmul(x, w, group_sizes, impl=None):
                              interpret=impl == "interpret")
 
 
+def _grouped_matmul_back(x, w, group_sizes, grad, impl):
+    """The two other directions of ``grouped_matmul(x, w, group_sizes,
+    impl)`` for its cotangent ``grad`` (N, out), each called once:
+    ``(grad``'s rows times their group's matrix (N, in), ``x^T grad`` a
+    group (G, out, in)``)``.  ``jax.vjp`` of the product would run the
+    product first; these are the calls megablox's VJP makes after it
+    (same tiles, same transposition of the stack's gradient), and the
+    transposes of XLA's ragged product where ``impl`` is False.  The
+    rows of no group come out undefined, as forward."""
+    if not impl:
+        product = lambda x, w: lax.ragged_dot_general(
+            x, w, group_sizes, _RAGGED_OUT_IN)
+        return (jax.linear_transpose(lambda t: product(t, w), x)(grad)[0],
+                jax.linear_transpose(lambda t: product(x, t), w)(grad)[0])
+    from jax.experimental.pallas.ops.tpu.megablox.ops import backend
+    tiling = _gmm_tiling(x.shape[0], w.shape[0], x.shape[1], w.shape[1])
+    with jax.named_scope("pallas.grouped_matmul"):
+        dx = backend.gmm(grad, w, group_sizes, x.dtype, tiling,
+                         interpret=impl == "interpret")
+        dw = backend.tgmm(x.swapaxes(0, 1), grad, group_sizes, w.dtype,
+                          tiling, num_actual_groups=w.shape[0],
+                          interpret=impl == "interpret")
+    return dx, dw.swapaxes(1, 2)
+
+
 def _token_sum_impl(how, rows, tokens, width, dtype):
     """How the sums over a token's rows run where ``k > 1``, given the
     grouped products' decision ``how``: with the compiled kernels, the
@@ -393,7 +418,10 @@ def _row_buckets(tokens, k, held, num_experts, slack=1.25):
     would send every other step to the worst case; a model whose rows
     choose ALIKE, such as a diffusion pass's masked rows, says a larger
     ``slack``: its count moves by whole shares of the rows with which
-    experts they favour).  Top-1 has the one size."""
+    experts they favour).  Top-1 has the one size.  With two, the
+    smaller is also what the layer keeps for its backward pass (two
+    arrays of that many rows, ``hidden`` wide); a step that takes the
+    larger keeps nothing and computes a slab again on the way back."""
     worst = tokens * min(k, held)
     expected = -(-tokens * k * held // num_experts)
     size = min(worst, max(tokens, int(math.ceil(slack * expected))))
@@ -431,11 +459,19 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
     count); a step whose count passes it
     (``lax.switch``) runs its pairs a slab of that size at a time,
     forward and backward, so the worst case holds one slab's
-    intermediates (with two arms the forward is computed again in the
-    backward pass: differentiating through a switch keeps every
-    branch's).
-    ``impl`` is :func:`grouped_matmul`'s (None: chosen once a size for
-    all three products, and with them for the sums)."""
+    intermediates.  With two arms the layer's gradient is its own
+    (``jax.custom_vjp``; differentiating through a switch would keep
+    every branch's intermediates).  The small arm, which every step
+    near the expected count takes, keeps its gate and up products after
+    their mask (``(rows, hidden)`` in ``x``'s dtype; nothing ``d`` wide)
+    with the pairs' token order, and goes back from them by hand: the
+    six other directions of the three products, each once, the gather
+    of ``x`` and the gate's elementwise product again, and no forward
+    product a second time.  The worst arm keeps nothing and computes
+    each slab again before it goes back through it.  The routing
+    (``order``, ``sizes``, ``real``) enters that function as arguments.
+    ``impl`` is :func:`grouped_matmul`'s (None: chosen once for all
+    three products, and with them for the sums)."""
     N, d = x.shape
     k = experts.shape[1]
     held = w_gate.shape[0]
@@ -455,32 +491,47 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
         sizes = lax.slice_in_dim(counts, first, first + held)
         real = jnp.sum(sizes)
 
-    def run(rows, x, weights, wg, wu, wd, start=None):
+    buckets = _row_buckets(N, k, held, E, slack)
+    rows = buckets[0]       # of the buffer, and of a slab of the worst case
+    how = _grouped_matmul_impl(rows, x.dtype, held) if impl is None else impl
+    summed = k > 1 and _token_sum_impl(how, rows, N, d, x.dtype)
+
+    def placed(order, sizes, real, start=None):
+        """The first ``rows`` sorted pairs, or with ``start`` the
+        ``rows`` from there (a slab): each row's pair and token, the
+        groups' sizes among them, and the mask of the real rows."""
+        if start is None:
+            at = lax.slice_in_dim(order, 0, rows)
+            here_s = (jnp.arange(rows) < real)[:, None]
+        else:
+            # the slab may pass the end of the pairs: rows of no
+            # group, masked like the others past ``real``
+            at = lax.dynamic_slice_in_dim(
+                jnp.pad(order, (0, rows)), start, rows)
+            ends = jnp.clip(jnp.cumsum(sizes), start, start + rows)
+            sizes = jnp.diff(ends, prepend=start)
+            here_s = (start + jnp.arange(rows) < real)[:, None]
+        return at, at // k if k > 1 else at, sizes, here_s
+
+    def masked(here_s):
+        # what a grouped product leaves in the rows of no group is not
+        # defined, forward or backward (it may be NaN): every operand
+        # and result is masked there, and with it its gradient
+        return lambda t: jnp.where(here_s, t, 0)
+
+    def gated(g, u):
+        return gate(g.astype(f32)) * u.astype(f32)
+
+    def run(operands, routing, start=None):
         """The layer over the first ``rows`` sorted pairs; with
         ``start``, over the ``rows`` pairs from there (a slab of the
-        worst case: its part of the result, float32)."""
-        how = _grouped_matmul_impl(rows, x.dtype, held) if impl is None \
-            else impl
+        worst case: its part of the result, float32).  Beside the
+        result, what :func:`back` is handed: the gate and up products
+        and the held pairs in token order."""
+        x, weights, wg, wu, wd = operands
         with jax.named_scope("moe.dispatch"):
-            if start is None:
-                at = lax.slice_in_dim(order, 0, rows)
-                sizes_here = sizes
-                here_s = (jnp.arange(rows) < real)[:, None]
-            else:
-                # the slab may pass the end of the pairs: rows of no
-                # group, masked like the others past ``real``
-                at = lax.dynamic_slice_in_dim(
-                    jnp.pad(order, (0, rows)), start, rows)
-                ends = jnp.clip(jnp.cumsum(sizes), start, start + rows)
-                sizes_here = jnp.diff(ends, prepend=start)
-                here_s = (start + jnp.arange(rows) < real)[:, None]
-            token = at // k if k > 1 else at
-            # what a grouped product leaves in the rows of no group is
-            # not defined, forward or backward (it may be NaN): every
-            # operand and result is masked there, and with it its
-            # gradient
-            own = lambda t: jnp.where(here_s, t, 0)
-            summed = k > 1 and _token_sum_impl(how, rows, N, d, x.dtype)
+            at, token, sizes, here_s = placed(*routing, start)
+            own = masked(here_s)
             if summed:
                 # the held pairs in token order, once for both sums over
                 # a token's rows: the combine, and the gather's gradient
@@ -489,70 +540,119 @@ def dropless_topk_experts(x, experts, weights, w_gate, w_up, w_down,
                 by_token = (key, perm, _rows(key, perm))
                 xs = own(_taken(x, token, *by_token, summed))
             else:
+                by_token = ()
                 xs = own(jnp.take(x, token, axis=0, unique_indices=k == 1))
         with jax.named_scope("moe.experts"):
-            g = own(grouped_matmul(xs, wg, sizes_here, how))
-            u = own(grouped_matmul(xs, wu, sizes_here, how))
-            mid = own((gate(g.astype(f32)) * u.astype(f32))
-                      .astype(x.dtype))
-            ys = own(grouped_matmul(mid, wd, sizes_here, how))
+            g = own(grouped_matmul(xs, wg, sizes, how))
+            u = own(grouped_matmul(xs, wu, sizes, how))
+            mid = own(gated(g, u).astype(x.dtype))
+            ys = own(grouped_matmul(mid, wd, sizes, how))
         with jax.named_scope("moe.combine"):
             w = jnp.take(weights.reshape(N * k), at)
+            out = x.dtype if start is None else f32
             if summed:
-                return _summed(w, ys, *by_token, N,
-                               x.dtype if start is None else f32, summed)
-            ys = ys.astype(f32) * w[:, None]
-            if k == 1:      # every token has its one row
-                return jnp.zeros((N, d), x.dtype).at[token].set(
-                    ys.astype(x.dtype), unique_indices=True)
-            y = jnp.zeros((N, d), f32).at[token].add(ys)
-            return y.astype(x.dtype) if start is None else y
+                y = _summed(w, ys, *by_token, N, out, summed)
+            else:
+                ys = ys.astype(f32) * w[:, None]
+                if k == 1:  # every token has its one row
+                    y = jnp.zeros((N, d), x.dtype).at[token].set(
+                        ys.astype(x.dtype), unique_indices=True)
+                else:
+                    y = jnp.zeros((N, d), f32).at[token].add(ys).astype(out)
+        return y, (g, u) + by_token
 
-    buckets = _row_buckets(N, k, held, E, slack)
+    def back(operands, routing, kept, dy):
+        """:func:`run`'s gradients over the first ``rows`` pairs from
+        what it kept, each grouped product's two other directions called
+        once (:func:`_grouped_matmul_back`): of the forward only the
+        gather of ``x`` and the gate's elementwise product run again.
+        The down product's input gradient is taken WITHOUT the pair's
+        weight ``c``, ``t = got w_down``: then ``dc = sum_f t * mid`` (a
+        row dot over ``hidden`` columns that needs no forward down
+        product), ``dmid = c * t``, and the weight rides the narrow side
+        of the stack's gradient, ``(c * mid)^T got``."""
+        x, weights, wg, wu, wd = operands
+        g, u, *by_token = kept
+        with jax.named_scope("moe.combine"):
+            at, token, sizes, here_s = placed(*routing)
+            own = masked(here_s)
+            c = jnp.take(weights.reshape(N * k), at)[:, None]
+            got = own(_rows(dy, token))
+        with jax.named_scope("moe.experts"):
+            mid, gated_back = jax.vjp(gated, g, u)
+            t, dwd = _grouped_matmul_back(
+                (c * mid).astype(x.dtype), wd, sizes, got, how)
+            t = own(t).astype(f32)
+            dc = jnp.sum(t * mid.astype(x.dtype).astype(f32), axis=-1)
+            dg, du = gated_back(c * t)
+        with jax.named_scope("moe.dispatch"):
+            xs = own(_rows(x, token))
+        with jax.named_scope("moe.experts"):
+            dxg, dwg = _grouped_matmul_back(xs, wg, sizes, dg, how)
+            dxu, dwu = _grouped_matmul_back(xs, wu, sizes, du, how)
+        with jax.named_scope("moe.dispatch"):
+            dxs = own(dxg + dxu)
+            dx = _summed(None, dxs, *by_token, N, x.dtype, summed) \
+                if summed else jnp.zeros((N, d), x.dtype).at[token].add(dxs)
+        dw = jnp.zeros(N * k, f32).at[at].add(dc).reshape(N, k)
+        return dx, dw.astype(weights.dtype), dwg, dwu, dwd
+
+    operands = (x, weights, w_gate, w_up, w_down)
     if len(buckets) == 1:
-        return run(buckets[0], x, weights, w_gate, w_up, w_down), counts
+        return run(operands, (order, sizes, real))[0], counts
 
     # a step whose pairs pass the smaller size runs them a slab of that
     # size at a time, as many slabs as hold the real pairs: the worst
     # case costs what its pairs cost, and holds one slab's intermediates
-    size = buckets[0]
-    which = (real > size).astype(jnp.int32)
-    slabs = (real + size - 1) // size
+    def slab(j, routing, *operands):
+        return run(operands, routing, j * rows)[0]
 
-    def slab(j, *operands):
-        return run(size, *operands, start=j * size)
+    def slabs(routing):
+        _, _, real = routing
+        return (real + rows - 1) // rows
 
-    def worst(*operands):
+    def worst(operands, routing):
         return lax.fori_loop(
-            0, slabs, lambda j, y: y + slab(j, *operands),
+            0, slabs(routing), lambda j, y: y + slab(j, routing, *operands),
             jnp.zeros((N, d), f32)).astype(x.dtype)
 
-    def worst_bwd(operands, dy):
+    def worst_back(operands, routing, kept, dy):
         def more(j, acc):
-            got = jax.vjp(_functools.partial(slab, j), *operands)[1](
+            got = jax.vjp(_functools.partial(slab, j, routing), *operands)[1](
                 dy.astype(f32))
             return tuple(a + g.astype(f32) for a, g in zip(acc, got))
 
-        acc = lax.fori_loop(0, slabs, more, tuple(
+        acc = lax.fori_loop(0, slabs(routing), more, tuple(
             jnp.zeros(o.shape, f32) for o in operands))
         return tuple(a.astype(o.dtype) for a, o in zip(acc, operands))
 
-    small = _functools.partial(run, size)
+    def arm(routing):
+        _, _, real = routing
+        return (real > rows).astype(jnp.int32)
 
     @jax.custom_vjp
-    def sized(x, weights, wg, wu, wd):
-        return lax.switch(which, [small, worst], x, weights, wg, wu, wd)
+    def sized(operands, routing):
+        return lax.switch(arm(routing), [lambda *a: run(*a)[0], worst],
+                          operands, routing)
 
-    def sized_fwd(*operands):
-        return sized(*operands), operands
+    def sized_fwd(operands, routing):
+        # the small arm hands its backward what ``run`` keeps; the worst
+        # arm keeps nothing (zeros of those shapes) and runs each slab
+        # again on the way back
+        nothing = (jnp.zeros((rows, w_gate.shape[1]), x.dtype),) * 2 \
+            + (jnp.zeros(rows, jnp.int32),) * (3 if summed else 0)
+        y, kept = lax.switch(
+            arm(routing), [run, lambda *a: (worst(*a), nothing)],
+            operands, routing)
+        return y, (operands, routing, kept)
 
-    def sized_bwd(operands, dy):
-        return lax.switch(
-            which, [lambda ops, dy: jax.vjp(small, *ops)[1](dy), worst_bwd],
-            operands, dy)
+    def sized_bwd(res, dy):
+        operands, routing, kept = res
+        return lax.switch(arm(routing), [back, worst_back],
+                          operands, routing, kept, dy), None
 
     sized.defvjp(sized_fwd, sized_bwd)
-    return sized(x, weights, w_gate, w_up, w_down), counts
+    return sized(operands, (order, sizes, real)), counts
 
 
 def dropless_top1_experts(x, prob, w_gate, w_up, w_down, held_first=0,

@@ -32,6 +32,7 @@ starts in the run's second minute.
 import functools
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -65,6 +66,46 @@ def _compile(fn, one_chip, *specs):
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
+
+
+def branch_kernels(text):
+    """``{a conditional's op_name less the jit's: [the op_names of the
+    Mosaic kernels each of its branches calls, whiles and fusions
+    included]}`` of a compiled module's text."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        start = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if start:
+            name = start.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(line)
+
+    def arms(line):
+        found = re.search(r"branch_computations=\{([^}]*)\}", line)
+        return [a.strip(" %") for a in found.group(1).split(",")] \
+            if found else []
+
+    def kernels(name):
+        found = []
+        for line in bodies[name]:
+            if 'custom_call_target="tpu_custom_call"' in line:
+                found.append(re.search(r'op_name="([^"]*)"', line).group(1))
+            for other in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                    line) + arms(line):
+                found += kernels(other)
+        return found
+
+    out = {}
+    for lines in bodies.values():
+        for line in lines:
+            if " conditional(" in line:
+                op = re.search(r'op_name="([^"]*)"', line).group(1)
+                out[op.split("/", 1)[1]] = [kernels(a) for a in arms(line)]
+    return out
 
 
 def on_the_chip(args, one_chip):
@@ -326,6 +367,16 @@ def test_topk_expert_layer_with_the_token_ordered_sum_compiles(
     wide = [line for line in text.splitlines()
             if " scatter(" in line and "%d]" % d in line.split("scatter(")[0]]
     assert not wide, wide[:2]
+    # the small arm goes back from what its forward kept: three grouped
+    # products forward, and their six other directions backward, none of
+    # the forward's a second time (nine until PR 46); the worst arm still
+    # runs a slab's three again before its six
+    products = {
+        which: [sum("pallas.grouped_matmul" in name for name in arm)
+                for arm in arms]
+        for which, arms in branch_kernels(text).items()}
+    assert products == {"jvp()/cond": [3, 3],
+                        "transpose(jvp())/cond": [6, 9]}, products
 
 
 def test_gated_delta_rule_fwd_grad_compiles(one_chip):

@@ -394,3 +394,92 @@ def test_token_sum_keeps_float32_weights_and_sums(weighted, dtype):
             assert float(jnp.abs(a.astype(jnp.float32)
                                  - b.astype(jnp.float32)).max()) \
                 <= 1e-6 * float(jnp.abs(b.astype(jnp.float32)).max())
+
+
+# ----------------------------------------------------------------------
+# the small arm's own backward
+# ----------------------------------------------------------------------
+def _masked_dense(act, x, e, w, wg, wu, wd, first):
+    """Every held expert over every token in float32, a mask keeping its
+    pairs: the layer with no sorting, buffer or arm."""
+    gate = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    x, wg, wu, wd = (t.astype(jnp.float32) for t in (x, wg, wu, wd))
+    y = jnp.zeros_like(x)
+    for i in range(wg.shape[0]):
+        mine = jnp.sum(jnp.where(e == first + i, w, 0.0), -1, keepdims=True)
+        y = y + mine * ((gate(x @ wg[i].T) * (x @ wu[i].T)) @ wd[i].T)
+    return y
+
+
+def _held_pairs(rng, routing, N, k, held, E, first, size):
+    """(N, k) choices of distinct experts: ``even`` among all;
+    ``collapsed`` all held (every slab of the worst case is full);
+    ``empty`` none held; ``one-past`` exactly ``size + 1`` pairs held,
+    the fewest that take the slab arm."""
+    inside = np.arange(first, first + held)
+    outside = np.setdiff1d(np.arange(E), inside)
+    pool = {"even": np.arange(E), "collapsed": inside}.get(routing, outside)
+    e = np.stack([rng.permutation(pool)[:k] for _ in range(N)])
+    if routing == "one-past":
+        full, rest = divmod(size + 1, k)
+        e[:full] = np.stack([rng.permutation(inside)[:k]
+                             for _ in range(full)])
+        e[full, :rest] = rng.permutation(inside)[:rest]
+    return jnp.asarray(e, jnp.int32)
+
+
+@pytest.mark.parametrize("impl", ["interpret", False])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", ["even", "collapsed", "empty", "one-past"])
+@pytest.mark.parametrize("act", ["silu", "relu"])
+def test_sized_backward_matches_the_masked_dense_form(act, routing, dtype,
+                                                      impl):
+    """With two buffer sizes the layer's gradient is its own: the small
+    arm keeps its gate and up products and goes back from them by hand
+    (six grouped products, the router weights' gradient a row dot over
+    ``hidden``), the worst arm runs each slab again.  All five gradients
+    (``x``, ``weights``, the three stacks) against ``jax.grad`` of the
+    masked dense form in float32: 256 tokens (a tile of the
+    token-ordered sum), 3 choices of 16 experts, 4 held, a buffer of 256
+    or 768 rows; with the Pallas kernels interpreted and as XLA runs
+    it."""
+    from mxnet_tpu.parallel import moe
+    N, d, F, E, k, first, held = 256, 128, 32, 16, 3, 4, 4
+    size, worst = moe._row_buckets(N, k, held, E)
+    assert (size, worst) == (256, 768)
+    rng = np.random.default_rng(46)
+    e = _held_pairs(rng, routing, N, k, held, E, first, size)
+    assert (np.sort(np.asarray(e), -1)[:, 1:]
+            != np.sort(np.asarray(e), -1)[:, :-1]).all()
+    real = int(((e >= first) & (e < first + held)).sum())
+    assert {"collapsed": real == worst, "empty": real == 0,
+            "one-past": real == size + 1, "even": 0 < real <= size}[routing]
+    draw = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    w = jax.nn.softmax(draw(N, k), -1)
+    x = draw(N, d).astype(dtype)
+    wg, wu = ((draw(held, F, d) * d ** -0.5).astype(dtype) for _ in "gu")
+    wd = (draw(held, d, F) * F ** -0.5).astype(dtype)
+    cot = draw(N, d)
+
+    def grads(layer):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(layer(*a).astype(jnp.float32) * cot),
+            (0, 1, 2, 3, 4)))(x, w, wg, wu, wd)
+
+    got = grads(lambda x, w, *stacks: moe.dropless_topk_experts(
+        x, e, w, *stacks, E, first, impl=impl, act=act)[0])
+    want = grads(lambda x, w, *stacks: _masked_dense(
+        act, x, e, w, *stacks, first))
+    # float32: the same products summed in another order; bfloat16: the
+    # layer rounds each product where the dense form rounds nothing
+    tol = 5e-5 if dtype == "float32" else 2e-2
+    for name, a, b in zip(("x", "weights", "gate", "up", "down"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.isfinite(a).all(), name
+        if routing == "empty":
+            assert np.abs(a).max() == 0.0, name
+        else:
+            assert np.abs(b).max() > 0, name
+        assert np.abs(a - b).max() <= tol * max(np.abs(b).max(), 1e-30), \
+            (name, np.abs(a - b).max(), np.abs(b).max())

@@ -541,3 +541,38 @@ def test_three_fit_steps_match_the_reference(ref, dtype):
     assert reg.get("moe_tokens_away").labels(layer=0).value \
         == B * S * kw["top_k"] - here[0].sum()
     assert np.array_equal(load["counts"], counts)
+    # which arm each layer took, from the node's own top_k and rows_slack
+    from mxnet_tpu.parallel.moe import _row_buckets
+    size = _row_buckets(B * S, kw["top_k"], n, 16)[0]
+    assert reg.get("moe_layers_over_size").value \
+        == (here.sum(axis=1) > size).sum()
+
+
+@pytest.mark.parametrize("held_pairs, k, slack, over", [
+    ((0, 100, 128), 3, 1.25, 0),            # at the small size is inside it
+    ((129, 40, 384), 3, 1.25, 2),
+    ((129, 200, 384), 3, 1.6, 2),           # a buffer of 154 rows
+    ((129, 200, 384), 3, 3.0, 1),           # ... of 288
+    ((128, 128), 1, 1.25, 0)])              # top-1 has one size
+def test_layers_over_size_counts_the_layers_that_ran_slabs(
+        monkeypatch, held_pairs, k, slack, over):
+    """``moe_layers_over_size`` from hand-made counts: 128 tokens, ``k``
+    choices of 16 experts, 4 held (4-7), so a buffer of 128 rows at five
+    quarters.  A layer whose held pairs fit reads 0; one pair more and
+    the layer ran its pairs a slab at a time."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.parallel.moe import _row_buckets
+    N, E, first, held = 128, 16, 4, 4
+    assert _row_buckets(N, 3, held, E) == [128, 384]
+    assert _row_buckets(N, 3, held, E, 1.6) == [154, 384]
+    assert _row_buckets(N, 3, held, E, 3.0) == [288, 384]
+    counts = np.zeros((len(held_pairs), E), np.int32)
+    for row, n in zip(counts, held_pairs):
+        row[first:first + held] = [n - 3 * (n // 4)] + 3 * [n // 4]
+        row[0] = N * k - n              # the other pairs are away
+    monkeypatch.setattr(telemetry.moe, "_last", None)
+    assert telemetry.moe.publish() is None
+    telemetry.moe.note(counts, first, held, k, slack)
+    load = telemetry.moe.publish()
+    assert np.array_equal(load["counts"], counts)
+    assert telemetry.REGISTRY.get("moe_layers_over_size").value == over
