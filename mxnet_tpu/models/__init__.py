@@ -4,7 +4,7 @@ Reference parity: example/image-classification/symbols/ (mlp, lenet,
 alexnet, vgg, resnet, resnext, mobilenet, inception-bn, googlenet,
 squeezenet, densenet). Each module exposes ``get_symbol(num_classes, ...)``
 returning a Symbol ending in SoftmaxOutput, so any of them drops into
-``Module.fit`` / ``benchmark/run.py`` unchanged.  Seven language-model
+``Module.fit`` / ``benchmark/run.py`` unchanged.  Eight language-model
 families beside them, with the same factory signature: ``transformer``
 (GPT-2's block), ``zaya`` (compressed convolutional attention and a
 dropless top-1 expert sublayer), ``qwen3_next`` (Gated DeltaNet layers
@@ -14,10 +14,12 @@ with a selection bias before the top-k sublayer), ``keye_vl2``
 (attention over the keys a learned index scorer picks, trained by a
 second loss head, before a softmax top-k sublayer), ``smallthinker``
 (window and full grouped-query attention layers in one model, a router
-that reads the layer's input, ReLU-gated experts) and ``sdar_moe``
+that reads the layer's input, ReLU-gated experts), ``sdar_moe``
 (trained by block diffusion: a clean and a noised copy of the sequence
 in one pass under a block-structured mask, a masked and weighted loss
-head); the last six hand
+head) and ``kimi_linear`` (Kimi Delta Attention, a delta rule gated per
+key channel, three layers to one of latent attention without position,
+a leading dense layer under a linear mixer); the last seven hand
 out the experts' token counts as an output, and share one frame
 (``_decoder.py``: ``experts_held``, the embedding, the closing norm, the
 head, the loss and the counts' output), so that a family's file is its
@@ -45,6 +47,7 @@ from . import kanana2
 from . import keye_vl2
 from . import smallthinker
 from . import sdar_moe
+from . import kimi_linear
 
 _NETWORKS = {
     "transformer": transformer,
@@ -54,6 +57,7 @@ _NETWORKS = {
     "keye_vl2": keye_vl2,
     "smallthinker": smallthinker,
     "sdar_moe": sdar_moe,
+    "kimi_linear": kimi_linear,
     "mlp": mlp,
     "lenet": lenet,
     "alexnet": alexnet,

@@ -1,5 +1,6 @@
 """The frame of the expert-layer decoders (``zaya``, ``qwen3_next``,
-``kanana2``, ``keye_vl2``, ``smallthinker``, ``sdar_moe``): everything
+``kanana2``, ``keye_vl2``, ``smallthinker``, ``sdar_moe``,
+``kimi_linear``): everything
 such a model is apart from its layers.  A family's file says its mixer,
 its expert sublayer's attributes and its layer schedule; token ids enter
 and logits leave here:

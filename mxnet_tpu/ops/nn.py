@@ -1439,7 +1439,7 @@ def _rotary_interleaved(x, theta):
 @register("_contrib_LatentAttention", aliases=("LatentAttention",))
 def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
                      o_weight, *, heads, nope_dim, rope_dim, v_dim, kv_rank,
-                     rope_theta=1e6, eps=1e-6):
+                     rope_theta=1e6, eps=1e-6, rotary=True):
     """Multi-head latent attention (MLA, DeepSeek-V2/V3's, without the
     query's low-rank path) as one sublayer, (B, S, d) -> (B, S, d), on
     an already normalised stream; no bias.
@@ -1456,6 +1456,11 @@ def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
     ``(nope_dim + rope_dim) ** -0.5`` with values ``v_dim`` wide, then
     ``o_weight`` (d, heads * v_dim).  Weights are (out, in), as
     FullyConnected's, in the source's row order.
+
+    ``rotary=False`` is a layer with no position at all (Kimi-Linear's,
+    whose linear layers carry the order): the ``rope_dim`` channels stay
+    in every query and as the one key part all heads share, unturned,
+    and ``rope_theta`` is unused.
 
     Head-major like the other mixers; ``_causal_attention_core`` runs
     the core at keys 192 wide and values 128 (its fold joins q's float32
@@ -1490,10 +1495,11 @@ def latent_attention(data, q_weight, kva_weight, kv_norm_gamma, kvb_weight,
 
     @jax.checkpoint
     def position(q0, k0, ckr):
+        turn = (lambda t: _rotary_interleaved(t, theta)) if rotary \
+            else (lambda t: t)
         qf = q0.astype(f32) * fold
-        q = jnp.concatenate(
-            [qf[..., :Dn], _rotary_interleaved(qf[..., Dn:], theta)], -1)
-        kr = _rotary_interleaved(ckr[..., C:].astype(f32), theta)
+        q = jnp.concatenate([qf[..., :Dn], turn(qf[..., Dn:])], -1)
+        kr = turn(ckr[..., C:].astype(f32))
         k = jnp.concatenate(
             [k0, jnp.broadcast_to(kr[:, None].astype(k0.dtype),
                                   (B, H, S, Dr))], -1)
@@ -1739,6 +1745,96 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, A_log,
 
     with jax.named_scope("gdn.proj"):
         return jnp.einsum("bhse,dhe->bsd", o, out_weight.reshape(d, Hv, Dv))
+
+
+@register("_contrib_KimiDeltaAttention", aliases=("KimiDeltaAttention",))
+def kimi_delta_attention(data, q_weight, k_weight, v_weight, conv_weight,
+                         fa_weight, fb_weight, A_log, dt_bias, b_weight,
+                         ga_weight, gb_weight, gb_bias, norm_gamma, o_weight,
+                         *, heads, head_dim, conv_kernel=4, eps=1e-5):
+    """The Kimi Delta Attention mixer (arXiv:2510.26692) as one
+    sublayer, (B, S, d) -> (B, S, d), on an already normalised stream:
+    a delta rule whose forget gate is one value a KEY CHANNEL.
+
+    ``q_weight``, ``k_weight``, ``v_weight`` (heads head_dim, d) give
+    every head its own query, key and value; each passes its own causal
+    depthwise convolution over the sequence (``conv_weight`` (3 heads
+    head_dim, conv_kernel), the rows of ``[q; k; v]`` head-major, tap
+    ``conv_kernel - 1`` the position itself) and SiLU; q and k are
+    L2-normalised a head, q scaled by ``head_dim ** -0.5``.  The forget
+    gate comes through a low-rank pair, ``fa_weight`` (head_dim, d) then
+    ``fb_weight`` (heads head_dim, head_dim), no bias: ``g = -exp(A_log)
+    softplus(a + dt_bias)`` in float32, one value a head and key channel
+    (``A_log`` (heads,), ``dt_bias`` (heads head_dim,)); ``beta =
+    sigmoid(data b_weight^T)``, one a head (``b_weight`` (heads, d)).
+    The channel-gated delta rule (``ops/delta_rule.py``
+    ``gated_delta_rule`` with a gate of rank 4: chunks of 64 tokens,
+    state float32) gives ``o``, which is RMS-normalised a head with gain
+    ``norm_gamma`` (head_dim,) and gated by ``sigmoid`` of a second
+    low-rank pair (``ga_weight`` (head_dim, d), ``gb_weight`` (heads
+    head_dim, head_dim), ``gb_bias``), then ``o_weight`` (d, heads
+    head_dim).
+
+    Head-major like the other mixers.  Between the passes the layer
+    keeps its input, the three projections' result (``[q; k; v]`` before
+    the convolution), the two 128-wide low-rank rows, the scan's result
+    and, on the kernels' path, the state every run of chunks starts
+    from; the convolution (:func:`gdn_mix`, as Gated DeltaNet's), the
+    gates, so the scan's inputs q, k, v, g and beta, the norm and the
+    output gate's second map are made again in the backward pass.
+    Scopes: ``kda.proj``, ``kda.conv`` (``pallas.gdn_mix`` inside it),
+    ``kda.gate``, ``kda.scan`` (``pallas.kda_delta_rule`` inside it),
+    ``kda.norm``."""
+    from .delta_rule import RUN_STARTS, gated_delta_rule
+    B, S, d = data.shape
+    H, D = int(heads), int(head_dim)
+    f32 = jnp.float32
+
+    with jax.named_scope("kda.proj"):
+        w = jnp.concatenate([q_weight, k_weight, v_weight]).reshape(
+            3 * H, D, d)
+        qkv = jnp.einsum("bsd,hed->bhse", data, w)
+        low_rank = jnp.einsum(
+            "bsd,ted->tbse", data, jnp.stack([fa_weight, ga_weight]))
+        b = jnp.einsum("bsd,hd->bhs", data, b_weight,
+                       preferred_element_type=f32)
+
+    # convolution, gates and scan as one rematerialized stretch: of all
+    # they make the backward pass finds only the state every run of
+    # chunks started from (the scan's kernels name it), and makes q, k,
+    # v, g and beta again from ``qkv`` and the low-rank rows
+    @_functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(RUN_STARTS))
+    def scanned(qkv, wc, fa, b, wb, a_log, dt):
+        with jax.named_scope("kda.conv"):
+            q, k, v = gdn_mix(qkv, wc, H)
+        with jax.named_scope("kda.gate"):
+            a = jnp.einsum("bse,hfe->bhsf", fa, wb.reshape(H, D, D),
+                           preferred_element_type=f32)
+            g = -jnp.exp(a_log.astype(f32)).reshape(1, H, 1, 1) \
+                * jax.nn.softplus(a + dt.astype(f32).reshape(1, H, 1, D))
+            beta = jax.nn.sigmoid(b)
+        with jax.named_scope("kda.scan"):
+            return gated_delta_rule(q, k, v, g, beta)
+
+    o = scanned(qkv, conv_weight, low_rank[0], b, fb_weight, A_log, dt_bias)
+
+    @jax.checkpoint
+    def gated_norm(o, ga, wb, bias, gain):
+        z = jnp.einsum("bse,hfe->bhsf", ga, wb.reshape(H, D, D),
+                       preferred_element_type=f32) \
+            + bias.astype(f32).reshape(1, H, 1, D)
+        o = o.astype(f32)
+        inv = lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + eps)
+        return (gain.astype(f32) * o * inv
+                * jax.nn.sigmoid(z)).astype(data.dtype)
+
+    with jax.named_scope("kda.norm"):
+        o = gated_norm(o, low_rank[1], gb_weight, gb_bias, norm_gamma)
+
+    with jax.named_scope("kda.proj"):
+        return jnp.einsum("bhse,dhe->bsd", o, o_weight.reshape(d, H, D))
 
 
 # ----------------------------------------------------------------------

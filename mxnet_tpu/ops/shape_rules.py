@@ -255,6 +255,23 @@ def _gdn_shapes(known, attrs):
 _set("_contrib_GatedDeltaNet", _gdn_shapes)
 
 
+def _kda_shapes(known, attrs):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    H, D = int(attrs["heads"]), int(attrs["head_dim"])
+    wide = {"%s_weight" % n: (H * D, d) for n in "qkv"}
+    return dict(wide, conv_weight=(3 * H * D, int(attrs.get("conv_kernel", 4))),
+                fa_weight=(D, d), fb_weight=(H * D, D), A_log=(H,),
+                dt_bias=(H * D,), b_weight=(H, d), ga_weight=(D, d),
+                gb_weight=(H * D, D), gb_bias=(H * D,), norm_gamma=(D,),
+                o_weight=(d, H * D))
+
+
+_set("_contrib_KimiDeltaAttention", _kda_shapes)
+
+
 def _cca_shapes(known, attrs):
     data = known.get("data")
     if data is None:

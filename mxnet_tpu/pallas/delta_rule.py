@@ -1,10 +1,10 @@
-"""The chunked gated delta rule as Pallas kernels (docs/KERNELS.md).
-
-Same result as ``ops/delta_rule.py`` ``chunk_gated_delta_rule`` (the
-WY form in chunks of 64 tokens, arXiv:2412.06464), with every array of
-a chunk (the Gram and score blocks, the decays, the triangle and its
-inverse, ``w``, ``u``, the new values) living in VMEM only: HBM sees
-one read of q, k, v, g, beta and one write of o.  A grid step takes a
+"""The chunked delta rule under a SCALAR gate a head and token (Gated
+DeltaNet) as Pallas kernels (docs/KERNELS.md); a gate a key channel is
+``pallas/kda_delta_rule.py``, which takes this file's solve, hand-over
+and masks.  Same result as ``ops/delta_rule.py``
+``chunk_gated_delta_rule`` (the WY form in chunks of 64 tokens,
+arXiv:2412.06464), every array of a chunk in VMEM only: HBM sees one
+read of q, k, v, g, beta and one write of o.  A grid step takes a
 key head, the value heads that read it and a run of chunks; the
 float32 state (Dk x Dv a value head) stays in a VMEM scratch across
 the sequential grid dimension over the sequence.
@@ -59,8 +59,8 @@ _F32 = jnp.float32
 
 
 def supported(q, k, v):
-    """Whether the kernels take these operands: head widths that fill
-    whole lane tiles, an even number of value heads a key head (or
+    """Whether the scalar-gate kernels take these operands: head widths
+    of whole lane tiles, an even number of value heads a key head (or
     one), bfloat16 or float32.  Returns ``(ok, why)``."""
     Hk, Dk = k.shape[1], k.shape[3]
     Hv, Dv = v.shape[1], v.shape[3]

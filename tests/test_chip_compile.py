@@ -404,6 +404,32 @@ def test_gated_delta_rule_fwd_grad_compiles(one_chip):
     assert grad.memory_analysis().temp_size_in_bytes < 50e6
 
 
+def test_kda_delta_rule_fwd_grad_compiles(one_chip):
+    """The channel-gated delta rule's kernels at the Kimi-Linear cell's
+    sizes (one sequence of 8192 tokens, 32 heads each with its own keys,
+    128 wide, bf16; the gate (S, 128) a head, float32): the forward
+    alone, and the gradient's forward (which keeps the float32 state
+    each run of chunks starts from) and backward kernel."""
+    from mxnet_tpu.ops.delta_rule import gated_delta_rule
+    from mxnet_tpu.pallas import kda_delta_rule as kda
+    B, H, S, D = 1, 32, 8192, 128
+    wide = ((B, H, S, D), jnp.bfloat16)
+    shapes = (wide, wide, wide, ((B, H, S, D), jnp.float32),
+              ((B, H, S), jnp.float32))
+    rule = lambda *a: gated_delta_rule(*a, impl="compiled")
+    text = _compile(rule, one_chip, *shapes).as_text()
+    assert "tpu_custom_call" in text and "kda_delta_rule_forward" in text
+    grad = _compile(
+        jax.grad(lambda *a: rule(*a).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2, 3, 4)), one_chip, *shapes)
+    text = grad.as_text()
+    assert "kda_delta_rule_forward" in text
+    assert "kda_delta_rule_backward" in text
+    # the run starts are the only residual besides the inputs
+    starts = B * H * (S // (kda._RUN * 64)) * D * D * 4
+    assert grad.memory_analysis().temp_size_in_bytes < starts + 50e6
+
+
 def test_gdn_mix_fwd_grad_compiles(one_chip):
     """Gated DeltaNet's convolution, SiLU and L2 norms as kernels
     (``pallas/gdn_mix.py``) at the Qwen3-Next cell's sizes (one sequence
@@ -621,6 +647,44 @@ def test_kanana2_fit_program_compiles_and_fits_the_chip(one_chip,
     assert "splash_mha_fwd" in text and "gmm" in text and "ragged" not in text
     assert "flash_attention_backward" in text and "splash_mha_dkv" not in text
     assert device_bytes(compiled, "kanana2") < 15e9
+
+
+def test_kimi_linear_fit_program_compiles_and_fits_the_chip(one_chip,
+                                                            monkeypatch):
+    """The fused fit program of the cell ``kimilinear_48b_train_ep32``
+    at its own sizes (a KDA layer with the dense FFN, then KDA, KDA,
+    latent attention without position and KDA with 8 of 256 experts
+    held, 20 480 rows of the vocabulary, one sequence of 8192 tokens,
+    bf16 with f32 masters), compiled for the described chip with the
+    kernels the chip would choose: the channel-gated delta rule's pair,
+    the convolution's pair before it, the flash kernel at key width 192
+    / value width 128 and the Pallas grouped matmul over 8 groups of
+    width 1024 in both sizes of the sorted rows' buffer.
+    ``memory_analysis`` stays under 15 GB of the chip's 16: the
+    configuration's ``reduced_why`` quotes the number printed here."""
+    from mxnet_tpu.ops import delta_rule, nn
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(nn, "_use_flash_attention",
+                        lambda *a, **k: "compiled")
+    monkeypatch.setattr(moe, "_grouped_matmul_impl",
+                        lambda *a, **k: "compiled")
+    monkeypatch.setattr(delta_rule, "_delta_rule_impl",
+                        lambda *a, **k: "compiled")
+    monkeypatch.setattr(nn, "_gdn_mix_impl", lambda *a, **k: "compiled")
+    cfg = cell_config("kimi_linear_48b_train")
+    kw = cfg["kwargs"]
+    assert (kw["num_layers"], kw["experts_held"], kw["num_classes"],
+            kw["seq_len"]) == (5, [0, 8], 20480, 8192)
+    compiled = fit_program(cfg, one_chip)
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "gmm" in text and "ragged" not in text
+    assert "flash_attention_backward" in text
+    for kernel in ("forward", "backward"):
+        assert "kda_delta_rule_" + kernel in text
+        assert "gdn_mix_" + kernel in text
+        assert "gated_delta_rule_" + kernel not in text
+    assert device_bytes(compiled, "kimi_linear") < 15e9
 
 
 def test_keye_vl2_fit_program_compiles_and_fits_the_chip(one_chip,
@@ -849,6 +913,9 @@ def test_sdar_fit_program_compiles_and_fits_the_chip(one_chip, monkeypatch):
     # rows that choose alike: twice the even share (``rows_slack``)
     ("sdar_30b_train_bd4_s8k", 16384, 128, None, (16384, 8, 16, 128, 2.0),
      [32768, 131072]),
+    # 256 rows an expert: the smaller size is one row a token
+    ("kimilinear_48b_train_ep32", 8192, 192, 128, (8192, 8, 8, 256),
+     [8192, 65536]),
 ])
 def test_accepted_cells_geometries_give_what_they_gave(cell, S_, D, Dv, rows,
                                                        buckets):

@@ -95,6 +95,10 @@ DECODER_GRAPHS = {
     ("sdar_moe", "sdar_30b_a3b_train"): (
         "dcd0ecf0e73bd67504aa181928423d5f31d7a2031c709979af1bdcfeea6365da",
         "09e4fd547b4f4c4963d5562384de8e2881e2424d2c68954ba9576fefb03359b2"),
+    # the eighth family, as PR 47 built it on the frame
+    ("kimi_linear", "kimi_linear_48b_train"): (
+        "fb6e423e295deb808de5baffa7d9aecbe16a1996d1434e3397fd72d7b8e5cc21",
+        "3ce5af78ea4bcd1582c58e1dc7e9d5e0f8c0663cc3562940a84ac8723b3850fe"),
 }
 
 
