@@ -20,11 +20,19 @@ head's stem, the logits as their producer wrote them, and
 ``get_outputs()`` builds the head from it on demand (loss_head.py;
 docs/TRAINING.md, "What a fused step returns").
 
-Parameters, optimizer state, residuals, aux states, and the metric
-accumulator are DONATED, so HBM holds one copy of the training state and
-a steady-state step is a single device launch with zero host syncs —
+Parameters, optimizer state, residuals, aux states, the scaler's and the
+sentinel's carry are DONATED, so HBM holds one copy of the training state
+and a steady-state step is a single device launch with zero host syncs —
 the same shape as parallel/trainer.py's TrainStep, brought to the
 Module/kvstore path that ``fit``, ``model.py``, and user scripts use.
+
+What a step derives from the module, the optimizer and the metric, and
+not from the batch, is its PLAN (``_StepPlan``): derived at the first
+step, kept with the compiled program, and derived again only when
+something it rests on was replaced (``FusedFitStep._plan_holds``;
+counter ``fit_plan_builds``).  A step then places its batch, reads the
+buffers off the plan's flat lists, advances the optimizer's counts and
+launches.
 
 Eligibility (checked once per optimizer init, cheaply re-checked per
 batch): dense f32/f16/bf16 params with grad_req='write', an optimizer
@@ -68,6 +76,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ndarray import NDArray
+from .. import config as _config
 from .. import fused_update as _fused
 from .. import loss_head as _loss_head
 from .. import optimizer as opt_mod
@@ -124,6 +133,13 @@ FIT_RETRACES = _telemetry.REGISTRY.counter(
 _SITE = _telemetry.RetraceSite(FIT_RETRACES, _telemetry.JIT_COMPILE_MS,
                                site="fit_step")
 _note_retrace = _SITE.note
+# what a step derives from the module, the optimizer and the metric is
+# derived once (FusedFitStep._build_plan): one over a steady run, one
+# more whenever something the plan rests on was replaced
+FIT_PLAN_BUILDS = _telemetry.REGISTRY.counter(
+    "fit_plan_builds",
+    "step plans the fused fit step built or rebuilt (one over a steady run)",
+    vital=True)
 
 
 def __getattr__(name):
@@ -365,17 +381,44 @@ def _build_fit_program(graph_fn, param_order, threshold, mode, tpls,
         return (new_ps, new_ss, new_res, macc, new_scaler, new_sent,
                 new_auxs, results, stems)
 
-    # params/states/residuals/macc/scaler/sentinels/auxs donate in place
-    donate = (0, 1, 2, 3, 4, 5, 7)
+    # params/states/residuals/scaler/sentinels/auxs donate in place.  The
+    # metric's pair (argument 3, eight bytes) does not: the step after a
+    # ``metric.reset()`` is handed the plan's one pair of zeros, which
+    # then serves every reset
+    donate = (0, 1, 2, 4, 5, 7)
     fn = jax.jit(step, donate_argnums=donate)
     _telemetry.programs.note_donation(fn, donate)
     return fn
 
 
+_MISSING = object()
+
+
+class _StepPlan:
+    """What a fused step derives from the module, the optimizer and the
+    metric, and not from the batch.  ``FusedFitStep._build_plan`` fills
+    it, ``_plan_holds`` says each step whether it still stands.
+
+    ``group`` ``optimizer`` ``mode`` ``multi_precision`` ``metric``
+    ``mirror`` ``scaler_sig`` ``sent_on`` and ``state_objs`` (the
+    updater's state object of each parameter, as flattened) are what it
+    is held against; ``order`` ``ukeys`` the parameters' names and
+    updater keys; ``weights`` ``leaves`` ``fixed`` the NDArrays a step
+    reads its buffers off and hands the results back to (each state
+    flattened once); ``tpls`` ``mp_flags`` ``msig`` ``heads`` the
+    program's static description, ``graph_fn`` ``metric_fn`` ``tail``
+    what it is built from; ``programs`` the compiled step by ``use_wd``;
+    ``zeros`` the metric's pair after a reset."""
+
+    __slots__ = ("group", "optimizer", "mode", "multi_precision", "metric",
+                 "mirror", "scaler_sig", "sent_on", "state_objs",
+                 "order", "ukeys", "weights", "leaves", "fixed", "tpls",
+                 "mp_flags", "metric_fn", "msig", "heads", "tail",
+                 "graph_fn", "programs", "zeros")
+
+
 class FusedFitStep:
     """Per-Module driver for the single-launch fit step."""
-
-    _METRIC_UNSET = object()
 
     def __init__(self, module, updater, kv, threshold, mode, pmesh=None,
                  scaler=None):
@@ -395,9 +438,10 @@ class FusedFitStep:
         # rebind/init_optimizer, so these live as long as they are valid)
         self._order = None            # trainable param names, arg order
         self._ukeys = None            # matching updater state keys
-        self._metric_ref = FusedFitStep._METRIC_UNSET
-        self._metric_fn = None
-        self._msig = None
+        self._plan = None             # _StepPlan, while it holds
+        # the carry as the last launch left it (metric pair, scaler
+        # triple, sentinel vector): committed where the program runs
+        self._carry_out = ()
         # donated sentinel vector (f32[8], see _build_fit_program) and
         # the cumulative non-finite count already pushed to the registry
         self._sent_state = None
@@ -617,6 +661,9 @@ class FusedFitStep:
             for n, r in self._residuals.items():
                 self._kv._compression_residuals[(n, 0)] = NDArray(r)
         self._residuals = None
+        # whatever the eager path replaces meanwhile, the next fused
+        # step derives its plan again
+        self._plan = None
 
     # -- sentinel publish (sync boundaries only) ------------------------
     def publish_sentinels(self):
@@ -679,6 +726,7 @@ class FusedFitStep:
             # module's device state is not recoverable at this point)
             self._residuals = None
             self._sent_state = None
+            self._carry_out = ()
             raise
         if track_mem:
             self._mem_tracker.end()
@@ -703,7 +751,8 @@ class FusedFitStep:
         if live_updater is not self._updater:
             self._release()
             return None
-        mode = mod._optimizer._fused_fit_sig()
+        optimizer = mod._optimizer
+        mode = optimizer._fused_fit_sig()
         if mode is None or not _fused.supported(mode):
             self._release()
             return None
@@ -735,75 +784,6 @@ class FusedFitStep:
             self._release()
             return None
 
-        if self._order is None:
-            self._order = self._param_order()
-            # keys use the param's position in the FULL param_names list
-            # — frozen params keep their index slots in the eager path
-            # (model._update_params / Module._param_index_names), and
-            # the keys must agree for lr/wd mults and state interchange
-            pos = {n: i for i, n in enumerate(group.param_names)}
-            self._ukeys = [self._ukey(pos[n], n) for n in self._order]
-        order, ukeys = self._order, self._ukeys
-        if self._kv is not None:
-            # a preceding eager batch may still have overlapped pushes
-            # applying weights on the kvstore pipeline thread
-            # (kvstore_tpu.engine._OverlapPipeline); land them before
-            # snapshotting weights/state into the donated program
-            self._kv._flush_pending()
-        params = {n: exe.arg_dict[n]._data for n in order}
-        for n in exe._arg_names:
-            if n not in inputs and n not in params:
-                inputs[n] = exe.arg_dict[n]._data   # fixed/no-grad args
-
-        updater, optimizer = self._updater, mod._optimizer
-        # validate loaded states BEFORE any side effects: an abort here
-        # must not have advanced update counts or created state entries
-        for uk in ukeys:
-            st = updater.states.get(uk)
-            if st is not None:
-                leaves, _ = _fused.flatten_state(st)
-                if not all(isinstance(l, NDArray) for l in leaves):
-                    self._release()
-                    return None    # e.g. a host-side custom state blob
-        states_nd, tpls, mp_flags = [], [], []
-        for n, uk in zip(order, ukeys):
-            if uk not in updater.states:
-                updater.states[uk] = optimizer.create_state_multi_precision(
-                    uk, exe.arg_dict[n])
-                updater.states_synced[uk] = True
-            st = updater.states[uk]
-            states_nd.append(st)
-            tpls.append(_fused.state_template(st))
-            # multi-precision is an EXPLICIT static flag (an Adam
-            # (mean, var) pair is structurally ambiguous with an
-            # (inner, weight32) master tuple)
-            mp_flags.append(bool(optimizer.multi_precision)
-                            and _fused.is_low_precision(
-                                exe.arg_dict[n].dtype))
-        lr_vec, wd_vec, extra = optimizer._fused_runtime(ukeys)
-        use_wd = bool(_np.any(wd_vec != 0.0))
-        tpls, mp_flags = tuple(tpls), tuple(mp_flags)
-        if group._mesh is not None:
-            # optimizer-state leaves inherit each param's sharding, so
-            # mp-sharded params carry mp-sharded moments/masters inside
-            # the donated program (no resharding at the jit boundary)
-            from .. import sharding as _sharding
-            for n, st in zip(order, states_nd):
-                w = exe.arg_dict[n]._data
-                for l in _fused.flatten_state(st)[0]:
-                    l._set_data(_sharding.match_param(l._data, w))
-        states = {n: tuple(l._data for l in _fused.flatten_state(st)[0])
-                  for n, st in zip(order, states_nd)}
-        residuals = self._seed_residuals(order, exe) \
-            if self._threshold is not None else {}
-
-        if eval_metric is not self._metric_ref:
-            self._metric_fn, self._msig = _metric_closure(
-                eval_metric, group.label_names, mod._symbol.list_outputs())
-            self._metric_ref = eval_metric
-        metric_fn, msig = self._metric_fn, self._msig
-        from .. import config as _config
-        mirror = _config.backward_do_mirror()
         scaler = self._scaler
         if scaler is not None:
             # a checkpoint restore may have swapped the module's scaler
@@ -811,53 +791,58 @@ class FusedFitStep:
             # programs built against the old object stay valid
             scaler = getattr(mod, "_loss_scaler", None) or scaler
             self._scaler = scaler
-        scaler_sig = scaler.trace_sig() if scaler is not None else None
-        sent_on = _sentinel_enabled()
-        # a loss head's output is deferred (loss_head.py) where no one
-        # reads the outputs step by step: the metric folds inside the
-        # program, or there is none.  A metric that accumulates on the
-        # host reads get_outputs() after every step, so its program
-        # keeps returning them.  (A pod's outputs span processes and
-        # stay as they were: a tail program there would be a collective
-        # that only the reading rank enters.)
-        heads, tail = (), None
-        if (eval_metric is None or metric_fn is not None) \
-                and self._pmesh is None:
-            heads = tuple(p for p in _loss_head.plans(mod._symbol)
-                          if p.label in inputs)
-        at = tuple(p.index for p in heads)
-        sym_cache = _compiled_cache(mod._symbol)
-        graph_fn = sym_cache["graph_fn"]
-        if heads:
-            head_fns = sym_cache.setdefault("fit_heads", {})
-            if at not in head_fns:
-                head_fns[at] = (
-                    _build_graph_fn(mod._symbol,
-                                    also=[p.stem for p in heads]),
-                    _loss_head.tail_program(heads))
-            graph_fn, tail = head_fns[at]
-        cache = sym_cache.setdefault("fit_step", {})
-        # `mode` re-read above: mutating optimizer hyperparams mid-
-        # training switches programs (one retrace), like the eager path
-        key = (tuple(order), self._threshold, mode, tpls, mp_flags,
-               use_wd, msig, mirror, scaler_sig, sent_on, at)
-        fn = cache.get(key)
+        plan, built = self._plan, False
+        if plan is None or not self._plan_holds(plan, group, optimizer, mode,
+                                                eval_metric, scaler):
+            plan = self._plan = self._build_plan(group, optimizer, mode,
+                                                 eval_metric, scaler)
+            if plan is None:
+                self._release()
+                return None    # e.g. a host-side custom state blob
+            built = True
+        order = plan.order
+        if self._kv is not None:
+            # a preceding eager batch may still have overlapped pushes
+            # applying weights on the kvstore pipeline thread
+            # (kvstore_tpu.engine._OverlapPipeline); land them before
+            # snapshotting weights/state into the donated program
+            self._kv._flush_pending()
+        params = {n: w._data for n, w in zip(order, plan.weights)}
+        for n, a in plan.fixed:
+            inputs[n] = a._data                     # fixed/no-grad args
+
+        # `mode` re-read above and held against the plan's: mutating
+        # optimizer hyperparams mid-training switches programs (one
+        # retrace), like the eager path
+        lr_vec, wd_vec, extra = optimizer._fused_runtime(plan.ukeys)
+        use_wd = bool(wd_vec.any())
+        fn = plan.programs.get(use_wd)
         if fn is None:
-            fn = cache[key] = _build_fit_program(
-                graph_fn, tuple(order),
-                self._threshold, mode, tpls, mp_flags, use_wd,
-                metric_fn, mirror, scaler, sentinel=sent_on, heads=heads)
+            fn = plan.programs[use_wd] = self._program(plan, use_wd)
+        if group._mesh is not None:
+            # optimizer-state leaves inherit each param's sharding, so
+            # mp-sharded params carry mp-sharded moments/masters inside
+            # the donated program (no resharding at the jit boundary)
+            from .. import sharding as _sharding
+            for w, leaves in zip(plan.weights, plan.leaves):
+                for l in leaves:
+                    l._set_data(_sharding.match_param(l._data, w._data))
+        states = {n: tuple([l._data for l in leaves])
+                  for n, leaves in zip(order, plan.leaves)}
+        residuals = self._seed_residuals(order, exe) \
+            if self._threshold is not None else {}
 
+        # The carry goes in the way it comes back: the program's own
+        # results from the second step on, and after ``metric.reset()``
+        # the plan's pair of zeros, made once where the program runs
+        # (an eager ``jnp.float32(0.0)`` is a program on the chip).
         macc = ()
-        if metric_fn is not None:
-            macc = (eval_metric._dev_sum
-                    if eval_metric._dev_sum is not None else jnp.float32(0.0),
-                    eval_metric._dev_num
-                    if eval_metric._dev_num is not None else jnp.float32(0.0))
-
+        if plan.metric_fn is not None:
+            macc = plan.zeros if eval_metric._dev_sum is None \
+                else (eval_metric._dev_sum, eval_metric._dev_num)
         scaler_state = scaler.device_state() if scaler is not None else ()
         sent_state = ()
-        if sent_on:
+        if plan.sent_on:
             sent_state = self._sent_state
             if sent_state is None:
                 sent_state = jnp.zeros(8, jnp.float32)
@@ -865,17 +850,20 @@ class FusedFitStep:
         # Everything the program carries comes back COMMITTED to where
         # it ran — under a mesh typed with it (replicated NamedSharding).
         # jax keys the trace on that type and the lowering on the
-        # placement, so fresh state that goes in uncommitted (a new
-        # scalar accumulator, initializer outputs, lazily created
-        # optimizer state) costs a second trace under a mesh and a
-        # second full XLA compile without one.  Hand it over the way it
-        # comes back: the carry is stable from step one, and each
-        # device_put is a no-op for what the previous step returned.
-        macc, scaler_state, sent_state = jax.device_put(
-            (macc, scaler_state, sent_state),
-            group._repl_sharding() if group._mesh is not None
-            else exe._ctx.jax_device)
-        if self.launches == 0:
+        # placement, so fresh state that goes in uncommitted (a restored
+        # scaler, initializer outputs, lazily created optimizer state)
+        # costs a second trace under a mesh and a second full XLA
+        # compile without one.  What is not yet where the program leaves
+        # it is put there; what the last launch returned, and the plan's
+        # zeros, are.
+        left = plan.zeros + self._carry_out
+        carry = (*macc, *scaler_state,
+                 *([sent_state] if plan.sent_on else ()))
+        if not all(any(leaf is known for known in left) for leaf in carry):
+            macc, scaler_state, sent_state = jax.device_put(
+                (macc, scaler_state, sent_state),
+                self._carry_placement(group, exe))
+        if built:
             params, states, residuals, auxs = jax.tree.map(
                 lambda a: jax.device_put(a, a.sharding),
                 (params, states, residuals, auxs))
@@ -894,38 +882,175 @@ class FusedFitStep:
                       for n, v in inputs.items()}
             macc = tuple(self._lift_repl(m) for m in macc)
             scaler_state = tuple(self._lift_repl(s) for s in scaler_state)
-            if sent_on:
+            if plan.sent_on:
                 sent_state = self._lift_repl(sent_state)
 
         seed = exe._next_seed()
         rescale = _np.float32(optimizer.rescale_grad)
         args = (params, states, residuals, macc, scaler_state, sent_state,
                 inputs, auxs, lr_vec, wd_vec, rescale, extra, seed)
-        deferred = (heads, [inputs[p.label] for p in heads], tail) \
-            if heads else None
-        return fn, args, (exe, order, states_nd, scaler, sent_on,
-                          metric_fn is not None, deferred)
+        deferred = (plan.heads, [inputs[p.label] for p in plan.heads],
+                    plan.tail) if plan.heads else None
+        return fn, args, (plan, scaler, deferred)
 
-    def _rebind(self, result, eval_metric, exe, order, states_nd, scaler,
-                sent_on, has_metric, deferred):
+    def _carry_placement(self, group, exe):
+        """Where the program leaves what it carries."""
+        return group._repl_sharding() if group._mesh is not None \
+            else exe._ctx.jax_device
+
+    def _plan_holds(self, plan, group, optimizer, mode, eval_metric, scaler):
+        """Whether everything ``plan`` was derived from is still what it
+        was, by what a step can observe at the cost of a few
+        comparisons.  (The monitor flag and the live updater's identity
+        are held before this: they send the batch to the eager pair.)"""
+        if not (plan.group is group and plan.optimizer is optimizer
+                and plan.mode == mode
+                and plan.multi_precision == bool(optimizer.multi_precision)
+                and plan.metric is eval_metric
+                and plan.sent_on == _sentinel_enabled()
+                and plan.mirror == _config.backward_do_mirror()
+                and plan.scaler_sig == (scaler.trace_sig()
+                                        if scaler is not None else None)):
+            return False
+        # load_optimizer_states, or a state set by hand, replaces the
+        # objects the plan flattened
+        get = self._updater.states.get
+        for uk, st in zip(plan.ukeys, plan.state_objs):
+            if get(uk, _MISSING) is not st:
+                return False
+        return True
+
+    def _build_plan(self, group, optimizer, mode, eval_metric, scaler):
+        """Derive the step's plan; None where the optimizer's state
+        cannot ride the program (the batch then takes the eager pair)."""
+        mod, updater, exe = self._mod, self._updater, group._exec
+        if self._order is None:
+            self._order = self._param_order()
+            # keys use the param's position in the FULL param_names list
+            # — frozen params keep their index slots in the eager path
+            # (model._update_params / Module._param_index_names), and
+            # the keys must agree for lr/wd mults and state interchange
+            pos = {n: i for i, n in enumerate(group.param_names)}
+            self._ukeys = [self._ukey(pos[n], n) for n in self._order]
+        order, ukeys = self._order, self._ukeys
+        # validate loaded states BEFORE any side effects: an abort here
+        # must not have advanced update counts or created state entries
+        flat = {}
+        for uk in ukeys:
+            st = updater.states.get(uk)
+            if st is not None:
+                flat[uk] = _fused.flatten_state(st)
+                if not all(isinstance(l, NDArray) for l in flat[uk][0]):
+                    return None
+        plan = _StepPlan()
+        plan.group, plan.optimizer, plan.mode = group, optimizer, mode
+        plan.multi_precision = bool(optimizer.multi_precision)
+        plan.order, plan.ukeys = tuple(order), ukeys
+        plan.weights = [exe.arg_dict[n] for n in order]
+        # the group fixes which inputs a step places; every other
+        # argument that is no parameter goes in as it stands
+        placed = set(group.data_names) | set(group.label_names)
+        plan.fixed = [(n, exe.arg_dict[n]) for n in exe._arg_names
+                      if n not in placed and n not in order]
+        placed.update(n for n, _ in plan.fixed)
+        plan.state_objs, plan.leaves, tpls, mp_flags = [], [], [], []
+        for uk, w in zip(ukeys, plan.weights):
+            if uk not in updater.states:
+                updater.states[uk] = optimizer.create_state_multi_precision(
+                    uk, w)
+                updater.states_synced[uk] = True
+            st = updater.states[uk]
+            leaves, tpl = flat.get(uk) or _fused.flatten_state(st)
+            plan.state_objs.append(st)
+            plan.leaves.append(leaves)
+            tpls.append(tpl)
+            # multi-precision is an EXPLICIT static flag (an Adam
+            # (mean, var) pair is structurally ambiguous with an
+            # (inner, weight32) master tuple)
+            mp_flags.append(plan.multi_precision
+                            and _fused.is_low_precision(w.dtype))
+        plan.tpls, plan.mp_flags = tuple(tpls), tuple(mp_flags)
+
+        plan.metric = eval_metric
+        plan.metric_fn, plan.msig = _metric_closure(
+            eval_metric, group.label_names, mod._symbol.list_outputs())
+        plan.mirror = _config.backward_do_mirror()
+        plan.scaler_sig = scaler.trace_sig() if scaler is not None else None
+        plan.sent_on = _sentinel_enabled()
+        # a loss head's output is deferred (loss_head.py) where no one
+        # reads the outputs step by step: the metric folds inside the
+        # program, or there is none.  A metric that accumulates on the
+        # host reads get_outputs() after every step, so its program
+        # keeps returning them.  (A pod's outputs span processes and
+        # stay as they were: a tail program there would be a collective
+        # that only the reading rank enters.)
+        plan.heads, plan.tail = (), None
+        if (eval_metric is None or plan.metric_fn is not None) \
+                and self._pmesh is None:
+            plan.heads = tuple(p for p in _loss_head.plans(mod._symbol)
+                               if p.label in placed)
+        at = tuple(p.index for p in plan.heads)
+        sym_cache = _compiled_cache(mod._symbol)
+        plan.graph_fn = sym_cache["graph_fn"]
+        if plan.heads:
+            head_fns = sym_cache.setdefault("fit_heads", {})
+            if at not in head_fns:
+                head_fns[at] = (
+                    _build_graph_fn(mod._symbol,
+                                    also=[p.stem for p in plan.heads]),
+                    _loss_head.tail_program(plan.heads))
+            plan.graph_fn, plan.tail = head_fns[at]
+        plan.programs = {}
+        plan.zeros = ()
+        if plan.metric_fn is not None:
+            zero = _np.float32(0.0)
+            plan.zeros = tuple(self._lift_repl(z) for z in jax.device_put(
+                (zero, zero), self._carry_placement(group, exe)))
+        FIT_PLAN_BUILDS.inc()
+        return plan
+
+    def _program(self, plan, use_wd):
+        """The compiled step for ``plan`` with or without the weight
+        decay's term (the decays are runtime scalars), from the symbol's
+        cache."""
+        cache = _compiled_cache(self._mod._symbol).setdefault("fit_step", {})
+        key = (plan.order, self._threshold, plan.mode, plan.tpls,
+               plan.mp_flags, use_wd, plan.msig, plan.mirror,
+               plan.scaler_sig, plan.sent_on,
+               tuple(p.index for p in plan.heads))
+        fn = cache.get(key)
+        if fn is None:
+            fn = cache[key] = _build_fit_program(
+                plan.graph_fn, plan.order, self._threshold, plan.mode,
+                plan.tpls, plan.mp_flags, use_wd, plan.metric_fn,
+                plan.mirror, self._scaler, sentinel=plan.sent_on,
+                heads=plan.heads)
+        return fn
+
+    def _rebind(self, result, eval_metric, plan, scaler, deferred):
         """Hand every donated buffer its new value."""
         (new_ps, new_ss, new_res, macc, new_scaler, new_sent, new_auxs,
          outs, stems) = result
-        mod = self._mod
-        kv_store = self._kv._store \
-            if (self._kv is not None and mod._update_on_kvstore) else None
-        for n, st in zip(order, states_nd):
-            exe.arg_dict[n]._set_data(new_ps[n])
-            if kv_store is not None and n in kv_store:
-                kv_store[n]._set_data(new_ps[n])
-            for leaf, new_leaf in zip(_fused.flatten_state(st)[0],
-                                      new_ss[n]):
+        mod, exe = self._mod, plan.group._exec
+        for n, w, leaves in zip(plan.order, plan.weights, plan.leaves):
+            w._set_data(new_ps[n])
+            for leaf, new_leaf in zip(leaves, new_ss[n]):
                 leaf._set_data(new_leaf)
+        if self._kv is not None and mod._update_on_kvstore:
+            # the store's own copy of each weight, looked up by name: a
+            # checkpoint restore replaces the store's arrays
+            store = self._kv._store
+            for n in plan.order:
+                twin = store.get(n)
+                if twin is not None:
+                    twin._set_data(new_ps[n])
         if self._threshold is not None:
             self._residuals = dict(new_res)
         if scaler is not None:
             scaler.set_device_state(new_scaler)
-        self._sent_state = new_sent if sent_on else None
+        self._sent_state = new_sent if plan.sent_on else None
+        self._carry_out = (*macc, *new_scaler,
+                           *([new_sent] if plan.sent_on else ()))
         exe._write_auxs(new_auxs)
         if deferred is not None:
             # the heads' values are made when Executor.outputs is read
@@ -946,7 +1071,7 @@ class FusedFitStep:
         exe._pending_train_fwd = False
         exe._train_seed = None
         exe._train_auxs = None
-        if has_metric:
+        if plan.metric_fn is not None:
             eval_metric._dev_sum, eval_metric._dev_num = macc
             eval_metric._device_consumed = True
         mod._params_dirty = True

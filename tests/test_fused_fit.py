@@ -208,6 +208,132 @@ def test_hyperparam_mutation_switches_program():
     assert fused_fit.TRACE_COUNT == traced + 1
 
 
+def _plan_builds():
+    return fused_fit.FIT_PLAN_BUILDS.value
+
+
+def _ev_nothing(mod, m, tmp_path, b):
+    return m
+
+
+def _ev_load_optimizer_states(mod, m, tmp_path, b):
+    fname = str(tmp_path / "mid.states")
+    mod.save_optimizer_states(fname)
+    mod.load_optimizer_states(fname)     # new state objects, same values
+    return m
+
+
+def _ev_swapped_metric(mod, m, tmp_path, b):
+    return metric_mod.Accuracy()
+
+
+def _ev_mutated_hyperparameter(mod, m, tmp_path, b):
+    mod._optimizer.momentum = 0.5
+    return m
+
+
+def _ev_set_updater_and_back(mod, m, tmp_path, b):
+    kv = mod._kvstore
+    live = kv._updater
+    kv.set_updater(lambda key, grad, weight: None)
+    assert not mod._fused_fit.step(b, m)         # the eager pair's batch
+    mod.fit_step(b, m)
+    kv.set_updater(live)
+    return m
+
+
+def _ev_monitored_batches(mod, m, tmp_path, b):
+    mod._monitor_installed = True
+    for _ in range(2):
+        assert not mod._fused_fit.step(b, m)     # falls back per batch
+        mod.fit_step(b, m)
+        mod.update_metric(m, b.label)
+    mod._monitor_installed = False
+    return m
+
+
+@pytest.mark.parametrize("event,builds", [
+    (_ev_nothing, 1),
+    (_ev_load_optimizer_states, 2),
+    (_ev_swapped_metric, 2),
+    (_ev_mutated_hyperparameter, 2),
+    (_ev_set_updater_and_back, 2),
+    (_ev_monitored_batches, 2),
+], ids=lambda v: v.__name__[4:] if callable(v) else None)
+def test_step_plan_holds_and_breaks(event, builds, tmp_path):
+    """The step plan (docs/TRAINING.md): derived once over steady steps
+    with a ``metric.reset()`` between each, derived again after what
+    replaces something it rests on — and a run that keeps its plan ends
+    bit for bit where a run that derives everything every step ends."""
+    X, y = _data()
+    batches = [mx.io.DataBatch(data=[nd.array(X[i * 16:(i + 1) * 16])],
+                               label=[nd.array(y[i * 16:(i + 1) * 16])])
+               for i in range(6)]
+
+    def run(keep_plan):
+        mod = _make_mod(True, kvstore="device", compress=0.005)
+        fused = mod._get_fused_fit()
+        m, values, b0 = metric_mod.Accuracy(), [], _plan_builds()
+        for i, b in enumerate(batches):
+            if i == 3:
+                m = event(mod, m, tmp_path, b)
+            if not keep_plan:
+                fused._plan = None       # derive everything, as before
+            assert mod.fit_step(b, m)
+            mod.update_metric(m, b.label)
+            values.append(m.get()[1])
+            m.reset()
+        assert fused.launches == 6
+        return mod.get_params()[0], values, _plan_builds() - b0
+
+    params, values, built = run(keep_plan=True)
+    assert built == builds
+    ref_params, ref_values, ref_built = run(keep_plan=False)
+    assert ref_built >= 6
+    assert values == ref_values
+    for k in params:
+        np.testing.assert_array_equal(params[k].asnumpy(),
+                                      ref_params[k].asnumpy(), err_msg=k)
+
+
+def test_no_eager_program_after_metric_reset(monkeypatch):
+    """From the second step on a fused step launches ONE program, also
+    right after ``metric.reset()``: the accumulator's zeros are the
+    plan's, not two eager ``jnp.float32(0.0)`` (each a program of its
+    own on the chip)."""
+    import jax.numpy as jnp
+    from jax._src import dispatch
+    eager = []
+    real = dispatch.xla_primitive_callable
+
+    def counting(prim, **params):
+        eager.append(prim.name)
+        return real(prim, **params)
+
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", counting)
+    jnp.float32(0.0)
+    if not eager:
+        pytest.skip("this jax does not route eager primitives through "
+                    "dispatch.xla_primitive_callable")
+    mod = _make_mod(True, kvstore="device")
+    m = metric_mod.Accuracy()
+    X, y = _data()
+    b = mx.io.DataBatch(data=[nd.array(X[:16])], label=[nd.array(y[:16])])
+    assert mod.fit_step(b, m)                    # compile + warm
+    for _ in range(4):
+        m.get()
+        m.reset()
+        assert m._dev_sum is None
+        del eager[:]
+        d0 = profiler.DEVICE_DISPATCHES.value
+        assert mod.fit_step(b, m)
+        assert eager == [], "eager programs inside a fused step: %s" % eager
+        assert profiler.DEVICE_DISPATCHES.value - d0 == 1
+        mod.update_metric(m, b.label)
+    assert m.get()[1] == pytest.approx(m.sum_metric + float(m._dev_sum)
+                                       / float(m._dev_num))
+
+
 def test_monitor_falls_back_per_batch():
     """An installed monitor routes batches to the eager (tappable) path
     without losing 2-bit residual state: fused→eager→fused matches the

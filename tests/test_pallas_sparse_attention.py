@@ -233,6 +233,10 @@ def test_the_choice_is_counted_and_has_no_knob(monkeypatch):
     assert dict(os.environ) == environ
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # a one-device program: an earlier test's multi-context bind in this
+    # process leaves its 'dp' mesh in the registry
+    from mxnet_tpu.parallel import mesh as mesh_mod
+    monkeypatch.setitem(mesh_mod._CURRENT, "mesh", None)
     cell = (512, 512, 16384)
     assert sa._cores_impl(*_cell_shapes(), _scorer_shape(), *cell) \
         == "compiled"
