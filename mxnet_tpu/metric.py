@@ -13,6 +13,7 @@ the metric layer performs.
 from __future__ import annotations
 
 import math
+from time import perf_counter_ns as _now_ns
 
 import numpy as _np
 
@@ -189,11 +190,25 @@ class EvalMetric:
         if self._dev_sum is None:
             return self.sum_metric, self.num_inst
         HOST_SYNCS.increment()
-        # the wait for the step and the two scalar transfers, on the
-        # profiler's clock (docs/OBSERVABILITY.md)
+        # on the profiler's clock (docs/OBSERVABILITY.md): the wait for
+        # the step (the device is busy) apart from the two scalar
+        # transfers (the device waits).  The same three boundaries go
+        # into the open fit step's record (tracing.steps()): the wait of
+        # its first readback, the end of its last
+        rec = _tracing.open_step()
         with _tracing.span("metric.readback"):
-            return (self.sum_metric + float(self._dev_sum),
-                    self.num_inst + float(self._dev_num))
+            if rec is not None and rec.wait0 is None:
+                rec.wait0 = _now_ns()
+            with _tracing.span("metric.wait"):
+                self._dev_sum.block_until_ready()
+            if rec is not None and rec.wait1 is None:
+                rec.wait1 = _now_ns()
+            with _tracing.span("metric.transfer"):
+                totals = (self.sum_metric + float(self._dev_sum),
+                          self.num_inst + float(self._dev_num))
+            if rec is not None:
+                rec.transfer1 = _now_ns()
+            return totals
 
     def get(self):
         total, num = self._totals()

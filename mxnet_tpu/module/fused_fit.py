@@ -70,6 +70,7 @@ from __future__ import annotations
 
 import os
 import weakref
+from time import perf_counter_ns as _now_ns
 
 import numpy as _np
 import jax
@@ -703,8 +704,12 @@ class FusedFitStep:
         time (docs/OBSERVABILITY.md): ``fit.prepare`` (eligibility,
         placing inputs, gathering the program's arguments),
         ``fit.fused_dispatch`` (the jit call) and ``fit.rebind`` (every
-        donated buffer handed its new value)."""
+        donated buffer handed its new value).  The same boundaries are
+        stamped on the host's clock into the step timeline
+        (``tracing.steps()``), whose record of a step begins here and
+        ends at the next entry."""
         tracing = _telemetry.tracing
+        rec = tracing.step_entry(self)
         with tracing.span("fit.prepare"):
             prep = self._prepare(data_batch, eval_metric)
         if prep is None:
@@ -718,7 +723,7 @@ class FusedFitStep:
         try:
             with _dispatch_span("fit.fused_dispatch",
                                 "Module::fused_fit_step"):
-                result = _SITE.timed(fn, *args)
+                result = _SITE.timed(fn, *args, timeline=rec)
         except Exception:
             # a runtime failure after donation consumes the donated
             # buffers — drop our residual refs so a later spill doesn't
@@ -732,6 +737,9 @@ class FusedFitStep:
             self._mem_tracker.end()
         with tracing.span("fit.rebind"):
             self._rebind(result, eval_metric, *carried)
+        if rec is not None:
+            rec.rebind1 = _now_ns()
+            rec.fused = True
         self.launches += 1
         return True
 

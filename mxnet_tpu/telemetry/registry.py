@@ -92,8 +92,10 @@ class RetraceSite:
 
     * call :meth:`note` INSIDE the traced body (trace-time host code);
     * dispatch through :meth:`timed` — wall time goes to
-      ``dispatch_hist`` (when given), and calls during which THIS
-      thread (re)traced also observe into ``compile_hist``
+      ``dispatch_hist`` (when given), its two clock reads to
+      ``timeline`` (a ``tracing.StepRecord``, when given), and calls
+      during which THIS thread (re)traced also observe into
+      ``compile_hist``
       (trace + compile + first run in one sample, exception or not;
       ``program_build_seconds{site, phase}`` has the same call's
       trace, lowering and load apart, from jax's own events).  For the
@@ -117,20 +119,25 @@ class RetraceSite:
         self.counter.inc()
         self._tally.count += 1
 
-    def timed(self, fn, *args, dispatch_hist=None):
+    def timed(self, fn, *args, dispatch_hist=None, timeline=None):
         import time
         r0 = self._tally.count
         outer = BUILD_SITE.name
         if self.site is not None:
             # analyze: ok(threads) a threading.local: every thread writes its own
             BUILD_SITE.name = self.site
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         try:
             return fn(*args)
         finally:
             # analyze: ok(threads) a threading.local: every thread writes its own
             BUILD_SITE.name = outer
-            dt_ms = (time.perf_counter() - t0) * 1e3
+            t1 = time.perf_counter_ns()
+            if timeline is not None:
+                # the step timeline's record of this call's step
+                # (telemetry/tracing.py): the same two clock reads
+                timeline.dispatch0, timeline.dispatch1 = t0, t1
+            dt_ms = (t1 - t0) / 1e6
             if dispatch_hist is not None:
                 dispatch_hist.observe(dt_ms)
             if self._compile_hist is not None and self._tally.count > r0:
@@ -290,6 +297,13 @@ class Counter(_Metric):
     @property
     def value(self):
         return self._value
+
+    @property
+    def total(self):
+        """This counter's value plus its labeled children's (a plain
+        read of each: a count, not a snapshot)."""
+        return self._value + sum(
+            [c._value for c in tuple(self._children.values())])
 
 
 class Gauge(_Metric):

@@ -14,6 +14,14 @@ Design rules (the same overhead contract as the registry):
   ``traceparent``) is OFF by default; every instrumentation site goes
   through :func:`span`/:func:`start_span`, which cost one module-global
   check when disabled.  No clock read, no lock, nothing recorded.
+* **The fit step alone reads the clock always.**  A fused fit step
+  stamps its boundaries into the step timeline (below) whether or not
+  recording is on: eight ``perf_counter_ns`` reads, one
+  ``thread_time_ns``, one ``gc.get_stats`` and one append a step,
+  7-8 us on the sandbox's CPU in a stub loop (docs/OBSERVABILITY.md,
+  "The step timeline", has the measurements).  ``telemetry.disable()`` brings it
+  to one attribute check a site.  Every other site of the steady path
+  keeps the rule above.
 * **A set-up span keeps its seconds anyway.**  A site that a process
   walks once (``module.bind``, ``module.init_params``,
   ``module.init_optimizer``, ``fit.build``) passes a counter's child
@@ -21,7 +29,9 @@ Design rules (the same overhead contract as the registry):
   recording on or off (``setup_seconds{phase}``), and while the span is
   open the thread's program builds are laid to its name
   (``program_build_seconds{site}``).  Sites that pass none, every site
-  of the steady path, take the path above.
+  of the steady path, take the path above.  Such a span mints no ids
+  and reads no wall clock while recording is off: the ring never sees
+  it.
 * **Always on the profiler's clock.**  The context form :func:`span`
   enters a ``jax.profiler.TraceAnnotation`` of the span's name whether
   or not recording is enabled, so any ``jax.profiler`` trace (a
@@ -55,20 +65,38 @@ flight recorder appends them to every dump (``{"span": {...}}`` lines),
 and ``profiler.dump()`` renders the ring, once, as chrome-trace ``X``
 events with ``trace_id``/``span_id``/``parent_id`` args
 (:func:`chrome_events`).
+
+**The step timeline.**  Beside the spans, the program keeps one record
+a fit step on the host's monotonic clock (:func:`steps`): when the
+step passed each of its boundaries, the thread's CPU time, full
+garbage collections and program builds over it.  A step runs from one
+entry of ``FusedFitStep.step`` to the next, the caller's loop
+included, which is the interval a throughput inverts.  At each entry
+the step that just ended is held against the median of the last 64
+(:func:`_judge`): a slow step is counted by the phase that holds most
+of its excess (``fit_slow_steps{phase}``) and logged in one line.
 """
 from __future__ import annotations
 
+import gc
+import itertools
+import logging
 import os
 import threading
 import time
 from collections import deque
 
+from . import registry as _registry
 from .registry import BUILD_SITE, REGISTRY
 
 __all__ = ["Span", "SpanContext", "enable", "disable", "enabled",
            "span", "start_span", "current", "traceparent", "extract",
            "spans", "drain_spans", "clear", "chrome_events",
-           "find_trace", "SPAN_CAPACITY", "SETUP_SECONDS"]
+           "find_trace", "SPAN_CAPACITY", "SETUP_SECONDS",
+           "StepRecord", "step_entry", "open_step", "steps", "phases",
+           "clear_steps", "STEP_CAPACITY", "STEP_STAMPS", "STEP_PHASES"]
+
+log = logging.getLogger(__name__)
 
 SPAN_CAPACITY = int(os.environ.get("MXNET_TRACE_CAPACITY", "4096") or 4096)
 
@@ -166,9 +194,12 @@ class Span:
     def __init__(self, name, trace_id, parent_id, attrs):
         self.name = name
         self.trace_id = trace_id
-        self.span_id = _new_span_id()
+        # ids and the wall clock are the ring's: a set-up span while
+        # recording is off (trace_id None) feeds its counter alone
+        recorded = trace_id is not None
+        self.span_id = _new_span_id() if recorded else None
         self.parent_id = parent_id
-        self.t0 = time.time()
+        self.t0 = time.time() if recorded else None
         self.t_mono = time.perf_counter()
         self.attrs = attrs
         self._ended = False
@@ -342,6 +373,241 @@ def span(name, parent="current", seconds_to=None, **attrs):
     sp._seconds_to = seconds_to
     sp._ann = ann
     return sp
+
+
+# ----------------------------------------------------------------------
+# the step timeline: one record a fit step, on the host's clock
+# ----------------------------------------------------------------------
+STEP_CAPACITY = 4096
+# a step's boundaries in the order it passes them (perf_counter_ns;
+# None for one it did not pass)
+STEP_STAMPS = ("entry", "dispatch0", "dispatch1", "rebind1", "wait0",
+               "wait1", "transfer1", "next_entry")
+# what the stamps cut a step's interval into; ``outside`` is the rest:
+# ``update_metric``, ``metric.reset`` and the caller's loop
+_PHASE_STAMPS = (("prepare", "entry", "dispatch0"),
+                 ("dispatch", "dispatch0", "dispatch1"),
+                 ("rebind", "dispatch1", "rebind1"),
+                 ("wait", "wait0", "wait1"),
+                 ("transfer", "wait1", "transfer1"))
+STEP_PHASES = tuple(p for p, _, _ in _PHASE_STAMPS) + ("outside",)
+# a slow step: past _SLOW_FACTOR medians of the last _RECENT steps,
+# once _JUDGE_AFTER are complete; the median is refreshed every
+# _REFRESH steps at most
+_SLOW_FACTOR, _RECENT, _JUDGE_AFTER, _REFRESH = 2, 64, 8, 16
+
+SLOW_STEPS = REGISTRY.counter(
+    "fit_slow_steps", "fit steps that took over twice the median of "
+    "the last 64, labeled by `phase`: the one that holds most of the "
+    "excess over its own median (prepare, dispatch, rebind, wait, "
+    "transfer, outside)", unit="steps")
+SLOW_STEP_SECONDS = REGISTRY.counter(
+    "fit_slow_step_seconds", "seconds the slow fit steps took over "
+    "the median step", unit="s")
+
+_now = time.perf_counter_ns
+_steps = deque(maxlen=STEP_CAPACITY)
+_slow_seen = itertools.count(1)     # the process's slow steps, for the log
+
+
+class StepRecord:
+    """One fit step, from one entry of ``FusedFitStep.step`` to the
+    next.  The sites write the stamps they pass; the rest stay None."""
+
+    dispatch0 = dispatch1 = rebind1 = None
+    wait0 = wait1 = transfer1 = next_entry = None
+    cpu_ns = gc2 = builds = None
+    fused = False
+
+    def __init__(self, step, entry, cpu, gc2, builds):
+        self.step = step
+        self.entry = entry
+        self._at_entry = (cpu, gc2, builds)
+
+    def _close(self, now, cpu, gc2, builds):
+        cpu0, gc0, builds0 = self._at_entry
+        self.next_entry = now
+        self.cpu_ns = cpu - cpu0
+        self.gc2 = gc2 - gc0
+        self.builds = builds - builds0
+
+    __getitem__ = object.__getattribute__       # a stamp by its name
+
+    def as_dict(self):
+        out = {"step": self.step, "fused": self.fused,
+               "open": self.next_entry is None,
+               "cpu_ns": self.cpu_ns, "gc2": self.gc2,
+               "builds": self.builds}
+        for name in STEP_STAMPS:
+            out[name] = self[name]
+        return out
+
+
+class _History:
+    """What one thread's steps are judged against, and its open step."""
+
+    __slots__ = ("open", "owner", "count", "done", "recent", "median",
+                 "refreshed", "builds_metric")
+
+    def __init__(self):
+        self.open = None
+        self.builds_metric = None
+        self.restart(None)
+
+    def restart(self, owner):
+        """Another stepping object (its ``id``): a fresh history."""
+        self.owner = owner
+        self.count = 0              # steps entered
+        self.done = 0               # steps ended and judged
+        self.recent = deque(maxlen=_RECENT)     # the last of those
+        self.median = None          # of their intervals, ns
+        self.refreshed = 0          # ``done`` when it was taken
+
+
+class _Timeline(threading.local):
+    """Each thread's :class:`_History` (one attribute: the entry pays
+    one thread-local read)."""
+
+    def __init__(self):
+        self.history = _History()
+
+
+_timeline = _Timeline()
+
+
+def _marks(hist):
+    """What is differenced at the entries: the thread's CPU ns, full
+    garbage collections, program builds."""
+    m = hist.builds_metric
+    if m is None:
+        # aot/store.py registers it; a process that never built reads 0
+        m = hist.builds_metric = REGISTRY.get("program_builds")
+    return (time.thread_time_ns(), gc.get_stats()[2]["collections"],
+            0 if m is None else m.total)
+
+
+def step_entry(owner=None):
+    """A fit step begins: close the thread's open record at this
+    instant (and judge it), open the next and hand it to the caller,
+    who stamps the boundaries it passes.  None under
+    ``telemetry.disable()``.  ``owner`` is the stepping object: another
+    one starts a fresh history (its steps are not the last one's)."""
+    if not _registry._ENABLED:
+        return None
+    hist = _timeline.history
+    cpu, gc2, builds = _marks(hist)
+    now = _now()        # last: the entry is where ``fit.prepare`` opens
+    same = id(owner) == hist.owner
+    prev = hist.open
+    if prev is not None:
+        prev._close(now, cpu, gc2, builds)
+        if same:
+            _judge(hist, prev, now - prev.entry)
+    if not same:
+        hist.restart(id(owner))
+    hist.count += 1
+    rec = hist.open = StepRecord(hist.count, now, cpu, gc2, builds)
+    _steps.append(rec)      # no lock: atomic, and readers copy the deque
+    return rec
+
+
+def open_step():
+    """The calling thread's open step record, for a site inside the
+    step to stamp (``EvalMetric._totals``); None when there is none."""
+    if not _registry._ENABLED:
+        return None
+    return _timeline.history.open
+
+
+def _median(values):
+    return sorted(values)[len(values) // 2]
+
+
+def _judge(hist, rec, interval):
+    """Hold the step that just ended against the thread's median."""
+    if hist.median is not None and interval > _SLOW_FACTOR * hist.median:
+        _slow_step(hist, rec, interval)
+    hist.recent.append(rec)
+    hist.done += 1
+    if hist.done >= _JUDGE_AFTER and (
+            hist.median is None or hist.done - hist.refreshed >= _REFRESH):
+        hist.median = _median([r.next_entry - r.entry for r in hist.recent])
+        hist.refreshed = hist.done
+
+
+def _slow_step(hist, rec, interval):
+    """Count a slow step under the phase that holds most of its excess
+    over that phase's own median, note it in the flight recorder and
+    log it: the first five of a process, then every hundredth."""
+    mine = phases(rec)
+    past = [phases(r) for r in hist.recent]
+    over = {p: mine[p] - _median([q[p] for q in past]) for p in STEP_PHASES}
+    phase = max(STEP_PHASES, key=over.get)
+    SLOW_STEPS.labels(phase=phase).inc()
+    SLOW_STEP_SECONDS.inc((interval - hist.median) / 1e9)
+    seconds = {p: mine[p] / 1e9 for p in STEP_PHASES}
+    cpu_s = rec.cpu_ns / 1e9
+    from .flight import RECORDER
+    RECORDER.note("slow_step", fit_step=rec.step, phase=phase,
+                  seconds=interval / 1e9, median_s=hist.median / 1e9,
+                  phases=seconds, cpu_s=cpu_s, gc2=rec.gc2,
+                  builds=rec.builds)
+    nth = next(_slow_seen)
+    if nth > 5 and nth % 100:
+        return
+    rest = ", ".join("%s %.3g" % (p, seconds[p])
+                     for p in STEP_PHASES if p != phase)
+    log.warning("fit step %d took %.3g s (median %.3g): %s %.3g s, %s; "
+                "thread cpu %.3g s; gc2 %d; builds %d", rec.step,
+                interval / 1e9, hist.median / 1e9, phase, seconds[phase],
+                rest, cpu_s, rec.gc2, rec.builds)
+
+
+def phases(rec):
+    """``{phase: ns}`` of one of :func:`steps`' records (or a closed
+    :class:`StepRecord`), in :data:`STEP_PHASES`: the five stretches
+    between the stamps the step passed (0 for one it did not) and
+    ``outside``, the rest of its interval, so that they sum to
+    ``next_entry - entry``."""
+    out = {}
+    for phase, a, b in _PHASE_STAMPS:
+        t0, t1 = rec[a], rec[b]
+        out[phase] = t1 - t0 if t0 is not None and t1 is not None else 0
+    out["outside"] = rec["next_entry"] - rec["entry"] - sum(out.values())
+    return out
+
+
+def steps(last=None):
+    """The step timeline, newest last (the ``last`` newest when given):
+    one dict a step with its ordinal on its thread (``step``),
+    ``fused``, the :data:`STEP_STAMPS` and, over its interval, the
+    thread's CPU ns (``cpu_ns``), full garbage collections (``gc2``)
+    and program builds (``builds``).  A step that no entry has ended
+    yet says ``open`` and is closed at the time of the call (its
+    ``cpu_ns`` is None when another thread asks)."""
+    recs = list(_steps)
+    if last is not None:
+        recs = recs[-last:] if last > 0 else []
+    hist = _timeline.history
+    now = _now()
+    cpu, gc2, builds = _marks(hist)
+    out = []
+    for rec in recs:
+        row = rec.as_dict()
+        if row["open"]:
+            cpu0, gc0, builds0 = rec._at_entry
+            row.update(next_entry=now,
+                       cpu_ns=cpu - cpu0 if rec is hist.open else None,
+                       gc2=gc2 - gc0, builds=builds - builds0)
+        out.append(row)
+    return out
+
+
+def clear_steps():
+    """Tests/teardown: empty the timeline and the thread's history."""
+    _steps.clear()
+    # analyze: ok(threads) a threading.local: every thread writes its own
+    _timeline.history = _History()
 
 
 # ----------------------------------------------------------------------
